@@ -2,7 +2,7 @@
 # of native code — the TPU compute path is JAX/XLA compiled at runtime.
 PY ?= python
 
-.PHONY: help test test-fast test-policy lint lint-invariants lint-changed fmt smoke bench bench-smoke bench-proxy-smoke chaos-smoke fleet-smoke fleet-trace-smoke kv-economy-smoke econ-smoke trajectory dashboards-validate helm-lint airgap clean
+.PHONY: help test test-fast test-policy lint lint-invariants lint-changed fmt smoke chip-smoke bench-smoke chaos-smoke fleet-smoke fleet-trace-smoke kv-economy-smoke econ-smoke trajectory dashboards-validate helm-lint airgap clean
 
 help:
 	@grep -E '^[a-z-]+:' Makefile | sed 's/:.*//' | sort | uniq
@@ -76,8 +76,8 @@ smoke:  ## full pipeline on the CPU-faked mesh, no hardware
 	  $(PY) -m kserve_vllm_mini_tpu bench --self-serve --model llama-tiny \
 	  --requests 20 --concurrency 4 --max-tokens 8
 
-bench:  ## driver benchmark (one JSON line) on the attached accelerator
-	$(PY) bench.py
+chip-smoke:  ## the serving path on the attached TPU (fails without one)
+	$(PY) chip_smoke.py
 
 # asserts the decode-pipeline counters (docs/DECODE_PIPELINE.md) land in
 # results.json via the real stage chain — the same tier-1 gate CI runs.
@@ -134,18 +134,7 @@ kv-economy-smoke:  ## zero-copy handoff + prefix migration + host tier, no TPU
 econ-smoke:  ## live $/1K-tok + Wh/1K-tok rail, events, cost-aware policy
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_economics.py -q -m "not slow"
 
-# the never-dark acceptance gate (docs/PROFILING.md): with no TPU,
-# `python bench.py` must exit 0 with a schema-valid `proxy` block
-# (validate_proxy), a config over mocked HBM headroom must DOWNSHIFT
-# (labeled) instead of RESOURCE_EXHAUSTing, and the trajectory must
-# render the round into its report section. Runs the real bench.py
-# children end-to-end on the forced 8-device host platform.
-bench-proxy-smoke:  ## full CPU-mesh proxy tier end-to-end, no TPU
-	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-	  $(PY) -m pytest tests/test_bench_proxy.py tests/test_profiling.py \
-	  tests/test_trajectory.py -q
-
-trajectory:  ## perf trend table over the committed BENCH_*.json rounds
+trajectory:  ## perf trend table over BENCH_*.json rounds in the working directory (none are committed)
 	$(PY) -m kserve_vllm_mini_tpu trajectory --glob 'BENCH_*.json'
 
 dashboards-validate:  ## dashboard JSON structure + panel/query checks

@@ -239,7 +239,7 @@ def register(parser: argparse.ArgumentParser) -> None:
                         help="Work units (output tokens) per synthetic request")
     parser.add_argument("--rate-per-replica", type=float, default=2000.0,
                         help="Serving rate per replica, work-units/s "
-                             "(tokens/s/chip; see docs/PERFORMANCE.md)")
+                             "(tokens/s/chip; measure it — PERF.md)")
     parser.add_argument("--provision-delay", type=float, default=180.0,
                         help="Seconds before a scale-up becomes capacity "
                              "(TPU pools provision in minutes)")

@@ -449,8 +449,8 @@ def register(parser: argparse.ArgumentParser) -> None:
                         help="Live $/1K-token budget for the "
                              "cost_burn_exceeded / replica_unprofitable "
                              "monitor events (docs/ECONOMICS.md; also "
-                             "KVMINI_BENCH_COST_BUDGET and the profile "
-                             "key cost_budget_usd_per_1k_tok)")
+                             "the profile key "
+                             "cost_budget_usd_per_1k_tok)")
 
 
 def run(args: argparse.Namespace) -> int:

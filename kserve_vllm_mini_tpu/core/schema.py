@@ -167,11 +167,6 @@ class Results:
     # runs or scraped from /metrics (analysis/telemetry.py
     # COMPILE_METRIC_KEYS); absent for external engines
     compile_stats: Optional[dict[str, Any]] = None
-    # proxy-tier block (docs/PROFILING.md): the CPU-mesh fallback bench's
-    # cost-model metrics, shape gated by validate_proxy — present only
-    # for rounds that ran without a device; NEVER carries device
-    # throughput claims (series is always "proxy")
-    proxy: Optional[dict[str, Any]] = None
 
     # KV-cache & HBM observability block (docs/TROUBLESHOOTING.md "HBM
     # pressure & KV thrash"): prefix-cache attribution (hit-depth
@@ -463,80 +458,6 @@ TIMELINE_SAMPLE_SCHEMA: dict[str, Any] = {
 
 def _num(v: Any) -> bool:
     return isinstance(v, (int, float)) and not isinstance(v, bool)
-
-
-# -- proxy block schema -------------------------------------------------------
-#
-# The CPU-mesh proxy bench tier's output (docs/PROFILING.md): the block
-# bench.py emits in its artifact's `detail.proxy` when the TPU probe
-# failed, and the `proxy` results.json field. Hand-rolled validator like
-# the others — no jsonschema dependency in the harness layers. `make
-# bench-proxy-smoke` gates on it.
-
-PROXY_JSON_SCHEMA: dict[str, Any] = {
-    "$schema": "http://json-schema.org/draft-07/schema#",
-    "title": "kvmini-tpu proxy bench block (CPU-mesh fallback tier)",
-    "type": "object",
-    "required": ["series", "flops", "bytes_accessed", "compile_wall_s",
-                 "peak_bytes", "step_count_ratio"],
-    "properties": {
-        "series": {"const": "proxy"},
-        "platform": {"type": "string"},
-        "n_devices": {"type": "integer", "minimum": 1},
-        "model": {"type": "string"},
-        "exec_model": {"type": "string"},
-        # quantization labels: a proxy round's compile drift is only
-        # comparable against rounds of the same quant/quant_mode/kv_quant
-        "quant": {"type": "string"},
-        "quant_mode": {"enum": ["dequant", "w8a8"]},
-        "kv_quant": {"type": "boolean"},
-        "flops": {"type": "number", "minimum": 0},
-        "bytes_accessed": {"type": "number", "minimum": 0},
-        "compile_wall_s": {"type": "number", "exclusiveMinimum": 0},
-        "peak_bytes": {"type": "number", "minimum": 0},
-        "step_count_ratio": {"type": "number", "exclusiveMinimum": 0},
-        "compile_stats": {"type": "object"},
-        "analytic_bytes": {"type": "object"},
-        "exec": {"type": "object"},
-        "hbm_headroom": {"type": "object"},
-    },
-}
-
-
-def validate_proxy(doc: Any) -> list[str]:
-    """Validate a proxy block against PROXY_JSON_SCHEMA's contract.
-    Returns violations; empty = valid. The hard rule: ``series`` must be
-    the literal "proxy" — a proxy number that could be mistaken for a
-    device measurement is worse than no number."""
-    errs: list[str] = []
-    if not isinstance(doc, dict):
-        return ["proxy block is not an object"]
-    if doc.get("series") != "proxy":
-        errs.append(
-            f"series must be the literal 'proxy' (got {doc.get('series')!r})"
-        )
-    for key in ("flops", "bytes_accessed", "peak_bytes"):
-        v = doc.get(key)
-        if not _num(v) or v < 0:
-            errs.append(f"{key} missing or not a non-negative number")
-    for key in ("compile_wall_s", "step_count_ratio"):
-        v = doc.get(key)
-        if not _num(v) or v <= 0:
-            errs.append(f"{key} missing or not a positive number")
-    if "n_devices" in doc and (
-        not isinstance(doc["n_devices"], int)
-        or isinstance(doc["n_devices"], bool)
-        or doc["n_devices"] < 1
-    ):
-        errs.append("n_devices is not a positive integer")
-    for key in ("compile_stats", "analytic_bytes", "exec", "hbm_headroom"):
-        if key in doc and not isinstance(doc[key], dict):
-            errs.append(f"{key} is not an object")
-    if "quant_mode" in doc and doc["quant_mode"] not in ("dequant", "w8a8"):
-        errs.append(
-            f"quant_mode must be 'dequant' or 'w8a8' (got {doc['quant_mode']!r})"
-        )
-    return errs
 
 
 # -- kv_cache block schema ----------------------------------------------------

@@ -46,8 +46,7 @@ class Pricing:
 
 # top-level sections a pricing sheet may carry; anything else is almost
 # certainly a typo ("tpu_chip_hourli") that would silently price every
-# run at the fallback default — fail LOUD at the load, the same
-# convention bench.py's _ENV_KNOBS validators follow
+# run at the fallback default — fail LOUD at the load
 _KNOWN_TOP_KEYS = ("tpu_chip_hourly", "host", "calculation", "energy")
 
 

@@ -83,9 +83,11 @@ def serve_replica_cmd(
     env_overrides: Optional[dict[str, str]] = None,
 ) -> Callable[[int, str], tuple[list[str], dict[str, str]]]:
     """The default replica factory: one ``kvmini-tpu serve`` per port,
-    flags appended verbatim. Returns (argv, env) per replica so tests
-    and the bench fleet row can force e.g. ``JAX_PLATFORMS=cpu`` without
-    touching the parent's environment."""
+    flags appended verbatim. Returns (argv, env) per replica so a caller
+    can force e.g. ``JAX_PLATFORMS=cpu`` without touching the parent's
+    environment. Each child inherits the parent's view of the devices: on
+    a chip machine every replica would claim the same chip (docs/FLEET.md
+    "Replicas on chips")."""
 
     def cmd(port: int, rid: str) -> tuple[list[str], dict[str, str]]:
         argv = [

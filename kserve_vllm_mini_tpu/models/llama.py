@@ -36,23 +36,6 @@ Params = dict[str, Any]
 # "k_s" is the static flag that selects the quantized cache path)
 KVCache = dict[str, jnp.ndarray]
 
-# Test hook: True/False forces the Pallas paged-decode kernel on/off
-# regardless of backend (None = auto: kernel on TPU, gather oracle
-# elsewhere). See run_cached_layers' use_paged_kernel.
-_FORCE_PAGED_KERNEL: Optional[bool] = None
-
-# Same hook for the DENSE int8-KV decode kernel (ops/paged_attention.py
-# dense_decode_attention): None = auto (kernel on TPU, the eager
-# dequantize-on-read oracle elsewhere). See use_dense_kernel.
-_FORCE_DENSE_KERNEL: Optional[bool] = None
-
-# Same hook for the int8-KV CACHED-PREFILL kernel (ops/flash_attention.py
-# cached_prefill_attention — continuation chunks attending the cache):
-# None = auto (kernel on TPU, the eager dequantize-on-read oracle
-# elsewhere). See use_chunk_kernel.
-_FORCE_CHUNK_KERNEL: Optional[bool] = None
-
-
 def _stacked_weight_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     """Per-layer shape of every stacked transformer matmul weight (last two
     axes are [in, out]; MoE expert weights carry a leading expert axis), in a
@@ -174,9 +157,8 @@ def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
 def init_params_quantized(rng: jax.Array, cfg: ModelConfig, bits: int = 8) -> Params:
     """Random init straight into int8 (or int4) leaves, one layer at a time.
 
-    Fixes the round-2 flagship failure (VERDICT.md Weak #1): materializing
-    the 8B bf16 tree first needs ~16 GB — the whole v5e HBM — before
-    quantization can even start. Here each stacked matmul weight is drawn
+    Materializing the 8B bf16 tree first needs ~16 GB — the whole v5e HBM —
+    before quantization can even start. Here each stacked matmul weight is drawn
     per layer inside a ``lax.scan`` and quantized immediately, so the peak
     transient is ONE layer's f32 weight (~1 GB for 8B) on top of the int8
     output. Equal to ``quantize_params(init_params(rng, cfg))`` to within
@@ -522,10 +504,12 @@ def run_cached_layers(
                                  # rides the layer scan like the base
                                  # weights
     lora_ids: Optional[jnp.ndarray] = None,  # [B] adapter index per row
-    paged_kernel_ok: bool = True,  # False under GSPMD-sharded pools: a
-                                 # pallas_call inside an auto-partitioned
-                                 # jit would see global shapes; the gather
-                                 # path partitions per kv head instead
+    kernels: str = "auto",       # which attention calls take a Pallas kernel
+                                 # (ops/flash_attention.py pallas_interpret):
+                                 # "auto" = compiled kernels on a TPU, jnp
+                                 # elsewhere; "off" = jnp only (GSPMD-
+                                 # partitioned jits — Mosaic calls cannot be
+                                 # auto-partitioned); "interpret" = tests
 ) -> tuple[jnp.ndarray, KVCache]:
     """The cached transformer stack: scan over stacked layers, writing this
     block's K/V at ``cache_offsets`` and attending with positional masking
@@ -557,71 +541,48 @@ def run_cached_layers(
         s = block_table.shape[1] * blk        # flattened key axis (abs order)
     else:
         s = kv_cache["k"].shape[3]
-    # Pallas paged decode kernel: table-driven block DMA instead of the
-    # gather copy. TPU-only (the gather path stays the CPU oracle every
-    # bit-parity test pins against); plain-causal decode steps only —
-    # int8-KV pools dequantize in-kernel. _FORCE_PAGED_KERNEL overrides
-    # for interpret-mode tests.
-    use_paged_kernel = (
-        paged
-        and paged_kernel_ok
-        and positions.shape[1] == 1
-        and cfg.attn_softcap is None
-        and cfg.sliding_window is None
-        and (
-            _FORCE_PAGED_KERNEL
-            if _FORCE_PAGED_KERNEL is not None
-            else jax.default_backend() == "tpu"
-        )
+    from kserve_vllm_mini_tpu.ops.flash_attention import (
+        cached_prefill_blocks,
+        pallas_interpret,
     )
-    # Dense int8-KV decode kernel: the dense twin — each BLK stripe of the
-    # per-slot cache is DMA'd int8 and dequantized in-kernel, so the
-    # materialized bf16 [B,KVH,S,D] tensor the eager _read_layer builds
-    # never exists. Plain-causal single-token decode on the full-slot-axis
-    # cache only (the pp executor's write_gate/slot_base sub-views keep
-    # the eager oracle); _read_layer stays the non-kernel fallback.
     from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_block
 
-    use_dense_kernel = (
-        (not paged)
-        and quantized_kv
-        and paged_kernel_ok
-        and write_gate is None
-        and slot_base is None
-        and positions.shape[1] == 1
+    # None = jnp paths; else the interpret flag every Pallas call below gets
+    interpret = pallas_interpret(kernels)
+    # The cache-reading kernels are plain-causal only (no window, no
+    # softcap) and read the full-slot-axis cache (the pp executor's
+    # write_gate/slot_base sub-views keep the eager _read_layer path,
+    # which is also the oracle every parity test pins against).
+    plain_causal = (
+        interpret is not None
         and cfg.attn_softcap is None
         and cfg.sliding_window is None
+    )
+    full_dense = (not paged) and write_gate is None and slot_base is None
+    # Pallas paged decode kernel: table-driven block DMA instead of the
+    # gather copy; int8-KV pools dequantize in-kernel.
+    use_paged_kernel = plain_causal and paged and T == 1
+    # Dense int8-KV decode kernel: each BLK stripe of the per-slot cache
+    # is DMA'd int8 and dequantized in-kernel, so the materialized bf16
+    # [B,KVH,S,D] tensor the eager _read_layer builds never exists.
+    use_dense_kernel = (
+        plain_causal
+        and full_dense
+        and quantized_kv
+        and T == 1
         and dense_decode_block(s) is not None
-        and (
-            _FORCE_DENSE_KERNEL
-            if _FORCE_DENSE_KERNEL is not None
-            else jax.default_backend() == "tpu"
-        )
     )
     # Int8-KV cached-prefill kernel (ops/flash_attention.py
     # cached_prefill_attention): continuation chunks (T > 1 against the
     # cache, NOT fresh_prefill) stream the int8 stripes with in-kernel
     # dequant instead of materializing the eager read's bf16 KV tensor.
-    # Plain-causal, full-slot-axis dense caches only — same exclusions as
-    # the dense decode kernel, plus the tiling contract on (T, S).
-    from kserve_vllm_mini_tpu.ops.flash_attention import cached_prefill_blocks
-
     use_chunk_kernel = (
-        (not paged)
-        and (not fresh_prefill)
+        plain_causal
+        and full_dense
         and quantized_kv
-        and paged_kernel_ok
-        and write_gate is None
-        and slot_base is None
-        and positions.shape[1] > 1
-        and cfg.attn_softcap is None
-        and cfg.sliding_window is None
-        and cached_prefill_blocks(positions.shape[1], s) is not None
-        and (
-            _FORCE_CHUNK_KERNEL
-            if _FORCE_CHUNK_KERNEL is not None
-            else jax.default_backend() == "tpu"
-        )
+        and (not fresh_prefill)
+        and T > 1
+        and cached_prefill_blocks(T, s) is not None
     )
     kj = jnp.arange(s)[None, None, :]
     qi = positions[:, :, None]
@@ -674,9 +635,9 @@ def run_cached_layers(
 
     def _read_layer(cache, name, lidx):
         """Eager (non-kernel) cache read: gather/slice this layer's live
-        view and dequantize on read. The fallback path wherever the Pallas
-        decode kernels don't apply (prefill-against-cache, pp sub-views,
-        windowed/softcap models, CPU oracle)."""
+        view and dequantize on read. The path wherever the Pallas kernels
+        don't apply (bf16 prefill-against-cache, pp sub-views,
+        windowed/softcap models, every non-TPU backend)."""
         vals = jax.lax.dynamic_index_in_dim(cache[name], lidx, axis=0, keepdims=False)
         if paged:
             # the flattened axis is absolute position order, so downstream
@@ -756,7 +717,7 @@ def run_cached_layers(
             else:
                 from kserve_vllm_mini_tpu.ops.flash_attention import prefill_attention
 
-                o = prefill_attention(q, k, v)
+                o = prefill_attention(q, k, v, kernels=kernels)
         elif use_paged_kernel:
             # Pallas paged decode: the block table drives per-block DMA
             # straight from the LAYER-STACKED pool — no gathered KV copy,
@@ -773,6 +734,7 @@ def run_cached_layers(
                 qg, cache["k"], cache["v"], block_table,
                 cache_offsets, layer=lidx, scale=attn_scale,
                 k_scale=cache.get("k_s"), v_scale=cache.get("v_s"),
+                interpret=interpret,
             )
             o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
         elif use_dense_kernel:
@@ -790,6 +752,7 @@ def run_cached_layers(
                 qg, cache["k"], cache["v"], cache_offsets,
                 layer=lidx, scale=attn_scale,
                 k_scale=cache.get("k_s"), v_scale=cache.get("v_s"),
+                interpret=interpret,
             )
             o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
         elif use_chunk_kernel:
@@ -806,6 +769,7 @@ def run_cached_layers(
                 q, cache["k"], cache["v"], cache_offsets,
                 layer=lidx, scale=attn_scale,
                 k_scale=cache.get("k_s"), v_scale=cache.get("v_s"),
+                interpret=interpret,
             )
         else:
             k_layer = _read_layer(cache, "k", lidx)
@@ -864,8 +828,8 @@ def forward(
                         # (cached) path only — the cache-free training path
                         # ignores it
     lora_ids: Optional[jnp.ndarray] = None,  # [B] adapter index per row
-    paged_kernel_ok: bool = True,  # False for GSPMD-sharded paged pools
-                        # (run_cached_layers docstring)
+    kernels: str = "auto",  # Pallas kernel selection; the engine passes
+                        # "off" under a GSPMD mesh (run_cached_layers)
 ) -> tuple[jnp.ndarray, Optional[KVCache]]:
     """Returns (logits [B, T, V] float32, updated cache).
 
@@ -897,7 +861,7 @@ def forward(
         x, new_cache_dict = run_cached_layers(
             layers, cfg, x, positions, cos, sin, kv_cache, cache_offsets,
             fresh_prefill=fresh_prefill, block_table=block_table,
-            lora=lora, lora_ids=lora_ids, paged_kernel_ok=paged_kernel_ok,
+            lora=lora, lora_ids=lora_ids, kernels=kernels,
         )
     else:
         def scan_body_nocache(carry, xs):

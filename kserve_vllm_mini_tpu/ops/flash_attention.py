@@ -11,9 +11,9 @@ The jnp reference (ops/attention.py) is the correctness oracle; tests compare
 against it in interpret mode on CPU. The serving path reaches the kernel via
 ``prefill_attention`` below (models/llama.py ``forward(fresh_prefill=True)``,
 called by runtime/engine.py's prefill step), which compiles the kernel on TPU
-— the MXU sees [block_q, d] x [d, block_k] bf16 tiles — and falls back to the
-jnp oracle on other backends. bench.py asserts the prefill executable
-actually lowers to a tpu_custom_call.
+— the MXU sees [block_q, d] x [d, block_k] bf16 tiles — and takes the jnp
+oracle on other backends. chip_smoke.py asserts every prefill executable
+holds a tpu_custom_call.
 
 ``cached_prefill_attention`` below is the CONTINUATION-chunk variant: a
 chunk's queries attend the slot's whole dense cache stripe (earlier
@@ -21,7 +21,7 @@ chunks' cached KV plus the chunk's own just-written rows) with positional
 masking, reading int8-KV stripes with in-kernel dequant — the prefill
 twin of ops/paged_attention.py ``dense_decode_attention``, sharing its
 ``k_scale``/``v_scale`` conventions. The eager ``_read_layer`` dequant
-path (models/llama.py) stays the fallback/consistency oracle.
+path (models/llama.py) is the consistency oracle and the path off-TPU.
 """
 
 from __future__ import annotations
@@ -94,7 +94,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,  # Pallas interpreter: tests only
 ) -> jnp.ndarray:
     """Causal flash attention. T and S must be multiples of the block sizes
     (the runtime pads sequences to bucket boundaries anyway)."""
@@ -106,8 +106,6 @@ def flash_attention(
         raise ValueError(f"T={T}, S={S} must be multiples of blocks ({block_q},{block_k})")
     n_rep = H // KVH
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     grid = (B, H, T // block_q, S // block_k)
     kernel = functools.partial(
@@ -142,6 +140,7 @@ def _cached_prefill_kernel(
     scale: float,
     block_q: int,
     block_k: int,
+    n_rep: int,
     quantized: bool,
 ):
     """One key-block step of the CACHED-prefill online-softmax recurrence:
@@ -162,6 +161,9 @@ def _cached_prefill_kernel(
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
     off = offset_ref[b]
+    # this query head's kv head: the [1, 1, KVH, BK] scale block spans every
+    # kv head (``_scale_spec``), so the row is picked here
+    head = pl.ds(pl.program_id(1) // n_rep, 1)
 
     @pl.when(ki == 0)
     def _init():
@@ -184,7 +186,7 @@ def _cached_prefill_kernel(
             preferred_element_type=jnp.float32,
         ) * scale                            # [BQ, BK]
         if quantized:
-            logits = logits * k_s_ref[0, 0, 0][None, :]
+            logits = logits * k_s_ref[0, 0, head, :]       # [1, BK]
         qpos = off + qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0
         )
@@ -199,7 +201,7 @@ def _cached_prefill_kernel(
         p = jnp.exp(logits - m_new)
         l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
         if quantized:
-            pv = (p * v_s_ref[0, 0, 0][None, :]).astype(jnp.float32)
+            pv = (p * v_s_ref[0, 0, head, :]).astype(jnp.float32)
             vv = v.astype(jnp.float32)
         else:
             pv = p.astype(v.dtype)
@@ -245,7 +247,7 @@ def cached_prefill_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, B, KVH, S] f32: int8-KV
     v_scale: Optional[jnp.ndarray] = None,  # per-position dequant scales
-    interpret: Optional[bool] = None,
+    interpret: bool = False,  # Pallas interpreter: tests only
 ) -> jnp.ndarray:
     """Flash prefill OVER THE CACHE: a continuation chunk's T queries
     attend the slot's whole dense cache stripe — earlier chunks' cached
@@ -277,8 +279,6 @@ def cached_prefill_attention(
     bq, bk = blocks
     n_rep = H // KVH
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     offsets = offsets.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape((1,))
@@ -290,9 +290,11 @@ def cached_prefill_attention(
         )
 
     def _scale_spec():
+        # all KVH heads per stripe: a one-head (1, bk) block over [KVH, S]
+        # is not a legal TPU tile (same rule as ops/paged_attention.py)
         return pl.BlockSpec(
-            (1, 1, 1, bk),
-            lambda b, h, qi, ki, layer, off: (layer[0], b, h // n_rep, ki),
+            (1, 1, KVH, bk),
+            lambda b, h, qi, ki, layer, off: (layer[0], b, 0, ki),
         )
 
     in_specs = [
@@ -322,7 +324,7 @@ def cached_prefill_attention(
     )
     kernel = functools.partial(
         _cached_prefill_kernel, scale=scale, block_q=bq, block_k=bk,
-        quantized=quantized,
+        n_rep=n_rep, quantized=quantized,
     )
     return pl.pallas_call(
         kernel,
@@ -332,11 +334,30 @@ def cached_prefill_attention(
     )(layer_arr, offsets, *operands)
 
 
+def pallas_interpret(kernels: str) -> Optional[bool]:
+    """Resolve a ``kernels`` argument (models/llama.py ``forward``) into
+    the ``interpret`` flag its Pallas calls get, or None for the jnp paths:
+
+    - ``"auto"``: compiled kernels iff the default backend is a TPU;
+    - ``"off"``: jnp paths only — a GSPMD-partitioned jit cannot hold a
+      Mosaic call ("Mosaic kernels cannot be automatically partitioned");
+    - ``"interpret"``: kernels through the Pallas interpreter. Tests only:
+      nothing on the serving path passes it, so a serving process cannot
+      land in interpret mode."""
+    if kernels == "auto":
+        return False if jax.default_backend() == "tpu" else None
+    if kernels == "off":
+        return None
+    if kernels == "interpret":
+        return True
+    raise ValueError(f"unknown kernels mode {kernels!r}; known: auto, off, interpret")
+
+
 def prefill_attention(
     q: jnp.ndarray,   # [B, H, T, D]
     k: jnp.ndarray,   # [B, KVH, T, D]
     v: jnp.ndarray,   # [B, KVH, T, D]
-    use_flash: Optional[bool] = None,
+    kernels: str = "auto",
 ) -> jnp.ndarray:
     """Serving-prefill attention over a freshly projected block.
 
@@ -344,9 +365,9 @@ def prefill_attention(
     0, so block-causal attention over (q, k, v) themselves is exact — no
     cache readback, and attention cost is T x T instead of T x max_seq.
 
-    Dispatch: the compiled Pallas kernel on TPU (prompts pad to power-of-two
-    buckets, so shapes are always block-aligned), the jnp oracle elsewhere.
-    ``use_flash`` forces the choice for tests (interpret mode off-TPU).
+    Dispatch (``pallas_interpret``): the compiled Pallas kernel on TPU
+    (prompts pad to power-of-two buckets, so shapes are always
+    block-aligned), the jnp oracle elsewhere.
     """
     T = q.shape[2]
     bq = min(DEFAULT_BLOCK_Q, T)
@@ -361,10 +382,10 @@ def prefill_attention(
         and (pow2 or T % DEFAULT_BLOCK_Q == 0)
         and q.shape[1] % k.shape[1] == 0
     )
-    if use_flash is None:
-        use_flash = jax.default_backend() == "tpu" and aligned
-    if use_flash:
-        return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk)
+    interpret = pallas_interpret(kernels)
+    if interpret is not None and aligned:
+        return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                               interpret=interpret)
     from kserve_vllm_mini_tpu.ops.attention import attention, causal_mask
 
     return attention(q, k, v, causal_mask(T, T)[None, None])

@@ -63,7 +63,8 @@ def _decode_block_body(
     k_ref,       # [1, 1, 1, BLK, D] this grid step's key block
     v_ref,       # [1, 1, 1, BLK, D]
     rest,        # [k_s_ref, v_s_ref,] o_ref, m_ref, l_ref, acc_ref —
-                 # int8-KV mode carries per-position scale blocks
+                 # int8-KV mode carries [1, 1, KVH, BLK] per-position scale
+                 # blocks for ALL kv heads (see ``_scale_spec``)
     block_k: int,
     scale: float,
     quantized: bool,
@@ -78,6 +79,9 @@ def _decode_block_body(
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(2)
     nb = pl.num_programs(2)
+    # this grid step's kv head: the scale block spans every head (a
+    # one-head block is not a legal TPU tile), so the row is picked here
+    head = pl.ds(pl.program_id(1), 1)
 
     @pl.when(b == 0)
     def _init():
@@ -102,7 +106,7 @@ def _decode_block_body(
         if quantized:
             # per-position dequant folds into the [G, BLK] intermediates:
             # (q . k_j s_j) = (q . k_j) * s_j, and p @ (v s) = (p * s) @ v
-            logits = logits * k_s_ref[0, 0, 0][None, :]
+            logits = logits * k_s_ref[0, 0, head, :]       # [1, BLK]
         kpos = b * block_k + jax.lax.broadcasted_iota(
             jnp.int32, logits.shape, 1
         )
@@ -114,7 +118,7 @@ def _decode_block_body(
         p = jnp.exp(logits - m_new)
         l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
         if quantized:
-            pv = (p * v_s_ref[0, 0, 0][None, :]).astype(jnp.float32)
+            pv = (p * v_s_ref[0, 0, head, :]).astype(jnp.float32)
             vv = v.astype(jnp.float32)
         else:
             pv = p.astype(v.dtype)
@@ -176,7 +180,7 @@ def paged_decode_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, P, KVH, BLK] f32: int8-KV
     v_scale: Optional[jnp.ndarray] = None,  # per-position dequant scales
-    interpret: Optional[bool] = None,
+    interpret: bool = False,  # Pallas interpreter: tests only
 ) -> jnp.ndarray:
     """Returns [S, KVH, G, D] attention outputs, reading each slot's live
     blocks straight from the pool (table-driven DMA, no gather copy). The
@@ -193,8 +197,6 @@ def paged_decode_attention(
     L, P, _, BLK, _ = k_pool.shape
     MAXB = table.shape[1]
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     # bounds safety only: dead-but-in-range ids DMA whatever they name
     # (the engine's scratch-row convention concentrates that traffic)
@@ -211,9 +213,13 @@ def paged_decode_attention(
         )
 
     def _scale_spec():
+        # all KVH heads of the block: the TPU lowering wants the last two
+        # block dims tile-divisible (8, 128) or equal to the array's, and a
+        # one-head (1, BLK) block over [KVH, BLK] is neither — the kernel
+        # body picks its head's row
         return pl.BlockSpec(
-            (1, 1, 1, BLK),
-            lambda s, h, b, layer, table, qpos: (layer[0], table[s, b], h, 0),
+            (1, 1, KVH, BLK),
+            lambda s, h, b, layer, table, qpos: (layer[0], table[s, b], 0, 0),
         )
 
     in_specs = [
@@ -277,7 +283,7 @@ def dense_decode_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, B, KVH, S] f32: int8-KV
     v_scale: Optional[jnp.ndarray] = None,  # per-position dequant scales
-    interpret: Optional[bool] = None,
+    interpret: bool = False,  # Pallas interpreter: tests only
 ) -> jnp.ndarray:
     """Decode attention straight over the DENSE per-slot cache: the twin of
     ``paged_decode_attention`` for ``kv_layout="dense"``.
@@ -312,8 +318,6 @@ def dense_decode_attention(
             f"(>= 8); got S={S} — use the eager read path"
         )
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     qpos = qpos.astype(jnp.int32)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape((1,))
@@ -326,9 +330,11 @@ def dense_decode_attention(
         )
 
     def _scale_spec():
+        # all KVH heads per stripe (same tile rule as the paged kernel's
+        # scale block); the kernel body picks its head's row
         return pl.BlockSpec(
-            (1, 1, 1, BLK),
-            lambda s, h, b, layer, qpos: (layer[0], s, h, b),
+            (1, 1, KVH, BLK),
+            lambda s, h, b, layer, qpos: (layer[0], s, 0, b),
         )
 
     in_specs = [

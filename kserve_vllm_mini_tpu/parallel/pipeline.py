@@ -13,8 +13,7 @@ mechanism is owned. TPU-native design:
   (a ``lax.scan`` over them) to its current activation buffer, then hands
   the result to the next stage with a single ``lax.ppermute`` — the
   activation transfer rides ICI, once per tick, instead of every layer
-  (which is what naively scanning pp-sharded layers would do;
-  VERDICT.md round-1 Weak #5).
+  (which is what naively scanning pp-sharded layers would do).
 - The schedule is SPMD: all stages execute the same program each tick;
   stage identity comes from ``lax.axis_index("pp")``. Warmup/drain bubbles
   process don't-care data that is never emitted.
@@ -29,12 +28,12 @@ are stage-partitioned.
 
 from __future__ import annotations
 
-import inspect
 from functools import partial
 from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
@@ -45,18 +44,6 @@ from kserve_vllm_mini_tpu.models.llama import (
 )
 
 from kserve_vllm_mini_tpu.ops.rope import rope_frequencies
-
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
-# jax renamed the replication-check knob check_rep -> check_vma; resolve
-# the spelling this jax accepts so the executor traces on both lines
-_SM_CHECK_OFF = {
-    ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
 
 
 def _pipeline_specs(params: dict[str, Any]) -> dict[str, Any]:
@@ -102,7 +89,7 @@ def pipeline_loss_fn(
         mesh=mesh,
         in_specs=(p_specs, tok_spec),
         out_specs=P(),
-        **_SM_CHECK_OFF,
+        check_vma=False,
     )
     def spmd_loss(params, tokens):
         inp, tgt = tokens[:, :-1], tokens[:, 1:]

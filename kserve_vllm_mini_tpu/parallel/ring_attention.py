@@ -21,18 +21,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kserve_vllm_mini_tpu.ops.attention import repeat_kv
-
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
-# jax.lax.pvary landed with the 0.9 shard_map typing rules; on older jax
-# the accumulators need no device-varying declaration — identity is exact
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
 
 
 def _block_accumulate(q, k, v, q_pos, k_pos, m, l, acc, scale):
@@ -63,18 +55,21 @@ def _ring_attention_local(q, k, v, q_pos, k_pos, axis_name: str, scale: float,
     """Per-device body run under shard_map. Shapes are the local blocks.
 
     ``sp`` is the ring size, passed statically from the mesh (the perm
-    list needs a Python int; jax.lax.axis_size is not on older jax)."""
+    list needs a Python int)."""
     n_rep = q.shape[1] // k.shape[1]
     if n_rep > 1:
         k = repeat_kv(k, n_rep)
         v = repeat_kv(v, n_rep)
     B, H, Tq, D = q.shape
-    # pvary: the accumulators are logically device-varying over the ring axis
-    # from step 1 on; JAX 0.9's shard_map typing requires declaring that up
-    # front or the fori_loop carry types mismatch.
-    m = _pvary(jnp.full((B, H, Tq), -jnp.inf, dtype=jnp.float32), (axis_name,))
-    l = _pvary(jnp.zeros((B, H, Tq), dtype=jnp.float32), (axis_name,))
-    acc = _pvary(jnp.zeros((B, H, Tq, D), dtype=jnp.float32), (axis_name,))
+    # the accumulators are logically device-varying over the ring axis from
+    # step 1 on; shard_map's typing requires declaring that up front or the
+    # fori_loop carry types mismatch.
+    def varying(x):
+        return jax.lax.pcast(x, axis_name, to="varying")
+
+    m = varying(jnp.full((B, H, Tq), -jnp.inf, dtype=jnp.float32))
+    l = varying(jnp.zeros((B, H, Tq), dtype=jnp.float32))
+    acc = varying(jnp.zeros((B, H, Tq, D), dtype=jnp.float32))
 
     perm = [(i, (i + 1) % sp) for i in range(sp)]
 

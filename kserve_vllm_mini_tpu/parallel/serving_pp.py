@@ -38,12 +38,12 @@ meshes.
 
 from __future__ import annotations
 
-import inspect
 from functools import partial
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
@@ -54,18 +54,6 @@ from kserve_vllm_mini_tpu.models.llama import (
 )
 
 from kserve_vllm_mini_tpu.ops.rope import rope_frequencies
-
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-
-# jax renamed the replication-check knob check_rep -> check_vma; resolve
-# the spelling this jax accepts so the executor traces on both lines
-_SM_CHECK_OFF = {
-    ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-     else "check_rep"): False
-}
 
 
 def _pp_param_specs(params: dict[str, Any]) -> dict[str, Any]:
@@ -124,6 +112,7 @@ def make_pp_forward(cfg: ModelConfig, mesh: Mesh, microbatches: int = 1):
         cache_offsets: Optional[jnp.ndarray] = None,
         fresh_prefill: bool = False,
         logit_index: Optional[jnp.ndarray] = None,
+        kernels: str = "auto",
     ):
         if cfg_ is not cfg:
             raise ValueError(
@@ -150,14 +139,12 @@ def make_pp_forward(cfg: ModelConfig, mesh: Mesh, microbatches: int = 1):
 
         @partial(jax.jit, donate_argnums=(3,))
         def run(params, tokens, positions, cache, offsets, li):
-            # partial form: old-jax shard_map takes f positionally (it is
-            # not a decorator factory), new-jax accepts it too
             @partial(
                 shard_map,
                 mesh=mesh,
                 in_specs=(p_specs, rep, rep, c_specs, P(None), P(None)),
                 out_specs=(P(None, None, None), c_specs),
-                **_SM_CHECK_OFF,
+                check_vma=False,
             )
             def inner(params, tokens, positions, cache, offsets, li):
                 stage = jax.lax.axis_index("pp")
@@ -204,6 +191,7 @@ def make_pp_forward(cfg: ModelConfig, mesh: Mesh, microbatches: int = 1):
                         # global index of this stage's first layer: the
                         # alt-sliding-window phase follows GLOBAL parity
                         layer_offset=stage * (cfg.n_layers // n_pp),
+                        kernels=kernels,
                     )
                     # last stage emits microbatch t-(P-1) once the pipe fills
                     out_idx = t - (n_pp - 1)

@@ -1,21 +1,17 @@
-"""Never-dark perf observability (docs/PROFILING.md).
+"""Compile-time and memory observability (docs/PROFILING.md).
 
-Three layers, importable separately so the JAX-free harness stages never
-pay the runtime import:
+Layers, importable separately so the JAX-free harness stages never pay the
+runtime import:
 
 - ``compile_stats`` — explicit ``lower().compile()`` capture around the
   repo's jitted executables: compile wall time, XLA cost-model FLOPs and
   bytes-accessed, ``memory_analysis`` peak-buffer estimates, and HLO op
   histograms. ``InstrumentedJit`` wraps the engine's compiled steps so a
   serving process accumulates the same stats into ``/metrics``.
-- ``headroom`` — the admission/headroom guard: analytic HBM estimates for
-  a serving config pre-flighted against device capacity, downshifting
-  slots/context (labeled, never crashed) when a config would
-  RESOURCE_EXHAUST.
-- ``proxy`` — the CPU-mesh proxy bench tier: when the TPU probe fails,
-  bench.py degrades to the forced 8-device host platform and reports
-  compile stats, cost-model FLOPs/bytes, and sync-vs-pipelined step-count
-  ratios as clearly-labeled ``proxy:`` metrics instead of going dark.
+- ``headroom`` — analytic HBM estimates for a serving config, the live
+  ``memory_stats`` watermarks, and the estimate-vs-observed error.
+- ``devices`` — the device-facts table keyed by exact ``device_kind``
+  (capacity, roofline peaks, price/TDP row); an unknown TPU is an error.
 """
 
 from kserve_vllm_mini_tpu.profiling.compile_stats import (  # noqa: F401
@@ -28,7 +24,6 @@ from kserve_vllm_mini_tpu.profiling.compile_stats import (  # noqa: F401
 )
 from kserve_vllm_mini_tpu.profiling.headroom import (  # noqa: F401
     HeadroomPlan,
-    device_hbm_bytes,
     estimate_serving_bytes,
     plan_admission,
     serving_headroom_plan,

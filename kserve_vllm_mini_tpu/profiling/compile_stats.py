@@ -10,16 +10,15 @@ used to throw both away. This module makes them first-class run metrics:
   executable is returned so callers run exactly what was measured.
 - ``InstrumentedJit`` — a drop-in wrapper around a jitted callable: the
   first call per abstract signature compiles explicitly (stats land in a
-  ``CompileRecorder``), later calls hit the cached executable. Any failure
-  in the AOT path falls back to the plain jit call — instrumentation must
-  never cost correctness.
+  ``CompileRecorder``), later calls hit the cached executable. A compile
+  error raises, once, to the caller: a step that cannot compile has no
+  executable to serve with, and retrying it through plain ``jit`` would
+  compile the same program a second time and turn the stats off.
 - ``CompileRecorder`` — thread-safe accumulator the engine exports through
   ``snapshot_stats`` / ``/metrics`` (docs/API.md metrics table).
 
-These numbers are the proxy tier's backbone (docs/PROFILING.md): on a
-CPU mesh the cost model is the same analytic function of the program as
-on TPU, so FLOPs/bytes stay comparable across rounds even when no device
-time exists.
+Everything here is a count or a compiler estimate — compile wall time is
+set-up time, never a device metric (docs/PROFILING.md).
 """
 
 from __future__ import annotations
@@ -51,6 +50,10 @@ class CompileStats:
     temp_bytes: int
     generated_code_bytes: int
     hlo_ops: dict[str, int] = field(default_factory=dict)
+    # custom calls whose target is Mosaic: 0 means no Pallas kernel was
+    # compiled into this executable (the opcode histogram cannot tell a
+    # Mosaic call from XLA's own custom calls)
+    mosaic_calls: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -64,6 +67,7 @@ class CompileStats:
             "temp_bytes": self.temp_bytes,
             "generated_code_bytes": self.generated_code_bytes,
             "hlo_ops": dict(self.hlo_ops),
+            "mosaic_calls": self.mosaic_calls,
         }
 
 
@@ -91,49 +95,30 @@ def hlo_op_histogram(hlo_text: str, top: int = _TOP_OPS) -> dict[str, int]:
 def extract_compile_stats(
     compiled: Any, compile_wall_s: float, label: str = ""
 ) -> CompileStats:
-    """Pull cost/memory/HLO facts out of a ``jax.stages.Compiled``.
-
-    Every extraction is individually best-effort: a backend that lacks one
-    analysis (e.g. no cost model on an exotic plugin) yields zeros there,
-    never an exception — these stats decorate a run, they must not kill it.
-    """
-    flops = bytes_accessed = 0.0
-    try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        flops = float(ca.get("flops", 0.0) or 0.0)
-        bytes_accessed = float(ca.get("bytes accessed", 0.0) or 0.0)
-    except Exception:  # noqa: BLE001 — analysis availability is backend-specific
-        pass
-    arg = out = temp = code = alias = 0
-    try:
-        ma = compiled.memory_analysis()
-        arg = int(getattr(ma, "argument_size_in_bytes", 0) or 0)
-        out = int(getattr(ma, "output_size_in_bytes", 0) or 0)
-        temp = int(getattr(ma, "temp_size_in_bytes", 0) or 0)
-        code = int(getattr(ma, "generated_code_size_in_bytes", 0) or 0)
-        alias = int(getattr(ma, "alias_size_in_bytes", 0) or 0)
-    except Exception:  # noqa: BLE001 — same contract as above
-        pass
-    ops: dict[str, int] = {}
-    try:
-        ops = hlo_op_histogram(compiled.as_text())
-    except Exception:  # noqa: BLE001 — same contract as above
-        pass
+    """Pull cost/memory/HLO facts out of a ``jax.stages.Compiled``."""
+    ca = compiled.cost_analysis()
+    if isinstance(ca, (list, tuple)):
+        ca = ca[0] if ca else {}
+    ma = compiled.memory_analysis()
+    arg = int(ma.argument_size_in_bytes)
+    out = int(ma.output_size_in_bytes)
+    temp = int(ma.temp_size_in_bytes)
+    code = int(ma.generated_code_size_in_bytes)
+    hlo_text = compiled.as_text()
     return CompileStats(
         label=label,
         compile_wall_s=compile_wall_s,
-        flops=flops,
-        bytes_accessed=bytes_accessed,
+        flops=float(ca.get("flops", 0.0) or 0.0),
+        bytes_accessed=float(ca.get("bytes accessed", 0.0) or 0.0),
         # aliased (donated) buffers are counted inside argument bytes but
         # reuse their input allocation — subtract so the peak isn't double
-        peak_bytes=max(arg + out + temp + code - alias, 0),
+        peak_bytes=max(arg + out + temp + code - int(ma.alias_size_in_bytes), 0),
         argument_bytes=arg,
         output_bytes=out,
         temp_bytes=temp,
         generated_code_bytes=code,
-        hlo_ops=ops,
+        hlo_ops=hlo_op_histogram(hlo_text),
+        mosaic_calls=hlo_text.count('custom_call_target="tpu_custom_call"'),
     )
 
 
@@ -143,7 +128,7 @@ def capture_compile_stats(
     """Explicitly ``lower().compile()`` a jitted callable and keep the
     stats. Arguments may be concrete arrays or ``jax.ShapeDtypeStruct``
     trees (abstract lowering compiles the real program without ever
-    materializing the weights — the proxy tier's cost-model path).
+    materializing the weights).
 
     Returns ``(compiled_executable, stats)``; the executable accepts the
     same (concrete) calling convention as the jitted function, donation
@@ -216,9 +201,9 @@ class InstrumentedJit:
     The first call per abstract signature runs ``lower().compile()``
     explicitly (timed, stats into the recorder) and caches the executable;
     later calls dispatch straight to it — one compile total, same donation
-    semantics as the wrapped jit. Any failure anywhere in the AOT path
-    permanently falls back to the plain jit call for that signature, so
-    instrumentation can degrade but never break serving."""
+    semantics as the wrapped jit. A compile error (a Mosaic lowering
+    failure, RESOURCE_EXHAUSTED) propagates: nothing is cached for that
+    signature, so every executable that ever ran is in the recorder."""
 
     def __init__(self, fn: Callable, recorder: CompileRecorder,
                  label: str = "") -> None:
@@ -242,31 +227,14 @@ class InstrumentedJit:
                 return self._sole_exe(*args, **kwargs)
             except (TypeError, ValueError):
                 self._sole_exe = None
-        try:
-            key = _signature(args, kwargs)
-        except Exception:  # noqa: BLE001 — unhashable/exotic leaf: plain path
-            return self._fn(*args, **kwargs)
+        key = _signature(args, kwargs)
         exe = self._exes.get(key)
         if exe is None:
-            try:
-                compiled, stats = capture_compile_stats(
-                    self._fn, *args, label=self._label, **kwargs
-                )
-                self._recorder.record(stats)
-                exe = compiled
-            except Exception:  # noqa: BLE001 — AOT unsupported here: plain path
-                exe = self._fn
+            exe, stats = capture_compile_stats(
+                self._fn, *args, label=self._label, **kwargs
+            )
+            self._recorder.record(stats)
             self._exes[key] = exe
-            if len(self._exes) == 1 and exe is not self._fn:
+            if len(self._exes) == 1:
                 self._sole_exe = exe
         return exe(*args, **kwargs)
-
-
-def abstractify(tree: Any) -> Any:
-    """Map a pytree of arrays to ``ShapeDtypeStruct`` leaves for abstract
-    lowering (compile the flagship program without 16 GB of weights)."""
-    import jax
-
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree
-    )

@@ -1,12 +1,11 @@
-"""Admission/headroom guard: downshift, never RESOURCE_EXHAUST.
+"""Admission/headroom estimate: price a serving shape before building it.
 
-BENCH_r02 died mid-run with ``RESOURCE_EXHAUSTED`` — the 80-slot headline
-config plus its KV cache didn't fit the v5e's HBM and the whole round
-produced zero signal. The guard pre-flights a serving config's memory
-footprint against device capacity BEFORE any weights are materialized and,
-when it wouldn't fit, *downshifts* (halve slots, then halve context) and
-labels the measurement ``downshifted:`` — a smaller real number beats a
-crashed round every time (docs/PROFILING.md).
+The estimate pre-flights a serving config's memory footprint against
+device capacity BEFORE any weights are materialized; ``plan_admission``
+turns it into a labelled downshift (halve slots, then halve context) for
+callers that prefer a smaller shape to a ``RESOURCE_EXHAUSTED``
+(docs/PROFILING.md). Nothing on the serving path downshifts on its own:
+the engine builds the shape it was asked for or fails.
 
 The estimate is analytic (weights + KV + logits workspace + a fusion
 margin), so it is deterministic, testable with mocked capacities, and
@@ -20,68 +19,22 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Callable, Optional
 
-# Public per-chip HBM capacities by device-kind substring. Matched in
-# order: a "v6 lite" (Trillium) kind must hit v6e before the bare "v5"/
-# "lite" checks (same pitfall bench.py's economics leg documents).
-HBM_BYTES_BY_KIND: tuple[tuple[str, int], ...] = (
-    ("v6e", 32_000_000_000),
-    ("v6", 32_000_000_000),
-    ("v5e", 16_000_000_000),
-    ("lite", 16_000_000_000),
-    ("v5", 95_000_000_000),   # v5p
-    ("v4", 32_000_000_000),
-)
-
 # fraction of HBM the plan may fill: XLA needs slack for fusion scratch,
 # infeed buffers, and the donation double-buffer window
 DEFAULT_HEADROOM_FRACTION = 0.9
 
 
-def device_hbm_bytes(device: Any = None) -> Optional[int]:
-    """Per-chip HBM capacity: runtime ``memory_stats`` when the backend
-    reports it, the public spec table by device kind otherwise, ``None``
-    on CPU/unknown (no capacity -> the guard disables itself)."""
-    if device is None:
-        try:
-            import jax
-
-            device = jax.devices()[0]
-        except Exception:  # noqa: BLE001 — no backend at all
-            return None
-    try:
-        stats = device.memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit:
-            return int(limit)
-    except Exception:  # noqa: BLE001 — CPU devices raise/return nothing
-        pass
-    kind = str(getattr(device, "device_kind", "")).lower()
-    if "tpu" not in kind and "cpu" in kind:
-        return None
-    for sub, cap in HBM_BYTES_BY_KIND:
-        if sub in kind:
-            return cap
-    return None
-
-
 def hbm_watermarks(device: Any = None) -> dict[str, int]:
     """Live HBM watermarks from ``device.memory_stats()``:
     ``bytes_in_use`` always, ``peak_bytes_in_use``/``bytes_limit`` when
-    the backend reports them. Gracefully ABSENT — ``{}``, never
-    fabricated zeros — on CPU backends (whose devices raise or return
-    None) and when no backend is up at all, so consumers can tell
+    the backend reports them. ABSENT — ``{}``, never fabricated zeros —
+    on CPU backends (whose devices report None), so consumers can tell
     "no HBM telemetry" from "HBM empty" (docs/TROUBLESHOOTING.md)."""
     if device is None:
-        try:
-            import jax
+        import jax
 
-            device = jax.devices()[0]
-        except Exception:  # noqa: BLE001 — no backend at all
-            return {}
-    try:
-        stats = device.memory_stats() or {}
-    except Exception:  # noqa: BLE001 — CPU devices raise/return nothing
-        return {}
+        device = jax.devices()[0]
+    stats = device.memory_stats() or {}
     in_use = stats.get("bytes_in_use")
     if in_use is None:
         return {}
@@ -100,7 +53,7 @@ def headroom_error_pct(
     admission estimate vs the observed HBM peak. Positive = the model
     overestimates (safe but wasteful headroom); negative = it
     UNDERESTIMATES — the direction that RESOURCE_EXHAUSTs a run the
-    guard admitted (the BENCH_r02 class). None when either side is
+    guard admitted. None when either side is
     missing or non-positive (no peak observed = nothing to validate)."""
     try:
         est = float(estimate_bytes)

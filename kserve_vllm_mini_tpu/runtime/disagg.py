@@ -149,8 +149,12 @@ class PrefillLane:
         prefill_mesh: Optional[Any] = None,   # parallel/mesh.lane_meshes submesh
         max_inflight: Optional[int] = None,
         paged_prefill: Optional[Callable[[Any, dict], Any]] = None,
+        kernels: str = "auto",       # models/llama.py forward ``kernels``:
+                                     # "off" when the lane's steps are
+                                     # partitioned over a mesh
     ) -> None:
         self.cfg = cfg
+        self._kernels = kernels
         self.ecfg = ecfg
         self.pad_id = pad_id
         self._instrument = instrument or (lambda fn, label: fn)
@@ -378,6 +382,7 @@ class PrefillLane:
         from kserve_vllm_mini_tpu.models.llama import forward
 
         cfg = self.cfg
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1,))
         def fresh(params, cache, tokens, length):
@@ -390,6 +395,7 @@ class PrefillLane:
                 cache, jnp.zeros((1,), jnp.int32),
                 fresh_prefill=True,
                 logit_index=(length - 1)[None],
+                kernels=kernels,
             )
             return nc, logits[0, 0]
 
@@ -409,6 +415,7 @@ class PrefillLane:
         from kserve_vllm_mini_tpu.models.llama import forward
 
         cfg = self.cfg
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1,))
         def chunk(params, cache, tokens, length, offset):
@@ -417,6 +424,7 @@ class PrefillLane:
                 params, cfg, tokens, pos,
                 cache, offset[None],
                 logit_index=(length - 1)[None],
+                kernels=kernels,
             )
             return nc, logits[0, 0]
 

@@ -63,60 +63,8 @@ def _unpack_mask(packed, vocab_size: int):
     return flat[..., :vocab_size].astype(bool)
 
 
-def build_spec_step(cfg_t: ModelConfig, cfg_d: ModelConfig, k: int):
-    """Jitted fused speculative round, shared by the serving engine and
-    bench.py's drafter measurement: drafter proposes k tokens (scan), the
-    target verifies all of them in ONE T=k forward, and acceptance/bonus
-    selection happens on-device. Greedy exact-match acceptance ⇒ emitted
-    tokens are bit-identical to plain greedy decode of the target.
-
-    Returns ``(new_cache_t, new_cache_d, emit)`` where ``emit[s, j]`` is
-    draft j while accepted, the target's bonus token at the first mismatch,
-    and -1 after (the host emits the >=0 prefix)."""
-
-    @partial(jax.jit, donate_argnums=(1, 3))
-    def spec_step(params_t, cache_t, params_d, cache_d, last, lengths):
-        # drafter: k autoregressive proposals d1..dk
-        def dbody(carry, _):
-            c, tok, lens = carry
-            logits, nc = forward(
-                params_d, cfg_d, tok[:, None], lens[:, None], c, lens
-            )
-            nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
-            return (nc, nxt, lens + 1), nxt
-
-        (cache_d, _, _), drafts = jax.lax.scan(
-            dbody, (cache_d, last, lengths), None, length=k
-        )
-        drafts = drafts.T                                   # [S, k]
-        # target verifies [last, d1..d_{k-1}] in one forward
-        fed = jnp.concatenate([last[:, None], drafts[:, :-1]], axis=1)
-        pos = lengths[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
-        logits, nc_t = forward(
-            params_t, cfg_t, fed, pos, cache_t, lengths
-        )
-        preds = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, k]
-        # accepted draft count a in 0..k-1: longest prefix where the
-        # target's argmax agrees with the draft
-        matches = preds[:, : k - 1] == drafts[:, : k - 1]
-        a = jnp.where(
-            jnp.all(matches, axis=1),
-            k - 1,
-            jnp.argmin(matches.astype(jnp.int32), axis=1),
-        ) if k > 1 else jnp.zeros(last.shape, jnp.int32)
-        bonus = jnp.take_along_axis(preds, a[:, None], axis=1)[:, 0]
-        # emit[s, j] = draft j while j < a, the bonus at j == a, -1 after
-        j = jnp.arange(k, dtype=jnp.int32)[None, :]
-        emit = jnp.where(
-            j < a[:, None], drafts,
-            jnp.where(j == a[:, None], bonus[:, None], -1),
-        )
-        return nc_t, cache_d, emit
-
-    return spec_step
-
-
-def build_spec_step_sampled(cfg_t: ModelConfig, cfg_d: ModelConfig, k: int):
+def build_spec_step_sampled(cfg_t: ModelConfig, cfg_d: ModelConfig, k: int,
+                            kernels: str = "auto"):
     """Speculative round with REJECTION SAMPLING (Leviathan/Chen): sampled
     requests speculate too, and the emitted tokens are distributed exactly
     as plain sampling from the target's filtered distribution.
@@ -152,7 +100,8 @@ def build_spec_step_sampled(cfg_t: ModelConfig, cfg_d: ModelConfig, k: int):
         def dbody(carry, rng_step):
             c, tok, lens = carry
             logits, nc = forward(
-                params_d, cfg_d, tok[:, None], lens[:, None], c, lens
+                params_d, cfg_d, tok[:, None], lens[:, None], c, lens,
+                kernels=kernels,
             )
             q_lg = filter_logits(logits[:, 0, :], temps, topks, topps)
             nxt = jax.random.categorical(rng_step, q_lg).astype(jnp.int32)
@@ -166,7 +115,8 @@ def build_spec_step_sampled(cfg_t: ModelConfig, cfg_d: ModelConfig, k: int):
 
         fed = jnp.concatenate([last[:, None], drafts[:, :-1]], axis=1)
         pos = lengths[:, None] + jnp.arange(k, dtype=jnp.int32)[None, :]
-        logits, nc_t = forward(params_t, cfg_t, fed, pos, cache_t, lengths)
+        logits, nc_t = forward(params_t, cfg_t, fed, pos, cache_t, lengths,
+                               kernels=kernels)
         S, V = logits.shape[0], logits.shape[-1]
         p_all = jax.nn.softmax(
             filter_logits(
@@ -270,9 +220,10 @@ class EngineConfig:
     # attribute.
     quant_mode: str = "dequant"
     # decode steps fused into one dispatch. 1 = lowest per-token latency;
-    # larger values amortize host dispatch + readback (the dominant cost
-    # when the accelerator is remote) at the price of streaming granularity
-    # and up to chunk-1 wasted steps when a request finishes mid-chunk.
+    # larger values amortize host dispatch + readback over several tokens
+    # at the price of streaming granularity and up to chunk-1 wasted steps
+    # when a request finishes mid-chunk. Whether that trade pays on a
+    # directly attached chip is not measured (ROADMAP A5).
     decode_chunk: int = 1
     # speculative decoding: draft tokens proposed per round by the drafter
     # model (requires a drafter; 0 disables). Verification is rejection
@@ -360,9 +311,9 @@ class EngineConfig:
     # (profiling.compile_stats.InstrumentedJit) so compile wall time, the
     # XLA cost model's FLOPs/bytes, and peak-buffer estimates accumulate
     # into snapshot_stats / /metrics. One compile total per executable
-    # (the wrapper caches what it built); any AOT failure falls back to
-    # the plain jit call. Disabled automatically on meshes (AOT calls
-    # don't auto-reshard arguments the way jit does).
+    # (the wrapper caches what it built); a compile error raises to the
+    # step's caller. Disabled automatically on meshes (AOT calls don't
+    # auto-reshard arguments the way jit does).
     compile_stats: bool = True
     # In-process fault injection (docs/RESILIENCE.md): a KVMINI_FAULTS-
     # syntax string ("sweep_stall:after=5,duration=2;device_error:...")
@@ -398,11 +349,11 @@ class EngineConfig:
     default_deadline_s: Optional[float] = None
     # Live economics rail (docs/ECONOMICS.md): accelerator label used to
     # price the deployment against tpu-cost.yaml. None = auto-detect
-    # (the device_kind of TPU backends; CPU backends get NO rail — the
-    # absent-not-zero rule, a fabricated $0/1K-tok on a dev box would
-    # poison fleet aggregation). Setting it explicitly turns the rail on
-    # regardless of backend, which is how tests and mock fleets price a
-    # CPU engine as if it were the named chip.
+    # (the device-table row of a TPU backend's device_kind; CPU backends
+    # get NO rail — the absent-not-zero rule, a fabricated $0/1K-tok on a
+    # dev box would poison fleet aggregation). Setting it explicitly turns
+    # the rail on regardless of backend, which is how tests and mock fleets
+    # price a CPU engine as if it were the named chip.
     econ_accelerator: Optional[str] = None
 
 
@@ -573,6 +524,11 @@ class Engine:
         self.pad_id = pad_id
         self.params = params
 
+        # Pallas kernel selection for every compiled step (models/llama.py
+        # forward ``kernels``): steps jitted over a mesh are partitioned by
+        # GSPMD, which refuses a Mosaic call ("cannot be automatically
+        # partitioned") — those run attention through XLA
+        self._kernels = "off" if mesh is not None else "auto"
         # model executor: plain forward, or the pp-sharded drop-in when the
         # mesh pipelines layers (parallel/serving_pp.py — same signature, so
         # every compiled step below is executor-agnostic)
@@ -967,8 +923,10 @@ class Engine:
         # Live economics rail (docs/ECONOMICS.md): rolling-window $/1K-tok,
         # Wh/1K-tok, and the $/hr accrual derived from the busy/token
         # counters this engine already keeps, priced by tpu-cost.yaml.
-        # Auto-detected on TPU backends (device_kind names the chip the
-        # pricing sheet matches fuzzily), forced on any backend by
+        # Auto-detected on TPU backends through the device table
+        # (profiling/devices.py: the exact device_kind names the price and
+        # TDP row; a TPU kind it does not hold raises rather than being
+        # priced at the sheet's default), forced on any backend by
         # ecfg.econ_accelerator, and absent — no object, no keys, no
         # fabricated $0 — everywhere else. Fed/read under _obs_lock only
         # (the PR 8 gauge-cache discipline: published under a lock, not
@@ -976,12 +934,11 @@ class Engine:
         self._econ = None
         accel = self.ecfg.econ_accelerator
         if not accel:
-            try:
-                dev = jax.devices()[0]
-                if getattr(dev, "platform", "") == "tpu":
-                    accel = getattr(dev, "device_kind", "") or "tpu"
-            except Exception:
-                accel = None
+            dev = jax.devices()[0]
+            if dev.platform == "tpu":
+                from kserve_vllm_mini_tpu.profiling.devices import device_facts
+
+                accel = device_facts(dev.device_kind).name
         if accel:
             from kserve_vllm_mini_tpu.costs.live import LiveEconomics
 
@@ -1045,6 +1002,7 @@ class Engine:
                 ),
                 faults=self._faults,
                 prefill_mesh=prefill_mesh,
+                kernels="off" if prefill_mesh is not None else self._kernels,
                 # paged engines hand the lane the ENGINE's paged prefill
                 # path (shared pool, zero-copy v2 block-table handoff)
                 # instead of a staging cache + stripe
@@ -1158,7 +1116,7 @@ class Engine:
         # (quant guessing validated separately by the guard's own tests)
         # and the KV term priced by kv_bytes_per_token, so what
         # headroom_error_pct measures is the analytic KV/workspace/margin
-        # model — the part whose underestimate OOMed BENCH_r02.
+        # model — the part whose underestimate is the OOM direction.
         from kserve_vllm_mini_tpu.profiling.headroom import estimate_serving_bytes
 
         weight_bytes = sum(
@@ -1913,6 +1871,7 @@ class Engine:
             return self._prefill_fns[key]
         cfg = self._drafter_cfg if draft else self.cfg
         fwd = forward if draft else self._fwd
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1,), static_argnums=())
         def prefill(params, cache, tokens, length, slot, lora=None, ids=None):
@@ -1934,6 +1893,7 @@ class Engine:
                 sub, jnp.zeros((1,), jnp.int32),
                 fresh_prefill=True,
                 logit_index=(length - 1)[None],
+                kernels=kernels,
                 **kw,
             )
             return update_cache_slots(cache, new_sub, slot), logits[0, 0]  # [V] f32
@@ -1954,6 +1914,7 @@ class Engine:
             return self._prefill_fns[key]
         cfg = self._drafter_cfg if draft else self.cfg
         fwd = forward if draft else self._fwd
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1,))
         def chunk_prefill(params, cache, tokens, length, slot, offset,
@@ -1972,6 +1933,7 @@ class Engine:
                 params, cfg, tokens, pos,
                 sub, offset[None],
                 logit_index=(length - 1)[None],
+                kernels=kernels,
                 **kw,
             )
             return update_cache_slots(cache, new_sub, slot), logits[0, 0]
@@ -1990,7 +1952,7 @@ class Engine:
             return self._prefill_fns[key]
         cfg = self.cfg
         fwd = self._fwd
-        kernel_ok = self.mesh is None  # a 1-token chunk is a decode shape
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1,))
         def prefill(params, cache, tokens, length, trow, lora=None, ids=None):
@@ -2002,7 +1964,7 @@ class Engine:
                 fresh_prefill=True,
                 logit_index=(length - 1)[None],
                 block_table=trow,
-                paged_kernel_ok=kernel_ok,
+                kernels=kernels,
                 **kw,
             )
             return nc, logits[0, 0]
@@ -2017,7 +1979,7 @@ class Engine:
             return self._prefill_fns[key]
         cfg = self.cfg
         fwd = self._fwd
-        kernel_ok = self.mesh is None  # a 1-token chunk is a decode shape
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1,))
         def chunk_prefill(params, cache, tokens, length, offset, trow,
@@ -2029,7 +1991,7 @@ class Engine:
                 cache, offset[None],
                 logit_index=(length - 1)[None],
                 block_table=trow,
-                paged_kernel_ok=kernel_ok,
+                kernels=kernels,
                 **kw,
             )
             return nc, logits[0, 0]
@@ -2058,7 +2020,7 @@ class Engine:
         cfg = self.cfg
         fwd = self._fwd
         paged = self.paged
-        kernel_ok = self.mesh is None  # GSPMD-sharded pools use the gather
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1, 8))
         def decode(params, cache, tokens, lengths, temps, topks, topps, rng,
@@ -2066,10 +2028,9 @@ class Engine:
             def body(carry, _):
                 c, toks, lens, r, cnt = carry
                 r, sub = jax.random.split(r)
-                kw = {}
+                kw = {"kernels": kernels}
                 if paged:
                     kw["block_table"] = table
-                    kw["paged_kernel_ok"] = kernel_ok
                 if lora is not None:
                     kw["lora"], kw["lora_ids"] = lora, ids
                 logits, nc = fwd(
@@ -2111,17 +2072,16 @@ class Engine:
         cfg = self.cfg
         fwd = self._fwd
         paged = self.paged
-        kernel_ok = self.mesh is None
+        kernels = self._kernels
 
         @partial(jax.jit, donate_argnums=(1, 8))
         def decode_masked(params, cache, tokens, lengths,
                           temps, topks, topps, rng, counts, pres, freqs,
                           packed_mask, use_mask,
                           table=None, lora=None, ids=None):
-            kw = {}
+            kw = {"kernels": kernels}
             if paged:
                 kw["block_table"] = table
-                kw["paged_kernel_ok"] = kernel_ok
             if lora is not None:
                 kw["lora"], kw["lora_ids"] = lora, ids
             logits, nc = fwd(
@@ -2153,7 +2113,8 @@ class Engine:
         if self._spec_fn is None:
             self._spec_fn = self._instrument(
                 build_spec_step_sampled(
-                    self.cfg, self._drafter_cfg, self.ecfg.spec_tokens
+                    self.cfg, self._drafter_cfg, self.ecfg.spec_tokens,
+                    kernels=self._kernels,
                 ),
                 f"spec[k={self.ecfg.spec_tokens}]",
             )

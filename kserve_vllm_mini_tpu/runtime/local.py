@@ -66,6 +66,11 @@ def start_local_server(
         quant_mode=profile.get("quant_mode", "dequant") or "dequant",
         kv_cache_dtype=profile.get("kv_cache_dtype"),
         decode_chunk=int(profile.get("decode_chunk", 1)),
+        prefill_chunk=(
+            int(profile["prefill_chunk"])
+            if profile.get("prefill_chunk") is not None
+            else None
+        ),
         # disaggregated prefill/decode lanes (docs/DISAGGREGATION.md)
         disagg=bool(profile.get("disagg", False)),
         disagg_min_prompt=int(profile.get("disagg_min_prompt", 0)),

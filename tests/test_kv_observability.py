@@ -366,16 +366,13 @@ def test_hbm_watermarks_graceful_absence_and_passthrough():
             self._s = stats
 
         def memory_stats(self):
-            if isinstance(self._s, Exception):
-                raise self._s
             return self._s
 
     full = hbm_watermarks(Dev({"bytes_in_use": 5, "peak_bytes_in_use": 7,
                                "bytes_limit": 16}))
     assert full == {"bytes_in_use": 5, "peak_bytes_in_use": 7,
                     "bytes_limit": 16}
-    # no fabricated zeros: CPU devices raise or report nothing
-    assert hbm_watermarks(Dev(RuntimeError("no stats"))) == {}
+    # no fabricated zeros: CPU devices report nothing
     assert hbm_watermarks(Dev(None)) == {}
     assert hbm_watermarks(Dev({"largest_free_block": 3})) == {}
     # zero-valued peak/limit are dropped, in_use survives

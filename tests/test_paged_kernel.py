@@ -1,8 +1,8 @@
 """Pallas paged-attention decode kernel (ops/paged_attention.py): the
 table-driven block-DMA kernel must match the gather+masked-attention
 oracle in interpret mode, across lengths that start, split, and fill
-blocks, for MHA and GQA, and through the model's paged decode path when
-forced on (models/llama.py _FORCE_PAGED_KERNEL)."""
+blocks, for MHA and GQA, and through the model's paged decode path
+(models/llama.py forward ``kernels="interpret"``)."""
 
 import jax
 import jax.numpy as jnp
@@ -96,10 +96,9 @@ def test_kernel_int8_kv_dequant():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_model_paged_decode_path_int8_kv(monkeypatch):
-    """Forced kernel through the model's paged int8-KV decode path agrees
+def test_model_paged_decode_path_int8_kv():
+    """The kernel through the model's paged int8-KV decode path agrees
     with the gather path (which dequantizes to model dtype on read)."""
-    from kserve_vllm_mini_tpu.models import llama
     from kserve_vllm_mini_tpu.models.config import get_config
     from kserve_vllm_mini_tpu.models.llama import (
         forward,
@@ -117,27 +116,28 @@ def test_model_paged_decode_path_int8_kv(monkeypatch):
     pos = jnp.broadcast_to(jnp.arange(T), (B, T)).astype(jnp.int32)
     zero = jnp.zeros((B,), jnp.int32)
 
-    def step(force):
-        monkeypatch.setattr(llama, "_FORCE_PAGED_KERNEL", force)
+    def step(kernels):
         pool = init_paged_kv_cache(cfg, 20, BLK, quantized=True)
+        # the prefill stays on the jnp path in both runs: only the decode
+        # step's kernel is under test
         lg, pool = forward(params, cfg, toks, pos, pool, zero,
-                           fresh_prefill=True, block_table=table)
+                           fresh_prefill=True, block_table=table,
+                           kernels="off")
         nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
         lens = jnp.full((B,), T, jnp.int32)
         lg2, _ = forward(params, cfg, nxt[:, None], lens[:, None], pool, lens,
-                         block_table=table)
+                         block_table=table, kernels=kernels)
         return np.asarray(lg2[:, 0, :])
 
-    gather = step(False)
-    kernel = step(True)
+    gather = step("off")
+    kernel = step("interpret")
     # gather dequantizes in model dtype, the kernel in f32 — bf16-level drift
     np.testing.assert_allclose(kernel, gather, rtol=3e-2, atol=3e-2)
 
 
-def test_model_paged_decode_path_uses_kernel(monkeypatch):
-    """Force the kernel through the model's paged decode path and check
+def test_model_paged_decode_path_uses_kernel():
+    """Run the kernel through the model's paged decode path and check
     the logits agree with the gather path within kernel tolerance."""
-    from kserve_vllm_mini_tpu.models import llama
     from kserve_vllm_mini_tpu.models.config import get_config
     from kserve_vllm_mini_tpu.models.llama import (
         forward,
@@ -155,19 +155,19 @@ def test_model_paged_decode_path_uses_kernel(monkeypatch):
     pos = jnp.broadcast_to(jnp.arange(T), (B, T)).astype(jnp.int32)
     zero = jnp.zeros((B,), jnp.int32)
 
-    def prefill_and_step(force):
-        monkeypatch.setattr(llama, "_FORCE_PAGED_KERNEL", force)
+    def prefill_and_step(kernels):
         pool = init_paged_kv_cache(cfg, 20, BLK)
         lg, pool = forward(params, cfg, toks, pos, pool, zero,
-                           fresh_prefill=True, block_table=table)
+                           fresh_prefill=True, block_table=table,
+                           kernels="off")
         nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
         lens = jnp.full((B,), T, jnp.int32)
         lg2, _ = forward(params, cfg, nxt[:, None], lens[:, None], pool, lens,
-                         block_table=table)
+                         block_table=table, kernels=kernels)
         return np.asarray(lg2[:, 0, :])
 
-    gather = prefill_and_step(False)
-    kernel = prefill_and_step(True)
+    gather = prefill_and_step("off")
+    kernel = prefill_and_step("interpret")
     # the model runs bf16: two summation orders differ at bf16 rounding
     np.testing.assert_allclose(kernel, gather, rtol=3e-2, atol=3e-2)
     # and the distributions agree where it matters: same top token

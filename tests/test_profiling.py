@@ -1,24 +1,22 @@
 """Profiling subsystem (docs/PROFILING.md): compile-stats extraction on
 the CPU mesh (pinned keys, monotonic FLOPs with batch), the
-InstrumentedJit compile-once/fallback contract, the headroom downshift
-decision under mocked HBM capacities, the proxy-block validator, and the
-engine's end-to-end compile-stats surface."""
+InstrumentedJit compile-once / raise-once contract, the headroom downshift
+decision under mocked HBM capacities, and the engine's end-to-end
+compile-stats surface."""
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from kserve_vllm_mini_tpu.core.schema import validate_proxy
 from kserve_vllm_mini_tpu.profiling.compile_stats import (
     CompileRecorder,
     InstrumentedJit,
-    abstractify,
     capture_compile_stats,
     extract_compile_stats,
     hlo_op_histogram,
 )
+from kserve_vllm_mini_tpu.profiling.devices import device_facts
 from kserve_vllm_mini_tpu.profiling.headroom import (
-    HBM_BYTES_BY_KIND,
     estimate_serving_bytes,
     plan_admission,
     serving_headroom_plan,
@@ -73,7 +71,8 @@ def test_abstract_lowering_needs_no_weights():
     fn = _matmul_fn()
     x = jnp.ones((16, 16))
     _, concrete = capture_compile_stats(fn, x, x)
-    _, abstract = capture_compile_stats(fn, *abstractify((x, x)))
+    sds = jax.ShapeDtypeStruct(x.shape, x.dtype)
+    _, abstract = capture_compile_stats(fn, sds, sds)
     assert abstract.flops == concrete.flops
     assert abstract.argument_bytes == concrete.argument_bytes
 
@@ -96,16 +95,6 @@ def test_hlo_op_histogram_parses_and_caps():
     assert len(capped) == 5 and capped["other"] == 16
 
 
-def test_extract_survives_analysis_free_executable():
-    """A backend object lacking every analysis must yield zeros, never
-    raise — stats decorate a run, they cannot kill it."""
-    class Bare:
-        pass
-
-    cs = extract_compile_stats(Bare(), 0.5, label="bare")
-    assert cs.flops == 0 and cs.peak_bytes == 0 and cs.hlo_ops == {}
-
-
 # -- InstrumentedJit ----------------------------------------------------------
 
 def test_instrumented_jit_compiles_once_per_signature():
@@ -126,13 +115,25 @@ def test_instrumented_jit_compiles_once_per_signature():
     assert [e.label for e in rec.entries()] == ["mm", "mm"]
 
 
-def test_instrumented_jit_falls_back_when_aot_unsupported():
-    """A callable without .lower must still serve calls (plain path) and
-    record nothing — degradation, never breakage."""
+def test_instrumented_jit_raises_compile_error_once():
+    """A step that cannot compile raises to its caller — no retry through
+    plain jit (which would compile the program a second time and turn the
+    stats off), nothing recorded, nothing cached for the signature."""
+    traces = []
+
+    @jax.jit
+    def broken(a):
+        traces.append(1)
+        raise RuntimeError("RESOURCE_EXHAUSTED: mock compile failure")
+
     rec = CompileRecorder()
-    fn = InstrumentedJit(lambda a: a + 1, rec, label="plain")
-    assert int(fn(jnp.int32(41))) == 42
+    fn = InstrumentedJit(broken, rec, label="broken")
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        fn(jnp.ones((4,)))
+    assert len(traces) == 1          # lowered once, not re-tried via jit
     assert rec.snapshot()["compiles"] == 0
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        fn(jnp.ones((4,)))           # still an error, never a silent path
 
 
 def test_instrumented_jit_preserves_donation():
@@ -218,7 +219,7 @@ def test_serving_headroom_plan_mocked_capacities():
     admitted shape fits its budget."""
     from kserve_vllm_mini_tpu.models.config import get_config
 
-    v5e_hbm = dict(HBM_BYTES_BY_KIND)["v5e"]
+    v5e_hbm = device_facts("TPU v5 lite").hbm_bytes
     fits = serving_headroom_plan("llama-tiny", 80, 512, "int8", False,
                                  capacity_bytes=v5e_hbm)
     assert fits.fits and fits.downshifted is None
@@ -259,43 +260,6 @@ def test_estimate_prices_w8a8_activation_workspace():
     w8_32 = estimate_serving_bytes(cfg, 32, 512, quant="int8", quant_mode="w8a8")
     deq_32 = estimate_serving_bytes(cfg, 32, 512, quant="int8")
     assert (w8_32["workspace_bytes"] - deq_32["workspace_bytes"]) == 2 * extra
-
-
-# -- proxy block validator ----------------------------------------------------
-
-def _good_proxy():
-    return {
-        "series": "proxy", "platform": "cpu", "n_devices": 8,
-        "flops": 1e9, "bytes_accessed": 2e9, "compile_wall_s": 1.5,
-        "peak_bytes": 3e9, "step_count_ratio": 1.2,
-        "compile_stats": {}, "exec": {},
-        "quant": "int8", "quant_mode": "w8a8", "kv_quant": True,
-    }
-
-
-def test_validate_proxy_accepts_good_block():
-    assert validate_proxy(_good_proxy()) == []
-
-
-@pytest.mark.parametrize("mutate,fragment", [
-    (lambda d: d.pop("series"), "series"),
-    (lambda d: d.update(series="real"), "series"),
-    (lambda d: d.pop("flops"), "flops"),
-    (lambda d: d.update(compile_wall_s=0), "compile_wall_s"),
-    (lambda d: d.update(step_count_ratio=-1), "step_count_ratio"),
-    (lambda d: d.update(n_devices=0), "n_devices"),
-    (lambda d: d.update(exec="nope"), "exec"),
-    (lambda d: d.update(quant_mode="int8"), "quant_mode"),
-])
-def test_validate_proxy_rejects(mutate, fragment):
-    doc = _good_proxy()
-    mutate(doc)
-    errs = validate_proxy(doc)
-    assert errs and any(fragment in e for e in errs), errs
-
-
-def test_validate_proxy_rejects_non_object():
-    assert validate_proxy(None) == ["proxy block is not an object"]
 
 
 # -- engine surface -----------------------------------------------------------

@@ -238,10 +238,9 @@ def test_dense_kernel_block_helper():
 
 
 def test_model_dense_kernel_matches_eager_path():
-    """Forced dense kernel through the model's int8-KV decode path agrees
-    with the eager dequantize-on-read path (which rounds in bf16 — same
-    tolerance contract as the paged kernel's model test)."""
-    from kserve_vllm_mini_tpu.models import llama
+    """The dense kernel (interpreted) through the model's int8-KV decode
+    path agrees with the eager dequantize-on-read path (which rounds in
+    bf16 — same tolerance contract as the paged kernel's model test)."""
     from kserve_vllm_mini_tpu.models.llama import init_kv_cache
 
     cfg = get_config("llama-tiny", max_seq_len=64)
@@ -250,23 +249,18 @@ def test_model_dense_kernel_matches_eager_path():
     pos = jnp.broadcast_to(jnp.arange(12), (2, 12)).astype(jnp.int32)
     offs = jnp.zeros((2,), jnp.int32)
 
-    def one_step(force):
-        old = llama._FORCE_DENSE_KERNEL
-        llama._FORCE_DENSE_KERNEL = force
-        try:
-            cache = init_kv_cache(cfg, 2, max_seq=64, quantized=True)
-            lg, cache = forward(params, cfg, toks, pos, cache, offs,
-                                fresh_prefill=True)
-            nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
-            lens = jnp.full((2,), 12, jnp.int32)
-            lg2, _ = forward(params, cfg, nxt[:, None], lens[:, None],
-                             cache, lens)
-        finally:
-            llama._FORCE_DENSE_KERNEL = old
+    def one_step(kernels):
+        cache = init_kv_cache(cfg, 2, max_seq=64, quantized=True)
+        lg, cache = forward(params, cfg, toks, pos, cache, offs,
+                            fresh_prefill=True)
+        nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
+        lens = jnp.full((2,), 12, jnp.int32)
+        lg2, _ = forward(params, cfg, nxt[:, None], lens[:, None],
+                         cache, lens, kernels=kernels)
         return np.asarray(lg2[:, 0, :])
 
-    eager = one_step(False)
-    kernel = one_step(True)
+    eager = one_step("off")
+    kernel = one_step("interpret")
     # eager dequantizes in model dtype (bf16), the kernel in f32
     np.testing.assert_allclose(kernel, eager, rtol=3e-2, atol=3e-2)
     np.testing.assert_array_equal(eager.argmax(-1), kernel.argmax(-1))
@@ -274,11 +268,10 @@ def test_model_dense_kernel_matches_eager_path():
 
 def test_dense_vs_paged_kernel_consistency():
     """The two kernels see the SAME int8-KV stream through different
-    layouts: a dense-cache decode (dense kernel forced) and a paged-pool
-    decode (paged kernel forced) over the same token stream must produce
-    the same greedy tokens, and logits within kernel-vs-kernel rounding
-    (both dequantize in f32 in-kernel; only the sweep order differs)."""
-    from kserve_vllm_mini_tpu.models import llama
+    layouts: a dense-cache decode (dense kernel) and a paged-pool decode
+    (paged kernel) over the same token stream must produce the same
+    greedy tokens, and logits within kernel-vs-kernel rounding (both
+    dequantize in f32 in-kernel; only the sweep order differs)."""
     from kserve_vllm_mini_tpu.models.llama import init_kv_cache, init_paged_kv_cache
 
     cfg = get_config("llama-tiny", max_seq_len=64)
@@ -292,31 +285,24 @@ def test_dense_vs_paged_kernel_consistency():
     zero = jnp.zeros((B,), jnp.int32)
 
     def run(paged):
-        old_p, old_d = llama._FORCE_PAGED_KERNEL, llama._FORCE_DENSE_KERNEL
-        llama._FORCE_PAGED_KERNEL = paged
-        llama._FORCE_DENSE_KERNEL = not paged
-        try:
-            if paged:
-                cache = init_paged_kv_cache(cfg, 20, BLK, quantized=True)
-                kw = {"block_table": table}
-            else:
-                cache = init_kv_cache(cfg, B, max_seq=64, quantized=True)
-                kw = {}
-            lg, cache = forward(params, cfg, toks, pos, cache, zero,
-                                fresh_prefill=True, **kw)
-            nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
-            lens = jnp.full((B,), T, jnp.int32)
-            steps = []
-            for _ in range(4):
-                lg2, cache = forward(params, cfg, nxt[:, None], lens[:, None],
-                                     cache, lens, **kw)
-                nxt = jnp.argmax(lg2[:, 0, :], -1).astype(jnp.int32)
-                steps.append(np.asarray(nxt))
-                lens = lens + 1
-            return np.stack(steps), np.asarray(lg2[:, 0, :])
-        finally:
-            llama._FORCE_PAGED_KERNEL = old_p
-            llama._FORCE_DENSE_KERNEL = old_d
+        if paged:
+            cache = init_paged_kv_cache(cfg, 20, BLK, quantized=True)
+            kw = {"block_table": table}
+        else:
+            cache = init_kv_cache(cfg, B, max_seq=64, quantized=True)
+            kw = {}
+        lg, cache = forward(params, cfg, toks, pos, cache, zero,
+                            fresh_prefill=True, **kw)
+        nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
+        lens = jnp.full((B,), T, jnp.int32)
+        steps = []
+        for _ in range(4):
+            lg2, cache = forward(params, cfg, nxt[:, None], lens[:, None],
+                                 cache, lens, kernels="interpret", **kw)
+            nxt = jnp.argmax(lg2[:, 0, :], -1).astype(jnp.int32)
+            steps.append(np.asarray(nxt))
+            lens = lens + 1
+        return np.stack(steps), np.asarray(lg2[:, 0, :])
 
     toks_d, lg_d = run(paged=False)
     toks_p, lg_p = run(paged=True)
@@ -326,9 +312,8 @@ def test_dense_vs_paged_kernel_consistency():
 
 def test_dense_kernel_gate_excludes_unsupported_shapes():
     """Windowed/softcapped models and prefill-against-cache shapes must
-    keep the eager path even when the kernel is forced on — the gate, not
-    the force flag, owns correctness."""
-    from kserve_vllm_mini_tpu.models import llama
+    keep the eager path even when kernels are on — the gate, not the
+    caller's mode, owns correctness."""
     from kserve_vllm_mini_tpu.models.llama import init_kv_cache
 
     cfg = get_config("mistral-tiny", max_seq_len=64)  # sliding_window=16
@@ -336,20 +321,18 @@ def test_dense_kernel_gate_excludes_unsupported_shapes():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, cfg.vocab_size)
     pos = jnp.broadcast_to(jnp.arange(24), (2, 24)).astype(jnp.int32)
     offs = jnp.zeros((2,), jnp.int32)
-    old = llama._FORCE_DENSE_KERNEL
-    llama._FORCE_DENSE_KERNEL = True
-    try:
-        cache = init_kv_cache(cfg, 2, max_seq=64, quantized=True)
-        lg, cache = forward(params, cfg, toks, pos, cache, offs,
-                            fresh_prefill=True)
-        lens = jnp.full((2,), 24, jnp.int32)
-        nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
-        # windowed decode: the gate must route to the masked eager path
-        # (the kernel has no window support); finite logits prove it ran
-        lg2, _ = forward(params, cfg, nxt[:, None], lens[:, None], cache, lens)
-        assert bool(jnp.isfinite(lg2).all())
-    finally:
-        llama._FORCE_DENSE_KERNEL = old
+    cache = init_kv_cache(cfg, 2, max_seq=64, quantized=True)
+    lg, cache = forward(params, cfg, toks, pos, cache, offs,
+                        fresh_prefill=True)
+    lens = jnp.full((2,), 24, jnp.int32)
+    nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
+    # windowed decode: the gate must route to the masked eager path (the
+    # kernel has no window support) — bit-equal to the kernels-off run
+    lg_on, _ = forward(params, cfg, nxt[:, None], lens[:, None], cache, lens,
+                       kernels="interpret")
+    lg_off, _ = forward(params, cfg, nxt[:, None], lens[:, None], cache, lens,
+                        kernels="off")
+    np.testing.assert_array_equal(np.asarray(lg_on), np.asarray(lg_off))
 
 
 # -- perplexity gate -----------------------------------------------------------
@@ -441,67 +424,3 @@ def test_sweep_gate_fails_cell_past_threshold(tmp_path):
     assert by_q["int8"]["quality_perplexity_delta_vs_baseline"] == 0.1
     assert by_q["int4"]["status"] == "failed"
     assert "perplexity gate" in by_q["int4"]["error"]
-
-
-# -- the compiled-bytes acceptance pin ----------------------------------------
-
-
-def test_w8a8_decode_compiled_bytes_vs_bf16():
-    """THE acceptance criterion: on the 8-device CPU-mesh proxy rail
-    (profiling/proxy.py cost_model_stats — abstract compile, XLA cost
-    model), the fully-quantized decode step (int8 weights contracted
-    W8A8 + int8 KV) must access <= 60% of the bf16 dequant path's bytes
-    on llama-tiny. The quantized abstract trees mean the cost model
-    prices the int8 weight stream the deployment actually reads."""
-    from kserve_vllm_mini_tpu.profiling.proxy import cost_model_stats
-
-    bf16 = cost_model_stats("llama-tiny", "none", slots=8, max_seq=128)
-    w8a8 = cost_model_stats("llama-tiny", "int8", slots=8, max_seq=128,
-                            quant_mode="w8a8", kv_quant=True)
-    assert w8a8["quant_mode"] == "w8a8" and w8a8["kv_quant"] is True
-    ratio = (w8a8["decode"]["bytes_accessed"]
-             / max(bf16["decode"]["bytes_accessed"], 1.0))
-    assert ratio <= 0.60, f"compiled bytes ratio {ratio:.3f} > 0.60"
-    # and the weight stream itself halves (int8 vs bf16 leaves)
-    assert w8a8["analytic"]["weight_bytes"] < 0.6 * bf16["analytic"]["weight_bytes"]
-
-
-def test_quantized_prefill_compiled_bytes_vs_bf16():
-    """ISSUE 11 acceptance (PR 9's shape, prefill side): the fully-
-    quantized CONTINUATION-CHUNK prefill — the prefill executable that
-    READS the cache — must access <= 60% of the bf16 path's bytes on
-    llama-tiny. Chunk = min_prefill_bucket (16): the serving chunk size
-    where the weight stream amortizes over the fewest tokens, i.e. the
-    worst case for per-chunk efficiency, is exactly where the int8
-    stream's saving must still hold. (The int8-KV stripe read itself is
-    in-kernel on TPU — ops/flash_attention.cached_prefill_attention;
-    the CPU cost model prices the eager program.)"""
-    from kserve_vllm_mini_tpu.profiling.proxy import cost_model_stats
-
-    bf16 = cost_model_stats("llama-tiny", "none", slots=8, max_seq=128,
-                            prefill_chunk=16)
-    w8a8 = cost_model_stats("llama-tiny", "int8", slots=8, max_seq=128,
-                            quant_mode="w8a8", kv_quant=True,
-                            prefill_chunk=16)
-    assert w8a8["chunk_prefill"]["chunk_len"] == 16
-    ratio = (w8a8["chunk_prefill"]["bytes_accessed"]
-             / max(bf16["chunk_prefill"]["bytes_accessed"], 1.0))
-    assert ratio <= 0.60, f"chunk-prefill bytes ratio {ratio:.3f} > 0.60"
-
-
-def test_proxy_block_carries_quant_labels():
-    from kserve_vllm_mini_tpu.core.schema import validate_proxy
-    from kserve_vllm_mini_tpu.profiling.proxy import run_proxy_tier
-
-    block = run_proxy_tier(
-        "llama-tiny", exec_model="llama-tiny", quant="int8", slots=4,
-        max_seq=128, decode_steps=4, kv_quant=True, quant_mode="w8a8",
-        prefill_chunk=32,
-    )
-    assert validate_proxy(block) == []
-    assert block["quant_mode"] == "w8a8"
-    assert block["kv_quant"] is True
-    # the chunk-prefill entry rides the per-executable detail, sized by
-    # the knob (the chunked-prefill sweep axis)
-    assert block["compile_stats"]["chunk_prefill"]["chunk_len"] == 32
-    assert block["compile_stats"]["chunk_prefill"]["bytes_accessed"] > 0
