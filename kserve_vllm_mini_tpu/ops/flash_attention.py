@@ -127,6 +127,7 @@ def flash_attention(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="kvmini_flash_prefill",
     )(q, k, v)
 
 
@@ -331,6 +332,7 @@ def cached_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         interpret=interpret,
+        name="kvmini_cached_prefill",
     )(layer_arr, offsets, *operands)
 
 

@@ -256,6 +256,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KVH, G, D), q.dtype),
         interpret=interpret,
+        name="kvmini_paged_decode",
     )(layer_arr, safe_table, qpos, *operands)
 
 
@@ -371,4 +372,5 @@ def dense_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype),
         interpret=interpret,
+        name="kvmini_dense_decode",
     )(layer_arr, qpos, *operands)

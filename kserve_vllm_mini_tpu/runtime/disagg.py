@@ -62,9 +62,10 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
+
+from kserve_vllm_mini_tpu.runtime.tracing import now_s  # the runtime's one clock
 
 # Protocol versions stamped on every payload; bump whenever a payload's
 # layout/semantics change. Consume accepts exactly the version its KV
@@ -264,7 +265,7 @@ class PrefillLane:
                 except queue.Empty:
                     continue
                 ho = self._one_job(handle, meta)
-                ho.t_enqueued = time.time()
+                ho.t_enqueued = now_s()
                 self._ready.put(ho)
         finally:
             # the lane must NEVER exit with jobs unanswered: whatever is
@@ -278,7 +279,7 @@ class PrefillLane:
                 except queue.Empty:
                     break
                 ho = self._tombstone(h, "prefill lane stopped")
-                ho.t_enqueued = time.time()
+                ho.t_enqueued = now_s()
                 self._ready.put(ho)
 
     def _one_job(self, handle: Any, meta: Optional[dict] = None) -> KVHandoff:
@@ -325,9 +326,9 @@ class PrefillLane:
         _lane_paged_prefill (same executables as colocated — the KV
         lands directly in the reserved shared-pool blocks) and hand back
         a table-only payload: zero KV bytes cross the lanes."""
-        t0 = time.time()
+        t0 = now_s()
         logits, chunks = self._paged_prefill(handle, meta)
-        wall = time.time() - t0
+        wall = now_s() - t0
         n = len(handle.request.prompt_tokens)
         blk = max(getattr(self.ecfg, "kv_block_size", 64), 1)
         with self.stats.lock:
@@ -461,7 +462,7 @@ class PrefillLane:
         n = len(prompt)
         if self._staging is None:
             self._staging = self._make_staging()
-        t0 = time.time()
+        t0 = now_s()
         off, chunks = 0, 0
         last_logits = None
         budget = self.ecfg.max_prefill_len
@@ -492,7 +493,7 @@ class PrefillLane:
             logits = jax.device_get(logits)
         else:
             jax.block_until_ready(logits)
-        wall = time.time() - t0
+        wall = now_s() - t0
         blk = max(getattr(self.ecfg, "kv_block_size", 64), 1)
         with self.stats.lock:
             self.stats.prefills += 1

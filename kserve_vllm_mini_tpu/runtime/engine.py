@@ -47,6 +47,10 @@ from kserve_vllm_mini_tpu.runtime.sampling import (
     token_logprobs,
 )
 
+# request timestamps, phase durations, watchdog beats: all on the runtime's
+# one monotonic clock (runtime/tracing.py); only /traces converts to Unix time
+_now = rt_tracing.now_s
+
 # Constrained decoding speaks the TOKEN protocol (runtime/token_grammar.py):
 # machines expose token_mask(budget) -> bool[V] / advance_token(id). Raw
 # byte automata (runtime/constrain.py) passed as GenRequest.constraint are
@@ -449,7 +453,7 @@ class RequestHandle:
     def __init__(self, req: GenRequest) -> None:
         self.request = req
         self.events: "queue.Queue[tuple]" = queue.Queue()
-        self.t_submit = time.time()
+        self.t_submit = _now()  # the runtime's monotonic clock, like every t_*
         self.t_admit: float = 0.0   # queue wait ends / prefill begins
         self.t_first_token: float = 0.0
         self.t_done: float = 0.0
@@ -961,7 +965,7 @@ class Engine:
         self._res_lock = threading.Lock()
         self._faults = FaultRegistry(seed=self.ecfg.fault_seed,
                                      config=self.ecfg.faults or "")
-        self._watch_beat = time.time()   # last scheduler progress mark
+        self._watch_beat = _now()   # last scheduler progress mark
         self._sweep_ema_s = 0.0          # rolling dispatch->retire wall
         self._service_ema_s = 0.0        # rolling admit->done wall
         self._watchdog_trips = 0
@@ -1025,9 +1029,12 @@ class Engine:
             # "Long prompts stall streaming")
             "prefill_chunks": 0,
             "prefill_chunk_stall_s": 0.0,
+            # what the pieces were padded to: prefill_tokens over this is
+            # the share of the prefill programs' work that was real prompt
+            "prefill_bucket_tokens": 0,
             "requests_completed": 0,
             "busy_s": 0.0,        # exported: busy_seconds_total + duty_cycle
-            "started_at": time.time(),  # kvmini: metrics-ok — raw input; exposed as duty_cycle
+            "started_at": _now(),  # kvmini: metrics-ok — raw input; exposed as duty_cycle
             "queue_depth": 0,
             "spec_rounds": 0,       # fused drafter-propose/target-verify rounds
             "spec_accepted": 0,     # draft tokens accepted across all rounds
@@ -1108,6 +1115,17 @@ class Engine:
         self._phase_hist = {
             p: rt_tracing.PhaseHistogram() for p in rt_tracing.PHASES
         }
+        # /traces speaks Unix nanoseconds; every t_* is monotonic seconds
+        self._unix_offset_ns = rt_tracing.unix_offset_ns()
+        # the scheduler ledger (docs/TRACING.md): every phase of the engine
+        # thread a kvmini.* span on the profiler's clock and a
+        # kvmini_tpu_sched_* counter. Always on: it has no switch.
+        self._sched = rt_tracing.SchedLedger(
+            annotate=jax.profiler.TraceAnnotation,
+            exposed=self._host_exposed,
+            # asked on the scheduler thread, which is the one that writes it
+            sweep_s=lambda: self._sweep_ema_s,
+        )
 
         # Per-device analytic HBM footprint for headroom-model validation
         # (profiling/headroom.py; docs/TROUBLESHOOTING.md): the guard's
@@ -2234,7 +2252,7 @@ class Engine:
         if attrs:
             a.update(attrs)
         self.tracer.record(
-            name, req.trace_id, int(t0 * 1e9), int(t1 * 1e9),
+            name, req.trace_id, self._unix_ns(t0), self._unix_ns(t1),
             # host-local span parentage, same None-gate (kvmini: protocol-ok)
             parent_span_id=req.parent_span_id, ok=ok, attrs=a,
         )
@@ -2249,9 +2267,14 @@ class Engine:
         if self._engine_tracer is None:
             return
         self._engine_tracer.record(
-            name, self._engine_trace_id, int(t0 * 1e9), int(t1 * 1e9),
+            name, self._engine_trace_id, self._unix_ns(t0), self._unix_ns(t1),
             attrs=attrs,
         )
+
+    def _unix_ns(self, t: float) -> int:
+        """A monotonic ``_now()`` reading as the Unix nanoseconds /traces
+        speaks, through the one offset captured when the engine was built."""
+        return int(t * 1e9) + self._unix_offset_ns
 
     def _observe_phase(self, phase: str, seconds: float) -> None:
         self._phase_hist[phase].observe(seconds)
@@ -2561,12 +2584,16 @@ class Engine:
         draft = st["off"] >= n
         off = st["draft_off"] if draft else st["off"]
         piece = prompt[off : off + budget]
-        t0 = time.time()
-        last_logits = self._prefill_piece(
-            piece, slot, off, draft=draft, adapter_idx=st["adapter_idx"]
-        )
-        jax.block_until_ready(last_logits)
-        wall = time.time() - t0
+        bucket = self._bucket(len(piece))
+        with self._sched.phase(
+            "prefill", request_id=handle.request.request_id,
+            prompt_tokens=len(piece), bucket=bucket, offset=off,
+        ) as ph:
+            last_logits = self._prefill_piece(
+                piece, slot, off, draft=draft, adapter_idx=st["adapter_idx"]
+            )
+            jax.block_until_ready(last_logits)
+        wall = ph.seconds
         self.stats["busy_s"] += wall
         self.stats["prefill_chunks"] += 1
         if self._inflight or self._decode_active():
@@ -2578,6 +2605,8 @@ class Engine:
             st["off"] = off + len(piece)
             st["chunks"] += 1
             st["logits"] = last_logits
+            # target pieces only, like prefill_tokens
+            self.stats["prefill_bucket_tokens"] += bucket
             if self._slot_prefill[slot] is not None:
                 # interleaved mode: advance the frontier so concurrent
                 # sweeps' garbage writes stay >= it (see _slot_prefill)
@@ -2640,6 +2669,11 @@ class Engine:
         return inject
 
     def _consume_handoffs(self, on_decision=None) -> None:
+        if self._disagg is not None:
+            with self._sched.phase("handoff"):
+                self._drain_handoffs(on_decision)
+
+    def _drain_handoffs(self, on_decision=None) -> None:
         """Drain finished prefill-lane handoffs between sweeps: inject
         each staged stripe into its slot's cache region and activate the
         slot under the existing admission invariant (in-flight sweeps
@@ -2648,8 +2682,6 @@ class Engine:
         version mismatches degrade to colocated prefill; a routed slot
         whose handoff never arrives at all hits the HANDOFF_TIMEOUT_S
         last resort — no path leaves a client hanging."""
-        if self._disagg is None:
-            return
         from kserve_vllm_mini_tpu.runtime.disagg import (
             DENSE_HANDOFF_VERSION,
             DROPS_TO_DEGRADE,
@@ -2708,7 +2740,7 @@ class Engine:
                 self.stats["pipeline_fallback_active_set"] += 1
                 self._retire_all(on_decision)
             t_route = self._slot_handoff[slot]["t_route"]
-            now = time.time()
+            now = _now()
             wait = max(now - ho.t_enqueued, 0.0)
             self.stats["kv_handoffs"] += 1
             self.stats["kv_handoff_blocks"] += ho.n_blocks
@@ -2763,11 +2795,10 @@ class Engine:
         # machinery — re-prefills colocated after the timeout. Never
         # reached in lockstep: multihost refuses disaggregated engines
         # outright (check_multihost_engine), so _disagg is None there
-        # and this method early-returns before any clock read.
-        now = time.time()
+        # and _consume_handoffs returns before any clock read.
+        now = _now()
         for slot in range(self.ecfg.max_slots):
             hstate = self._slot_handoff[slot]
-            # kvmini: lockstep-ok — see above (disagg is host-local only)
             if hstate is None or now - hstate["t_route"] <= HANDOFF_TIMEOUT_S:
                 continue
             self.stats["kv_handoff_drops"] += 1
@@ -2848,7 +2879,7 @@ class Engine:
             # a reallocation could race the lane's stores
             self._orphan_blocks[id(handle)] = self._slot_blocks[slot]
             self._slot_blocks[slot] = []
-        handle.t_done = time.time()
+        handle.t_done = _now()
         handle.finish_reason = reason
         self._observe_phase("prefill", handle.t_done - handle.t_admit)
         self._trace_span(
@@ -2874,10 +2905,18 @@ class Engine:
 
     def _admit_one(self, handle: RequestHandle) -> None:
         req = handle.request
+        with self._sched.phase(
+            "admit", request_id=req.request_id,
+            prompt_tokens=len(req.prompt_tokens),
+        ):
+            self._admit(handle)
+
+    def _admit(self, handle: RequestHandle) -> None:
+        req = handle.request
         if handle.cancelled is not None:
             # cancelled while queued: report done without spending a
             # prefill (no tokens were produced)
-            handle.t_done = time.time()
+            handle.t_done = _now()
             handle.finish_reason = handle.cancelled
             self._observe_phase("queue", handle.t_done - handle.t_submit)
             self._trace_span(
@@ -2896,14 +2935,14 @@ class Engine:
         deadline_expired = (
             req.deadline_s is not None
             and not self._lockstep
-            and time.time() - handle.t_submit > req.deadline_s
+            and _now() - handle.t_submit > req.deadline_s
         )
         if deadline_expired:
             # deadline expired while queued: shed WITHOUT spending a
             # prefill (docs/RESILIENCE.md) — the client's budget is gone
             # either way, and burning decode steps on it would push
             # every queued neighbor past its own deadline too
-            handle.t_done = time.time()
+            handle.t_done = _now()
             handle.finish_reason = "shed"
             self._observe_phase("queue", handle.t_done - handle.t_submit)
             self._trace_span(
@@ -2921,7 +2960,7 @@ class Engine:
                 ),
             }))
             return
-        handle.t_admit = time.time()
+        handle.t_admit = _now()
         # queue phase: submit -> the scheduler picking the request up
         self._observe_phase("queue", handle.t_admit - handle.t_submit)
         self._trace_span(handle, "server.queue", handle.t_submit, handle.t_admit)
@@ -3049,12 +3088,18 @@ class Engine:
         prefill completion (_advance_prefills). Callers settle the
         in-flight pipeline first: _schedule_once retires before admitting
         and _advance_prefills retires before activating."""
+        with self._sched.phase("first_token") as ph:
+            self._sample_first(slot, st)
+        # the phase holds jit_first's device time (the readback waits for
+        # it), which is what makes it busy time
+        self.stats["busy_s"] += ph.seconds
+
+    def _sample_first(self, slot: int, st: dict) -> None:
         handle: RequestHandle = st["handle"]
         req = handle.request
         n = len(req.prompt_tokens)
         reused = st["reused"]
         last_logits = st["logits"]
-        t0 = time.time()
         # first token: sampled from the prompt's last-position logits,
         # grammar-masked when the request is constrained
         # multihost submit refuses constrained requests (req_payload has no
@@ -3078,13 +3123,12 @@ class Engine:
             jnp.bool_(machine is not None),
         )
         first_id = int(first_tok)
-        self.stats["busy_s"] += time.time() - t0
         self.stats["prefills"] += 1
         # only tokens actually prefilled: reused prefix tokens are counted
         # in prefix_tokens_reused, not here (throughput math stays honest)
         self.stats["prefill_tokens"] += n - reused
 
-        handle.t_first_token = time.time()
+        handle.t_first_token = _now()
         # prefill phase: admission -> first sampled token (chunked prefill
         # and the drafter's shadow prefill included; for interleaved
         # chunking this span also contains the decode sweeps that rode
@@ -3143,7 +3187,7 @@ class Engine:
         occupant's overwritten one."""
         handle = self._slot_req[slot]
         st = self._slot_prefill[slot]
-        handle.t_done = time.time()
+        handle.t_done = _now()
         handle.finish_reason = reason
         self._observe_phase("prefill", handle.t_done - handle.t_admit)
         self._trace_span(
@@ -3204,7 +3248,7 @@ class Engine:
     def _finish_slot(self, slot: int, reason: str) -> None:
         handle = self._slot_req[slot]
         if handle is not None:
-            handle.t_done = time.time()
+            handle.t_done = _now()
             handle.finish_reason = reason
             # decode phase: first token -> done (a first-token-only request
             # records a zero-length decode span — the phase still existed)
@@ -3370,36 +3414,41 @@ class Engine:
         lengths = jnp.asarray(self._slot_len, dtype=jnp.int32)
         temps, topks, topps, _pres, _freqs = self._get_sampling_arrays()
         self._rng, sub = jax.random.split(self._rng)
-        t0 = time.time()
-        with self._cache_lock:
-            self._cache, self._dcache, emit = spec(
-                self.params, self._cache,
-                self._drafter_params, self._dcache,
-                tokens, lengths, temps, topks, topps, sub,
-            )
-        # one transfer for the whole [S, k] block (same rationale as decode)
-        emit_host = np.asarray(jax.device_get(emit))
-        now = time.time()
+        # dispatch, readback and emission are the same child phases as on
+        # the plain path; what is left to spec_sweep itself is the preparation
+        with self._sched.phase("decode_dispatch") as ph:
+            with self._cache_lock:
+                self._cache, self._dcache, emit = spec(
+                    self.params, self._cache,
+                    self._drafter_params, self._dcache,
+                    tokens, lengths, temps, topks, topps, sub,
+                )
+        t0 = ph.t_start
+        with self._sched.phase("decode_retire") as ph:
+            # one transfer for the whole [S, k] block (same rationale as decode)
+            emit_host = np.asarray(jax.device_get(emit))
+        now = ph.t_end
         self.stats["busy_s"] += now - t0
+        self._note_sweep(t0, now)
         self.stats["spec_rounds"] += 1
         self.stats["spec_proposed"] += (k - 1) * len(active)
-
-        for i in active:
-            n_emitted = 0
-            for j in range(k):
-                tok = int(emit_host[i, j])
-                if tok < 0:
-                    break
-                n_emitted += 1
-                if self._emit_token(i, tok, now):
-                    break
-            # accepted drafts = emitted minus the bonus token
-            self.stats["spec_accepted"] += max(n_emitted - 1, 0)
+        with self._sched.phase("emit") as ph:
+            for i in active:
+                n_emitted = 0
+                for j in range(k):
+                    tok = int(emit_host[i, j])
+                    if tok < 0:
+                        break
+                    n_emitted += 1
+                    if self._emit_token(i, tok, now):
+                        break
+                # accepted drafts = emitted minus the bonus token
+                self.stats["spec_accepted"] += max(n_emitted - 1, 0)
+        self._observe_phase("emit", ph.seconds)
         self._trace_engine_span(
             "engine.decode.window", t0, now,
             attrs={"chunk": k, "slots": len(active), "mode": "spec"},
         )
-        self._observe_phase("emit", time.time() - now)
         # spec emission advanced _last_tokens host-side; the device carry
         # (if any) predates it, so the next plain dispatch must rebuild
         self._tokens_dev = None
@@ -3415,13 +3464,15 @@ class Engine:
             return
         spec_slots, plain_slots = self._spec_partition(active)
         if spec_slots:
-            self._spec_sweep(spec_slots)
+            with self._sched.phase("spec_sweep"):
+                self._spec_sweep(spec_slots)
         if plain_slots:
             constrained = [
                 i for i in plain_slots if self._slot_machine[i] is not None
             ]
             if constrained:
-                self._masked_sweep(plain_slots, constrained)
+                with self._sched.phase("masked_sweep"):
+                    self._masked_sweep(plain_slots, constrained)
             else:
                 self._dispatch_plain(plain_slots)
                 self._retire_one()
@@ -3517,69 +3568,71 @@ class Engine:
         carry stays on device as the next dispatch's feed; the stacked
         per-step outputs ride in _inflight until _retire_one() reads them
         back and emits."""
-        if self._faults.check("device_error"):
-            # dispatch-time device error (docs/RESILIENCE.md): raised as
-            # DeviceFault so the loop runs the engine-fault RECOVERY
-            # path (batch fails "engine_fault", engine degrades and
-            # keeps serving) instead of the generic fail-everything
-            # crash handler
-            from kserve_vllm_mini_tpu.runtime.faults import DeviceFault
+        with self._sched.phase("decode_prepare"):
+            if self._faults.check("device_error"):
+                # dispatch-time device error (docs/RESILIENCE.md): raised as
+                # DeviceFault so the loop runs the engine-fault RECOVERY
+                # path (batch fails "engine_fault", engine degrades and
+                # keeps serving) instead of the generic fail-everything
+                # crash handler
+                from kserve_vllm_mini_tpu.runtime.faults import DeviceFault
 
-            raise DeviceFault("injected dispatch-time device error")
-        chunk = self._chunk_for(active)
-        tokens = self._feed_tokens(active)
-        # The fed token occupies absolute position slot_len + already-in-
-        # flight steps; forward writes its KV there and attends <=. The cap
-        # only ever binds on inactive rows (eligibility guarantees active
-        # windows), whose writes are masked-garbage either way.
-        lengths = np.minimum(
-            np.asarray(self._slot_len, dtype=np.int32) + self._pending_steps,
-            self.ecfg.max_seq_len - 1,
-        )
-        temps, topks, topps, pres, freqs = self._get_sampling_arrays()
-        rng_prev = self._rng
-        self._rng, sub = jax.random.split(self._rng)
-        lkw = {}
-        if self.paged:
-            lkw["table"] = self._table()
-        if self._lora is not None:
-            lkw["lora"] = self._lora["layers"]
-            lkw["ids"] = self._adapter_ids()
-        t0 = time.time()
-        if self._bubble_anchor:
-            self.stats["bubble_s"] += max(t0 - self._bubble_anchor, 0.0)
-            self._bubble_anchor = 0.0
-        decode = self._get_decode_fn(chunk)
-        with jax.profiler.TraceAnnotation("kvmini.decode_dispatch"):
-            with self._cache_lock:
-                self._cache, self._counts, next_toks, ys = decode(
-                    self.params, self._cache,
-                    tokens, jnp.asarray(lengths, dtype=jnp.int32),
-                    temps, topks, topps, sub,
-                    self._counts, pres, freqs, **lkw,
-                )
-        self._tokens_dev = next_toks
-        self._tokens_dev_slots = frozenset(active)
-        self._inflight.append({
-            "ys": ys,
-            "active": list(active),
-            # handle identity per slot: retire must never emit into a
-            # handle that replaced the one this sweep was dispatched for
-            "handles": {i: self._slot_req[i] for i in active},
-            "chunk": chunk,
-            "t_dispatch": t0,
-            # rng state BEFORE this dispatch's split: if every slot
-            # finishes before this sweep is retired, the sweep is dropped
-            # and the split rewound, keeping the dispatch/rng sequence
-            # identical to the synchronous loop's
-            "rng_prev": rng_prev,
-        })
-        self._pending_steps += chunk
-        depth = len(self._inflight)
-        if depth > 1:
-            self.stats["pipelined_sweeps"] += 1
-        if depth > self.stats["dispatch_depth"]:
-            self.stats["dispatch_depth"] = depth
+                raise DeviceFault("injected dispatch-time device error")
+            chunk = self._chunk_for(active)
+            tokens = self._feed_tokens(active)
+            # The fed token occupies absolute position slot_len + already-in-
+            # flight steps; forward writes its KV there and attends <=. The cap
+            # only ever binds on inactive rows (eligibility guarantees active
+            # windows), whose writes are masked-garbage either way.
+            lengths = np.minimum(
+                np.asarray(self._slot_len, dtype=np.int32) + self._pending_steps,
+                self.ecfg.max_seq_len - 1,
+            )
+            temps, topks, topps, pres, freqs = self._get_sampling_arrays()
+            rng_prev = self._rng
+            self._rng, sub = jax.random.split(self._rng)
+            lkw = {}
+            if self.paged:
+                lkw["table"] = self._table()
+            if self._lora is not None:
+                lkw["lora"] = self._lora["layers"]
+                lkw["ids"] = self._adapter_ids()
+            decode = self._get_decode_fn(chunk)
+            # the jitted call alone: what bench/trace_reduce.py names gaps by
+            with self._sched.phase("decode_dispatch") as ph:
+                with self._cache_lock:
+                    self._cache, self._counts, next_toks, ys = decode(
+                        self.params, self._cache,
+                        tokens, jnp.asarray(lengths, dtype=jnp.int32),
+                        temps, topks, topps, sub,
+                        self._counts, pres, freqs, **lkw,
+                    )
+            t0 = ph.t_start
+            if self._bubble_anchor:
+                self.stats["bubble_s"] += max(t0 - self._bubble_anchor, 0.0)
+                self._bubble_anchor = 0.0
+            self._tokens_dev = next_toks
+            self._tokens_dev_slots = frozenset(active)
+            self._inflight.append({
+                "ys": ys,
+                "active": list(active),
+                # handle identity per slot: retire must never emit into a
+                # handle that replaced the one this sweep was dispatched for
+                "handles": {i: self._slot_req[i] for i in active},
+                "chunk": chunk,
+                "t_dispatch": t0,
+                # rng state BEFORE this dispatch's split: if every slot
+                # finishes before this sweep is retired, the sweep is dropped
+                # and the split rewound, keeping the dispatch/rng sequence
+                # identical to the synchronous loop's
+                "rng_prev": rng_prev,
+            })
+            self._pending_steps += chunk
+            depth = len(self._inflight)
+            if depth > 1:
+                self.stats["pipelined_sweeps"] += 1
+            if depth > self.stats["dispatch_depth"]:
+                self.stats["dispatch_depth"] = depth
 
     def _retire_one(self) -> None:
         """Read back and emit the OLDEST in-flight sweep. Emission skips a
@@ -3588,7 +3641,8 @@ class Engine:
         stream. When every slot finished, any younger in-flight sweep is
         pure garbage: drop it and rewind the rng split it consumed."""
         rec = self._inflight.pop(0)
-        with jax.profiler.TraceAnnotation("kvmini.decode_retire"):
+        # the device_get alone: what bench/trace_reduce.py names gaps by
+        with self._sched.phase("decode_retire") as ph:
             # ONE host transfer for the whole chunk block — per-element
             # int(row[i]) costs a separate device readback each (chunk x
             # slots round-trips per sweep; this line was the serving
@@ -3596,47 +3650,39 @@ class Engine:
             toks_h, lps_h, tids_h, tlps_h = (
                 np.asarray(a) for a in jax.device_get(rec["ys"])
             )
-        t_ready = time.time()
-        self.stats["busy_s"] += t_ready - max(rec["t_dispatch"], self._t_last_ready)
-        self._t_last_ready = t_ready
-        # watchdog food (docs/RESILIENCE.md): a retire IS scheduler
-        # progress, and its wall time feeds the rolling sweep EMA the
-        # wedge threshold scales from
-        span = t_ready - rec["t_dispatch"]
-        with self._res_lock:
-            self._watch_beat = t_ready
-            self._sweep_ema_s = (
-                span if self._sweep_ema_s == 0.0
-                else 0.8 * self._sweep_ema_s + 0.2 * span
+        t_ready = ph.t_end
+        with self._sched.phase("emit") as ph:
+            self.stats["busy_s"] += t_ready - max(
+                rec["t_dispatch"], self._t_last_ready
             )
-        self._pending_steps -= rec["chunk"]
-        self.stats["decode_steps"] += rec["chunk"]
-        overlapped = bool(self._inflight)  # device still computing N+1
-        now = time.time()
-        for step in range(toks_h.shape[0]):
-            for i in rec["active"]:
-                h = self._slot_req[i]
-                if h is None or h is not rec["handles"][i]:
-                    continue  # finished earlier in this chunk, or freed
-                if h.cancelled is not None and not self._lockstep:
-                    # cancelled between dispatch and retire: drop its
-                    # tokens. In lockstep the race is host-local (the
-                    # follower can't see it) — there the cancel DECISION,
-                    # which precedes the retire in the stream, is what
-                    # stops emission on both sides.
-                    continue
-                lp_info = None
-                if h.request.logprobs:
-                    lp_info = (
-                        float(lps_h[step, i]),
-                        list(zip(tids_h[step, i].tolist(),
-                                 tlps_h[step, i].tolist())),
-                    )
-                self._emit_token(i, int(toks_h[step, i]), now, lp_info)
-        t_emitted = time.time()
+            self._t_last_ready = t_ready
+            self._note_sweep(rec["t_dispatch"], t_ready)
+            self._pending_steps -= rec["chunk"]
+            self.stats["decode_steps"] += rec["chunk"]
+            overlapped = bool(self._inflight)  # device still computing N+1
+            for step in range(toks_h.shape[0]):
+                for i in rec["active"]:
+                    h = self._slot_req[i]
+                    if h is None or h is not rec["handles"][i]:
+                        continue  # finished earlier in this chunk, or freed
+                    if h.cancelled is not None and not self._lockstep:
+                        # cancelled between dispatch and retire: drop its
+                        # tokens. In lockstep the race is host-local (the
+                        # follower can't see it) — there the cancel
+                        # DECISION, which precedes the retire in the
+                        # stream, is what stops emission on both sides.
+                        continue
+                    lp_info = None
+                    if h.request.logprobs:
+                        lp_info = (
+                            float(lps_h[step, i]),
+                            list(zip(tids_h[step, i].tolist(),
+                                     tlps_h[step, i].tolist())),
+                        )
+                    self._emit_token(i, int(toks_h[step, i]), t_ready, lp_info)
         # emit phase: readback -> host emission done for this window; the
         # engine-lane span records the dispatch->retire window itself
-        self._observe_phase("emit", t_emitted - t_ready)
+        self._observe_phase("emit", ph.seconds)
         self._trace_engine_span(
             "engine.decode.window", rec["t_dispatch"], t_ready,
             attrs={"chunk": rec["chunk"], "slots": len(rec["active"]),
@@ -3645,7 +3691,7 @@ class Engine:
         if overlapped:
             # emission ran while the device computed the next sweep — the
             # host time the synchronous loop would have serialized
-            self.stats["host_overlap_s"] += t_emitted - t_ready
+            self.stats["host_overlap_s"] += ph.seconds
         any_active = bool(self._decode_active())
         if not any_active and self._inflight:
             # every decode slot finished: younger sweeps computed only garbage.
@@ -3658,6 +3704,18 @@ class Engine:
         self._bubble_anchor = (
             t_ready if (any_active and not self._inflight) else 0.0
         )
+
+    def _note_sweep(self, t_dispatch: float, t_ready: float) -> None:
+        """Watchdog food (docs/RESILIENCE.md): a sweep read back IS scheduler
+        progress, and its dispatch -> ready wall feeds the rolling EMA that
+        the wedge threshold and the ledger's stall threshold scale from."""
+        span = t_ready - t_dispatch
+        with self._res_lock:
+            self._watch_beat = t_ready
+            self._sweep_ema_s = (
+                span if self._sweep_ema_s == 0.0
+                else 0.8 * self._sweep_ema_s + 0.2 * span
+            )
 
     def _retire_all(self, on_decision=None) -> None:
         while self._inflight:
@@ -3716,10 +3774,6 @@ class Engine:
         lengths = jnp.asarray(self._slot_len, dtype=jnp.int32)
         temps, topks, topps, pres, freqs = self._get_sampling_arrays()
         self._rng, sub = jax.random.split(self._rng)
-        t0 = time.time()
-        if self._bubble_anchor:
-            self.stats["bubble_s"] += max(t0 - self._bubble_anchor, 0.0)
-            self._bubble_anchor = 0.0
         mask = np.zeros((S, (self.cfg.vocab_size + 7) // 8), dtype=np.uint8)
         for i in constrained:
             budget = min(
@@ -3736,48 +3790,49 @@ class Engine:
             lkw["lora"] = self._lora["layers"]
             lkw["ids"] = self._adapter_ids()
         decode = self._get_masked_decode_fn()
-        with self._cache_lock:
-            self._cache, self._counts, next_toks, ys = decode(
-                self.params, self._cache,
-                tokens, lengths, temps, topks, topps, sub,
-                self._counts, pres, freqs,
-                jnp.asarray(mask), jnp.asarray(use_mask), **lkw,
-            )
+        # dispatch, readback and emission are the same child phases as on
+        # the plain path; masked_sweep's own time is the preparation above
+        with self._sched.phase("decode_dispatch") as ph:
+            with self._cache_lock:
+                self._cache, self._counts, next_toks, ys = decode(
+                    self.params, self._cache,
+                    tokens, lengths, temps, topks, topps, sub,
+                    self._counts, pres, freqs,
+                    jnp.asarray(mask), jnp.asarray(use_mask), **lkw,
+                )
+        t0 = ph.t_start
+        if self._bubble_anchor:
+            self.stats["bubble_s"] += max(t0 - self._bubble_anchor, 0.0)
+            self._bubble_anchor = 0.0
         self._tokens_dev = next_toks
         self._tokens_dev_slots = frozenset(active)
-        toks_h, lps_h, tids_h, tlps_h = (
-            # the constrained path is synchronous by design: the next
-            # mask depends on the byte just emitted  # kvmini: sync-ok
-            np.asarray(a) for a in jax.device_get(ys)
-        )
-        now = time.time()
+        with self._sched.phase("decode_retire") as ph:
+            toks_h, lps_h, tids_h, tlps_h = (
+                # the constrained path is synchronous by design: the next
+                # mask depends on the byte just emitted  # kvmini: sync-ok
+                np.asarray(a) for a in jax.device_get(ys)
+            )
+        now = ph.t_end
         self.stats["busy_s"] += now - t0
         self._t_last_ready = now
-        with self._res_lock:  # watchdog beat + sweep EMA (masked path)
-            self._watch_beat = now
-            span = now - t0
-            self._sweep_ema_s = (
-                span if self._sweep_ema_s == 0.0
-                else 0.8 * self._sweep_ema_s + 0.2 * span
-            )
+        self._note_sweep(t0, now)
         self.stats["decode_steps"] += 1
-        for step in range(toks_h.shape[0]):
-            for i in active:
-                if self._slot_req[i] is None:
-                    continue  # finished earlier in this chunk
-                lp_info = None
-                if self._slot_req[i].request.logprobs:
-                    lp_info = (
-                        float(lps_h[step, i]),
+        with self._sched.phase("emit") as ph:
+            for step in range(toks_h.shape[0]):
+                for i in active:
+                    if self._slot_req[i] is None:
+                        continue  # finished earlier in this chunk
+                    lp_info = None
+                    if self._slot_req[i].request.logprobs:
                         # kvmini: sync-ok — lps/tids are host numpy already
-                        list(zip(tids_h[step, i].tolist(), tlps_h[step, i].tolist())),
-                    )
-                self._emit_token(i, int(toks_h[step, i]), now, lp_info)
+                        top = zip(tids_h[step, i].tolist(), tlps_h[step, i].tolist())
+                        lp_info = (float(lps_h[step, i]), list(top))
+                    self._emit_token(i, int(toks_h[step, i]), now, lp_info)
+        self._observe_phase("emit", ph.seconds)
         self._trace_engine_span(
             "engine.decode.window", t0, now,
             attrs={"chunk": 1, "slots": len(active), "mode": "masked"},
         )
-        self._observe_phase("emit", time.time() - now)
         if self._decode_active():
             self._bubble_anchor = now
 
@@ -3940,10 +3995,12 @@ class Engine:
                 # beat for a handoff to land instead (pre-disagg this
                 # state was unreachable: occupied slots were always
                 # decode-active or in the prefill fifo).
-                time.sleep(0.02)
+                with self._sched.phase("idle"):
+                    time.sleep(0.02)
                 return
             try:
-                handle = self._pending.get(timeout=0.02)
+                with self._sched.phase("idle"):
+                    handle = self._pending.get(timeout=0.02)
             except queue.Empty:
                 return
             if handle.cancelled is not None:
@@ -3960,31 +4017,35 @@ class Engine:
 
         while self._running:
             try:
-                with self._res_lock:
-                    pending = self._fault_pending
-                if pending is not None:
-                    # the watchdog declared a wedge while this thread was
-                    # stuck — drain the poisoned pipeline and degrade
-                    # BEFORE touching new work
-                    self._recover_engine_fault(pending)
-                    continue
-                self._schedule_once()
-                with self._res_lock:
-                    # watchdog beat: one full iteration IS progress (an
-                    # idle engine must never look wedged)
-                    self._watch_beat = time.time()
-                # republish the derived KV gauges from THIS thread so
-                # /metrics & /healthz (event-loop handlers) can read a
-                # consistent snapshot without ever blocking on a sweep;
-                # ~4 Hz is plenty for the monitor's 1 Hz scrape
-                with self._obs_lock:
-                    stale = time.time() - self._kv_gauges_t >= 0.25
-                if stale:
-                    self._kv_admin_snapshot()
-                    if self.paged:
-                        # host-RAM tier thrash guard rides the same
-                        # cadence as the gauge republish (~4 Hz)
-                        self._tier_thrash_tick()
+                # one iteration, gauge republish included: the ledger's
+                # outermost phase, so the phases' self times add up
+                # to this thread's wall time
+                with self._sched.phase("loop"):
+                    with self._res_lock:
+                        pending = self._fault_pending
+                    if pending is not None:
+                        # the watchdog declared a wedge while this thread was
+                        # stuck — drain the poisoned pipeline and degrade
+                        # BEFORE touching new work
+                        self._recover_engine_fault(pending)
+                        continue
+                    self._schedule_once()
+                    with self._res_lock:
+                        # watchdog beat: one full iteration IS progress (an
+                        # idle engine must never look wedged)
+                        self._watch_beat = _now()
+                    # republish the derived KV gauges from THIS thread so
+                    # /metrics & /healthz (event-loop handlers) can read a
+                    # consistent snapshot without ever blocking on a sweep;
+                    # ~4 Hz is plenty for the monitor's 1 Hz scrape
+                    with self._obs_lock:
+                        stale = time.time() - self._kv_gauges_t >= 0.25
+                    if stale:
+                        self._kv_admin_snapshot()
+                        if self.paged:
+                            # host-RAM tier thrash guard rides the same
+                            # cadence as the gauge republish (~4 Hz)
+                            self._tier_thrash_tick()
             except DeviceFault as exc:
                 # injected (or classified) dispatch-time device error:
                 # recoverable by design — fail the batch, degrade, keep
@@ -4089,7 +4150,7 @@ class Engine:
             threshold = max(
                 self.ecfg.watchdog_factor * ema, self.ecfg.watchdog_min_s
             )
-            stalled = time.time() - beat
+            stalled = _now() - beat
             if stalled < threshold or beat == tripped_beat:
                 continue
             tripped_beat = beat
@@ -4097,7 +4158,7 @@ class Engine:
                 f"watchdog: no sweep retired for {stalled:.2f}s "
                 f"(threshold {threshold:.2f}s, sweep EMA {ema:.3f}s)"
             )
-            now = time.time()
+            now = _now()
             faulted: list[str] = []
             for h in live:
                 # cancel first: the wedged sweep's retire (when the
@@ -4132,7 +4193,7 @@ class Engine:
         self._pending_steps = 0
         self._tokens_dev = None
         self._tokens_dev_slots = frozenset()
-        now = time.time()
+        now = _now()
         with self._res_lock:
             faulted = set(self._faulted_ids)
             self._faulted_ids.clear()
@@ -4260,7 +4321,7 @@ class Engine:
         # path to fix that is the wrong trade. Each read below carries its
         # own thread-ok so a NEW cross-thread surface still gets flagged.
         s = dict(self.stats)
-        wall = max(time.time() - s["started_at"], 1e-9)
+        wall = max(_now() - s["started_at"], 1e-9)
         s["duty_cycle"] = min(s["busy_s"] / wall, 1.0)
         # kvmini: thread-ok — benign racy snapshot (see above)
         s["active_slots"] = sum(1 for h in self._slot_req if h is not None)
@@ -4271,6 +4332,14 @@ class Engine:
         s["queue_depth"] = self._queue_depth()
         # kvmini: thread-ok — benign racy snapshot (see above)
         s["inflight_sweeps"] = len(self._inflight)
+        # the scheduler ledger (docs/TRACING.md): where this thread's time
+        # went, by phase — written by the scheduler thread only, and at
+        # worst one phase boundary stale here
+        sched = self._sched.snapshot()
+        s["sched_s"] = sched["seconds"]
+        s["sched_exposed_s"] = sched["exposed"]
+        s["sched_slow_s"] = sched["slow"]
+        s["sched_spans"] = sched["spans"]
         # Derived KV gauges (occupancy, fragmentation, retained fraction,
         # hit-depth percentiles) come from ONE consistent scheduler-thread
         # pass (_kv_admin_snapshot): a ratio built from independent
@@ -4366,6 +4435,17 @@ class Engine:
                 s["econ_wh_per_1k_tokens"] = econ["wh_per_1k_tokens"]
                 s["econ_tokens_per_sec"] = econ["tokens_per_sec"]
         return s
+
+    def _host_exposed(self) -> bool:
+        """The scheduler ledger's ``exposed`` test: work is live (a slot
+        occupied or a request waiting) and no decode sweep is in flight, so
+        whatever the engine thread does now, the device waits for it.
+        O(1): the ledger asks at every phase boundary — so from inside a
+        phase, on the scheduler thread that owns both lists."""
+        return not self._inflight and (
+            len(self._free) < self.ecfg.max_slots
+            or not self._pending.empty()
+        )
 
     def kv_bytes_per_token(self) -> int:
         """Physical KV bytes one cached position costs, parameterized by

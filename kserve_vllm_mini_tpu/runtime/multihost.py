@@ -353,7 +353,9 @@ def run_primary(engine: Engine, publisher: CommandPublisher,
 
     try:
         while not stop_event.is_set():
-            engine._schedule_once(on_decision=publish)
+            # the scheduler ledger's outermost phase, as in Engine._loop
+            with engine._sched.phase("loop"):
+                engine._schedule_once(on_decision=publish)
     except Exception as exc:  # noqa: BLE001 — propagate as request failures
         import traceback
 
@@ -367,37 +369,43 @@ def run_follower(engine: Engine, subscriber: CommandSubscriber) -> None:
     """Replay the primary's decision stream. Blocks until ('stop',)."""
     check_multihost_engine(engine)
     engine._lockstep = True
-    for cmd in subscriber.commands():
-        op = cmd[0]
-        if op == "admit":
-            # bypass submit(): the primary already applied truncation; the
-            # payload is the exact request its engine admitted
-            engine._admit_one(RequestHandle(req_from_payload(cmd[1])))
-        elif op == "sweep":
-            engine._decode_sweep()
-        elif op == "dispatch":
-            # double-buffered steady state (docs/DECODE_PIPELINE.md): the
-            # primary dispatched sweep N+1 before retiring sweep N. The
-            # active set is deterministic from the replayed stream, so the
-            # follower issues the identical jitted call with identical
-            # operands (the token feed is the previous sweep's on-device
-            # carry on both sides).
-            engine._replay_dispatch()
-        elif op == "retire":
-            engine._retire_one()
-        elif op == "cancel":
-            # mirror the primary's early finish so the follower's slot
-            # free-list stays identical for the replayed admissions
-            _rid, reason = cmd[1], cmd[2]
-            for slot in range(engine.ecfg.max_slots):
-                h = engine._slot_req[slot]
-                if h is not None and h.request.request_id == _rid:
-                    engine._finish_slot(slot, reason)
-                    break
-        elif op == "stop":
-            return
-        else:
-            raise ValueError(f"unknown multihost command {op!r}")
+    # the scheduler ledger on a follower: waiting for the primary is `idle`,
+    # replaying a command is `loop` and the phases it enters
+    commands = subscriber.commands()
+    while True:
+        with engine._sched.phase("idle"):
+            cmd = next(commands)
+        with engine._sched.phase("loop"):
+            op = cmd[0]
+            if op == "admit":
+                # bypass submit(): the primary already applied truncation; the
+                # payload is the exact request its engine admitted
+                engine._admit_one(RequestHandle(req_from_payload(cmd[1])))
+            elif op == "sweep":
+                engine._decode_sweep()
+            elif op == "dispatch":
+                # double-buffered steady state (docs/DECODE_PIPELINE.md): the
+                # primary dispatched sweep N+1 before retiring sweep N. The
+                # active set is deterministic from the replayed stream, so the
+                # follower issues the identical jitted call with identical
+                # operands (the token feed is the previous sweep's on-device
+                # carry on both sides).
+                engine._replay_dispatch()
+            elif op == "retire":
+                engine._retire_one()
+            elif op == "cancel":
+                # mirror the primary's early finish so the follower's slot
+                # free-list stays identical for the replayed admissions
+                _rid, reason = cmd[1], cmd[2]
+                for slot in range(engine.ecfg.max_slots):
+                    h = engine._slot_req[slot]
+                    if h is not None and h.request.request_id == _rid:
+                        engine._finish_slot(slot, reason)
+                        break
+            elif op == "stop":
+                return
+            else:
+                raise ValueError(f"unknown multihost command {op!r}")
 
 
 class PrimaryHandle:

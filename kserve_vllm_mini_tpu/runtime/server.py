@@ -1343,6 +1343,10 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             "# TYPE kvmini_tpu_prefill_chunk_stall_seconds_total counter",
             "kvmini_tpu_prefill_chunk_stall_seconds_total "
             f"{s['prefill_chunk_stall_s']:.6f}",
+            # what those pieces were padded to (their bucket sizes, target
+            # pieces only): with prefill_tokens_total, the padding's share
+            "# TYPE kvmini_tpu_prefill_bucket_tokens_total counter",
+            f"kvmini_tpu_prefill_bucket_tokens_total {s['prefill_bucket_tokens']}",
             # decode-pipeline telemetry (docs/DECODE_PIPELINE.md): depth >= 2
             # + low bubble = the double-buffered steady state is engaged
             "# TYPE kvmini_tpu_dispatch_depth gauge",
@@ -1562,6 +1566,35 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
                     "kvmini_tpu_econ_tokens_per_sec "
                     f"{s['econ_tokens_per_sec']:.6f}",
                 ]
+        # the scheduler ledger (docs/TRACING.md "Scheduler ledger"): the
+        # engine thread's time by phase — self time, the part of it with
+        # work live and nothing in flight, entries (seconds over entries: a
+        # phase's mean cost), and the self time of entries that took longer
+        # than two decode sweeps (at least 50 ms). Spelled out family by
+        # family: kvmini-lint pairs a stats key with its series through
+        # the literal f-string (KVM031)
+        lines.append("# TYPE kvmini_tpu_sched_seconds_total counter")
+        lines += [
+            f"kvmini_tpu_sched_seconds_total{{phase=\"{p}\"}} {s['sched_s'][p]:.6f}"
+            for p in s["sched_s"]
+        ]
+        lines.append("# TYPE kvmini_tpu_sched_exposed_seconds_total counter")
+        lines += [
+            f"kvmini_tpu_sched_exposed_seconds_total{{phase=\"{p}\"}} "
+            f"{s['sched_exposed_s'][p]:.6f}"
+            for p in s["sched_exposed_s"]
+        ]
+        lines.append("# TYPE kvmini_tpu_sched_slow_seconds_total counter")
+        lines += [
+            f"kvmini_tpu_sched_slow_seconds_total{{phase=\"{p}\"}} "
+            f"{s['sched_slow_s'][p]:.6f}"
+            for p in s["sched_slow_s"]
+        ]
+        lines.append("# TYPE kvmini_tpu_sched_spans_total counter")
+        lines += [
+            f"kvmini_tpu_sched_spans_total{{phase=\"{p}\"}} {s['sched_spans'][p]}"
+            for p in s["sched_spans"]
+        ]
         # per-phase latency histograms (docs/TRACING.md): queue / prefill /
         # decode / emit durations the engine observes at phase transitions
         from kserve_vllm_mini_tpu.runtime.tracing import render_phase_histograms

@@ -22,13 +22,33 @@ Design constraints (the overhead guard, pinned by tests/test_tracing.py):
 Phase histograms (``kvmini_tpu_phase_seconds``) live here too: plain
 cumulative-bucket counters the engine observes once per phase transition
 and /metrics renders in Prometheus histogram exposition.
+
+So does the scheduler ledger (``SchedLedger``): the one place where the
+engine thread's own time is recorded, phase by phase, as spans on the
+profiler's clock and as ``kvmini_tpu_sched_*`` counters.
 """
 
 from __future__ import annotations
 
+import contextlib
 import secrets
+import time
 from collections import deque
-from typing import Any, Iterable, Optional
+from typing import Any, Callable, Iterable, Optional
+
+# The one clock every duration of the runtime is taken from (the ledger,
+# the phase histograms, the request timestamps that feed them): monotonic,
+# so a stepped wall clock can never produce a negative or inflated phase.
+# /traces keeps Unix nanoseconds through ONE offset per engine
+# (``unix_offset_ns``, captured at engine start).
+now_s = time.perf_counter
+now_ns = time.perf_counter_ns
+
+
+def unix_offset_ns() -> int:
+    """What to add to a ``now_ns`` reading to get Unix nanoseconds."""
+    return time.time_ns() - now_ns()
+
 
 # the engine's per-request span ceiling: server.queue + server.handoff
 # (disaggregated admissions only, docs/DISAGGREGATION.md) +
@@ -259,6 +279,144 @@ class PhaseHistogram:
             total += c
             cum.append(total)
         return {"buckets": cum, "sum": self.sum, "count": self.count}
+
+
+# the phases of the engine thread (docs/TRACING.md "Scheduler ledger"):
+# each is a ``kvmini.<phase>`` span on the profiler's clock and one label
+# value of the kvmini_tpu_sched_* counters. They nest as the calls do.
+SCHED_PHASES = (
+    "loop", "idle", "admit", "prefill", "first_token", "decode_prepare",
+    "decode_dispatch", "decode_retire", "emit", "spec_sweep",
+    "masked_sweep", "handoff",
+)
+
+_SPAN_NAMES = {p: "kvmini." + p for p in SCHED_PHASES}
+
+# an entry is a stall, not work, once its own time passes twice the wall of
+# a decode sweep (``sweep_s``: the engine's rolling dispatch -> retire time,
+# which scales with the model, ``decode_chunk`` and the pipeline's depth; the
+# longest ordinary wait of any phase is for one sweep), and never under 50 ms
+SCHED_SLOW_FLOOR_NS = 50_000_000
+SCHED_SLOW_SWEEPS = 2.0
+
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _no_annotation(_name: str, **_args: Any):
+    return _NO_SPAN
+
+
+class _SchedPhase:
+    """One entry of one phase: ``with ledger.phase(name) as ph``. After the
+    block, ``ph.seconds`` is the entry's whole duration and ``ph.t_start`` /
+    ``ph.t_end`` the ``now_s`` instants it began and ended — what the
+    engine's older counters (``busy_s``, the ``emit`` histogram) take
+    instead of timing the same interval again."""
+
+    __slots__ = ("_ledger", "name", "_span", "_own_ns", "_t0_ns", "_t1_ns")
+
+    def __init__(self, ledger: "SchedLedger", name: str, span: Any) -> None:
+        self._ledger, self.name, self._span = ledger, name, span
+        self._own_ns = self._t0_ns = self._t1_ns = 0
+
+    def __enter__(self) -> "_SchedPhase":
+        self._span.__enter__()
+        self._t0_ns = self._ledger._switch(self, entering=True)
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self._t1_ns = self._ledger._switch(self, entering=False)
+        self._span.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        return (self._t1_ns - self._t0_ns) * 1e-9
+
+    @property
+    def t_start(self) -> float:
+        return self._t0_ns * 1e-9
+
+    @property
+    def t_end(self) -> float:
+        return self._t1_ns * 1e-9
+
+
+class SchedLedger:
+    """Where the scheduler thread's time goes, kept in one place.
+
+    The engine enters a phase as a context manager. Entering opens
+    ``annotate("kvmini.<phase>", **args)`` — the engine hands in
+    ``jax.profiler.TraceAnnotation``, so the span sits on the device
+    trace's clock; the default is a no-op and this module stays JAX-free —
+    and every entry or exit charges the time since the previous one to the
+    innermost open phase. A phase is therefore charged its SELF time (its
+    duration less its children's), and the self times of all phases
+    partition the thread's wall time: their sum over an interval is that
+    interval. Time with no phase open is charged to nothing (the engine
+    keeps ``loop`` open around every iteration).
+
+    Per phase: ``seconds`` (self time), ``exposed`` (the part of it spent
+    while ``exposed()`` held when the stretch began: work live and no
+    decode sweep in flight, so the device had nothing queued), ``spans``
+    (entries) and ``slow`` (self time of the entries whose own time passed
+    ``SCHED_SLOW_SWEEPS`` x ``sweep_s()``, at least ``SCHED_SLOW_FLOOR_NS``).
+    Written by the one thread that enters phases; a snapshot from another
+    thread is at worst one stretch stale."""
+
+    def __init__(
+        self,
+        annotate: Optional[Callable[..., Any]] = None,
+        exposed: Optional[Callable[[], bool]] = None,
+        sweep_s: Optional[Callable[[], float]] = None,
+    ) -> None:
+        self._annotate = annotate or _no_annotation
+        self._exposed = exposed or (lambda: False)
+        self._sweep_s = sweep_s or (lambda: 0.0)
+        self._stack: list[_SchedPhase] = []
+        self._mark_ns = 0
+        self._mark_exposed = False
+        self._self_ns = dict.fromkeys(SCHED_PHASES, 0)
+        self._exposed_ns = dict.fromkeys(SCHED_PHASES, 0)
+        self._slow_ns = dict.fromkeys(SCHED_PHASES, 0)
+        self._spans = dict.fromkeys(SCHED_PHASES, 0)
+
+    def phase(self, name: str, **args: Any) -> _SchedPhase:
+        """``args`` become the span's arguments: only for the rare phases
+        (a few a second) — the annotation formats them whether or not a
+        profiler is listening."""
+        return _SchedPhase(self, name, self._annotate(_SPAN_NAMES[name], **args))
+
+    def _switch(self, ph: _SchedPhase, entering: bool) -> int:
+        now = now_ns()
+        if self._stack:
+            top = self._stack[-1]
+            took = now - self._mark_ns
+            top._own_ns += took
+            self._self_ns[top.name] += took
+            if self._mark_exposed:
+                self._exposed_ns[top.name] += took
+        if entering:
+            self._stack.append(ph)
+            self._spans[ph.name] += 1
+        else:
+            self._stack.pop()
+            # the sweep wall is asked only about the few entries over the floor
+            if (ph._own_ns > SCHED_SLOW_FLOOR_NS
+                    and ph._own_ns > SCHED_SLOW_SWEEPS * 1e9 * self._sweep_s()):
+                self._slow_ns[ph.name] += ph._own_ns
+        self._mark_ns = now
+        self._mark_exposed = self._exposed()
+        return now
+
+    def snapshot(self) -> dict[str, dict[str, float]]:
+        """``seconds`` / ``exposed`` / ``slow`` in seconds and ``spans`` as
+        counts, each by phase, as /metrics exports them."""
+        return {
+            "seconds": {p: v * 1e-9 for p, v in self._self_ns.items()},
+            "exposed": {p: v * 1e-9 for p, v in self._exposed_ns.items()},
+            "slow": {p: v * 1e-9 for p, v in self._slow_ns.items()},
+            "spans": dict(self._spans),
+        }
 
 
 def render_phase_histograms(

@@ -35,6 +35,7 @@ from kserve_vllm_mini_tpu.runtime.engine import (
     GenRequest,
     RequestHandle,
 )
+from kserve_vllm_mini_tpu.runtime.tracing import now_s
 
 CFG = get_config("llama-tiny")
 
@@ -221,6 +222,7 @@ def _harness(slots=2):
     eng._faults = FaultRegistry()
     eng._faulted_ids = set()
     eng._phase_hist = {p: rt_tracing.PhaseHistogram() for p in rt_tracing.PHASES}
+    eng._sched = rt_tracing.SchedLedger()
     eng.stats = {"requests_completed": 0, "queue_depth": 0}
     eng._slot_req = [None] * slots
     eng._slot_machine = [None] * slots
@@ -252,7 +254,7 @@ def _harness(slots=2):
 
 def _route(eng, slot, rid="r1"):
     h = RequestHandle(GenRequest(prompt_tokens=[1, 2, 3], request_id=rid))
-    h.t_admit = time.time()
+    h.t_admit = now_s()
     eng._slot_req[slot] = h
     eng._slot_handoff[slot] = {"handle": h, "t_route": h.t_admit}
     return h
@@ -320,7 +322,7 @@ def test_orphan_handoff_dropped_by_identity_check():
     stray = RequestHandle(GenRequest(prompt_tokens=[1, 2, 3]))
     ho = KVHandoff(version=HANDOFF_VERSION, request_id="x", handle=stray,
                    n_tokens=3, n_blocks=1, busy_s=0.5, kv={}, logits=None)
-    ho.t_enqueued = time.time()
+    ho.t_enqueued = now_s()
     with lane._lock:
         lane._inflight += 1
     lane._ready.put(ho)
@@ -860,7 +862,7 @@ def test_version_negotiation_paged_refuses_v1_stripe():
     ho = KVHandoff(version=DENSE_HANDOFF_VERSION, request_id="r1",
                    handle=h, n_tokens=3, n_blocks=1, busy_s=0.25,
                    kv={}, logits=None)
-    ho.t_enqueued = time.time()
+    ho.t_enqueued = now_s()
     with lane._lock:
         lane._inflight += 1
     lane._ready.put(ho)

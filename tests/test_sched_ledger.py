@@ -1,0 +1,558 @@
+"""The scheduler ledger (runtime/tracing.py ``SchedLedger``, docs/TRACING.md):
+the engine thread's time by phase, as spans on the profiler's clock and as
+``kvmini_tpu_sched_*`` counters.
+
+The arithmetic runs on a hand-driven clock; the books are then balanced on
+tiny engines serving a few requests on the CPU. The last tests pin, by name,
+what the benchmark reads from the program (bench/trace_reduce.py,
+bench/readers/): span names, program names, counter names.
+"""
+
+import asyncio
+import subprocess
+import sys
+import time
+
+import pytest
+
+from kserve_vllm_mini_tpu.runtime import tracing as rt_tracing
+from kserve_vllm_mini_tpu.runtime.tracing import SCHED_PHASES, SchedLedger
+
+MS = 1_000_000
+
+
+class Clock:
+    """``now_ns`` under the test's hand."""
+
+    def __init__(self) -> None:
+        self.ns = 5_000 * MS
+
+    def __call__(self) -> int:
+        return self.ns
+
+    def tick(self, ms: float) -> None:
+        self.ns += int(ms * MS)
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = Clock()
+    monkeypatch.setattr(rt_tracing, "now_ns", c)
+    return c
+
+
+# -- the arithmetic -----------------------------------------------------------
+
+
+def test_tracing_imports_without_jax():
+    code = ("import sys; sys.modules['jax'] = None; "
+            "import kserve_vllm_mini_tpu.runtime.tracing as t; "
+            "led = t.SchedLedger(); "
+            "exec('with led.phase(\"loop\"): pass'); "
+            "print(led.snapshot()['spans']['loop'])")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "1"
+
+
+def test_child_time_is_taken_from_its_parent(clock):
+    led = SchedLedger()
+    with led.phase("loop") as loop:
+        clock.tick(2)
+        with led.phase("admit") as admit:
+            clock.tick(3)
+            with led.phase("prefill") as prefill:
+                clock.tick(40)
+            clock.tick(1)
+        clock.tick(4)
+    s = led.snapshot()
+    assert s["seconds"]["loop"] == pytest.approx(0.006)
+    assert s["seconds"]["admit"] == pytest.approx(0.004)
+    assert s["seconds"]["prefill"] == pytest.approx(0.040)
+    assert s["spans"]["loop"] == s["spans"]["admit"] == s["spans"]["prefill"] == 1
+    # an entry's own numbers are its WHOLE duration and its two instants
+    assert loop.seconds == pytest.approx(0.050)
+    assert admit.seconds == pytest.approx(0.044)
+    assert prefill.seconds == pytest.approx(0.040)
+    assert admit.t_start == pytest.approx(5.002) and admit.t_end == pytest.approx(5.046)
+
+
+def test_self_times_partition_the_interval(clock):
+    """Whatever the nesting, the self times add up to the time between the
+    first entry and the last exit: nothing is counted twice, nothing lost."""
+    led = SchedLedger()
+    t0 = clock.ns
+    for i in range(7):
+        with led.phase("loop"):
+            clock.tick(0.3)
+            if i % 2:
+                with led.phase("admit"):
+                    clock.tick(0.7)
+                    with led.phase("prefill"):
+                        clock.tick(11 + i)
+                    with led.phase("first_token"):
+                        clock.tick(1.1)
+            with led.phase("decode_prepare"):
+                clock.tick(0.2)
+                with led.phase("decode_dispatch"):
+                    clock.tick(0.5)
+            with led.phase("decode_retire"):
+                clock.tick(19)
+            with led.phase("emit"):
+                clock.tick(0.4)
+    s = led.snapshot()
+    assert sum(s["seconds"].values()) == pytest.approx((clock.ns - t0) * 1e-9, rel=1e-9)
+    assert s["spans"]["loop"] == 7 and s["spans"]["prefill"] == 3
+    assert set(s["seconds"]) == set(SCHED_PHASES)
+
+
+def test_exposed_splits_on_inflight(clock):
+    """``exposed`` is asked where each stretch of self time begins: the part
+    of a phase after a dispatch is no longer exposed."""
+    inflight = []
+    led = SchedLedger(exposed=lambda: not inflight)
+    with led.phase("loop"):
+        clock.tick(1)                      # exposed: nothing in flight
+        with led.phase("decode_prepare"):
+            clock.tick(2)                  # exposed
+            with led.phase("decode_dispatch"):
+                clock.tick(3)              # exposed: entered with nothing in flight
+                inflight.append("sweep")
+            clock.tick(4)                  # not exposed
+        clock.tick(5)                      # not exposed
+        inflight.clear()
+        with led.phase("emit"):
+            clock.tick(6)                  # exposed again
+    s = led.snapshot()
+    assert s["exposed"]["loop"] == pytest.approx(0.001)
+    assert s["seconds"]["loop"] == pytest.approx(0.006)
+    assert s["exposed"]["decode_prepare"] == pytest.approx(0.002)
+    assert s["seconds"]["decode_prepare"] == pytest.approx(0.006)
+    assert s["exposed"]["decode_dispatch"] == pytest.approx(0.003)
+    assert s["exposed"]["emit"] == pytest.approx(0.006)
+
+
+@pytest.mark.parametrize("own_ms,slow", [(49.0, False), (51.0, True)])
+def test_slow_threshold_is_50ms_of_own_time(clock, own_ms, slow):
+    led = SchedLedger()
+    with led.phase("emit"):
+        clock.tick(own_ms)
+    assert led.snapshot()["slow"]["emit"] == pytest.approx(own_ms / 1e3 if slow else 0.0)
+
+
+@pytest.mark.parametrize("sweep_ms,own_ms,slow", [
+    (10.0, 51.0, True),       # short sweeps: the 50 ms floor holds
+    (41.0, 70.0, False),      # the benchmark's models, two sweeps in flight: 82 ms
+    (41.0, 101.0, True),      # a 0.1 s stall still counts there
+    (300.0, 400.0, False),    # a large model or decode_chunk: a sweep's wait is work
+    (300.0, 601.0, True),
+])
+def test_slow_threshold_scales_with_the_sweep_wall(clock, sweep_ms, own_ms, slow):
+    """``sweep_s`` is the engine's rolling dispatch -> retire wall: an entry is a
+    stall once its own time passes two of them, whatever the model's step."""
+    led = SchedLedger(sweep_s=lambda: sweep_ms / 1e3)
+    with led.phase("decode_retire"):
+        clock.tick(own_ms)
+    got = led.snapshot()["slow"]["decode_retire"]
+    assert got == pytest.approx(own_ms / 1e3 if slow else 0.0)
+    assert rt_tracing.SCHED_SLOW_SWEEPS == 2.0 and rt_tracing.SCHED_SLOW_FLOOR_NS == 50 * MS
+
+
+def test_sweep_wall_is_asked_only_over_the_floor(clock):
+    asked = []
+    led = SchedLedger(sweep_s=lambda: asked.append(1) or 0.0)
+    for own_ms in (0.2, 19.0, 49.0):
+        with led.phase("decode_retire"):
+            clock.tick(own_ms)
+    assert not asked
+    with led.phase("decode_retire"):
+        clock.tick(60)
+    assert asked == [1]
+
+
+def test_a_long_child_does_not_make_its_parent_slow(clock):
+    led = SchedLedger()
+    with led.phase("admit"):
+        clock.tick(2)
+        with led.phase("prefill"):
+            clock.tick(120)
+        clock.tick(2)
+    s = led.snapshot()
+    assert s["slow"]["admit"] == 0.0
+    assert s["slow"]["prefill"] == pytest.approx(0.120)
+
+
+def test_annotation_factory_gets_names_and_args():
+    seen = []
+
+    class Span:
+        def __init__(self, name, **args):
+            seen.append([name, args, "made"])
+            self._rec = seen[-1]
+
+        def __enter__(self):
+            self._rec[2] = "open"
+
+        def __exit__(self, *exc):
+            self._rec[2] = "closed"
+
+    led = SchedLedger(annotate=Span)
+    with led.phase("prefill", request_id="r1", prompt_tokens=37, bucket=64, offset=0):
+        assert seen[0][2] == "open"
+        with led.phase("decode_retire"):
+            pass
+    assert seen == [
+        ["kvmini.prefill",
+         {"request_id": "r1", "prompt_tokens": 37, "bucket": 64, "offset": 0}, "closed"],
+        ["kvmini.decode_retire", {}, "closed"],
+    ]
+
+
+def test_an_exception_closes_the_phase(clock):
+    led = SchedLedger()
+    with pytest.raises(RuntimeError):
+        with led.phase("loop"):
+            with led.phase("decode_prepare"):
+                clock.tick(1)
+                raise RuntimeError("device fault")
+    with led.phase("loop"):
+        clock.tick(2)
+    s = led.snapshot()
+    assert s["seconds"]["loop"] == pytest.approx(0.002)
+    assert s["seconds"]["decode_prepare"] == pytest.approx(0.001)
+
+
+# -- the books of a serving engine --------------------------------------------
+
+jax = pytest.importorskip("jax")
+
+from kserve_vllm_mini_tpu.models.config import get_config  # noqa: E402
+from kserve_vllm_mini_tpu.models.llama import init_params  # noqa: E402
+from kserve_vllm_mini_tpu.runtime.engine import (  # noqa: E402
+    Engine,
+    EngineConfig,
+    GenRequest,
+)
+
+CFG = get_config("llama-tiny")
+CORE = ("loop", "idle", "admit", "prefill", "first_token", "decode_prepare",
+        "decode_dispatch", "decode_retire", "emit")
+
+
+class ForcedSequence:
+    """A grammar that allows exactly one token a step: the masked sweep."""
+
+    def __init__(self, seq):
+        self.seq, self.i = list(seq), 0
+
+    @property
+    def done(self):
+        return self.i >= len(self.seq)
+
+    def min_close(self):
+        return len(self.seq) - self.i
+
+    def token_mask(self, budget):
+        import numpy as np
+
+        mask = np.zeros((CFG.vocab_size,), dtype=bool)
+        mask[self.seq[self.i]] = True
+        return mask
+
+    def advance_token(self, tid):
+        self.i += 1
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+def serve(params, requests, drafter=False, **ecfg):
+    """A tiny engine, its ledger handed a recording annotation factory,
+    serving ``requests`` to the end. Returns the engine (stopped), the spans
+    the factory saw as (name, args), and the engine thread's wall time as
+    the spans' own clock reads it: first ``loop`` entered -> last one left."""
+    eng = Engine(
+        params, CFG,
+        EngineConfig(max_slots=2, max_seq_len=128, max_prefill_len=64,
+                     min_prefill_bucket=16, **ecfg),
+        drafter=(params, CFG) if drafter else None,
+    )
+    spans, loops = [], []
+
+    class Span:
+        def __init__(self, name, **args):
+            spans.append((name, args))
+            self.is_loop = name == "kvmini.loop"
+
+        def __enter__(self):
+            if self.is_loop:
+                loops.append(time.perf_counter())
+
+        def __exit__(self, *exc):
+            if self.is_loop:
+                loops.append(time.perf_counter())
+
+    eng._sched = SchedLedger(annotate=Span, exposed=eng._host_exposed,
+                             sweep_s=lambda: eng._sweep_ema_s)
+    # queued before the thread starts: every admission finds work live (a
+    # request waiting or a slot occupied), whatever the threads' timing
+    handles = [eng.submit(r) for r in requests]
+    eng.start()
+    try:
+        for h in handles:
+            while h.events.get(timeout=120)[0] != "done":
+                pass
+        time.sleep(0.05)            # a few idle waits
+    finally:
+        eng.stop()
+    return eng, spans, loops[-1] - loops[0]
+
+
+@pytest.fixture(scope="module")
+def plain(params):
+    unix_before = time.time_ns()
+    eng, spans, wall = serve(params, [
+        GenRequest(prompt_tokens=[3, 1, 4, 1, 5], max_new_tokens=12, request_id="a"),
+        GenRequest(prompt_tokens=list(range(1, 38)), max_new_tokens=12, request_id="b"),
+        GenRequest(prompt_tokens=[2, 7], max_new_tokens=8, request_id="c"),
+        GenRequest(prompt_tokens=[7, 8], max_new_tokens=8, request_id="d",
+                   constraint=ForcedSequence([40, 41, 42])),
+    ])
+    return {"eng": eng, "spans": spans, "wall": wall, "stats": eng.snapshot_stats(),
+            "unix": (unix_before, time.time_ns())}
+
+
+def test_self_times_sum_to_the_engine_threads_wall_time(plain):
+    """The acceptance criterion: the phases' self times add up to the wall
+    time of the thread that entered them, within 2%."""
+    total = sum(plain["stats"]["sched_s"].values())
+    assert total == pytest.approx(plain["wall"], rel=0.02)
+    assert total <= plain["wall"]      # the spans enclose what is counted
+
+
+def test_engine_phases_and_counts(plain):
+    s = plain["stats"]
+    names = {name for name, _ in plain["spans"]}
+    assert names == {f"kvmini.{p}" for p in CORE + ("masked_sweep",)}
+    assert s["sched_spans"]["admit"] == 4 == s["prefills"]
+    assert s["sched_spans"]["prefill"] == s["prefill_chunks"] == 4
+    assert s["sched_spans"]["first_token"] == 4
+    assert s["sched_spans"]["decode_retire"] == s["sched_spans"]["emit"] > 0
+    assert s["sched_spans"]["masked_sweep"] >= 2
+    for p in SCHED_PHASES:
+        assert 0.0 <= s["sched_exposed_s"][p] <= s["sched_s"][p] + 1e-9
+        assert s["sched_slow_s"][p] <= s["sched_s"][p] + 1e-9
+    # a wait for work and a device wait are never exposed HOST time
+    assert s["sched_exposed_s"]["idle"] == 0.0
+    # admissions run with the pipeline settled: all of it is exposed
+    assert s["sched_exposed_s"]["admit"] == pytest.approx(s["sched_s"]["admit"])
+
+
+def test_span_args_only_on_the_rare_phases(plain):
+    by_name = {}
+    for name, args in plain["spans"]:
+        by_name.setdefault(name, []).append(args)
+    assert all(not a for n in ("loop", "idle", "decode_prepare", "decode_dispatch",
+                               "decode_retire", "emit", "first_token")
+               for a in by_name[f"kvmini.{n}"])
+    prefill = {a["request_id"]: a for a in by_name["kvmini.prefill"]}
+    assert prefill["b"] == {"request_id": "b", "prompt_tokens": 37, "bucket": 64, "offset": 0}
+    assert prefill["a"]["bucket"] == 16 and prefill["a"]["prompt_tokens"] == 5
+    admit = {a["request_id"]: a for a in by_name["kvmini.admit"]}
+    assert admit["b"] == {"request_id": "b", "prompt_tokens": 37}
+
+
+def test_bucket_tokens_count_the_padding(plain):
+    s = plain["stats"]
+    assert s["prefill_tokens"] == 5 + 37 + 2 + 2
+    assert s["prefill_bucket_tokens"] == 16 + 64 + 16 + 16
+
+
+def test_older_counters_take_the_ledgers_durations(plain):
+    """One stopwatch an interval: the ``emit`` histogram IS the ``emit``
+    phase, on the masked path too (``emit`` has no child phase, so its self
+    time is its whole duration), and ``busy_s`` holds every prefill and
+    first-token phase whole."""
+    eng, s = plain["eng"], plain["stats"]
+    hist = eng.snapshot_phase_hist()
+    assert hist["emit"]["count"] == s["sched_spans"]["emit"]
+    assert hist["emit"]["sum"] == pytest.approx(s["sched_s"]["emit"], abs=1e-6)
+    assert s["busy_s"] >= s["sched_s"]["prefill"] + s["sched_s"]["first_token"] - 1e-9
+    assert s["busy_s"] <= plain["wall"]
+
+
+def test_synchronous_sweeps_enter_the_plain_paths_child_phases(params, plain):
+    """``masked_sweep`` and ``spec_sweep`` keep no stopwatch of their own:
+    each sweep enters ``decode_dispatch``, ``decode_retire`` and ``emit`` once,
+    like a plain step, and ``busy_s`` and the ``emit`` histogram read those."""
+    s = plain["stats"]
+    masked = s["sched_spans"]["masked_sweep"]
+    # every decode step of the plain engine, masked or not, was read back once
+    assert s["sched_spans"]["decode_retire"] == s["decode_steps"] >= masked >= 2
+    assert s["sched_spans"]["decode_dispatch"] >= s["sched_spans"]["decode_retire"]
+    spec, _, _ = serve(params, [GenRequest(prompt_tokens=[3, 1, 4], max_new_tokens=8)],
+                       drafter=True, spec_tokens=2)
+    t = spec.snapshot_stats()
+    rounds = t["sched_spans"]["spec_sweep"]
+    assert rounds == t["spec_rounds"] > 0
+    for child in ("decode_dispatch", "decode_retire", "emit"):
+        assert t["sched_spans"][child] == rounds
+    hist = spec.snapshot_phase_hist()["emit"]
+    assert hist["count"] == rounds
+    assert hist["sum"] == pytest.approx(t["sched_s"]["emit"], abs=1e-6)
+    # the rounds feed the sweep wall the stall threshold scales from
+    assert spec._sweep_ema_s > 0.0
+    assert t["busy_s"] >= t["sched_s"]["decode_retire"] - 1e-9
+
+
+def test_traces_keep_unix_time_through_one_offset(plain):
+    """Every duration is taken on the monotonic clock; /traces still speaks
+    Unix nanoseconds."""
+    eng = plain["eng"]
+    lo, hi = plain["unix"]
+    spans = eng.traces_otlp()["resourceSpans"][0]["scopeSpans"][0]["spans"]
+    assert {s["name"] for s in spans} >= {"server.queue", "server.prefill", "server.decode"}
+    for s in spans:
+        assert lo <= int(s["startTimeUnixNano"]) <= int(s["endTimeUnixNano"]) <= hi
+    # the histograms saw the same monotonic durations: no negative, no epoch
+    for phase in ("queue", "prefill", "decode"):
+        h = eng.snapshot_phase_hist()[phase]
+        assert h["count"] == 4 and 0.0 <= h["sum"] < 4 * plain["wall"]
+
+
+def test_spec_and_handoff_phases_complete_the_table(params, plain):
+    """The phases no benchmark cell runs are there so that the partition is
+    whole: a drafter's engine shows ``spec_sweep``, a disaggregated one
+    ``handoff``; with the plain engine's, that is every name of the table
+    and no other."""
+    req = [GenRequest(prompt_tokens=[3, 1, 4], max_new_tokens=8)]
+    spec, spec_spans, spec_wall = serve(params, req, drafter=True, spec_tokens=2)
+    assert spec.snapshot_stats()["sched_spans"]["spec_sweep"] == spec.stats["spec_rounds"] > 0
+    lane, lane_spans, lane_wall = serve(
+        params, [GenRequest(prompt_tokens=list(range(1, 41)), max_new_tokens=4)],
+        disagg=True, disagg_min_prompt=8)
+    assert lane.stats["kv_handoffs"] == 1
+    assert lane.snapshot_stats()["sched_spans"]["handoff"] > 0
+    seen = {n for n, _ in plain["spans"] + spec_spans + lane_spans}
+    assert seen == {f"kvmini.{p}" for p in SCHED_PHASES}
+    for eng, wall in ((spec, spec_wall), (lane, lane_wall)):
+        assert sum(eng.snapshot_stats()["sched_s"].values()) == pytest.approx(wall, rel=0.02)
+
+
+def test_multihost_drivers_keep_the_partition(params, monkeypatch):
+    """The lockstep drivers run the scheduler outside ``Engine._loop``: the
+    primary opens ``loop`` around every iteration, the follower books its wait
+    for the next command as ``idle`` and the replay as ``loop``, so the self
+    times add up to the driver's wall time there too."""
+    import threading
+
+    from kserve_vllm_mini_tpu.runtime import multihost
+    from kserve_vllm_mini_tpu.runtime.multihost import run_follower, run_primary
+
+    # one process, no process-spanning mesh: the drivers' loops are the same
+    monkeypatch.setattr(multihost, "check_multihost_engine", lambda eng: None)
+
+    def engine():
+        eng = Engine(params, CFG, EngineConfig(max_slots=2, max_seq_len=128,
+                                               max_prefill_len=64, min_prefill_bucket=16))
+        edges = []
+
+        class Span:
+            def __init__(self, name, **args):
+                pass
+
+            def __enter__(self):
+                edges.append(time.perf_counter())
+
+            def __exit__(self, *exc):
+                edges.append(time.perf_counter())
+
+        eng._sched = SchedLedger(annotate=Span, exposed=eng._host_exposed)
+        return eng, edges
+
+    class Wire:
+        def __init__(self):
+            self.sent = []
+
+        def publish(self, decision):
+            self.sent.append(decision)
+
+        def commands(self):
+            return iter(self.sent)
+
+    wire, stop = Wire(), threading.Event()
+    primary, p_edges = engine()
+    driver = threading.Thread(target=run_primary, args=(primary, wire, stop))
+    driver.start()
+    try:
+        handles = [primary.submit(GenRequest(prompt_tokens=[3, 1, 4, 1], max_new_tokens=10)),
+                   primary.submit(GenRequest(prompt_tokens=[2, 7, 1], max_new_tokens=6))]
+        for h in handles:
+            while h.events.get(timeout=120)[0] != "done":
+                pass
+    finally:
+        stop.set()
+        driver.join(timeout=60)
+    assert wire.sent[-1] == ("stop",)
+    follower, f_edges = engine()
+    run_follower(follower, wire)
+    for eng, edges in ((primary, p_edges), (follower, f_edges)):
+        s = eng.snapshot_stats()
+        assert sum(s["sched_s"].values()) == pytest.approx(edges[-1] - edges[0], rel=0.02)
+        assert s["sched_spans"]["loop"] > 0 and s["sched_spans"]["first_token"] == 2
+    assert follower.snapshot_stats()["sched_spans"]["idle"] == len(wire.sent)
+    assert follower.stats["decode_tokens"] == primary.stats["decode_tokens"] > 0
+
+
+# -- what the benchmark reads by name -----------------------------------------
+
+
+def test_names_the_benchmark_reads(plain):
+    """bench/trace_reduce.py names idle gaps by these two spans and finds the
+    programs by these jit names; bench/readers/ read these series from
+    /metrics. A rename here is a change to the yardstick."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from kserve_vllm_mini_tpu.runtime.server import make_app
+    from kserve_vllm_mini_tpu.runtime.tokenizer import load_tokenizer
+
+    eng = plain["eng"]
+    assert {"kvmini.decode_dispatch", "kvmini.decode_retire"} <= {n for n, _ in plain["spans"]}
+
+    def jit_name(fn):
+        """The name XLA gives the program (``jit_<name>``): that of the
+        jitted function under the compile-statistics wrapper."""
+        return getattr(fn, "_fn", fn).__name__
+
+    assert jit_name(eng._get_decode_fn(1)) == "decode"
+    assert jit_name(eng._get_prefill_fn(16)) == "prefill"
+    assert jit_name(eng._get_first_fn()) == "first"
+
+    async def scrape():
+        async with TestClient(TestServer(make_app(eng, load_tokenizer(None), "llama-tiny"))) as c:
+            return await (await c.get("/metrics")).text()
+
+    values = {}
+    for line in asyncio.run(scrape()).splitlines():
+        if line and not line.startswith("#") and len(line.split()) == 2:
+            values[line.split()[0]] = float(line.split()[1])
+    s = plain["stats"]
+    assert values["kvmini_tpu_decode_steps_total"] == s["decode_steps"] > 0
+    assert values["kvmini_tpu_decode_tokens_total"] == s["decode_tokens"] > 0
+    assert values["kvmini_tpu_prefills_total"] == 4
+    assert values["kvmini_tpu_prefill_tokens_total"] == 46
+    assert values["kvmini_tpu_prefill_bucket_tokens_total"] == 112
+    for p in SCHED_PHASES:
+        assert values[f'kvmini_tpu_sched_seconds_total{{phase="{p}"}}'] == pytest.approx(
+            s["sched_s"][p], abs=1e-6)
+        assert values[f'kvmini_tpu_sched_exposed_seconds_total{{phase="{p}"}}'] == pytest.approx(
+            s["sched_exposed_s"][p], abs=1e-6)
+        assert values[f'kvmini_tpu_sched_slow_seconds_total{{phase="{p}"}}'] == pytest.approx(
+            s["sched_slow_s"][p], abs=1e-6)
+        assert values[f'kvmini_tpu_sched_spans_total{{phase="{p}"}}'] == s["sched_spans"][p]
+    for phase in ("queue", "prefill"):
+        assert values[f'kvmini_tpu_phase_seconds_count{{phase="{phase}"}}'] == 4
+        assert f'kvmini_tpu_phase_seconds_sum{{phase="{phase}"}}' in values

@@ -541,3 +541,8 @@ def test_engine_generation_stamps_phase_spans():
     hist = eng.snapshot_phase_hist()
     for phase in ("queue", "prefill", "decode"):
         assert hist[phase]["count"] == 3
+    # the emit histogram has no stopwatch of its own: it is fed the
+    # scheduler ledger's ``emit`` phase, entry for entry
+    sched = eng.snapshot_stats()
+    assert hist["emit"]["count"] == sched["sched_spans"]["emit"] > 0
+    assert hist["emit"]["sum"] == pytest.approx(sched["sched_s"]["emit"])
