@@ -11,15 +11,16 @@ three legs, set-up seconds only, no performance claim.
   poisson, prompts ~30-900 tokens in one queue, 32 output tokens) and
   ``analyze_run``. Pass: every request ok with exactly the tokens asked
   for, a repeated greedy prompt returns the same tokens, no engine
-  executable compiled inside the counted window, every ``prefill[*]``
-  executable holds a Mosaic call, results.json written, and the flash
+  executable compiled inside the counted window, every ``prefill[*]`` and
+  ``decode[*]`` executable holds a Mosaic call, results.json written, and the flash
   prefill agrees with the jnp attention path on the same weights.
 - **kernels** — the four Pallas kernels compiled (``interpret=False``) at
-  KVH 8 / G 4 / D 128 and the engine's default blocks, bf16 and int8-KV,
-  against the jnp oracle.
+  KVH 8 / G 4 / D 128 and the engine's default blocks (the dense decode
+  kernel also at KVH 32 / G 1), bf16 and int8-KV, against the jnp oracle.
 - **engines** — llama-1b served with ``kv_layout=paged``, then with
-  ``kv_cache_dtype=int8`` + ``prefill_chunk=256``: the decode and
-  chunk-prefill executables must hold a Mosaic call, and the kernel path
+  ``kv_cache_dtype=int8`` + ``prefill_chunk=256``: the paged decode and the
+  prefill / chunk-prefill executables must hold a Mosaic call (the dense
+  decode step of 64-wide heads keeps the eager read), and the kernel path
   must agree with the jnp path on the same weights.
 
 With no TPU it exits non-zero before doing anything; it never carries on on
@@ -335,6 +336,9 @@ def leg_main(dry: bool, topology: str | None) -> dict:
                   f"only {len(warm)} executables recorded for "
                   f"{len(phases)} buckets + decode: {sorted(warm)}")
             require_mosaic(after, "prefill[", dry)
+            # 128-wide heads, bf16 KV: the step reads through
+            # kvmini_dense_decode (models/llama.py dense_decode_kernel_block)
+            require_mosaic(after, "decode[", dry)
         say(f"main: backend compiles inside the window (incl. eager host "
             f"helpers; informational)={len(compiles_seen) - n_backend}")
 
@@ -379,7 +383,7 @@ def leg_kernels(dry: bool) -> dict:
         paged_decode_attention,
     )
 
-    KVH, G, D, L, LAYER = (2, 2, 32, 2, 1) if dry else (8, 4, 128, 2, 1)
+    KVH, G, D, L, LAYER = (2, 2, 128, 2, 1) if dry else (8, 4, 128, 2, 1)
     H = KVH * G
     SEQ, BLK, SLOTS = (128, 16, 2) if dry else (2048, 64, 8)
     TS = (32,) if dry else (128, 512)
@@ -456,7 +460,15 @@ def leg_kernels(dry: bool) -> dict:
                                      k_scale=ks, v_scale=vs, interpret=dry)
         want = oracle(q.reshape(SLOTS, H, 1, D), kf[LAYER], vf[LAYER], dec_mask)
         compare(f"dense_decode {tag}", out.reshape(SLOTS, H, 1, D), want)
-        n += 2
+        # the same under a window a quarter of the stripe: most slots' first
+        # live block is not block 0
+        win = SEQ // 4
+        out = dense_decode_attention(q, k, v, qpos, layer=LAYER, window=win,
+                                     k_scale=ks, v_scale=vs, interpret=dry)
+        want = oracle(q.reshape(SLOTS, H, 1, D), kf[LAYER], vf[LAYER],
+                      dec_mask & (kj > qpos[:, None, None, None] - win))
+        compare(f"dense_decode {tag} window={win}", out.reshape(SLOTS, H, 1, D), want)
+        n += 3
         for t in TS:
             qc = normal((1, H, t, D))
             off = jnp.asarray([SEQ // 2 - 7], jnp.int32)  # mid-block offset
@@ -468,6 +480,17 @@ def leg_kernels(dry: bool) -> dict:
             want = oracle(qc, kf[LAYER, :1], vf[LAYER, :1], kj <= qi)
             compare(f"cached_prefill {tag} T={t}", out, want)
             n += 1
+        # an MHA cache (codellama-7b: 32 kv heads, G 1): four times the
+        # heads a block, so the block rule halves it (dense_decode_block;
+        # at 512 keys Mosaic's scoped VMEM refuses the kernel)
+        mha = 4 * KVH
+        qm = normal((SLOTS, mha, 1, D))
+        k, v, ks, vs, kf, vf = kv((L, SLOTS, mha, SEQ, D), quantized)
+        out = dense_decode_attention(qm, k, v, qpos, layer=LAYER,
+                                     k_scale=ks, v_scale=vs, interpret=dry)
+        want = oracle(qm.reshape(SLOTS, mha, 1, D), kf[LAYER], vf[LAYER], dec_mask)
+        compare(f"dense_decode {tag} KVH={mha} G=1", out.reshape(SLOTS, mha, 1, D), want)
+        n += 1
     return {"cases": n, "worst_scaled_err": round(worst, 5)}
 
 
@@ -483,8 +506,10 @@ def leg_engines(dry: bool) -> dict:
          ["paged_prefill[", "decode[chunk=1].paged"],
          dict(chunks=sz["paged_parity"], decode=True,
               block_size=sz["paged_block"], kv_int8=False)),
+        # no "decode[": llama-1b's 64-wide heads keep the eager decode read
+        # (ops/paged_attention.py dense_decode_block)
         ("int8kv", {"kv_cache_dtype": "int8", "prefill_chunk": sz["chunk"]},
-         ["prefill[", "chunk_prefill[", "decode[chunk=1]"],
+         ["prefill[", "chunk_prefill["],
          dict(chunks=sz["int8_parity"], decode=True, block_size=None,
               kv_int8=True)),
     ]

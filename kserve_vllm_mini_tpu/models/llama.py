@@ -463,6 +463,32 @@ def layer_forward(
     return attn_out_and_mlp(p, cfg, x, o, h)
 
 
+def dense_decode_kernel_block(
+    cfg: ModelConfig, kernels: str, kv_cache: KVCache, paged: bool
+) -> Optional[int]:
+    """Key-block size with which a ``T == 1`` step over the full dense
+    cache takes ``kvmini_dense_decode`` (ops/paged_attention.py), or None
+    where it keeps the eager read. THE gate, read from what the call can
+    see: the kernels mode and backend, the cache's layout, shape and dtype
+    (``dense_decode_block``: a block that tiles the length and fits VMEM),
+    and the model's attention — no softcap (the kernel has none) and one
+    mask for every layer (``alt_sliding_window`` models alternate theirs
+    inside the scan). The engine asks the same question once, to count the
+    blocks a step fetches."""
+    from kserve_vllm_mini_tpu.ops.flash_attention import pallas_interpret
+    from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_block
+
+    if (
+        paged
+        or pallas_interpret(kernels) is None
+        or cfg.attn_softcap is not None
+        or cfg.alt_sliding_window
+    ):
+        return None
+    k = kv_cache["k"]                         # [L, B, KVH, S, D]
+    return dense_decode_block(k.shape[3], k.shape[2], k.shape[4], k.dtype)
+
+
 def run_cached_layers(
     layers: Params,              # stacked per-layer tree, leading axis = L (or a
                                  # local L/pp range under the pipeline executor)
@@ -545,32 +571,30 @@ def run_cached_layers(
         cached_prefill_blocks,
         pallas_interpret,
     )
-    from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_block
-
     # None = jnp paths; else the interpret flag every Pallas call below gets
     interpret = pallas_interpret(kernels)
-    # The cache-reading kernels are plain-causal only (no window, no
-    # softcap) and read the full-slot-axis cache (the pp executor's
-    # write_gate/slot_base sub-views keep the eager _read_layer path,
-    # which is also the oracle every parity test pins against).
+    # The paged-decode and cached-prefill kernels are plain-causal only (no
+    # window, no softcap); the dense kernels read the full-slot-axis cache
+    # (the pp executor's write_gate/slot_base sub-views keep the eager
+    # _read_layer path, which is also the oracle every parity test pins
+    # against).
     plain_causal = (
         interpret is not None
         and cfg.attn_softcap is None
         and cfg.sliding_window is None
     )
-    full_dense = (not paged) and write_gate is None and slot_base is None
+    full_slots = write_gate is None and slot_base is None
+    full_dense = (not paged) and full_slots
     # Pallas paged decode kernel: table-driven block DMA instead of the
     # gather copy; int8-KV pools dequantize in-kernel.
     use_paged_kernel = plain_causal and paged and T == 1
-    # Dense int8-KV decode kernel: each BLK stripe of the per-slot cache
-    # is DMA'd int8 and dequantized in-kernel, so the materialized bf16
-    # [B,KVH,S,D] tensor the eager _read_layer builds never exists.
+    # Dense decode kernel, bf16 and int8 KV alike: each slot fetches only
+    # the key/value blocks its query position (and a uniform window) can
+    # reach, instead of attending over the whole [B,KVH,S,D] stripe.
     use_dense_kernel = (
-        plain_causal
-        and full_dense
-        and quantized_kv
+        full_slots
         and T == 1
-        and dense_decode_block(s) is not None
+        and dense_decode_kernel_block(cfg, kernels, kv_cache, paged) is not None
     )
     # Int8-KV cached-prefill kernel (ops/flash_attention.py
     # cached_prefill_attention): continuation chunks (T > 1 against the
@@ -738,10 +762,12 @@ def run_cached_layers(
             )
             o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
         elif use_dense_kernel:
-            # dense int8-KV decode: BLK stripes of the LAYER-STACKED cache
-            # are DMA'd int8 and dequantized in-kernel — no materialized
-            # bf16 KV tensor, and no per-layer cache slice either (lidx
-            # rides the kernel's index map, same contract as paged)
+            # dense decode: only the blocks of the LAYER-STACKED cache
+            # that cache_offsets (and the window) reach are DMA'd, int8
+            # ones dequantized in-kernel — no full-stripe read, no
+            # materialized bf16 KV tensor, and no per-layer cache slice
+            # either (lidx rides the kernel's index map, same contract as
+            # paged)
             from kserve_vllm_mini_tpu.ops.paged_attention import (
                 dense_decode_attention,
             )
@@ -752,7 +778,7 @@ def run_cached_layers(
                 qg, cache["k"], cache["v"], cache_offsets,
                 layer=lidx, scale=attn_scale,
                 k_scale=cache.get("k_s"), v_scale=cache.get("v_s"),
-                interpret=interpret,
+                window=cfg.sliding_window, interpret=interpret,
             )
             o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
         elif use_chunk_kernel:

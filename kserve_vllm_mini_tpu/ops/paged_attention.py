@@ -18,11 +18,11 @@ in pre-grouped: q is [S, KVH, G, D] so each grid step contracts a [G, D]
 query tile against the [BLK, D] key block on the MXU.
 
 Blocks past the slot's live length are skipped (``pl.when``) — their DMA
-still happens (the grid is static), reading whatever block their table
-entry names. The engine parks freed/unwritten table rows on its scratch
-block (runtime/engine.py ``_paged_release``), which is what concentrates
-the dead traffic; the ``jnp.clip`` below is only bounds safety for ids
-outside [0, P).
+still happens (the paged grid is static and its index map follows the
+table), reading whatever block their table entry names. The engine parks
+freed/unwritten table rows on its scratch block (runtime/engine.py
+``_paged_release``), which is what concentrates the dead traffic; the
+``jnp.clip`` below is only bounds safety for ids outside [0, P).
 
 The kernel takes the LAYER-STACKED pool ([L, P, KVH, BLK, D]) plus the
 layer index as a scalar-prefetch value folded into the index map: slicing
@@ -30,18 +30,28 @@ one layer out before the call would hand XLA a dynamic-slice feeding a
 custom call, which materializes the whole layer pool in HBM per step —
 exactly the copy this kernel exists to avoid.
 
-The jnp gather path is the correctness oracle; tests compare in interpret
-mode on CPU (tests/test_paged_kernel.py). The serving path dispatches to
-the kernel on TPU for plain-causal, bf16-KV configs and keeps the exact
-gather path elsewhere (models/llama.py run_cached_layers).
+``dense_decode_attention`` is the DENSE-cache twin, for bf16 and int8 KV
+alike: the same online-softmax block update and the same ``k_scale``/
+``v_scale`` dequant-in-kernel convention, over the per-slot
+[L, B, KVH, S, D] cache stripes (no table). Its index maps read the
+prefetched query positions: a slot fetches only the key blocks between
+the first and the last key it can reach (``live_block_range`` — its
+position, and the model's window if it has one), and the slot's other
+grid steps name the first live block again, which issues no DMA
+(``fetched_block``). A decode step therefore reads the live KV, not the
+reserved stripes, and neither the eager path's full-stripe attention nor
+its materialized bf16 dequantized tensor exists (models/llama.py
+``_read_layer`` remains the fallback and the oracle).
 
-``dense_decode_attention`` is the DENSE-cache twin for the int8-KV
-layout: same shared online-softmax block body, same ``k_scale``/
-``v_scale`` dequant-in-kernel convention, but the key-block sweep walks
-the per-slot [L, B, KVH, S, D] cache stripes directly (no table) — so the
-eager read path's materialized bf16 [B, KVH, S, D] dequantized tensor
-never exists (models/llama.py ``_read_layer`` remains the fallback
-oracle).
+The jnp paths are the correctness oracle; tests compare in interpret mode
+on CPU (tests/test_paged_kernel.py, tests/test_qmatmul.py,
+tests/test_tpu_lowering.py). On a TPU the serving path takes the paged
+kernel for plain-causal configs under ``kv_layout="paged"`` and the dense
+kernel for every dense-layout config without a softcap or a per-layer
+(alternating) window whose cache ``dense_decode_block`` has a block for
+(heads a multiple of 128 wide; the block sized to VMEM), and keeps the
+exact eager path elsewhere (models/llama.py ``run_cached_layers``,
+``dense_decode_kernel_block``).
 """
 
 from __future__ import annotations
@@ -51,122 +61,192 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
 
-def _decode_block_body(
+def live_block_range(qpos, window: Optional[int], block_k: int):
+    """(first, last) key-block indices a decode query at ``qpos`` can reach:
+    the blocks that hold a key j with ``max(qpos - window + 1, 0) <= j <=
+    qpos`` (``window=None``: from 0). THE block-selection rule of the dense
+    decode kernel — its index maps, its ``run`` guard and the engine's
+    ``decode_kv_blocks_fetched`` counter all call this one function, on
+    traced scalars, numpy arrays and plain ints alike."""
+    xp = jnp if isinstance(qpos, jax.Array) else np
+    last = qpos // block_k
+    if window is None:
+        return xp.zeros_like(last), last
+    return xp.maximum(qpos - (window - 1), 0) // block_k, last
+
+
+def fetched_block(step, n_steps: int, first, last):
+    """(block, runs): the key block the dense decode kernel's ``step``-th
+    of ``n_steps`` grid steps names for a slot whose live range is
+    ``[first, last]``, and whether the step computes on it. Block ``first``
+    through the slot's dead steps (``runs`` False), then the live range in
+    order, so that the LAST step fetches ``last``.
+
+    An unchanged block index issues no new DMA, so a slot's dead blocks
+    cost neither bytes nor FLOPs. The dead steps come FIRST because the
+    Pallas pipeline prefetches exactly one grid step ahead: this way a
+    slot's first block is requested during the previous slot's last live
+    step and lands behind that step's matmuls, where dead steps at the end
+    (nothing to fetch, nothing to compute) would leave every slot's first
+    DMA exposed (a v5e, mistral-7b's 32 layers x 16 slots at 80-850
+    positions: 3.7 ms that way, 2.7 ms this way; PERF.md section 6, PR 27)."""
+    xp = jnp if isinstance(first, jax.Array) else np
+    live_step = step - (n_steps - (last - first + 1))
+    return first + xp.maximum(live_step, 0), live_step >= 0
+
+
+def _decode_block_update(
     qpos,        # scalar int32: this slot's query position
-    q_ref,       # [1, 1, G, D] this slot/head's query tile
-    k_ref,       # [1, 1, 1, BLK, D] this grid step's key block
-    v_ref,       # [1, 1, 1, BLK, D]
-    rest,        # [k_s_ref, v_s_ref,] o_ref, m_ref, l_ref, acc_ref —
-                 # int8-KV mode carries [1, 1, KVH, BLK] per-position scale
-                 # blocks for ALL kv heads (see ``_scale_spec``)
-    block_k: int,
+    kpos0,       # scalar int32: absolute position of the block's first key
+    q,           # [G, D] this slot/head's query tile
+    k,           # [BLK, D] key block (int8 when quantized)
+    v,           # [BLK, D]
+    k_s,         # [1, BLK] f32 per-position dequant scales, or None
+    v_s,
+    m_ref,       # [H', G, 1] f32 running max   } online-softmax scratch;
+    l_ref,       # [H', G, 1] f32 running sum   } row ``h`` is this head's
+    acc_ref,     # [H', G, D] f32 running out   }
+    h: int,
     scale: float,
-    quantized: bool,
+    window: Optional[int],
 ):
-    """One key-block step of the online-softmax decode recurrence — the
-    body BOTH decode kernels share (paged: the block arrived via the table
-    index map; dense: via the sequential S sweep). The m/l/acc scratch
-    persists across the innermost grid axis."""
-    if quantized:
-        k_s_ref, v_s_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    """One key block of one kv head's online-softmax decode recurrence —
+    the step BOTH decode kernels share (paged: the block arrived via the
+    table index map, one head a grid step; dense: via ``fetched_block``,
+    every head of the block in one grid step). A key at position j is
+    visible iff ``qpos - window < j <= qpos``."""
+    logits = jax.lax.dot_general(
+        q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale                            # [G, BLK]
+    if k_s is not None:
+        # per-position dequant folds into the [G, BLK] intermediates:
+        # (q . k_j s_j) = (q . k_j) * s_j, and p @ (v s) = (p * s) @ v
+        logits = logits * k_s
+    kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    visible = kpos <= qpos
+    if window is not None:
+        visible = visible & (kpos > qpos - window)
+    logits = jnp.where(visible, logits, _NEG_INF)
+    m_prev = m_ref[h]
+    m_cur = jnp.max(logits, axis=1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(logits - m_new)
+    l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+    if v_s is not None:
+        pv = (p * v_s).astype(jnp.float32)
+        vv = v.astype(jnp.float32)
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    b = pl.program_id(2)
-    nb = pl.num_programs(2)
-    # this grid step's kv head: the scale block spans every head (a
-    # one-head block is not a legal TPU tile), so the row is picked here
-    head = pl.ds(pl.program_id(1), 1)
+        pv = p.astype(v.dtype)
+        vv = v
+    acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+        pv, vv, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[h] = m_new
 
-    @pl.when(b == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # keys j of block b sit at positions b*BLK + j; the decode query at
-    # position qpos attends j <= qpos, so a block starting past qpos is
-    # all-masked — skip its FLOPs entirely
-    run = b * block_k <= qpos
-
-    @pl.when(run)
-    def _accumulate():
-        q = q_ref[0, 0]                      # [G, D]
-        k = k_ref[0, 0, 0]                   # [BLK, D] (int8 when quantized)
-        v = v_ref[0, 0, 0]
-        logits = jax.lax.dot_general(
-            q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                            # [G, BLK]
-        if quantized:
-            # per-position dequant folds into the [G, BLK] intermediates:
-            # (q . k_j s_j) = (q . k_j) * s_j, and p @ (v s) = (p * s) @ v
-            logits = logits * k_s_ref[0, 0, head, :]       # [1, BLK]
-        kpos = b * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1
-        )
-        logits = jnp.where(kpos <= qpos, logits, _NEG_INF)
-        m_prev = m_ref[:]
-        m_cur = jnp.max(logits, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(logits - m_new)
-        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        if quantized:
-            pv = (p * v_s_ref[0, 0, head, :]).astype(jnp.float32)
-            vv = v.astype(jnp.float32)
-        else:
-            pv = p.astype(v.dtype)
-            vv = v
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            pv, vv, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[:] = m_new
-
-    @pl.when(b == nb - 1)
-    def _finalize():
-        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+def _init_softmax_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
 def _paged_decode_kernel(
     layer_ref,   # [1] int32 layer index (scalar prefetch; used in index maps)
     table_ref,   # [S, MAXB] int32 (scalar prefetch)
     qpos_ref,    # [S] int32 query positions (scalar prefetch)
-    q_ref,
-    k_ref,       # the table-selected pool block
+    q_ref,       # [1, 1, G, D] this slot/head's query tile
+    k_ref,       # [1, 1, 1, BLK, D] the table-selected pool block
     v_ref,
-    *rest,
+    *rest,       # [k_s_ref, v_s_ref,] o_ref, m_ref, l_ref, acc_ref —
+                 # int8-KV mode carries [1, 1, KVH, BLK] per-position scale
+                 # blocks for ALL kv heads (see ``_scale_spec``)
     block_k: int,
     scale: float,
     quantized: bool,
 ):
-    _decode_block_body(
-        qpos_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest,
-        block_k=block_k, scale=scale, quantized=quantized,
-    )
+    if quantized:
+        k_s_ref, v_s_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+    qpos = qpos_ref[pl.program_id(0)]
+    b = pl.program_id(2)
+    # this grid step's kv head: the scale block spans every head (a
+    # one-head block is not a legal TPU tile), so the row is picked here
+    head = pl.ds(pl.program_id(1), 1)
+
+    @pl.when(b == 0)
+    def _init():
+        _init_softmax_state(m_ref, l_ref, acc_ref)
+
+    # keys j of block b sit at positions b*BLK + j; the decode query at
+    # position qpos attends j <= qpos, so a block starting past qpos is
+    # all-masked — skip its FLOPs entirely
+    @pl.when(b * block_k <= qpos)
+    def _accumulate():
+        _decode_block_update(
+            qpos, b * block_k, q_ref[0, 0], k_ref[0, 0, 0], v_ref[0, 0, 0],
+            k_s_ref[0, 0, head, :] if quantized else None,
+            v_s_ref[0, 0, head, :] if quantized else None,
+            m_ref, l_ref, acc_ref, 0, scale, None,
+        )
+
+    @pl.when(b == pl.num_programs(2) - 1)
+    def _finalize():
+        o_ref[0, 0] = (acc_ref[0] / jnp.maximum(l_ref[0], 1e-30)).astype(o_ref.dtype)
 
 
 def _dense_decode_kernel(
     layer_ref,   # [1] int32 layer index (scalar prefetch; used in index maps)
     qpos_ref,    # [B] int32 query positions (scalar prefetch)
-    q_ref,
-    k_ref,       # this slot's b-th BLK-position stripe of the dense cache
+    q_ref,       # [1, KVH, G, D] this slot's query tiles, every kv head
+    k_ref,       # [1, 1, KVH, BLK, D] the slot's ``fetched_block`` stripe
     v_ref,
-    *rest,
+    *rest,       # [k_s_ref, v_s_ref,] o_ref, m_ref, l_ref, acc_ref; the
+                 # int8-KV scale blocks are [1, 1, KVH, BLK]
     block_k: int,
     scale: float,
+    window: Optional[int],
     quantized: bool,
 ):
-    _decode_block_body(
-        qpos_ref[pl.program_id(0)], q_ref, k_ref, v_ref, rest,
-        block_k=block_k, scale=scale, quantized=quantized,
-    )
+    if quantized:
+        k_s_ref, v_s_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+    qpos = qpos_ref[pl.program_id(0)]
+    b, nb = pl.program_id(1), pl.num_programs(1)
+    blk, runs = fetched_block(b, nb, *live_block_range(qpos, window, block_k))
+
+    @pl.when(b == 0)
+    def _init():
+        _init_softmax_state(m_ref, l_ref, acc_ref)
+
+    # the slot's dead steps come first (fetched_block): they re-name its
+    # first live block, nothing was fetched for them and nothing runs
+    @pl.when(runs)
+    def _accumulate():
+        for h in range(q_ref.shape[1]):
+            _decode_block_update(
+                qpos, blk * block_k, q_ref[0, h], k_ref[0, 0, h], v_ref[0, 0, h],
+                k_s_ref[0, 0, h:h + 1, :] if quantized else None,
+                v_s_ref[0, 0, h:h + 1, :] if quantized else None,
+                m_ref, l_ref, acc_ref, h, scale, window,
+            )
+
+    @pl.when(b == nb - 1)
+    def _finalize():
+        o_ref[0] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        ).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -243,9 +323,9 @@ def paged_decode_attention(
             (1, 1, G, D), lambda s, h, b, layer, table, qpos: (s, h, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((1, G, 1), jnp.float32),
+            pltpu.VMEM((1, G, 1), jnp.float32),
+            pltpu.VMEM((1, G, D), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -260,16 +340,54 @@ def paged_decode_attention(
     )(layer_arr, safe_table, qpos, *operands)
 
 
-def dense_decode_block(seq_len: int) -> Optional[int]:
-    """Key-block size the dense decode kernel sweeps ``seq_len`` with, or
-    None when no supported block divides it (the caller then keeps the
-    eager read path). Powers of two down to 8: the sweep grid must tile
-    the cache's S axis exactly — Pallas pads partial blocks with whatever
-    HBM holds, and while the positional mask would zero those scores, a
-    dense cache length that is not even 8-aligned is a test-only shape
-    not worth the kernel."""
+# Mosaic's default scoped-VMEM limit on a v5e is 16 MiB a kernel. The dense
+# decode kernel's pipelined operands (K and V blocks, int8-KV scale blocks,
+# two buffers each) may take half of it; the query/output tiles, the
+# softmax scratch and the body's [G, BLK] / [BLK, D] temporaries live in the
+# other half.
+_DENSE_BLOCK_VMEM_BUDGET = 8 << 20
+
+
+def dense_block_vmem_bytes(
+    block_k: int, kv_heads: int, head_dim: int, kv_dtype
+) -> int:
+    """VMEM the dense decode kernel's pipelined cache operands occupy at
+    key-block size ``block_k``: the K and V blocks of [KVH, BLK, D], plus
+    the two [KVH, BLK] f32 scale blocks of an int8 cache, double-buffered."""
+    itemsize = jnp.dtype(kv_dtype).itemsize
+    per_key = head_dim * itemsize + (4 if itemsize == 1 else 0)
+    return 2 * 2 * kv_heads * block_k * per_key
+
+
+def dense_decode_block(
+    seq_len: int, kv_heads: int, head_dim: int, kv_dtype
+) -> Optional[int]:
+    """Key-block size the dense decode kernel sweeps a [.., KVH, S, D]
+    cache of ``kv_dtype`` with, or None when it has none (the caller then
+    keeps the eager read path): the largest power of two from 512 down to
+    8 that divides ``seq_len`` and whose all-heads blocks fit the VMEM
+    budget (``dense_block_vmem_bytes``) — 512 for 8 bf16 heads of 128,
+    256 for 32 of them.
+
+    The sweep grid must tile the cache's S axis exactly — Pallas pads
+    partial blocks with whatever HBM holds, and while the positional mask
+    would zero those scores, a dense cache length that is not even
+    8-aligned is a test-only shape not worth the kernel. A head size that
+    is not a multiple of 128 lanes has none either: XLA keeps such a cache
+    in a layout the Mosaic call does not take, and copies the WHOLE cache
+    to feed it, every step. The cap of 512 is a reading, not a guess:
+    smaller blocks fetch fewer dead positions in a slot's last live block,
+    but on a v5e a 256-position step of 8 heads costs 2.3 us for 1 MB where
+    a 512-position one costs 2.9 us for 2 MB, and 512 won every length mix
+    tried (PERF.md section 6, PR 27)."""
+    if head_dim % 128:
+        return None
     for cand in (512, 256, 128, 64, 32, 16, 8):
-        if seq_len % cand == 0:
+        if (
+            seq_len % cand == 0
+            and dense_block_vmem_bytes(cand, kv_heads, head_dim, kv_dtype)
+            <= _DENSE_BLOCK_VMEM_BUDGET
+        ):
             return cand
     return None
 
@@ -284,26 +402,38 @@ def dense_decode_attention(
     scale: Optional[float] = None,
     k_scale: Optional[jnp.ndarray] = None,  # [L, B, KVH, S] f32: int8-KV
     v_scale: Optional[jnp.ndarray] = None,  # per-position dequant scales
+    window: Optional[int] = None,  # sliding window: key j is visible iff
+                           # qpos - window < j <= qpos (None: plain causal)
     interpret: bool = False,  # Pallas interpreter: tests only
 ) -> jnp.ndarray:
-    """Decode attention straight over the DENSE per-slot cache: the twin of
-    ``paged_decode_attention`` for ``kv_layout="dense"``.
+    """Decode attention straight over the DENSE per-slot cache, reading
+    from HBM only the key/value blocks a slot's query can reach: the twin
+    of ``paged_decode_attention`` for ``kv_layout="dense"``, for bf16 and
+    int8 KV alike.
 
-    The eager int8-KV read path (models/llama.py ``_read_layer``)
-    dequantizes the whole [B, KVH, S, D] stripe into a materialized bf16
-    tensor before attention — 3x the live-KV bytes in HBM traffic (int8
-    read + bf16 write + attention read) plus a full dequantized copy in
-    HBM. Here each BLK-position stripe is DMA'd int8 from HBM into VMEM
-    and dequantized in-register inside the online-softmax sweep (the same
-    shared block body as the paged kernel, same ``k_scale``/``v_scale``
-    layout), so the bf16 KV tensor never exists. The layer index rides the
-    index map so the caller never slices the stacked cache (a dynamic-
-    slice operand feeding a custom call would materialize the whole layer
-    in HBM — the copy this kernel exists to avoid).
+    The eager read path (models/llama.py ``_read_layer``) attends over the
+    whole [B, KVH, S, D] stripe every step whatever is live (and for int8
+    KV dequantizes it into a materialized bf16 tensor first: 3x the bytes).
+    Here the grid is (slots, S/BLK) and each step's BlockSpec index map
+    names ``fetched_block`` of the slot's ``live_block_range`` — the first
+    of the blocks between ``max(qpos - window + 1, 0)`` and ``qpos`` for
+    as many steps as the slot has dead blocks, then those blocks in order.
+    The Pallas pipeline issues a DMA only when a step's block index
+    differs from the previous step's, so a slot at position 300 of 2048
+    costs one 512-position block of HBM traffic, not four; the body's
+    guard skips the repeated steps.
 
-    Blocks past a slot's live length are skipped by the block body's
-    ``run`` guard; their DMA still happens (static grid) but reads the
-    slot's own dead cache tail, never another slot's data."""
+    One block carries EVERY kv head ([KVH, BLK, D], BLK from
+    ``dense_decode_block``: the largest that fits VMEM), so a layer is
+    B * S/BLK grid steps rather than B * KVH * S/BLK: a dead step still
+    costs ~0.35 us, and at 8 heads x 512 positions a live one is bound by
+    its DMA (2.9 us for 2 MB of bf16 K+V), where 256-position blocks took
+    2.3 us for half the bytes. int8 blocks are dequantized in-register
+    inside the online-softmax sweep (the same shared block update as the
+    paged kernel, same ``k_scale``/``v_scale`` layout). The layer index rides
+    the index map so the caller never slices the stacked cache (a
+    dynamic-slice operand feeding a custom call would materialize the
+    whole layer in HBM)."""
     if k_cache.ndim == 4:
         k_cache = k_cache[None]
         v_cache = v_cache[None]
@@ -312,40 +442,42 @@ def dense_decode_attention(
     quantized = k_scale is not None
     B, KVH, G, D = q.shape
     L, _, _, S, _ = k_cache.shape
-    BLK = dense_decode_block(S)
+    BLK = dense_decode_block(S, KVH, D, k_cache.dtype)
     if BLK is None:
         raise ValueError(
-            f"dense decode kernel needs a power-of-two-tileable seq axis "
-            f"(>= 8); got S={S} — use the eager read path"
+            f"dense decode kernel has no key block for S={S}, KVH={KVH}, "
+            f"D={D}, {k_cache.dtype} (dense_decode_block) — use the eager "
+            f"read path"
         )
     scale = scale if scale is not None else D ** -0.5
+    if window is not None and window >= S:
+        window = None  # wider than the cache: the lower bound never binds
 
-    qpos = qpos.astype(jnp.int32)
+    # bounds safety only: the engine never feeds a position past S - 1
+    qpos = jnp.clip(qpos.astype(jnp.int32), 0, S - 1)
     layer_arr = jnp.asarray(layer, jnp.int32).reshape((1,))
-    nb = S // BLK
+
+    def _block(s, b, qpos):
+        return fetched_block(b, S // BLK, *live_block_range(qpos[s], window, BLK))[0]
 
     def _cache_spec():
         return pl.BlockSpec(
-            (1, 1, 1, BLK, D),
-            lambda s, h, b, layer, qpos: (layer[0], s, h, b, 0),
+            (1, 1, KVH, BLK, D),
+            lambda s, b, layer, qpos: (layer[0], s, 0, _block(s, b, qpos), 0),
         )
 
     def _scale_spec():
-        # all KVH heads per stripe (same tile rule as the paged kernel's
-        # scale block); the kernel body picks its head's row
         return pl.BlockSpec(
             (1, 1, KVH, BLK),
-            lambda s, h, b, layer, qpos: (layer[0], s, 0, b),
+            lambda s, b, layer, qpos: (layer[0], s, 0, _block(s, b, qpos)),
         )
 
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, G, D),
-            lambda s, h, b, layer, qpos: (s, h, 0, 0),
-        ),
-        _cache_spec(),
-        _cache_spec(),
-    ]
+    def _q_spec():
+        return pl.BlockSpec(
+            (1, KVH, G, D), lambda s, b, layer, qpos: (s, 0, 0, 0)
+        )
+
+    in_specs = [_q_spec(), _cache_spec(), _cache_spec()]
     operands = [q, k_cache, v_cache]
     if quantized:
         in_specs += [_scale_spec(), _scale_spec()]
@@ -353,19 +485,18 @@ def dense_decode_attention(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, nb),
+        grid=(B, S // BLK),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, G, D), lambda s, h, b, layer, qpos: (s, h, 0, 0)
-        ),
+        out_specs=_q_spec(),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, D), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _dense_decode_kernel, block_k=BLK, scale=scale, quantized=quantized
+        _dense_decode_kernel, block_k=BLK, scale=scale, window=window,
+        quantized=quantized,
     )
     return pl.pallas_call(
         kernel,

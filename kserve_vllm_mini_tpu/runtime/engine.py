@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
-from kserve_vllm_mini_tpu.models.llama import forward
+from kserve_vllm_mini_tpu.models.llama import dense_decode_kernel_block, forward
 from kserve_vllm_mini_tpu.profiling.compile_stats import (
     CompileRecorder,
     InstrumentedJit,
@@ -533,6 +533,10 @@ class Engine:
         # GSPMD, which refuses a Mosaic call ("cannot be automatically
         # partitioned") — those run attention through XLA
         self._kernels = "off" if mesh is not None else "auto"
+        # key-block size of the dense decode kernel where decode steps take
+        # it (models/llama.py dense_decode_kernel_block), resolved in
+        # start(): what _count_decode_kv_blocks counts by
+        self._dense_decode_blk: Optional[int] = None
         # model executor: plain forward, or the pp-sharded drop-in when the
         # mesh pipelines layers (parallel/serving_pp.py — same signature, so
         # every compiled step below is executor-agnostic)
@@ -1032,6 +1036,13 @@ class Engine:
             # what the pieces were padded to: prefill_tokens over this is
             # the share of the prefill programs' work that was real prompt
             "prefill_bucket_tokens": 0,
+            # how often the dense decode kernel's block selection engages
+            # (ops/paged_attention.py live_block_range): key/value blocks
+            # the target's plain and masked decode steps fetched, summed
+            # over slots, against slots x S/BLK; a layer's worth (x layers
+            # is a constant). Both stay 0 where the eager read runs.
+            "decode_kv_blocks_fetched": 0,
+            "decode_kv_blocks": 0,
             "requests_completed": 0,
             "busy_s": 0.0,        # exported: busy_seconds_total + duty_cycle
             "started_at": _now(),  # kvmini: metrics-ok — raw input; exposed as duty_cycle
@@ -2301,6 +2312,9 @@ class Engine:
         if self._running:
             return
         self._running = True
+        self._dense_decode_blk = dense_decode_kernel_block(
+            self.cfg, self._kernels, self._cache, self.paged
+        )
         if self._disagg is not None:
             self._disagg.start()
         self._thread = threading.Thread(target=self._loop, daemon=True, name="engine-loop")
@@ -3292,6 +3306,25 @@ class Engine:
             self._retained[slot] = self._slot_tokens[slot][: self._slot_len[slot]]
         self._release_slot(slot)
 
+    def _count_decode_kv_blocks(self, lengths: np.ndarray, steps: int) -> None:
+        """Book the key/value blocks that ``steps`` fused decode steps from
+        ``lengths`` fetch through the dense decode kernel, by the kernel's
+        own block rule; nothing where they take the eager read."""
+        # kvmini: thread-ok — written once in start(), before this thread exists
+        blk = self._dense_decode_blk
+        if blk is None:
+            return
+        # (pallas is loaded only where a step holds the kernel)
+        from kserve_vllm_mini_tpu.ops.paged_attention import live_block_range
+
+        seq = self.ecfg.max_seq_len
+        qpos = np.minimum(
+            lengths[None, :] + np.arange(steps, dtype=np.int32)[:, None], seq - 1
+        )
+        first, last = live_block_range(qpos, self.cfg.sliding_window, blk)
+        self.stats["decode_kv_blocks_fetched"] += int((last - first + 1).sum())
+        self.stats["decode_kv_blocks"] += qpos.size * (seq // blk)
+
     def _release_slot(self, slot: int) -> None:
         """Slot-release bookkeeping shared by _finish_slot, engine-fault
         recovery, and the shutdown drain — ONE copy of the invariants so
@@ -3315,6 +3348,11 @@ class Engine:
             self._paged_release(slot)
         self._slot_adapter[slot] = 0
         self._adapter_ids_dev = None
+        # a free slot parks on the first row past what it retains for the
+        # prefix cache (row 0 without one): the all-slots sweep writes its
+        # discarded KV there, and the dense decode kernel fetches only the
+        # blocks that reach that row instead of the slot's stale length
+        self._slot_len[slot] = len(self._retained[slot])
         self._free.append(slot)
         self._sampling_arrays = None  # slot population changed
 
@@ -3588,6 +3626,7 @@ class Engine:
                 np.asarray(self._slot_len, dtype=np.int32) + self._pending_steps,
                 self.ecfg.max_seq_len - 1,
             )
+            self._count_decode_kv_blocks(lengths, chunk)
             temps, topks, topps, pres, freqs = self._get_sampling_arrays()
             rng_prev = self._rng
             self._rng, sub = jax.random.split(self._rng)
@@ -3771,7 +3810,8 @@ class Engine:
         nothing to dispatch ahead."""
         S = self.ecfg.max_slots
         tokens = self._feed_tokens(active)
-        lengths = jnp.asarray(self._slot_len, dtype=jnp.int32)
+        lengths = np.asarray(self._slot_len, dtype=np.int32)
+        self._count_decode_kv_blocks(lengths, 1)
         temps, topks, topps, pres, freqs = self._get_sampling_arrays()
         self._rng, sub = jax.random.split(self._rng)
         mask = np.zeros((S, (self.cfg.vocab_size + 7) // 8), dtype=np.uint8)
@@ -3796,7 +3836,7 @@ class Engine:
             with self._cache_lock:
                 self._cache, self._counts, next_toks, ys = decode(
                     self.params, self._cache,
-                    tokens, lengths, temps, topks, topps, sub,
+                    tokens, jnp.asarray(lengths), temps, topks, topps, sub,
                     self._counts, pres, freqs,
                     jnp.asarray(mask), jnp.asarray(use_mask), **lkw,
                 )

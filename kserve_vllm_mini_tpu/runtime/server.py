@@ -1347,6 +1347,14 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             # pieces only): with prefill_tokens_total, the padding's share
             "# TYPE kvmini_tpu_prefill_bucket_tokens_total counter",
             f"kvmini_tpu_prefill_bucket_tokens_total {s['prefill_bucket_tokens']}",
+            # the dense decode kernel's block selection: key/value blocks
+            # decode steps fetched against slots x S/BLK (0 / 0 where the
+            # eager read runs)
+            "# TYPE kvmini_tpu_decode_kv_blocks_fetched_total counter",
+            "kvmini_tpu_decode_kv_blocks_fetched_total "
+            f"{s['decode_kv_blocks_fetched']}",
+            "# TYPE kvmini_tpu_decode_kv_blocks_total counter",
+            f"kvmini_tpu_decode_kv_blocks_total {s['decode_kv_blocks']}",
             # decode-pipeline telemetry (docs/DECODE_PIPELINE.md): depth >= 2
             # + low bubble = the double-buffered steady state is engaged
             "# TYPE kvmini_tpu_dispatch_depth gauge",

@@ -208,7 +208,7 @@ def test_dense_kernel_matches_eager_oracle():
     from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_attention
 
     rng = np.random.default_rng(4)
-    L, B, KVH, G, D, S = 2, 3, 2, 2, 32, 64
+    L, B, KVH, G, D, S = 2, 3, 2, 2, 128, 64
     kq = jnp.asarray(rng.integers(-127, 128, size=(L, B, KVH, S, D)), jnp.int8)
     vq = jnp.asarray(rng.integers(-127, 128, size=(L, B, KVH, S, D)), jnp.int8)
     ks = jnp.asarray(rng.uniform(0.01, 0.1, size=(L, B, KVH, S)).astype(np.float32))
@@ -231,10 +231,50 @@ def test_dense_kernel_matches_eager_oracle():
 def test_dense_kernel_block_helper():
     from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_block
 
-    assert dense_decode_block(1024) == 512
-    assert dense_decode_block(64) == 64
-    assert dense_decode_block(24) == 8
-    assert dense_decode_block(7) is None  # not 8-aligned: eager fallback
+    bf16 = jnp.bfloat16
+    assert dense_decode_block(1024, 8, 128, bf16) == 512
+    assert dense_decode_block(64, 8, 128, bf16) == 64
+    assert dense_decode_block(24, 8, 128, bf16) == 8
+    assert dense_decode_block(7, 8, 128, bf16) is None  # not 8-aligned: eager fallback
+
+
+# (preset, kv heads, head size) of every shape the presets bring; the block
+# the rule gives a 2048-long bf16 / int8 cache, None = eager read
+_PRESET_BLOCKS = [
+    ("mistral-7b", 8, 128, 512, 512),     # llama-3.x, mixtral alike
+    ("qwen2-7b", 4, 128, 512, 512),
+    ("codellama-7b", 32, 128, 256, 256),  # MHA: four 4 MiB buffers at 512
+    ("phi-2.7b", 32, 80, None, None),     # } a head that is not a multiple
+    ("llama-1b", 8, 64, None, None),      # } of 128 lanes: XLA would copy
+    ("smoke-125m", 12, 64, None, None),   # } the whole cache to feed the call
+    ("gemma-2-9b", 8, 256, 512, 512),     # (its softcap keeps it eager anyway)
+]
+
+
+@pytest.mark.parametrize("preset,kvh,d,blk_bf16,blk_int8", _PRESET_BLOCKS)
+def test_dense_kernel_block_fits_vmem(preset, kvh, d, blk_bf16, blk_int8):
+    """The block rule adapts to what it sees (KVH, D, itemsize): every
+    preset's shape gets the largest block whose double-buffered K and V
+    (and scale) blocks stay inside half of Mosaic's 16 MiB scoped VMEM, or
+    keeps the eager read. KVH 32 / D 128 bf16 at 512 is the shape a v5e
+    compile refuses (16.05 MiB scoped)."""
+    from kserve_vllm_mini_tpu.ops.paged_attention import (
+        dense_block_vmem_bytes,
+        dense_decode_block,
+    )
+
+    cfg = get_config(preset)
+    assert (cfg.n_kv_heads, cfg.head_dim) == (kvh, d)
+    for dtype, want in ((jnp.bfloat16, blk_bf16), (jnp.int8, blk_int8)):
+        blk = dense_decode_block(2048, kvh, d, dtype)
+        assert blk == want
+        if blk is None:
+            continue
+        assert dense_block_vmem_bytes(blk, kvh, d, dtype) <= 8 << 20
+        # and the next size up would not have fitted, where there is one
+        if blk < 512:
+            assert dense_block_vmem_bytes(2 * blk, kvh, d, dtype) > 8 << 20
+    assert dense_block_vmem_bytes(512, 32, 128, jnp.bfloat16) == 16 << 20
 
 
 def test_model_dense_kernel_matches_eager_path():
@@ -243,7 +283,8 @@ def test_model_dense_kernel_matches_eager_path():
     bf16 — same tolerance contract as the paged kernel's model test)."""
     from kserve_vllm_mini_tpu.models.llama import init_kv_cache
 
-    cfg = get_config("llama-tiny", max_seq_len=64)
+    # 128-wide heads: narrower ones keep the eager read (dense_decode_block)
+    cfg = get_config("llama-tiny", max_seq_len=64).scaled(explicit_head_dim=128)
     params = init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, cfg.vocab_size)
     pos = jnp.broadcast_to(jnp.arange(12), (2, 12)).astype(jnp.int32)
@@ -274,7 +315,7 @@ def test_dense_vs_paged_kernel_consistency():
     dequantize in f32 in-kernel; only the sweep order differs)."""
     from kserve_vllm_mini_tpu.models.llama import init_kv_cache, init_paged_kv_cache
 
-    cfg = get_config("llama-tiny", max_seq_len=64)
+    cfg = get_config("llama-tiny", max_seq_len=64).scaled(explicit_head_dim=128)
     params = init_params(jax.random.PRNGKey(0), cfg)
     B, T, BLK = 2, 16, 8
     table = jnp.asarray(
@@ -311,28 +352,204 @@ def test_dense_vs_paged_kernel_consistency():
 
 
 def test_dense_kernel_gate_excludes_unsupported_shapes():
-    """Windowed/softcapped models and prefill-against-cache shapes must
-    keep the eager path even when kernels are on — the gate, not the
-    caller's mode, owns correctness."""
-    from kserve_vllm_mini_tpu.models.llama import init_kv_cache
+    """Softcapped / alternating-window models keep the eager path even
+    when kernels are on — the gate, not the caller's mode, owns
+    correctness (the kernel has no softcap and one mask for every layer).
+    A uniform window no longer excludes: see
+    tests/test_tpu_lowering.py::test_model_decode_takes_dense_kernel."""
+    from kserve_vllm_mini_tpu.models.llama import (
+        dense_decode_kernel_block,
+        init_kv_cache,
+    )
 
-    cfg = get_config("mistral-tiny", max_seq_len=64)  # sliding_window=16
+    # softcap + alternating window; 128-wide heads, so that it is the
+    # attention and not the head size that the gate turns away
+    cfg = get_config("gemma-tiny", max_seq_len=64).scaled(explicit_head_dim=128)
     params = init_params(jax.random.PRNGKey(0), cfg)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, cfg.vocab_size)
     pos = jnp.broadcast_to(jnp.arange(24), (2, 24)).astype(jnp.int32)
     offs = jnp.zeros((2,), jnp.int32)
     cache = init_kv_cache(cfg, 2, max_seq=64, quantized=True)
+    assert dense_decode_kernel_block(cfg, "interpret", cache, False) is None
+    plain = cfg.scaled(attn_softcap=None, alt_sliding_window=False)
+    assert dense_decode_kernel_block(plain, "interpret", cache, False) == 64
+    assert dense_decode_kernel_block(plain, "interpret", cache, True) is None
+    assert dense_decode_kernel_block(plain, "off", cache, False) is None
     lg, cache = forward(params, cfg, toks, pos, cache, offs,
                         fresh_prefill=True)
     lens = jnp.full((2,), 24, jnp.int32)
     nxt = jnp.argmax(lg[:, -1, :], -1).astype(jnp.int32)
-    # windowed decode: the gate must route to the masked eager path (the
-    # kernel has no window support) — bit-equal to the kernels-off run
+    # the gate must route to the masked eager path — bit-equal to the
+    # kernels-off run
     lg_on, _ = forward(params, cfg, nxt[:, None], lens[:, None], cache, lens,
                        kernels="interpret")
     lg_off, _ = forward(params, cfg, nxt[:, None], lens[:, None], cache, lens,
                         kernels="off")
     np.testing.assert_array_equal(np.asarray(lg_on), np.asarray(lg_off))
+
+
+# -- the dense decode kernel reads only what a slot can reach ------------------
+
+_D = 128
+# (G, KVH, S): the two benchmark head groupings at two 512-key blocks a
+# slot, and an MHA shape whose 32 heads the block rule gives 256-key blocks
+_HEADS = {"G4-KVH8": (4, 8, 1024), "G7-KVH4": (7, 4, 1024), "G1-KVH32": (1, 32, 512)}
+# a window the last slot's position is well past / one wider than the cache
+_WINDOWS = {"causal": None, "window<S": 300, "window>S": 5000}
+
+
+def _dense_blk(kv, heads):
+    from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_block
+
+    _, KVH, S = _HEADS[heads]
+    blk = dense_decode_block(S, KVH, _D, jnp.int8 if kv == "int8" else jnp.bfloat16)
+    assert S // blk >= 2  # the sweep crosses a block boundary
+    return blk
+
+
+def _dense_qpos(kv, heads):
+    """One batch mixes the positions at which the block rule changes."""
+    blk = _dense_blk(kv, heads)
+    return [0, blk - 1, blk, _HEADS[heads][2] - 1]
+
+
+def _dense_case(seed, kv, heads):
+    """q, cache operands (keyword form) and the dequantized f32 K/V of
+    layer 1 of a two-layer dense cache, four slots."""
+    rng = np.random.default_rng(seed)
+    G, KVH, S = _HEADS[heads]
+    B, L = 4, 2
+    shape = (L, B, KVH, S, _D)
+    q = jnp.asarray(rng.normal(size=(B, KVH, G, _D)).astype(np.float32))
+    if kv == "int8":
+        k = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.01, 0.1, size=shape[:-1]).astype(np.float32))
+        vs = jnp.asarray(rng.uniform(0.01, 0.1, size=shape[:-1]).astype(np.float32))
+        ops = {"k_cache": k, "v_cache": v, "k_scale": ks, "v_scale": vs}
+        kf = k[1].astype(jnp.float32) * ks[1][..., None]
+        vf = v[1].astype(jnp.float32) * vs[1][..., None]
+    else:
+        k = jnp.asarray(rng.standard_normal(shape, np.float32), jnp.bfloat16)
+        v = jnp.asarray(rng.standard_normal(shape, np.float32), jnp.bfloat16)
+        ops = {"k_cache": k, "v_cache": v}
+        kf, vf = k[1].astype(jnp.float32), v[1].astype(jnp.float32)
+    return q, ops, kf, vf
+
+
+def _visible(qpos, window, S):
+    kpos = np.arange(S)[None, :]
+    qp = np.asarray(qpos)[:, None]
+    vis = kpos <= qp
+    if window is not None:
+        vis &= kpos > qp - window
+    return vis                                            # [B, S]
+
+
+@pytest.mark.parametrize("heads", list(_HEADS))
+@pytest.mark.parametrize("window", list(_WINDOWS))
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_dense_kernel_parity_with_eager_oracle(kv, window, heads):
+    """bf16 and int8 KV x {no window, a window the last slot's position is
+    past, a window wider than the cache} x both benchmark head groupings
+    and an MHA one, positions 0 / BLK-1 / BLK / S-1 in one batch, against
+    masked eager attention over the whole stripe."""
+    from kserve_vllm_mini_tpu.ops.attention import attention
+    from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_attention
+
+    G, KVH, S = _HEADS[heads]
+    W = _WINDOWS[window]
+    q, ops, kf, vf = _dense_case(11, kv, heads)
+    qpos = _dense_qpos(kv, heads)
+    out = dense_decode_attention(q, qpos=jnp.asarray(qpos, jnp.int32), layer=1,
+                                 window=W, interpret=True, **ops)
+    mask = jnp.asarray(_visible(qpos, W, S))[:, None, None, :]
+    B = len(qpos)
+    ref = attention(q.reshape(B, KVH * G, 1, _D), kf, vf, mask)
+    # bf16 KV: the kernel rounds the softmax weights to bf16 before p @ v,
+    # as the paged kernel does; int8 KV dequantizes in f32 (up to 1024
+    # keys summed block by block against all at once)
+    tol = 2e-2 if kv == "bf16" else 2e-4
+    np.testing.assert_allclose(np.asarray(out).reshape(ref.shape),
+                               np.asarray(ref), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("window", ["causal", "window<S"])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_dense_kernel_ignores_poisoned_dead_positions(kv, window):
+    """NaN in every key (and key scale) a slot cannot reach — past ``qpos``
+    and before ``qpos - W + 1`` — and in every value of a block outside
+    the live range: the output is finite and bit-equal to the clean run.
+    (A dead VALUE inside a live block gets a huge finite number instead:
+    its softmax weight is exactly 0, and 0 * NaN is NaN in any p @ v, the
+    eager oracle's included — the cache never holds NaN, only stale rows.)"""
+    from kserve_vllm_mini_tpu.ops.paged_attention import (
+        dense_decode_attention,
+        live_block_range,
+    )
+
+    W = _WINDOWS[window]
+    heads = "G4-KVH8"
+    S, blk = _HEADS[heads][2], _dense_blk(kv, heads)
+    q, ops, _, _ = _dense_case(12, kv, heads)
+    qpos = np.asarray(_dense_qpos(kv, heads), np.int32)
+    dead = ~_visible(qpos, W, S)                          # [B, S]
+    first, last = live_block_range(qpos, W, blk)
+    blk_of = np.arange(S) // blk
+    dead_blk = (blk_of[None] < first[:, None]) | (blk_of[None] > last[:, None])
+
+    def poison(a, where, value):
+        """``a`` with ``value`` at the [B, S] positions ``where``, in every
+        layer, head and channel."""
+        m = where.reshape((1, len(qpos), 1, S) + (1,) * (a.ndim - 4))
+        return jnp.where(jnp.asarray(m), jnp.asarray(value, a.dtype), a)
+
+    # int8 cannot hold NaN: there the poison rides the per-position scales
+    kn, vn = ("k_scale", "v_scale") if kv == "int8" else ("k_cache", "v_cache")
+    bad = dict(ops)
+    bad[kn] = poison(ops[kn], dead, np.nan)
+    bad[vn] = poison(poison(ops[vn], dead, 1e30), dead_blk, np.nan)
+    kw = dict(qpos=jnp.asarray(qpos), layer=1, window=W, interpret=True)
+    clean = np.asarray(dense_decode_attention(q, **kw, **ops))
+    out = np.asarray(dense_decode_attention(q, **kw, **bad))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean)
+
+
+@pytest.mark.parametrize("window", [None, 1, 24, 64, 100])
+@pytest.mark.parametrize("blk", [16, 64])
+def test_dense_kernel_block_selection_rule(window, blk):
+    """The index map as a plain function: for every position of a 64-long
+    stripe the grid steps name the first reachable block through the dead
+    steps and then exactly the blocks between the first and the last
+    reachable key, in order, ending on the last — never a block outside
+    ``[first_live, last_live]`` — and traced scalars follow the same rule
+    as numpy arrays."""
+    from kserve_vllm_mini_tpu.ops.paged_attention import (
+        fetched_block,
+        live_block_range,
+    )
+
+    _S = 64
+    nb = _S // blk
+    qpos = np.arange(_S, dtype=np.int32)
+    first, last = live_block_range(qpos, window, blk)
+    lo_key = qpos if window is None else np.maximum(qpos - window + 1, 0)
+    np.testing.assert_array_equal(first, 0 if window is None else lo_key // blk)
+    np.testing.assert_array_equal(last, qpos // blk)
+    steps = [fetched_block(b, nb, first, last) for b in range(nb)]
+    named = np.stack([blk for blk, _ in steps])           # [nb, S]
+    runs = np.stack([run for _, run in steps])
+    assert (named >= first).all() and (named <= last).all()
+    for p in range(_S):
+        live = list(range(first[p], last[p] + 1))
+        assert named[:, p].tolist() == [first[p]] * (nb - len(live)) + live
+        assert runs[:, p].tolist() == [False] * (nb - len(live)) + [True] * len(live)
+        # a change of index is a DMA: exactly one per live block
+        assert 1 + int((np.diff(named[:, p]) != 0).sum()) == len(live)
+    traced = jax.jit(lambda p, b: fetched_block(b, nb, *live_block_range(p, window, blk))[0])
+    for p, b in [(0, 0), (blk - 1, nb - 1), (_S - 1, 0), (_S - 1, nb - 1)]:
+        assert int(traced(jnp.int32(p), jnp.int32(b))) == named[b, p]
 
 
 # -- perplexity gate -----------------------------------------------------------

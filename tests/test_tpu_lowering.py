@@ -9,11 +9,17 @@ those rules (no libtpu, no device, seconds). It stops at the Mosaic module:
 whether Mosaic COMPILES it is chip_smoke.py's kernels leg.
 
 Shapes: KVH 8 / G 4 (the GQA grouping of the 7B/8B presets), head_dim 128
-and 64, the engine's default blocks (kv_block_size 64, dense stripe 512).
+and 64, the engine's default blocks (kv_block_size 64, dense stripe 512);
+the dense decode kernel also at qwen2's KVH 4 / G 7 and with a window.
+
+The model- and engine-level tests at the end pin which configurations take
+``kvmini_dense_decode`` in a decode step, and that taking it changes
+neither the logits nor the greedy tokens.
 """
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from kserve_vllm_mini_tpu.ops.flash_attention import (
@@ -74,15 +80,44 @@ def test_paged_decode_lowers(d, quantized):
 @pytest.mark.parametrize("d", [128, 64])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
 def test_dense_decode_lowers(d, quantized):
+    """128-wide heads lower; 64-wide ones are turned away before any
+    lowering (``dense_decode_block``: XLA would copy the whole cache to
+    feed the call), and the model's gate keeps them on the eager read."""
     slots, seq = 8, 2048
 
     def fn(q, qpos, layer, k, v, ks=None, vs=None):
         return dense_decode_attention(q, k, v, qpos, layer=layer,
                                       k_scale=ks, v_scale=vs)
 
-    _lowers_for_tpu(
+    args = (
         fn, _sds((slots, KVH, G, d), BF), _sds((slots,), I32), _sds((), I32),
         *_kv((L, slots, KVH, seq, d), quantized),
+    )
+    if d % 128:
+        with pytest.raises(ValueError, match="no key block"):
+            _lowers_for_tpu(*args)
+    else:
+        _lowers_for_tpu(*args)
+
+
+@pytest.mark.parametrize("heads", [(8, 4), (4, 7), (32, 1)],
+                         ids=["KVH8-G4", "KVH4-G7", "KVH32-G1"])
+@pytest.mark.parametrize("window", [None, 1024], ids=["causal", "window"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+def test_dense_decode_all_heads_block_lowers(heads, window, quantized):
+    """The benchmark's shapes and codellama's MHA one: 16 slots x 2048, the
+    [KVH, BLK, 128] block of every kv head (BLK 512, 256 for 32 heads),
+    index maps that read ``qpos`` (and the window)."""
+    kvh, g = heads
+    slots, seq, d = 16, 2048, 128
+
+    def fn(q, qpos, layer, k, v, ks=None, vs=None):
+        return dense_decode_attention(q, k, v, qpos, layer=layer,
+                                      k_scale=ks, v_scale=vs, window=window)
+
+    _lowers_for_tpu(
+        fn, _sds((slots, kvh, g, d), BF), _sds((slots,), I32), _sds((), I32),
+        *_kv((L, slots, kvh, seq, d), quantized),
     )
 
 
@@ -99,3 +134,145 @@ def test_cached_prefill_lowers(d, quantized):
         fn, _sds((1, H, t, d), BF), _sds((1,), I32), _sds((), I32),
         *_kv((L, 1, KVH, seq, d), quantized),
     )
+
+
+# -- which decode steps take the dense kernel ----------------------------------
+
+
+def _decode_step(preset, quantized, kernels, **over):
+    """(jaxpr text, logits) of one T == 1 step after a 24-token prefill."""
+    from kserve_vllm_mini_tpu.models.config import get_config
+    from kserve_vllm_mini_tpu.models.llama import forward, init_kv_cache, init_params
+
+    # 128-wide heads: the tiny presets' 32 would keep the eager read
+    cfg = get_config(preset, max_seq_len=64).scaled(**{"explicit_head_dim": 128, **over})
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0, cfg.vocab_size)
+    pos = jnp.broadcast_to(jnp.arange(24), (2, 24)).astype(I32)
+    cache = init_kv_cache(cfg, 2, max_seq=64, quantized=quantized)
+    lg, cache = forward(params, cfg, toks, pos, cache, jnp.zeros((2,), I32),
+                        fresh_prefill=True)
+    nxt = jnp.argmax(lg[:, -1, :], -1).astype(I32)
+    lens = jnp.full((2,), 24, I32)
+
+    def step(c):
+        return forward(params, cfg, nxt[:, None], lens[:, None], c, lens,
+                       kernels=kernels)[0]
+
+    return str(jax.make_jaxpr(step)(cache)), np.asarray(step(cache)[:, 0, :])
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+@pytest.mark.parametrize("preset", ["mistral-tiny", "qwen-tiny"])
+def test_model_decode_takes_dense_kernel(preset, quantized):
+    """A mistral-like (uniform window 16, which the step at position 24 is
+    past) and a qwen2-like (no window, qkv bias) configuration take
+    ``kvmini_dense_decode`` in a T == 1 step, bf16 and int8 KV through the
+    same gate, and give the logits of the eager path."""
+    jaxpr_on, lg_on = _decode_step(preset, quantized, "interpret")
+    jaxpr_off, lg_off = _decode_step(preset, quantized, "off")
+    assert "kvmini_dense_decode" in jaxpr_on
+    assert "kvmini_dense_decode" not in jaxpr_off
+    # the two differ by where bf16 rounds (eager: the dequantized stripe
+    # and the softmax weights; kernel: the weights only, int8 in f32)
+    np.testing.assert_allclose(lg_on, lg_off, rtol=3e-2, atol=3e-2)
+    np.testing.assert_array_equal(lg_on.argmax(-1), lg_off.argmax(-1))
+
+
+@pytest.mark.parametrize("case", ["softcap+alt-window", "alt-window", "softcap"])
+def test_model_decode_keeps_eager_path(case):
+    """A softcap or a per-layer mask keeps the eager read: bit-equal
+    logits with kernels on and off, no Pallas call in the step."""
+    over = {
+        "softcap+alt-window": {},
+        "alt-window": {"attn_softcap": None},
+        "softcap": {"alt_sliding_window": False},
+    }[case]
+    jaxpr_on, lg_on = _decode_step("gemma-tiny", False, "interpret", **over)
+    _, lg_off = _decode_step("gemma-tiny", False, "off", **over)
+    assert "kvmini_dense_decode" not in jaxpr_on
+    np.testing.assert_array_equal(lg_on, lg_off)
+
+
+def _serve_greedy(kernels):
+    """A 4-slot mistral-tiny engine (window 16, 128-wide heads, 1024
+    positions = two key blocks a slot) after serving three staggered greedy
+    requests, and their tokens. In f32: a random-init model's near-ties
+    flip on where bf16 rounds (the kernel and the eager read differ there),
+    and the test is about which keys are read, not about rounding."""
+    from kserve_vllm_mini_tpu.models.config import get_config
+    from kserve_vllm_mini_tpu.models.llama import init_params
+    from kserve_vllm_mini_tpu.runtime.engine import Engine, EngineConfig, GenRequest
+    from kserve_vllm_mini_tpu.runtime.tokenizer import ByteTokenizer
+
+    cfg = get_config("mistral-tiny", max_seq_len=1024).scaled(
+        explicit_head_dim=128, dtype="float32")
+    tok = ByteTokenizer()
+    engine = Engine(
+        init_params(jax.random.PRNGKey(0), cfg), cfg,
+        EngineConfig(max_slots=4, max_seq_len=1024, max_prefill_len=64,
+                     min_prefill_bucket=16),
+        pad_id=tok.pad_id,
+    )
+    # what a TPU backend makes of the engine's "auto": the interpreter
+    # stands in for the compiled kernel (nothing in the program picks it)
+    engine._kernels = kernels
+    engine.start()
+    try:
+        handles = [
+            engine.submit(GenRequest(prompt_tokens=tok.encode(p), max_new_tokens=n))
+            for p, n in (("hello there", 30), ("the quick brown fox jumps", 12),
+                         ("a", 20))
+        ]
+        outs = []
+        for h in handles:
+            toks = []
+            while True:
+                kind, *rest = h.events.get(timeout=300)
+                if kind != "token":
+                    break
+                toks.append(rest[0])
+            outs.append(toks)
+    finally:
+        engine.stop()
+    return outs, engine
+
+
+def test_engine_greedy_tokens_and_kv_block_counters(monkeypatch):
+    """Greedy streams are identical with the kernel on and off; the two
+    counters advance by the kernel's block rule over the lengths each
+    decode step was dispatched with (free slots parked on row 0), and
+    stay 0 where the eager path runs."""
+    from kserve_vllm_mini_tpu.runtime.engine import Engine
+
+    outs_off, eager = _serve_greedy("off")
+    assert eager.stats["decode_kv_blocks"] == eager.stats["decode_kv_blocks_fetched"] == 0
+
+    seen = []  # (lengths, steps) of every decode dispatch
+    count = Engine._count_decode_kv_blocks
+
+    def spy(self, lengths, steps):
+        seen.append((np.array(lengths), steps))
+        count(self, lengths, steps)
+
+    monkeypatch.setattr(Engine, "_count_decode_kv_blocks", spy)
+    outs_on, engine = _serve_greedy("interpret")
+    assert outs_on == outs_off
+    assert [len(o) for o in outs_on] == [30, 12, 20]
+
+    blk = engine._dense_decode_blk
+    assert eager._dense_decode_blk is None and blk == 512
+    nb, W = 1024 // blk, engine.cfg.sliding_window
+    assert nb >= 2
+    fetched = total = 0
+    for lengths, steps in seen:
+        for t in range(steps):
+            hi = np.minimum(lengths + t, 1023)
+            lo = np.maximum(hi - W + 1, 0)
+            fetched += int((hi // blk - lo // blk + 1).sum())
+            total += 4 * nb
+    assert seen and engine.stats["decode_kv_blocks"] == total > 0
+    assert engine.stats["decode_kv_blocks_fetched"] == fetched
+    assert 0 < fetched < total
+    # every slot is free again and parked on row 0, not on its stale length
+    assert engine._slot_len == [0, 0, 0, 0]
