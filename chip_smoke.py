@@ -21,7 +21,11 @@ three legs, set-up seconds only, no performance claim.
   ``kv_cache_dtype=int8`` + ``prefill_chunk=256``: the paged decode and the
   prefill / chunk-prefill executables must hold a Mosaic call (the dense
   decode step of 64-wide heads keeps the eager read), and the kernel path
-  must agree with the jnp path on the same weights.
+  must agree with the jnp path on the same weights. Then olmo-hybrid-7b at
+  its published widths, two of its eight periods (6 linear-attention + 2
+  full-attention layers, int8 weights, prompts prefilled in pieces of 256):
+  its full layers' prefill and decode executables must hold their Mosaic
+  calls beside the linear layers' recurrent state.
 
 With no TPU it exits non-zero before doing anything; it never carries on on
 the CPU. ``--cpu-dry-run`` is the explicit exception for debugging the
@@ -263,10 +267,12 @@ def sizes(dry: bool) -> dict:
     positions, so it keeps the three short phases and shrinks the rest."""
     if dry:
         return dict(main_model="mistral-tiny", small_model="llama-tiny",
+                    hybrid_model="olmo-hybrid-tiny",
                     phases=MAIN_PHASES[:3], out_tokens=8, main_parity=(32,),
                     paged_block=16, paged_parity=(32,), engine_words=20,
                     chunk=32, int8_parity=(32, 32))
     return dict(main_model="mistral-7b", small_model="llama-1b",
+                hybrid_model=hybrid_preset(periods=2),
                 phases=MAIN_PHASES, out_tokens=32, main_parity=(128,),
                 paged_block=64, paged_parity=(128,), engine_words=160,
                 chunk=256, int8_parity=(256, 256))
@@ -496,6 +502,19 @@ def leg_kernels(dry: bool) -> dict:
 
 # -- leg: engines ----------------------------------------------------------------
 
+def hybrid_preset(periods: int) -> str:
+    """olmo-hybrid-7b cut to ``periods`` whole periods of its layer pattern
+    (every width as published), registered under a name of its own so that
+    ``build_engine`` serves it like any preset."""
+    from kserve_vllm_mini_tpu.models.config import PRESETS, get_config
+
+    full = get_config("olmo-hybrid-7b")
+    n = periods * full.layer_period
+    name = f"olmo-hybrid-7b-{periods}p"
+    PRESETS[name] = full.scaled(name=name, n_layers=n, layer_types=full.layer_types[:n])
+    return name
+
+
 def leg_engines(dry: bool) -> dict:
     from kserve_vllm_mini_tpu.runtime.local import start_local_server
 
@@ -512,6 +531,14 @@ def leg_engines(dry: bool) -> dict:
          ["prefill[", "chunk_prefill["],
          dict(chunks=sz["int8_parity"], decode=True, block_size=None,
               kv_int8=True)),
+        # the hybrid's full-attention layers (30 KV heads x 128) take the
+        # flash prefill and the dense decode kernel; continuation pieces of a
+        # bf16 cache keep the eager read
+        ("hybrid", {"model": sz["hybrid_model"], "quantization": "int8",
+                    "prefill_chunk": sz["chunk"]},
+         ["prefill[", "decode[chunk=1]"],
+         dict(chunks=sz["int8_parity"], decode=True, block_size=None,
+              kv_int8=False)),
     ]
     summary = {}
     for name, extra, need, parity in runs:

@@ -21,6 +21,16 @@ orthogonal architecture axes extend it to the other families:
   (1+w) norm weights, GeGLU MLP, sqrt(d_model)-scaled embeddings,
   attention/final logit soft-capping, explicit head_dim decoupled from
   d_model/n_heads, and sliding-window attention on alternating layers.
+- ``layer_types`` — a hybrid stack (Olmo-Hybrid): ``linear_attention``
+  layers (the gated delta rule, models/linear_attention.py, sized by the
+  ``linear_*`` fields) beside ``full_attention`` layers in one repeating
+  period; each kind's weights are stacked on their own, and the cache
+  holds a recurrent state and a convolution tail beside K/V. Its block is
+  ``block="olmo2"``, the OLMo-2/3 reordered norm: no pre-norms, an RMSNorm
+  on each branch's OUTPUT before its residual add, and an RMSNorm over the
+  whole q and k projections (QK-norm) before RoPE. ``olmo2`` is the hybrid
+  stack's block and no axis of its own: only a hybrid's parameter tree
+  holds its gains, so without ``layer_types`` it is refused.
 """
 
 from __future__ import annotations
@@ -92,6 +102,81 @@ class ModelConfig:
     # selects the traced program, so it lives on the config every
     # execution path already threads.
     quant_mode: str = "dequant"
+    # Hybrid stacks (Olmo-Hybrid), named as the published config names them:
+    # one mixer kind per layer, "linear_attention" or "full_attention", in a
+    # repeating period of k linear layers then one full layer. None = every
+    # layer is full attention. A list (from JSON) becomes a tuple: the
+    # config is a static jit argument and must hash.
+    layer_types: Optional[tuple[str, ...]] = None
+    # the gated-delta-rule mixer's sizes (models/linear_attention.py)
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # beta in (0, 2) instead of (0, 1): the state transition may have
+    # negative eigenvalues
+    linear_allow_neg_eigval: bool = False
+
+    def __post_init__(self) -> None:
+        if self.layer_types is None:
+            if self.block == "olmo2":
+                raise ValueError("block='olmo2' is the hybrid stack's block: "
+                                 "it needs layer_types")
+            return
+        types = tuple(self.layer_types)
+        object.__setattr__(self, "layer_types", types)
+        if len(types) != self.n_layers:
+            raise ValueError(
+                f"layer_types names {len(types)} layers, n_layers={self.n_layers}"
+            )
+        if "linear_attention" not in types:
+            raise ValueError("layer_types without a linear_attention layer: "
+                             "leave it None for a full-attention stack")
+        period = types.index("full_attention") + 1 if "full_attention" in types else 0
+        one = ("linear_attention",) * (period - 1) + ("full_attention",)
+        if period < 2 or self.n_layers % period or types != one * (self.n_layers // period):
+            raise ValueError(
+                "layer_types must repeat one period of k >= 1 linear_attention "
+                f"layers then one full_attention layer; got {types}"
+            )
+        if self.linear_num_key_heads != self.linear_num_value_heads:
+            raise ValueError(
+                "linear_num_key_heads != linear_num_value_heads (grouped "
+                "linear-attention heads) is not implemented"
+            )
+        if min(self.linear_num_key_heads, self.linear_key_head_dim,
+               self.linear_value_head_dim) < 1 or self.linear_conv_kernel_dim < 2:
+            raise ValueError("a hybrid stack needs linear_num_key_heads, "
+                             "linear_key_head_dim, linear_value_head_dim >= 1 "
+                             "and linear_conv_kernel_dim >= 2")
+        if self.block != "olmo2" or self.is_moe or self.alt_sliding_window:
+            raise ValueError("a hybrid stack takes block='olmo2' with a dense MLP "
+                             "and one attention mask for every full layer")
+
+    @property
+    def is_hybrid(self) -> bool:
+        return self.layer_types is not None
+
+    @property
+    def layer_period(self) -> int:
+        """Layers in one period of a hybrid stack (k linear + 1 full)."""
+        return self.layer_types.index("full_attention") + 1 if self.is_hybrid else 1
+
+    @property
+    def n_full_layers(self) -> int:
+        """Layers that hold K/V: all of them, or a hybrid's full-attention ones."""
+        return self.n_layers // self.layer_period
+
+    @property
+    def n_linear_layers(self) -> int:
+        return self.n_layers - self.n_full_layers
+
+    @property
+    def linear_conv_channels(self) -> int:
+        """Channels the linear mixer's depthwise convolution runs over: q, k and v."""
+        h = self.linear_num_key_heads
+        return h * (2 * self.linear_key_head_dim + self.linear_value_head_dim)
 
     @property
     def head_dim(self) -> int:
@@ -125,8 +210,22 @@ class ModelConfig:
         if self.attn_bias:
             attn += self.n_heads * self.head_dim + 2 * self.n_kv_heads * self.head_dim
         norms = (4 if self.block == "gemma2" else 2) * self.d_model
+        if self.block == "olmo2":
+            attn += self.n_heads * self.head_dim + self.n_kv_heads * self.head_dim  # QK-norm gains
         head = 0 if self.tie_embeddings else self.vocab_size * self.d_model
-        return emb + self.n_layers * (attn + mlp + norms) + self.d_model + head
+        blocks = self.n_full_layers * (attn + mlp + norms)
+        if self.is_hybrid:
+            h = self.linear_num_key_heads
+            kd, vd = h * self.linear_key_head_dim, h * self.linear_value_head_dim
+            mixer = (
+                self.d_model * (2 * kd + 2 * vd)          # q, k, v, output gate
+                + vd * self.d_model                        # o
+                + 2 * self.d_model * h                     # a, b
+                + self.linear_conv_channels * self.linear_conv_kernel_dim
+                + 2 * h + self.linear_value_head_dim       # A_log, dt_bias, norm gain
+            )
+            blocks += self.n_linear_layers * (mixer + mlp + norms)
+        return emb + blocks + self.d_model + head
 
     def scaled(self, **kwargs) -> "ModelConfig":
         return replace(self, **kwargs)
@@ -299,6 +398,30 @@ PRESETS: dict[str, ModelConfig] = {
         sliding_window=4096,
         alt_sliding_window=True,
     ),
+    # Olmo-Hybrid-7B (huggingface.co/allenai/Olmo-Hybrid-7B config.json):
+    # three gated-delta-rule linear-attention layers to one full-attention
+    # layer, eight times; OLMo-2/3's reordered norm and QK-norm. rope_theta
+    # is OLMo-3's (the source's is null).
+    "olmo-hybrid-7b": ModelConfig(
+        name="olmo-hybrid-7b",
+        vocab_size=100_352,
+        d_model=3840,
+        n_layers=32,
+        n_heads=30,
+        n_kv_heads=30,
+        d_ff=11_008,
+        max_seq_len=8192,
+        rope_theta=500_000.0,
+        rms_eps=1e-6,
+        block="olmo2",
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 8,
+        linear_num_key_heads=30,
+        linear_num_value_heads=30,
+        linear_key_head_dim=96,
+        linear_value_head_dim=192,
+        linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
+    ),
     # -- tiny CI variants (CPU in <1s) exercising each architecture axis ----
     "mistral-tiny": ModelConfig(
         name="mistral-tiny",
@@ -357,6 +480,27 @@ PRESETS: dict[str, ModelConfig] = {
         final_softcap=30.0,
         sliding_window=16,
         alt_sliding_window=True,
+    ),
+    # one whole period x 2 of the Olmo-Hybrid pattern (3 linear + 1 full)
+    "olmo-hybrid-tiny": ModelConfig(
+        name="olmo-hybrid-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=256,
+        max_seq_len=256,
+        rope_theta=500_000.0,
+        rms_eps=1e-6,
+        block="olmo2",
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2,
+        linear_num_key_heads=4,
+        linear_num_value_heads=4,
+        linear_key_head_dim=24,
+        linear_value_head_dim=48,
+        linear_conv_kernel_dim=4,
+        linear_allow_neg_eigval=True,
     ),
     "mixtral-tiny": ModelConfig(
         name="mixtral-tiny",

@@ -92,6 +92,8 @@ def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
     the equivalence oracle in tests/test_quant.py rests on)."""
     dt = cfg.jnp_dtype
     keys = _init_keys(rng, cfg)
+    if cfg.is_hybrid:
+        return _init_hybrid(keys, cfg, leaf_fn)
     L = cfg.n_layers
 
     layers: Params = {"attn_norm": jnp.ones((L, cfg.d_model), dtype=dt)}
@@ -138,6 +140,65 @@ def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
     if cfg.block == "phi":
         params["final_norm_b"] = jnp.zeros((cfg.d_model,), dtype=dt)
         params["lm_head_b"] = jnp.zeros((cfg.vocab_size,), dtype=dt)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt)
+    return params
+
+
+def _init_hybrid(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
+    """A hybrid stack's tree: ``layers`` holds each kind's weights stacked
+    on their own, ``{"full": [n_full, ...], "linear": [n_linear, ...]}``,
+    in stack order within the kind. Gains, taps and gate parameters get
+    values that show when they are left out (not 1, not 0)."""
+    from kserve_vllm_mini_tpu.models.linear_attention import mixer_weight_shapes
+    from kserve_vllm_mini_tpu.ops.quant import HYBRID_QUANTIZABLE
+
+    dt = cfg.jnp_dtype
+    shapes = _stacked_weight_shapes(cfg)
+
+    def stack(key, n, shape, fn):
+        def body(_, k):
+            return None, fn(_nrm(k, shape, dt))
+
+        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
+
+    def gains(key, n, width):
+        return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
+
+    def mlp_and_norms(key, n):
+        ks = jax.random.split(key, 5)
+        out = {name: stack(k, n, shapes[name], leaf_fn)
+               for name, k in zip(("w_gate", "w_up", "w_down"), ks)}
+        out["post_attn_norm"] = gains(ks[3], n, cfg.d_model)
+        out["post_mlp_norm"] = gains(ks[4], n, cfg.d_model)
+        return out
+
+    nf, nl, h = cfg.n_full_layers, cfg.n_linear_layers, cfg.linear_num_key_heads
+    kf = jax.random.split(keys["wq"], 8)
+    full: Params = mlp_and_norms(kf[0], nf)
+    for name, k in zip(("wq", "wk", "wv", "wo"), kf[1:5]):
+        full[name] = stack(k, nf, shapes[name], leaf_fn)
+    # a hybrid stack is an olmo2 block (ModelConfig holds it to that): QK-norm
+    full["q_norm"] = gains(kf[5], nf, cfg.n_heads * cfg.head_dim)
+    full["k_norm"] = gains(kf[6], nf, cfg.n_kv_heads * cfg.head_dim)
+    kl = jax.random.split(keys["wk"], 12)
+    lin: Params = mlp_and_norms(kl[0], nl)
+    for (name, shape), k in zip(mixer_weight_shapes(cfg).items(), kl[1:8]):
+        # wa, wb [d, heads] stay as they are in the int8 tree (ops/quant.py)
+        lin[name] = stack(k, nl, shape,
+                          leaf_fn if name in HYBRID_QUANTIZABLE else (lambda w: w))
+    lin["conv"] = (0.3 * jax.random.normal(
+        kl[8], (nl, cfg.linear_conv_kernel_dim, cfg.linear_conv_channels), jnp.float32)
+    ).astype(dt)
+    # decays between ~0.5 and ~0.999 a step at a zero pre-activation
+    lin["A_log"] = jnp.log(jax.random.uniform(kl[9], (nl, h), jnp.float32, 0.5, 4.0))
+    lin["dt_bias"] = jax.random.uniform(kl[10], (nl, h), jnp.float32, -6.0, -1.0)
+    lin["o_norm"] = gains(kl[11], nl, cfg.linear_value_head_dim)
+    params: Params = {
+        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
+        "layers": {"full": full, "linear": lin},
+        "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
+    }
     if not cfg.tie_embeddings:
         params["lm_head"] = _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt)
     return params
@@ -190,17 +251,27 @@ def init_kv_cache(
     a per-position amax scale — reference analog: the kv-cache-dtype knob
     the quantization sweep measures (sweeps/quantization_sweep.py:40-234)."""
     s = max_seq or cfg.max_seq_len
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, s, cfg.head_dim)
+    # a hybrid stack keeps K/V for its full-attention layers only, and beside
+    # them (same tree, slot axis still dim 1) what its linear layers keep
+    shape = (cfg.n_full_layers, batch, cfg.n_kv_heads, s, cfg.head_dim)
+    extra: KVCache = {}
+    if cfg.is_hybrid:
+        from kserve_vllm_mini_tpu.models.linear_attention import state_shapes
+
+        extra = {name: jnp.zeros(shp, dtype=dt)
+                 for name, (shp, dt) in state_shapes(cfg, batch).items()}
     if quantized:
         return {
             "k": jnp.zeros(shape, dtype=jnp.int8),
             "v": jnp.zeros(shape, dtype=jnp.int8),
             "k_s": jnp.zeros(shape[:-1], dtype=jnp.float32),
             "v_s": jnp.zeros(shape[:-1], dtype=jnp.float32),
+            **extra,
         }
     return {
         "k": jnp.zeros(shape, dtype=dtype or cfg.jnp_dtype),
         "v": jnp.zeros(shape, dtype=dtype or cfg.jnp_dtype),
+        **extra,
     }
 
 
@@ -227,6 +298,9 @@ def init_paged_kv_cache(
     scales]); the rank-5 value layout moves the slot axis to a block axis.
     Consumed by ``forward(..., block_table=...)``.
     """
+    if cfg.is_hybrid:
+        raise ValueError("a hybrid stack's recurrent state has no positions "
+                         "to page; use the dense cache")
     shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     if quantized:
         return {
@@ -297,6 +371,10 @@ def qkv_proj(
     v = adapted_linear(h, p["wv"], lora, "wv", lora_ids, mode=qm)
     if cfg.attn_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
+    if cfg.block == "olmo2":
+        # the family's QK-norm: over the whole projection, before the heads split
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
     q = q.reshape(B, T, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
@@ -349,7 +427,11 @@ def final_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarra
 
 def block_norm(p: Params, cfg: ModelConfig, x: jnp.ndarray, name: str) -> jnp.ndarray:
     """The block's norm: RMSNorm (llama family), biased LayerNorm (phi),
-    or (1+w)-weighted RMSNorm (gemma — weights stored as offsets)."""
+    or (1+w)-weighted RMSNorm (gemma — weights stored as offsets). The
+    olmo2 block has no pre-norm: its mixers read the residual stream as it
+    is, and ``branch_tail`` norms their outputs."""
+    if cfg.block == "olmo2" and name == "attn_norm":
+        return x
     if cfg.block == "phi":
         return layer_norm(x, p[name], p[name + "_b"], cfg.rms_eps)
     if cfg.block == "gemma2":
@@ -410,16 +492,43 @@ def attn_out_and_mlp(
         mlp_out = _al(gate * _al(h2, p["w_up"], lora, "w_up", lora_ids),
                       p["w_down"], lora, "w_down", lora_ids)
         return x + block_norm(p, cfg, mlp_out, "post_mlp_norm")
+    if cfg.block == "olmo2":
+        return branch_tail(p, cfg, x, _al(o, p["wo"], lora, "wo", lora_ids))
     x = x + _al(o, p["wo"], lora, "wo", lora_ids)
     h = rms_norm(x, p["mlp_norm"], cfg.rms_eps)
     if cfg.is_moe:
         from kserve_vllm_mini_tpu.models.moe import moe_mlp
 
         return x + moe_mlp(p, cfg, h)
+    return x + swiglu_mlp(p, cfg, h, lora, lora_ids)
+
+
+def swiglu_mlp(
+    p: Params,
+    cfg: ModelConfig,
+    h: jnp.ndarray,
+    lora: Optional[Params] = None,
+    lora_ids: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """``W_down(silu(W_gate h) * W_up h)``: the dense MLP of the llama and
+    olmo2 blocks, residual and norms left to the caller."""
+    from functools import partial
+
+    from kserve_vllm_mini_tpu.ops.lora import adapted_linear
+
+    _al = partial(adapted_linear, mode=cfg.quant_mode)
     gated = jax.nn.silu(
         _al(h, p["w_gate"], lora, "w_gate", lora_ids).astype(jnp.float32)
-    ).astype(dt) * _al(h, p["w_up"], lora, "w_up", lora_ids)
-    return x + _al(gated, p["w_down"], lora, "w_down", lora_ids)
+    ).astype(cfg.jnp_dtype) * _al(h, p["w_up"], lora, "w_up", lora_ids)
+    return _al(gated, p["w_down"], lora, "w_down", lora_ids)
+
+
+def branch_tail(p: Params, cfg: ModelConfig, x: jnp.ndarray,
+                mixer_out: jnp.ndarray) -> jnp.ndarray:
+    """The olmo2 block after its mixer, whichever kind that was:
+    ``h = x + RMSNorm(mixer_out)``, ``h + RMSNorm(MLP(h))``."""
+    x = x + rms_norm(mixer_out, p["post_attn_norm"], cfg.rms_eps)
+    return x + rms_norm(swiglu_mlp(p, cfg, x), p["post_mlp_norm"], cfg.rms_eps)
 
 
 def layer_forward(
@@ -536,6 +645,12 @@ def run_cached_layers(
                                  # elsewhere; "off" = jnp only (GSPMD-
                                  # partitioned jits — Mosaic calls cannot be
                                  # auto-partitioned); "interpret" = tests
+    valid_len: Optional[jnp.ndarray] = None,  # [B] int32: leading positions
+                                 # of this block that are real. Only a hybrid
+                                 # stack reads it: positions past it leave
+                                 # its recurrent state and convolution tail
+                                 # untouched (models/linear_attention.py);
+                                 # None = all T
 ) -> tuple[jnp.ndarray, KVCache]:
     """The cached transformer stack: scan over stacked layers, writing this
     block's K/V at ``cache_offsets`` and attending with positional masking
@@ -689,6 +804,9 @@ def run_cached_layers(
             p, lora_p, lidx = layer_xs
         else:
             (p, lidx), lora_p = layer_xs, None
+        return full_layer(y0, cache, p, lora_p, lidx), None
+
+    def full_layer(y0, cache, p, lora_p, lidx):
         h = block_norm(p, cfg, y0, "attn_norm")
         q, k, v = qkv_proj(p, cfg, h, positions, cos, sin,
                            lora=lora_p, lora_ids=lora_ids)
@@ -809,7 +927,58 @@ def run_cached_layers(
         return (
             attn_out_and_mlp(p, cfg, y0, o, h, lora=lora_p, lora_ids=lora_ids),
             cache,
-        ), None
+        )
+
+    if cfg.is_hybrid:
+        # one period = k linear layers then one full layer; each kind's
+        # weights are stacked on their own. The stack is ONE scan over the
+        # periods with the period's linear layers unrolled in its body, each
+        # indexing the linear stack by its own layer number: a nested scan
+        # (or a [periods, k, ...] view as xs) makes XLA copy a period's
+        # weights out of the stack every iteration, which doubles the
+        # weight stream of a decode step. Both kinds of state ride the
+        # carry (invariant 1 above holds for them too).
+        if (lora is not None or paged or write_gate is not None
+                or slot_base is not None):
+            raise ValueError(
+                "a hybrid stack serves from the dense cache on one device, "
+                "without adapters (no block table, pipeline stage or LoRA bank)"
+            )
+        from kserve_vllm_mini_tpu.models.linear_attention import linear_mixer
+
+        n_lin = cfg.layer_period - 1
+        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
+                 else valid_len.astype(jnp.int32))
+        lin_layers = layers["linear"]
+
+        def linear_layer(y0, cache, lidx):
+            p = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, lidx, 0, keepdims=False),
+                lin_layers)
+            state = jax.lax.dynamic_index_in_dim(cache["lin_state"], lidx, 0, keepdims=False)
+            tail = jax.lax.dynamic_index_in_dim(cache["lin_conv"], lidx, 0, keepdims=False)
+            if fresh_prefill:
+                # a new sequence starts from S_0 = 0 whatever the slot held
+                state, tail = jnp.zeros_like(state), jnp.zeros_like(tail)
+            out, state, tail = linear_mixer(p, cfg, y0, state, tail, valid)
+            cache = dict(cache)
+            cache["lin_state"] = jax.lax.dynamic_update_index_in_dim(
+                cache["lin_state"], state, lidx, 0)
+            cache["lin_conv"] = jax.lax.dynamic_update_index_in_dim(
+                cache["lin_conv"], tail, lidx, 0)
+            return branch_tail(p, cfg, y0, out), cache
+
+        def period(carry, period_xs):
+            p_full, pidx = period_xs
+            y, cache = carry
+            for j in range(n_lin):
+                y, cache = linear_layer(y, cache, pidx * n_lin + j)
+            return full_layer(y, cache, p_full, None, pidx), None
+
+        (x, new_cache), _ = jax.lax.scan(
+            period, (x, dict(kv_cache)), (layers["full"], jnp.arange(n_local)),
+        )
+        return x, new_cache
 
     xs = (
         (layers, lora, jnp.arange(n_local))
@@ -856,6 +1025,12 @@ def forward(
     lora_ids: Optional[jnp.ndarray] = None,  # [B] adapter index per row
     kernels: str = "auto",  # Pallas kernel selection; the engine passes
                         # "off" under a GSPMD mesh (run_cached_layers)
+    valid_len: Optional[jnp.ndarray] = None,  # [B] int32: how many leading
+                        # positions of this block are real (a padded prefill
+                        # bucket's prompt length; 0 for a decode row whose
+                        # slot is parked). Padding is harmless in a KV stripe
+                        # and corrupts a recurrent state, so a hybrid stack
+                        # needs it; every other model ignores it
 ) -> tuple[jnp.ndarray, Optional[KVCache]]:
     """Returns (logits [B, T, V] float32, updated cache).
 
@@ -883,11 +1058,22 @@ def forward(
         cache_offsets = jnp.zeros((B,), dtype=jnp.int32)
 
     layers = params["layers"]
-    if use_cache:
+    if cfg.is_hybrid and not use_cache:
+        # the cache-free forward of a hybrid is a fresh prefill into a cache
+        # of exactly this block, thrown away
+        if attention_fn is not None:
+            raise ValueError("a hybrid stack takes no attention_fn override")
+        x, _ = run_cached_layers(
+            layers, cfg, x, positions, cos, sin,
+            init_kv_cache(cfg, B, max_seq=T), jnp.zeros((B,), jnp.int32),
+            fresh_prefill=True, kernels=kernels, valid_len=valid_len,
+        )
+        new_cache_dict = None
+    elif use_cache:
         x, new_cache_dict = run_cached_layers(
             layers, cfg, x, positions, cos, sin, kv_cache, cache_offsets,
             fresh_prefill=fresh_prefill, block_table=block_table,
-            lora=lora, lora_ids=lora_ids, kernels=kernels,
+            lora=lora, lora_ids=lora_ids, kernels=kernels, valid_len=valid_len,
         )
     else:
         def scan_body_nocache(carry, xs):

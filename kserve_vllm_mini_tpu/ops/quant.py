@@ -144,15 +144,26 @@ def linear(x: jnp.ndarray, w: Any, mode: str = "dequant") -> jnp.ndarray:
 # (models/llama.py init_params layout). Norms, embeddings, and the lm_head
 # stay high-precision — standard practice, and the embed is a gather anyway.
 QUANTIZABLE = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# a hybrid stack's linear mixer (models/linear_attention.py) adds its output
+# gate; its wq/wk/wv/wo carry the attention names. Its small leaves (wa, wb
+# [d, heads], the convolution taps, A_log, dt_bias, gains) stay as they are:
+# noise-level bytes that set the state's decay and write strength.
+HYBRID_QUANTIZABLE = QUANTIZABLE + ("wg",)
 
 
 def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
-    """Quantize every transformer matmul weight in a Llama param tree."""
+    """Quantize every transformer matmul weight in a Llama param tree (or,
+    for a hybrid stack, in each kind's sub-tree of ``layers``)."""
+    def stack(layers: dict[str, Any], names: tuple[str, ...]) -> dict[str, Any]:
+        return {k: (quantize_weight(v, bits=bits) if k in names else v)
+                for k, v in layers.items()}
+
     out = dict(params)
-    out["layers"] = {
-        k: (quantize_weight(v, bits=bits) if k in QUANTIZABLE else v)
-        for k, v in params["layers"].items()
-    }
+    layers = params["layers"]
+    if "linear" in layers and "full" in layers:
+        out["layers"] = {kind: stack(sub, HYBRID_QUANTIZABLE) for kind, sub in layers.items()}
+    else:
+        out["layers"] = stack(layers, QUANTIZABLE)
     return out
 
 

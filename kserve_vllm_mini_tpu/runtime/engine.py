@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
+from kserve_vllm_mini_tpu.models.linear_attention import CHUNK as LINEAR_CHUNK
 from kserve_vllm_mini_tpu.models.llama import dense_decode_kernel_block, forward
 from kserve_vllm_mini_tpu.profiling.compile_stats import (
     CompileRecorder,
@@ -527,6 +528,9 @@ class Engine:
         self.mesh = mesh
         self.pad_id = pad_id
         self.params = params
+        self._hybrid = cfg.is_hybrid
+        if self._hybrid:
+            self._refuse_for_hybrid(mesh, drafter, lora, prefill_mesh)
 
         # Pallas kernel selection for every compiled step (models/llama.py
         # forward ``kernels``): steps jitted over a mesh are partitioned by
@@ -756,6 +760,14 @@ class Engine:
             self._cache = jax.jit(make_cache, out_shardings=sh)()
         else:
             self._cache = make_cache()
+        # what the cache tree reserves, by kind of state (gauge, fixed at build)
+        self._cache_bytes = {"kv": 0, "linear_state": 0, "conv_state": 0}
+        for name, leaf in self._cache.items():
+            kind = {"lin_state": "linear_state", "lin_conv": "conv_state"}.get(name, "kv")
+            self._cache_bytes[kind] += int(leaf.nbytes)
+        # which rows of a decode sweep may move a hybrid's recurrent state:
+        # the device mask of the last dispatch, keyed by its active set
+        self._live_dev: Optional[tuple[tuple[int, ...], jnp.ndarray]] = None
 
         # speculative decoding: the drafter keeps its own KV cache with the
         # same slot/seq geometry so slot bookkeeping is shared
@@ -1043,6 +1055,19 @@ class Engine:
             # is a constant). Both stay 0 where the eager read runs.
             "decode_kv_blocks_fetched": 0,
             "decode_kv_blocks": 0,
+            # a hybrid stack's recurrent state (models/linear_attention.py;
+            # all 0 for every other model). Chunks of 64 positions the
+            # target's prefill pieces ran through the chunk-parallel scan,
+            # and those of them that held only padding (a layer's worth);
+            # slot-steps the decode sweeps moved state for (slots x fused
+            # steps: a sweep reads and writes every slot's state), and
+            # those whose slot was not live, so its state was held; fresh
+            # prefills, each of which starts its slot's state from zero
+            "linear_scan_chunks": 0,
+            "linear_scan_pad_chunks": 0,
+            "linear_state_slot_steps": 0,
+            "linear_state_held_slot_steps": 0,
+            "linear_state_resets": 0,
             "requests_completed": 0,
             "busy_s": 0.0,        # exported: busy_seconds_total + duty_cycle
             "started_at": _now(),  # kvmini: metrics-ok — raw input; exposed as duty_cycle
@@ -1173,6 +1198,41 @@ class Engine:
         # /metrics served before the first sweep must still carry the
         # paged pool gauges rather than an empty fallback dict
         self._kv_admin_snapshot()
+
+    def _refuse_for_hybrid(self, mesh, drafter, lora, prefill_mesh) -> None:
+        """A hybrid stack (cfg.layer_types) keeps a recurrent state per
+        slot beside its K/V. A state has no positions: it cannot be rewound
+        to a shared prefix, cut into blocks, verified-and-rolled-back by a
+        drafter, or handed over as a stripe, and nothing here shards it.
+        Each such setting is an error at construction, never a fallback."""
+        e = self.ecfg
+        why = None
+        if e.prefix_cache:
+            why = ("prefix_cache: a retained slot's state is the state after "
+                   "its LAST token; reusing a prefix needs a snapshot of the "
+                   "state at the prefix's end, which nothing keeps")
+        elif e.kv_layout != "dense":
+            why = (f"kv_layout={e.kv_layout!r}: the recurrent state has no "
+                   "positions to page; it lives per slot beside dense stripes")
+        elif drafter is not None or e.spec_tokens > 0:
+            why = ("a drafter / spec_tokens: rejected draft tokens would have "
+                   "to be rolled back out of the state, and it has no rewind")
+        elif e.disagg or prefill_mesh is not None:
+            why = ("disagg: the handoff protocol ships K/V stripes or block "
+                   "tables, not a recurrent state and convolution tail")
+        elif lora is not None:
+            why = "a LoRA bank: the linear mixer's projections take no adapters"
+        elif e.kv_host_tier_bytes:
+            why = ("kv_host_tier_bytes: the host tier holds paged K/V blocks, "
+                   "and a hybrid has none")
+        elif mesh is not None:
+            why = ("a mesh (tp/dp/sp/pp): nothing shards the recurrent state "
+                   "or splits the period walk over pipeline stages")
+        if why is not None:
+            raise ValueError(
+                f"model {self.cfg.name!r} is a hybrid stack (linear-attention "
+                f"layers beside full attention) and cannot be served with {why}"
+            )
 
     # -- paged-KV block accounting ----------------------------------------
 
@@ -1758,6 +1818,11 @@ class Engine:
                 raise ValueError(
                     "multi-LoRA excludes drafters and prefix_cache"
                 )
+            if self._hybrid:
+                raise ValueError(
+                    "multi-LoRA excludes hybrid stacks: the linear mixer's "
+                    "projections take no adapters"
+                )
             _require_tp_only_mesh(self.mesh)
             # TRANSACTIONAL: every mutation lands on a local bank and
             # self._lora is only reassigned after install_adapter succeeds
@@ -1917,6 +1982,9 @@ class Engine:
             # full [1, bucket, V] f32 logits tensor is ~2 GB at 128k vocab
             # for the server-default 4096 bucket, on the per-request path
             kw = {"lora": lora, "lora_ids": ids} if lora is not None else {}
+            if cfg.is_hybrid:
+                # padding past the prompt must leave the recurrent state alone
+                kw["valid_len"] = length[None]
             logits, new_sub = fwd(
                 params, cfg, tokens, pos,
                 sub, jnp.zeros((1,), jnp.int32),
@@ -1958,6 +2026,8 @@ class Engine:
             pos = offset + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
             sub = slice_cache_slots(cache, slot)
             kw = {"lora": lora, "lora_ids": ids} if lora is not None else {}
+            if cfg.is_hybrid:
+                kw["valid_len"] = length[None]
             logits, new_sub = fwd(
                 params, cfg, tokens, pos,
                 sub, offset[None],
@@ -2053,11 +2123,17 @@ class Engine:
 
         @partial(jax.jit, donate_argnums=(1, 8))
         def decode(params, cache, tokens, lengths, temps, topks, topps, rng,
-                   counts, pres, freqs, table=None, lora=None, ids=None):
+                   counts, pres, freqs, table=None, lora=None, ids=None,
+                   live=None):
+            # live: [S] bool, hybrid stacks only — the rows whose slot is
+            # decoding. Every other row's recurrent state is held through
+            # the sweep (its K/V write is harmless, as for any model)
             def body(carry, _):
                 c, toks, lens, r, cnt = carry
                 r, sub = jax.random.split(r)
                 kw = {"kernels": kernels}
+                if live is not None:
+                    kw["valid_len"] = live.astype(jnp.int32)
                 if paged:
                     kw["block_table"] = table
                 if lora is not None:
@@ -2107,8 +2183,10 @@ class Engine:
         def decode_masked(params, cache, tokens, lengths,
                           temps, topks, topps, rng, counts, pres, freqs,
                           packed_mask, use_mask,
-                          table=None, lora=None, ids=None):
+                          table=None, lora=None, ids=None, live=None):
             kw = {"kernels": kernels}
+            if live is not None:
+                kw["valid_len"] = live.astype(jnp.int32)
             if paged:
                 kw["block_table"] = table
             if lora is not None:
@@ -2599,9 +2677,19 @@ class Engine:
         off = st["draft_off"] if draft else st["off"]
         piece = prompt[off : off + budget]
         bucket = self._bucket(len(piece))
+        span_args = {}
+        if self._hybrid:
+            # 64-position chunks this piece runs through each linear layer's
+            # scan, and those of them that hold only padding
+            chunks = -(-bucket // LINEAR_CHUNK)
+            span_args["linear_chunks"] = chunks
+            self.stats["linear_scan_chunks"] += chunks
+            self.stats["linear_scan_pad_chunks"] += chunks - -(-len(piece) // LINEAR_CHUNK)
+            if off == 0:
+                self.stats["linear_state_resets"] += 1
         with self._sched.phase(
             "prefill", request_id=handle.request.request_id,
-            prompt_tokens=len(piece), bucket=bucket, offset=off,
+            prompt_tokens=len(piece), bucket=bucket, offset=off, **span_args,
         ) as ph:
             last_logits = self._prefill_piece(
                 piece, slot, off, draft=draft, adapter_idx=st["adapter_idx"]
@@ -3537,6 +3625,21 @@ class Engine:
         self._tokens_dev_slots = frozenset(range(self.ecfg.max_slots))
         return self._tokens_dev
 
+    def _live_mask(self, active: list[int], steps: int) -> jnp.ndarray:
+        """[S] bool for a hybrid stack's decode dispatch: True for the
+        slots in ``active``. A slot that is parked, or between two pieces
+        of its prefill, is False and keeps its recurrent state through the
+        sweep. Books the slot-steps the sweep moves state for."""
+        S = self.ecfg.max_slots
+        self.stats["linear_state_slot_steps"] += S * steps
+        self.stats["linear_state_held_slot_steps"] += (S - len(active)) * steps
+        key = tuple(active)
+        if self._live_dev is None or self._live_dev[0] != key:
+            mask = np.zeros((S,), dtype=bool)
+            mask[active] = True
+            self._live_dev = (key, jnp.asarray(mask))
+        return self._live_dev[1]
+
     def _chunk_for(self, active: list[int]) -> int:
         """Fused-step count for the next plain dispatch: decode_chunk
         clamped into every active slot's REMAINING cache window (minus
@@ -3636,6 +3739,8 @@ class Engine:
             if self._lora is not None:
                 lkw["lora"] = self._lora["layers"]
                 lkw["ids"] = self._adapter_ids()
+            if self._hybrid:
+                lkw["live"] = self._live_mask(active, chunk)
             decode = self._get_decode_fn(chunk)
             # the jitted call alone: what bench/trace_reduce.py names gaps by
             with self._sched.phase("decode_dispatch") as ph:
@@ -3829,6 +3934,8 @@ class Engine:
         if self._lora is not None:
             lkw["lora"] = self._lora["layers"]
             lkw["ids"] = self._adapter_ids()
+        if self._hybrid:
+            lkw["live"] = self._live_mask(active, 1)
         decode = self._get_masked_decode_fn()
         # dispatch, readback and emission are the same child phases as on
         # the plain path; masked_sweep's own time is the preparation above
@@ -4389,6 +4496,7 @@ class Engine:
         s["kv_prefix_hit_depth_p50"] = kv.get("kv_prefix_hit_depth_p50", 0)
         s["kv_prefix_hit_depth_p95"] = kv.get("kv_prefix_hit_depth_p95", 0)
         s["kv_bytes_per_token"] = self.kv_bytes_per_token()
+        s["cache_bytes"] = dict(self._cache_bytes)
         # physical bytes the reused prompt tokens did NOT re-write — the
         # byte-denominated view of prefix_tokens_reused_total
         s["kv_reused_bytes"] = s["prefix_tokens_reused"] * s["kv_bytes_per_token"]
@@ -4505,7 +4613,8 @@ class Engine:
             )
         else:
             elem = kv_elem_bytes(cfg.head_dim, cfg.jnp_dtype.itemsize)
-        return int(2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * elem)
+        # K/V of the layers that hold them: all, or a hybrid's full-attention ones
+        return int(2 * cfg.n_full_layers * cfg.n_kv_heads * cfg.head_dim * elem)
 
     def _kv_admin_snapshot(self, force: bool = False) -> dict[str, Any]:
         """Every DERIVED KV gauge — pool occupancy, fragmentation,

@@ -1355,6 +1355,25 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"{s['decode_kv_blocks_fetched']}",
             "# TYPE kvmini_tpu_decode_kv_blocks_total counter",
             f"kvmini_tpu_decode_kv_blocks_total {s['decode_kv_blocks']}",
+            # a hybrid stack's recurrent state (docs/TRACING.md "Hybrid
+            # stacks"): what the cache tree reserves by kind of state, the
+            # chunks its prefill scans ran and padded, the slot-steps its
+            # decode sweeps moved state for and held, and the fresh
+            # prefills that zeroed a slot's state. 0 for every other model
+            "# TYPE kvmini_tpu_cache_bytes gauge",
+            *(f"kvmini_tpu_cache_bytes{{kind=\"{kind}\"}} {s['cache_bytes'][kind]}"
+              for kind in s["cache_bytes"]),
+            "# TYPE kvmini_tpu_linear_scan_chunks_total counter",
+            f"kvmini_tpu_linear_scan_chunks_total {s['linear_scan_chunks']}",
+            "# TYPE kvmini_tpu_linear_scan_pad_chunks_total counter",
+            f"kvmini_tpu_linear_scan_pad_chunks_total {s['linear_scan_pad_chunks']}",
+            "# TYPE kvmini_tpu_linear_state_slot_steps_total counter",
+            f"kvmini_tpu_linear_state_slot_steps_total {s['linear_state_slot_steps']}",
+            "# TYPE kvmini_tpu_linear_state_held_slot_steps_total counter",
+            "kvmini_tpu_linear_state_held_slot_steps_total "
+            f"{s['linear_state_held_slot_steps']}",
+            "# TYPE kvmini_tpu_linear_state_resets_total counter",
+            f"kvmini_tpu_linear_state_resets_total {s['linear_state_resets']}",
             # decode-pipeline telemetry (docs/DECODE_PIPELINE.md): depth >= 2
             # + low bubble = the double-buffered steady state is engaged
             "# TYPE kvmini_tpu_dispatch_depth gauge",

@@ -40,7 +40,8 @@ def _harness(prefix_cache=True, pool=POOL):
         min_prefill_bucket=BLK, decode_chunk=1,
     )
     eng.cfg = SimpleNamespace(
-        n_layers=2, n_kv_heads=2, head_dim=4, jnp_dtype=np.dtype("float32")
+        n_layers=2, n_full_layers=2, n_kv_heads=2, head_dim=4,
+        jnp_dtype=np.dtype("float32"),
     )
     eng.paged = True
     eng._blk = BLK
