@@ -889,6 +889,8 @@ class Engine:
         # sampling-parameter device arrays, rebuilt only on admit/finish —
         # never on the per-token hot path
         self._sampling_arrays: Optional[tuple] = None
+        # whether any row of those arrays asks filter_logits for its sort
+        self._sampler_filters = False
 
         # double-buffered decode state (docs/DECODE_PIPELINE.md):
         # _tokens_dev mirrors _last_tokens on device — in steady state it is
@@ -1055,6 +1057,11 @@ class Engine:
             # is a constant). Both stay 0 where the eager read runs.
             "decode_kv_blocks_fetched": 0,
             "decode_kv_blocks": 0,
+            # of decode_steps (plain and masked sweeps, booked with them
+            # at retire), those dispatched while some occupied slot sampled
+            # with a top-k or top-p filter: the steps in which the
+            # sampler's full-vocabulary sort ran (sampling.filter_logits)
+            "sampler_filter_steps": 0,
             # a hybrid stack's recurrent state (models/linear_attention.py;
             # all 0 for every other model). Chunks of 64 positions the
             # target's prefill pieces ran through the chunk-parallel scan,
@@ -3326,6 +3333,13 @@ class Engine:
     def _get_sampling_arrays(self) -> tuple:
         if self._sampling_arrays is None:
             S = self.ecfg.max_slots
+            # the predicate filter_logits computes on the device, from the
+            # same rows (a free slot's neutral row never asks)
+            self._sampler_filters = any(
+                h is not None and h.request.temperature > 0
+                and (h.request.top_k > 0 or h.request.top_p < 1.0)
+                for h in self._slot_req
+            )
             self._sampling_arrays = (
                 jnp.asarray(
                     [self._slot_req[i].request.temperature if self._slot_req[i] else 0.0
@@ -3764,6 +3778,9 @@ class Engine:
                 # handle that replaced the one this sweep was dispatched for
                 "handles": {i: self._slot_req[i] for i in active},
                 "chunk": chunk,
+                # whether a row of the sampling arrays this sweep was
+                # dispatched with made the sampler sort
+                "filters": self._sampler_filters,
                 "t_dispatch": t0,
                 # rng state BEFORE this dispatch's split: if every slot
                 # finishes before this sweep is retired, the sweep is dropped
@@ -3803,6 +3820,8 @@ class Engine:
             self._note_sweep(rec["t_dispatch"], t_ready)
             self._pending_steps -= rec["chunk"]
             self.stats["decode_steps"] += rec["chunk"]
+            if rec["filters"]:
+                self.stats["sampler_filter_steps"] += rec["chunk"]
             overlapped = bool(self._inflight)  # device still computing N+1
             for step in range(toks_h.shape[0]):
                 for i in rec["active"]:
@@ -3964,6 +3983,8 @@ class Engine:
         self._t_last_ready = now
         self._note_sweep(t0, now)
         self.stats["decode_steps"] += 1
+        if self._sampler_filters:
+            self.stats["sampler_filter_steps"] += 1
         with self._sched.phase("emit") as ph:
             for step in range(toks_h.shape[0]):
                 for i in active:

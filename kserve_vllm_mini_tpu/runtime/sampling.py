@@ -5,6 +5,12 @@ top-k, and top-p are [B] vectors so heterogeneous requests batch together
 (continuous batching must not re-trace when a new request's temperature
 differs). Greedy is temperature == 0 via jnp.where, not Python branching —
 everything stays traceable.
+
+The sampler does the sorting its batch's parameters ask for
+(``filter_logits``): no full-vocabulary sort while no sampled row sets
+``top_k`` or ``top_p``, one sort where a row does, and a row that sets
+neither reads the same either way. One executable: the choice is a
+``lax.cond`` on the device over the [B] parameter vectors.
 """
 
 from __future__ import annotations
@@ -49,7 +55,7 @@ def sample_tokens(
     rng: jax.Array,
     temperature: jnp.ndarray,   # [B] float32; 0 => greedy
     top_k: jnp.ndarray,         # [B] int32; 0 => disabled
-    top_p: jnp.ndarray,         # [B] float32; 1.0 => disabled
+    top_p: jnp.ndarray,         # [B] float32; >= 1.0 => disabled
 ) -> jnp.ndarray:
     """Returns [B] int32 sampled token ids.
 
@@ -70,12 +76,24 @@ def filter_logits(
     logits: jnp.ndarray,        # [B, V] float32
     temperature: jnp.ndarray,   # [B] float32; 0 => greedy (one-hot dist)
     top_k: jnp.ndarray,         # [B] int32; 0 => disabled
-    top_p: jnp.ndarray,         # [B] float32; 1.0 => disabled
+    top_p: jnp.ndarray,         # [B] float32; >= 1.0 => disabled
 ) -> jnp.ndarray:
     """The filtered/scaled logits whose softmax IS each row's sampling
     distribution — the single masking pipeline ``sample_tokens`` samples
     from and the speculative rejection test computes p/q with (one
     implementation, so they can never drift apart).
+
+    The full-vocabulary sort runs only when the batch asks for it: a
+    ``lax.cond`` on ``any(temperature > 0 and (top_k > 0 or top_p < 1))``,
+    computed on the device from the [B] parameters, takes the
+    sort-and-mask branch; a batch of greedy rows, plain-temperature rows
+    and free slots passes its scaled logits through untouched. The
+    predicate reads nothing but the call's own inputs, so the branch
+    cannot be seen in any row's distribution: inside the sort branch a row
+    with ``top_k == 0`` keeps every entry and a row with ``top_p >= 1.0``
+    gets no cut at all (not even of the ~1e-6 tail a float32 cumulative
+    sum would round away), so a row that asks for no filter reads
+    ``logits / temperature`` whether or not a neighbour filters.
 
     Temperature-0 rows become a one-hot at the argmax, which makes
     rejection-sampling verification DEGENERATE to the exact greedy accept
@@ -84,28 +102,35 @@ def filter_logits(
     greedy requests under the sampled spec path emit bit-identical tokens
     to plain greedy decode.
     """
-    B, V = logits.shape
-    greedy = jnp.argmax(logits, axis=-1)
-
+    V = logits.shape[-1]
     safe_t = jnp.where(temperature > 0, temperature, 1.0)
     scaled = logits / safe_t[:, None]
+    use_k, use_p = top_k > 0, top_p < 1.0
 
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    k_idx = jnp.clip(top_k - 1, 0, V - 1)
-    kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
-    scaled = jnp.where(
-        (top_k[:, None] > 0) & (scaled < kth), -jnp.inf, scaled
-    )
+    def sort_and_mask(scaled):
+        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        k_idx = jnp.clip(top_k - 1, 0, V - 1)
+        kth = jnp.take_along_axis(sorted_desc, k_idx[:, None], axis=-1)
+        below_k = use_k[:, None] & (scaled < kth)
+        scaled = jnp.where(below_k, -jnp.inf, scaled)
+        # the top-k mask applied to the sorted copy IS the sort of the
+        # masked row: what falls below kth is the sorted copy's tail
+        sorted_desc = jnp.where(
+            use_k[:, None] & (sorted_desc < kth), -jnp.inf, sorted_desc
+        )
+        probs_sorted = jax.nn.softmax(sorted_desc, axis=-1)
+        cum = jnp.cumsum(probs_sorted, axis=-1)
+        inside = cum - probs_sorted < top_p[:, None]
+        cut = jnp.min(jnp.where(inside, sorted_desc, jnp.inf), axis=-1)
+        below_p = use_p[:, None] & (scaled < cut[:, None])
+        return jnp.where(below_p, -jnp.inf, scaled)
 
-    sorted_desc2 = jnp.sort(scaled, axis=-1)[:, ::-1]
-    probs_sorted = jax.nn.softmax(sorted_desc2, axis=-1)
-    cum = jnp.cumsum(probs_sorted, axis=-1)
-    inside = cum - probs_sorted < top_p[:, None]
-    cut = jnp.min(jnp.where(inside, sorted_desc2, jnp.inf), axis=-1)
-    scaled = jnp.where(scaled < cut[:, None], -jnp.inf, scaled)
+    asks = (temperature > 0) & (use_k | use_p)
+    scaled = jax.lax.cond(jnp.any(asks), sort_and_mask, lambda x: x, scaled)
 
     greedy_lg = jnp.where(
-        jax.nn.one_hot(greedy, V, dtype=bool), 0.0, -jnp.inf
+        jax.nn.one_hot(jnp.argmax(logits, axis=-1), V, dtype=bool),
+        0.0, -jnp.inf,
     )
     return jnp.where(temperature[:, None] > 0, scaled, greedy_lg)
 

@@ -1333,6 +1333,10 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"kvmini_tpu_free_slots {s['free_slots']}",
             "# TYPE kvmini_tpu_decode_steps_total counter",
             f"kvmini_tpu_decode_steps_total {s['decode_steps']}",
+            # of those, the steps whose batch made the sampler sort
+            "# TYPE kvmini_tpu_sampler_filter_steps_total counter",
+            "kvmini_tpu_sampler_filter_steps_total "
+            f"{s['sampler_filter_steps']}",
             "# TYPE kvmini_tpu_prefills_total counter",
             f"kvmini_tpu_prefills_total {s['prefills']}",
             # chunked-prefill rail (docs/TROUBLESHOOTING.md "Long prompts
