@@ -2,7 +2,7 @@
 # of native code — the TPU compute path is JAX/XLA compiled at runtime.
 PY ?= python
 
-.PHONY: help test test-fast test-policy lint lint-invariants lint-changed fmt smoke chip-smoke bench-smoke chaos-smoke fleet-smoke fleet-trace-smoke kv-economy-smoke econ-smoke trajectory dashboards-validate helm-lint airgap clean
+.PHONY: help test test-fast test-policy lint lint-invariants lint-changed fmt smoke chip-smoke bench-smoke chaos-smoke fleet-smoke fleet-trace-smoke kv-economy-smoke econ-smoke dashboards-validate helm-lint airgap clean
 
 help:
 	@grep -E '^[a-z-]+:' Makefile | sed 's/:.*//' | sort | uniq
@@ -133,9 +133,6 @@ kv-economy-smoke:  ## zero-copy handoff + prefix migration + host tier, no TPU
 # breach veto the shed — no engine, no TPU.
 econ-smoke:  ## live $/1K-tok + Wh/1K-tok rail, events, cost-aware policy
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_economics.py -q -m "not slow"
-
-trajectory:  ## perf trend table over BENCH_*.json rounds in the working directory (none are committed)
-	$(PY) -m kserve_vllm_mini_tpu trajectory --glob 'BENCH_*.json'
 
 dashboards-validate:  ## dashboard JSON structure + panel/query checks
 	$(PY) -m pytest tests/test_assets.py -q -k "dashboard"

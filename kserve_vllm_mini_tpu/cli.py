@@ -51,8 +51,6 @@ _SUBCOMMANDS: dict[str, tuple[str, str]] = {
     "compile-sweep": ("kserve_vllm_mini_tpu.sweeps.compile_perf", "AOT compile-time vs serving-perf tradeoff"),
     "chaos": ("kserve_vllm_mini_tpu.chaos.harness", "Fault injection + MTTR measurement"),
     "profile": ("kserve_vllm_mini_tpu.profiling.capture", "Capture a TensorBoard trace of a live runtime"),
-    "trajectory": ("kserve_vllm_mini_tpu.analysis.trajectory",
-                   "Perf trend over BENCH_*.json rounds (real + proxy series)"),
     "autoscale-controller": ("kserve_vllm_mini_tpu.autoscale.controller",
                              "SLO/duty-signal-driven replica controller"),
     "fleet": ("kserve_vllm_mini_tpu.fleet.service",

@@ -7,9 +7,10 @@ One ``ast.parse`` per file, then cheap linear walks that record:
 - which functions are **jit roots** (decorated with / wrapped by
   ``jax.jit``/``pjit``/``shard_map``, including the repo's dominant
   ``@partial(jax.jit, ...)`` inner-def idiom) plus their static args,
-- which bindings *hold* jitted callables (``self._prefill_fns[key] =
-  prefill``, ``self._cache = jax.jit(...)``) and which functions
-  *return* them — so checkers can tell "this host function dispatches
+- which bindings *hold* jitted callables (``self._steps[key] = fn`` with
+  ``fn`` bound from a builder of ``runtime/steps.py``, ``self._cache =
+  jax.jit(...)``) and which functions *return* them (``Engine._step``, a
+  builder's ``return jax.jit(step, ...)``) — so checkers can tell "this host function dispatches
   compiled work" (the decode hot path) from ordinary host code,
 - a name-resolution-lite call graph: callee candidates per callsite with
   positional/keyword argument mapping, enough for the jit-purity
@@ -315,7 +316,7 @@ class _ModuleWalker(ast.NodeVisitor):
                   and isinstance(tgt.value.value, ast.Name)
                   and tgt.value.value.id == "self"
                   and self.class_stack and jitted):
-                # self._prefill_fns[key] = <jit-decorated def>
+                # self._fns[key] = <jit-decorated def>
                 self.f.jitted_attrs.add((self.class_stack[-1], tgt.value.attr))
         # jax.jit(fn) marks fn itself a root even when the wrapper is bound
         if isinstance(node.value, ast.Call) and _is_jit_wrapper(node.value.func):
@@ -341,6 +342,8 @@ class _ModuleWalker(ast.NodeVisitor):
         self.generic_visit(node)
 
     def visit_Return(self, node: ast.Return) -> None:
+        # children first: `return jax.jit(step, ...)` marks `step` a root
+        self.generic_visit(node)
         if (self.fn_stack and node.value is not None
                 and self._value_is_jitted(node.value)):
             me = self.fn_stack[-1]
@@ -354,7 +357,6 @@ class _ModuleWalker(ast.NodeVisitor):
                 fn = self._lookup_fn(root.id)
                 if fn is not None and fn.jit_root and fn not in me.returned_jit_roots:
                     me.returned_jit_roots.append(fn)
-        self.generic_visit(node)
 
 
 @dataclass
@@ -414,29 +416,60 @@ class FactIndex:
         return idx
 
     def _propagate_returns_jitted(self) -> None:
-        """`def _get_spec_fn(self): return build_spec_step(...)` — a getter
+        """`def _spec(self): return build_spec_step_sampled(...)` — a getter
         returning another jitted-returning factory's result is itself a
-        jitted-value source. Cross-module, so it runs after all modules
-        parse; small fixpoint (getter chains are short)."""
+        jitted-value source, and so is one that binds the result to a local
+        first (`fn = steps.build_decode(...); self._steps[k] = fn; return
+        fn`, the engine's one step getter): the attribute it files the
+        value under then holds jitted callables. Cross-module, so it runs
+        after all modules parse; small fixpoint (getter chains are short)."""
         for _ in range(4):
             changed = False
             for mod in self.modules.values():
                 for fn in mod.functions.values():
                     for node in iter_scope(fn.node):
-                        if not (isinstance(node, ast.Return)
-                                and isinstance(node.value, ast.Call)):
-                            continue
-                        for callee in self._resolve_expr(mod, fn, node.value.func):
-                            if callee.returns_jitted and not fn.returns_jitted:
+                        if isinstance(node, ast.Return) and node.value is not None:
+                            roots = self._factory_roots(mod, fn, node.value)
+                            if roots is None:
+                                continue
+                            if not fn.returns_jitted:
                                 fn.returns_jitted = True
                                 changed = True
-                            if callee.returns_jitted:
-                                for root in callee.returned_jit_roots:
-                                    if root not in fn.returned_jit_roots:
-                                        fn.returned_jit_roots.append(root)
-                                        changed = True
+                            for root in roots:
+                                if root not in fn.returned_jit_roots:
+                                    fn.returned_jit_roots.append(root)
+                                    changed = True
+                        elif (isinstance(node, ast.Assign) and fn.class_name
+                              and self._factory_roots(mod, fn, node.value) is not None):
+                            for tgt in node.targets:
+                                if isinstance(tgt, ast.Subscript):
+                                    tgt = tgt.value  # self._steps[key] = fn
+                                if (isinstance(tgt, ast.Attribute)
+                                        and isinstance(tgt.value, ast.Name)
+                                        and tgt.value.id == "self"):
+                                    mod.jitted_attrs.add((fn.class_name, tgt.attr))
             if not changed:
                 return
+
+    def _factory_roots(self, mod: ModuleFacts, fn: FunctionInfo,
+                       expr: ast.AST) -> Optional[list[FunctionInfo]]:
+        """The jit roots `expr` may evaluate to when it is a call of a
+        jitted-returning factory, or a local name bound to such a call
+        (the union, where a getter hands out several programs); None when
+        it is neither."""
+        if isinstance(expr, ast.Name):
+            calls = [a for a in fn.local_aliases.get(expr.id, [])
+                     if isinstance(a, ast.Call)]
+        else:
+            calls = [expr] if isinstance(expr, ast.Call) else []
+        factories = [
+            callee for call in calls
+            for callee in self._resolve_expr(mod, fn, call.func)
+            if callee.returns_jitted
+        ]
+        if not factories:
+            return None
+        return [root for f in factories for root in f.returned_jit_roots]
 
     # -- lookups ------------------------------------------------------------
     def functions(self) -> Iterable[FunctionInfo]:
@@ -500,6 +533,9 @@ class FactIndex:
                         out += self._resolve_name(mod, None, aliased, _depth + 1)
                     return out
                 dotted = mod.import_aliases.get(expr.value.id)
+                if dotted is None and expr.value.id in mod.from_imports:
+                    # `from pkg import module`: module-alias attributes too
+                    dotted = ".".join(mod.from_imports[expr.value.id])
                 if dotted is not None:
                     target = self.module_for_dotted(dotted)
                     if target is not None and expr.attr in target.functions:
@@ -556,7 +592,7 @@ class FactIndex:
             if (isinstance(sub.value, ast.Name) and sub.value.id == "self"
                     and fn.class_name
                     and (fn.class_name, sub.attr) in mod.jitted_attrs):
-                return True  # self._prefill_fns[key](...)
+                return True  # self._steps[key](...)
         return False
 
     # -- lockstep anchors ---------------------------------------------------

@@ -437,64 +437,6 @@ def cost_pareto_chart(rows: list[dict[str, Any]]) -> str:
     return _to_img(fig)
 
 
-def perf_trajectory_chart(traj: dict[str, Any]) -> str:
-    """The perf trajectory (analysis/trajectory.py) as two stacked lanes:
-    device tokens/s/chip for REAL rounds, compile-time + step-ratio for
-    PROXY rounds — separate axes because a proxy number must never read
-    as a device measurement. Dark rounds show as shaded gaps so lost
-    coverage stays visible."""
-    rows = traj.get("rounds") or []
-    if len(rows) < 2:
-        return ""
-    if not HAVE_MPL:
-        return _placeholder("perf trajectory")
-    xs = list(range(len(rows)))
-    names = [r.get("name", "?") for r in rows]
-    fig, (ax_real, ax_proxy) = plt.subplots(2, 1, figsize=(7, 4.6),
-                                            sharex=True)
-    real = [(x, r["tokens_per_sec_per_chip"]) for x, r in zip(xs, rows)
-            if r.get("tokens_per_sec_per_chip")]
-    if real:
-        ax_real.plot([x for x, _ in real], [v for _, v in real],
-                     marker="o", color=_PALETTE["primary"], linewidth=1.5,
-                     label="real device")
-        ax_real.legend(fontsize=8, loc="upper left")
-    ax_real.set_ylabel("tok/s/chip")
-    ax_real.set_title("Perf trajectory")
-    compile_s = [(x, r["proxy"]["compile_wall_s"]) for x, r in zip(xs, rows)
-                 if isinstance(r.get("proxy"), dict)
-                 and "compile_wall_s" in r["proxy"]]
-    ratio = [(x, r["proxy"]["step_count_ratio"]) for x, r in zip(xs, rows)
-             if isinstance(r.get("proxy"), dict)
-             and "step_count_ratio" in r["proxy"]]
-    if compile_s:
-        ax_proxy.plot([x for x, _ in compile_s], [v for _, v in compile_s],
-                      marker="s", color=_PALETTE["warm"], linewidth=1.2,
-                      label="proxy: compile s")
-    if ratio:
-        ax2 = ax_proxy.twinx()
-        ax2.plot([x for x, _ in ratio], [v for _, v in ratio],
-                 marker="^", color=_PALETTE["cold"], linewidth=1.2,
-                 label="proxy: step ratio")
-        ax2.set_ylabel("sync/chained")
-        lines1, labels1 = ax_proxy.get_legend_handles_labels()
-        lines2, labels2 = ax2.get_legend_handles_labels()
-        ax_proxy.legend(lines1 + lines2, labels1 + labels2, fontsize=8,
-                        loc="upper left")
-    elif compile_s:
-        ax_proxy.legend(fontsize=8, loc="upper left")
-    ax_proxy.set_ylabel("compile (s)")
-    for ax in (ax_real, ax_proxy):
-        ax.grid(color=_PALETTE["grid"], axis="y")
-        for x, r in zip(xs, rows):
-            if r.get("series") == "dark":
-                ax.axvspan(x - 0.35, x + 0.35, color=_PALETTE["grid"],
-                           alpha=0.6)
-    ax_proxy.set_xticks(xs, names, fontsize=8)
-    ax_proxy.set_xlabel("bench round")
-    return _to_img(fig)
-
-
 def cold_warm_chart(results: dict[str, Any]) -> str:
     cold, warm = results.get("cold_p95_ms"), results.get("warm_p95_ms")
     if not HAVE_MPL or cold is None or warm is None:

@@ -228,73 +228,6 @@ def _timeline_section(
     return "".join(parts)
 
 
-def trajectory_section(traj: dict[str, Any]) -> str:
-    """The "Perf trajectory" section (analysis/trajectory.py): coverage
-    line, real/proxy chart lanes, and the per-round trend table with
-    same-series regression deltas. Proxy rounds are visibly labeled —
-    their numbers are compile/cost-model metrics, never device
-    throughput (docs/PROFILING.md)."""
-    rows = traj.get("rounds") or []
-    if not rows:
-        return ""
-    cov = traj.get("coverage") or {}
-    parts = ["<section><h2>Perf trajectory</h2>"]
-    parts.append(
-        f"<p>{cov.get('total', len(rows))} rounds · "
-        f"{cov.get('real', 0)} real · {cov.get('proxy', 0)} proxy · "
-        f"{cov.get('dark', 0)} dark</p>"
-    )
-    chart = charts.perf_trajectory_chart(traj)
-    if chart:
-        parts.append(chart)
-    body = []
-    for r in rows:
-        tok = r.get("tokens_per_sec_per_chip")
-        delta = r.get("delta_vs_last_real_pct")
-        px = r.get("proxy") or {}
-        note = r.get("downshifted") or ""
-        cls = {"real": "ok", "proxy": "warn", "dark": "bad"}.get(
-            r.get("series", "dark"), "")
-        body.append(
-            "<tr>"
-            f"<td>{html_mod.escape(str(r.get('name', '?')))}</td>"
-            f"<td class='{cls}'>{html_mod.escape(str(r.get('series')))}</td>"
-            f"<td>{html_mod.escape(str(r.get('status')))}</td>"
-            f"<td>{tok if tok is not None else '—'}</td>"
-            f"<td>{f'{delta:+.1f}%' if delta is not None else '—'}</td>"
-            f"<td>{px.get('compile_wall_s', '—')}</td>"
-            f"<td>{px.get('step_count_ratio', '—')}</td>"
-            f"<td>{html_mod.escape(str(note))}</td></tr>"
-        )
-    parts.append(
-        "<table><tr><th>round</th><th>series</th><th>status</th>"
-        "<th>tok/s/chip</th><th>Δ vs last real</th>"
-        "<th>proxy compile s</th><th>proxy step ratio</th><th>note</th></tr>"
-        + "".join(body) + "</table>"
-    )
-    regs = traj.get("regressions") or []
-    for reg in regs:
-        parts.append(
-            f"<p class='bad'>regression {html_mod.escape(str(reg['round']))}: "
-            f"{html_mod.escape(str(reg['metric']))} {reg['value']} vs "
-            f"{reg['anchor']} ({reg['delta_pct']:+.1f}%)</p>"
-        )
-    parts.append("</section>")
-    return "".join(parts)
-
-
-def generate_trajectory_html(traj: dict[str, Any]) -> str:
-    """Standalone page for ``kvmini-tpu trajectory --html``."""
-    return (
-        "<html><head><meta charset='utf-8'>"
-        "<title>kvmini-tpu perf trajectory</title>"
-        f"<style>{_CSS}</style></head><body>"
-        "<h1>Perf trajectory</h1>"
-        + trajectory_section(traj)
-        + "</body></html>"
-    )
-
-
 def _compile_stats_section(results: dict[str, Any]) -> str:
     """Compile-stats block (docs/PROFILING.md) when the run carried one:
     totals plus the per-executable table from the self-serve snapshot."""

@@ -328,32 +328,34 @@ class PrefillLane:
         a table-only payload: zero KV bytes cross the lanes."""
         t0 = now_s()
         logits, chunks = self._paged_prefill(handle, meta)
-        wall = now_s() - t0
+        return self._payload(
+            handle, HANDOFF_VERSION, now_s() - t0, chunks, logits,
+            reused_prefix_tokens=int(meta.get("off", 0)),
+        )
+
+    def _payload(self, handle: Any, version: int, wall: float, chunks: int,
+                 logits: Any, kv: Any = None,
+                 reused_prefix_tokens: int = 0) -> KVHandoff:
+        """Book one finished lane prefill and wrap it into its handoff."""
         n = len(handle.request.prompt_tokens)
         blk = max(getattr(self.ecfg, "kv_block_size", 64), 1)
         with self.stats.lock:
             self.stats.prefills += 1
             self.stats.busy_s += wall
         return KVHandoff(
-            version=HANDOFF_VERSION,
+            version=version,
             request_id=handle.request.request_id,
             handle=handle,
             n_tokens=n,
             n_blocks=-(-n // blk),
-            reused_prefix_tokens=int(meta.get("off", 0)),
+            reused_prefix_tokens=reused_prefix_tokens,
             chunks=chunks,
             busy_s=wall,
-            kv=None,
+            kv=kv,
             logits=logits,
         )
 
     # -- compiled staging prefill (lane thread only) ------------------------
-
-    def _bucket(self, n: int) -> int:
-        b = self.ecfg.min_prefill_bucket
-        while b < n:
-            b *= 2
-        return min(b, self.ecfg.max_prefill_len)
 
     def _make_staging(self) -> dict[str, Any]:
         import jax.numpy as jnp
@@ -371,67 +373,29 @@ class PrefillLane:
             dtype=kv_dt, quantized=kv_quant,
         )
 
-    def _get_fresh_fn(self, bucket: int):
-        key = ("fresh", bucket)
-        fn = self._prefill_fns.get(key)
-        if fn is not None:
-            return fn
-        import jax
-        import jax.numpy as jnp
-        from functools import partial
-
+    def _prefill_piece(self, piece: list[int], off: int):
+        """One piece of a prompt into the staging cache, through
+        runtime/steps.py's one prefill builder addressed at the whole cache
+        — it IS the slot (B=1), so there is no slice/update pair. Returns
+        the piece's last-position logits."""
         from kserve_vllm_mini_tpu.models.llama import forward
+        from kserve_vllm_mini_tpu.runtime import steps
 
-        cfg = self.cfg
-        kernels = self._kernels
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def fresh(params, cache, tokens, length):
-            # tokens [1, bucket]; the staging cache IS the slot (B=1), so
-            # no slice/update pair — forward writes rows 0..bucket-1 and
-            # only the prompt's last position is sampled (logit_index)
-            pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
-            logits, nc = forward(
-                params, cfg, tokens, pos,
-                cache, jnp.zeros((1,), jnp.int32),
-                fresh_prefill=True,
-                logit_index=(length - 1)[None],
-                kernels=kernels,
+        bucket = steps.prefill_bucket(len(piece), self.ecfg)
+        fresh = off == 0
+        step = self._prefill_fns.get((bucket, fresh))
+        if step is None:
+            step = self._instrument(
+                steps.build_prefill(
+                    self.cfg, forward, self._kernels, "whole", fresh),
+                steps.prefill_label("whole", fresh, bucket),
             )
-            return nc, logits[0, 0]
-
-        fresh = self._instrument(fresh, f"disagg_prefill[{bucket}]")
-        self._prefill_fns[key] = fresh
-        return fresh
-
-    def _get_chunk_fn(self, bucket: int):
-        key = ("chunk", bucket)
-        fn = self._prefill_fns.get(key)
-        if fn is not None:
-            return fn
-        import jax
-        import jax.numpy as jnp
-        from functools import partial
-
-        from kserve_vllm_mini_tpu.models.llama import forward
-
-        cfg = self.cfg
-        kernels = self._kernels
-
-        @partial(jax.jit, donate_argnums=(1,))
-        def chunk(params, cache, tokens, length, offset):
-            pos = offset + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None]
-            logits, nc = forward(
-                params, cfg, tokens, pos,
-                cache, offset[None],
-                logit_index=(length - 1)[None],
-                kernels=kernels,
-            )
-            return nc, logits[0, 0]
-
-        chunk = self._instrument(chunk, f"disagg_chunk_prefill[{bucket}]")
-        self._prefill_fns[key] = chunk
-        return chunk
+            self._prefill_fns[bucket, fresh] = step
+        self._staging, last_logits = step(
+            self.params, self._staging,
+            *steps.piece_operands("whole", piece, bucket, self.pad_id, off),
+        )
+        return last_logits
 
     def _get_slice_fn(self):
         """Jitted UNDONATED copy of the staging stripe: the payload must
@@ -455,10 +419,8 @@ class PrefillLane:
         the staged KV and last-position logits are byte-identical to
         what the engine would have computed in place."""
         import jax
-        import jax.numpy as jnp
 
-        req = handle.request
-        prompt = req.prompt_tokens
+        prompt = handle.request.prompt_tokens
         n = len(prompt)
         if self._staging is None:
             self._staging = self._make_staging()
@@ -468,20 +430,8 @@ class PrefillLane:
         budget = self.ecfg.max_prefill_len
         while off < n:
             piece = prompt[off : off + budget]
-            m = len(piece)
-            bucket = self._bucket(m)
-            toks = piece + [self.pad_id] * (bucket - m)
-            tokens = jnp.asarray(toks, dtype=jnp.int32)[None]
-            if off == 0:
-                self._staging, last_logits = self._get_fresh_fn(bucket)(
-                    self.params, self._staging, tokens, jnp.int32(m)
-                )
-            else:
-                self._staging, last_logits = self._get_chunk_fn(bucket)(
-                    self.params, self._staging, tokens,
-                    jnp.int32(m), jnp.int32(off),
-                )
-            off += m
+            last_logits = self._prefill_piece(piece, off)
+            off += len(piece)
             chunks += 1
         payload = self._get_slice_fn()(self._staging)
         logits = last_logits
@@ -493,20 +443,7 @@ class PrefillLane:
             logits = jax.device_get(logits)
         else:
             jax.block_until_ready(logits)
-        wall = now_s() - t0
-        blk = max(getattr(self.ecfg, "kv_block_size", 64), 1)
-        with self.stats.lock:
-            self.stats.prefills += 1
-            self.stats.busy_s += wall
-        return KVHandoff(
-            version=DENSE_HANDOFF_VERSION,
-            request_id=req.request_id,
-            handle=handle,
-            n_tokens=n,
-            n_blocks=-(-n // blk),
-            reused_prefix_tokens=0,
-            chunks=chunks,
-            busy_s=wall,
+        return self._payload(
+            handle, DENSE_HANDOFF_VERSION, now_s() - t0, chunks, logits,
             kv=payload,
-            logits=logits,
         )

@@ -225,6 +225,38 @@ def test_out_of_root_paths_lint_without_crashing(tmp_path):
     assert [d.code for d in result.diagnostics] == ["KVM015"]
 
 
+def test_one_getter_over_a_module_of_builders_is_a_jitted_source(tmp_path):
+    """The engine's idiom since runtime/steps.py: a builder in another
+    module returns ``jax.jit(step, donate_argnums=...)``, one getter binds
+    the result to a local, files it in a dict and returns it. The function
+    that calls what the getter hands out is a jit-dispatch hot path, and
+    the builder's donations reach its call."""
+    (tmp_path / "steps.py").write_text(
+        "import jax\n\n"
+        "def build_decode(n):\n"
+        "    def step(params, cache):\n"
+        "        return cache\n"
+        "    return jax.jit(step, donate_argnums=(1,))\n")
+    (tmp_path / "eng.py").write_text(
+        "import steps\n\n"
+        "class Engine:\n"
+        "    def _step(self, kind, n):\n"
+        "        fn = self._steps.get((kind, n))\n"
+        "        if fn is None:\n"
+        "            fn = steps.build_decode(n)\n"
+        "            fn = self._instrument(fn, kind)\n"
+        "            self._steps[kind, n] = fn\n"
+        "        return fn\n\n"
+        "    def sweep(self, cache):\n"
+        "        decode = self._step('decode', 1)\n"
+        "        out = decode(self.params, cache)\n"
+        "        return out.item(), cache\n")
+    result = run_lint([tmp_path], root=tmp_path)
+    assert not result.parse_errors
+    assert sorted((d.code, d.context) for d in result.diagnostics) == [
+        ("KVM015", "Engine.sweep"), ("KVM071", "Engine.sweep")]
+
+
 def test_baseline_flags_stale_entries_as_ratchet(tmp_path):
     bl = tmp_path / "bl.json"
     baseline_mod.save(bl, [_diag(), _diag(ctx="fixed_since")])

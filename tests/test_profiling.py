@@ -312,6 +312,6 @@ def test_engine_compile_stats_can_be_disabled():
     eng = Engine(params, cfg,
                  EngineConfig(max_slots=2, max_seq_len=128,
                               max_prefill_len=64, compile_stats=False))
-    fn = eng._get_prefill_fn(16)
+    fn = eng._step("prefill", 16, True)
     assert not isinstance(fn, InstrumentedJit)
     assert eng.snapshot_stats()["compiles"] == 0

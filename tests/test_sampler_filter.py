@@ -246,16 +246,16 @@ def test_engine_programs_hold_no_sort_outside_a_conditional(params, program):
     toks, lens = jnp.zeros((S,), jnp.int32), jnp.zeros((S,), jnp.int32)
     packed = jnp.zeros((S, (vocab + 7) // 8), jnp.uint8)
     if program == "first":
-        lowered = _jitted(eng._get_first_fn()).lower(
+        lowered = _jitted(eng._step("first")).lower(
             jnp.zeros((vocab,), jnp.float32), rng, temps[0], top_k[0], top_p[0],
             packed[0], jnp.asarray(False))
     elif program == "decode.masked":
-        lowered = _jitted(eng._get_masked_decode_fn()).lower(
+        lowered = _jitted(eng._step("decode_masked")).lower(
             eng.params, eng._cache, toks, lens, temps, top_k, top_p, rng,
             eng._counts, pres, freqs, packed, jnp.zeros((S,), bool))
     else:
         chunk = int(program[len("decode[chunk="):-1])
-        lowered = _jitted(eng._get_decode_fn(chunk)).lower(
+        lowered = _jitted(eng._step("decode", chunk)).lower(
             eng.params, eng._cache, toks, lens, temps, top_k, top_p, rng,
             eng._counts, pres, freqs)
     assert stablehlo_sorts(lowered) == (1, 0)
@@ -263,7 +263,7 @@ def test_engine_programs_hold_no_sort_outside_a_conditional(params, program):
 
 def test_spec_step_holds_its_two_sorts_inside_conditionals(params):
     """The drafter's q and the target's p each come from the one function."""
-    from kserve_vllm_mini_tpu.runtime.engine import build_spec_step_sampled
+    from kserve_vllm_mini_tpu.runtime.steps import build_spec_step_sampled
 
     spec = build_spec_step_sampled(CFG, CFG, k=3)
     eng = tiny_engine(params)

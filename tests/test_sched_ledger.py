@@ -527,9 +527,9 @@ def test_names_the_benchmark_reads(plain):
         jitted function under the compile-statistics wrapper."""
         return getattr(fn, "_fn", fn).__name__
 
-    assert jit_name(eng._get_decode_fn(1)) == "decode"
-    assert jit_name(eng._get_prefill_fn(16)) == "prefill"
-    assert jit_name(eng._get_first_fn()) == "first"
+    assert jit_name(eng._step("decode", 1)) == "decode"
+    assert jit_name(eng._step("prefill", 16, True)) == "prefill"
+    assert jit_name(eng._step("first")) == "first"
 
     async def scrape():
         async with TestClient(TestServer(make_app(eng, load_tokenizer(None), "llama-tiny"))) as c:
