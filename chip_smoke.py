@@ -191,6 +191,17 @@ def require_mosaic(exes: dict[str, dict], prefix: str, dry: bool) -> None:
             check(n > 0, f"{lab} holds no Mosaic call (tpu_custom_call)")
 
 
+def require_weights_in_place(exes: dict[str, dict], dry: bool) -> None:
+    """``weight_copy_bytes`` of every executable (profiling/compile_stats.py:
+    per-layer integer weight slices copied, i.e. transposed, before their
+    matmul); no decode and no prefill program may hold one."""
+    for lab in sorted(exes):
+        n = exes[lab]["weight_copy_bytes"]
+        say(f"  {lab}: weight_copy_bytes={n}")
+        if not dry and (lab.startswith("decode[") or "prefill[" in lab):
+            check(n == 0, f"{lab} copies {n} bytes of weight slices a layer")
+
+
 # -- model-level parity: kernel path vs jnp path on the engine's own weights ----
 
 def forward_parity(engine, tag: str, *, chunks: tuple[int, ...], decode: bool,
@@ -345,6 +356,7 @@ def leg_main(dry: bool, topology: str | None) -> dict:
             # 128-wide heads, bf16 KV: the step reads through
             # kvmini_dense_decode (models/llama.py dense_decode_kernel_block)
             require_mosaic(after, "decode[", dry)
+            require_weights_in_place(after, dry)
         say(f"main: backend compiles inside the window (incl. eager host "
             f"helpers; informational)={len(compiles_seen) - n_backend}")
 
@@ -559,6 +571,7 @@ def leg_engines(dry: bool) -> dict:
             exes = executables(server.engine)
             for prefix in need:
                 require_mosaic(exes, prefix, dry)
+            require_weights_in_place(exes, dry)
             forward_parity(server.engine, name, dry=dry, **parity)
             hbm_line(f"engines:{name}")
             summary[name] = {"boot_s": round(server.boot_seconds, 1),

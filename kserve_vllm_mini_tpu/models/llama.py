@@ -375,6 +375,15 @@ def qkv_proj(
         # the family's QK-norm: over the whole projection, before the heads split
         q = rms_norm(q, p["q_norm"], cfg.rms_eps)
         k = rms_norm(k, p["k_norm"], cfg.rms_eps)
+    # The layout the head split wants stops here. Without the barrier the TPU
+    # compiler carries it back through the dot onto the weight (heads become a
+    # batch dimension of the dot), and feeds that dot by slicing the layer's
+    # weight out of its stack and transposing all of it to [H, hd, d_in] every
+    # step: 25 MB a layer on a 7B model, a fifth of a decode step. Behind the
+    # barrier each projection is one fusion that reads the stack in place, as
+    # wo and the MLP weights are read (profiling/compile_stats.py
+    # weight_copy_bytes; tests/test_weight_copy_preflight.py).
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     q = q.reshape(B, T, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)

@@ -34,6 +34,12 @@ from typing import Any, Callable
 # the right-hand side of the assignment (types carry brackets, not parens).
 _HLO_OPCODE = re.compile(r"([a-z][a-z0-9_\-]*)\(")
 _TOP_OPS = 16  # histogram cap: top-N opcodes, remainder folded into "other"
+# `%copy.69 = s8[1,4096,4096]{1,2,0:T(8,128)(4,1)S(1)} copy(%slice)`: a copy
+# (not `copy-start`/`copy-done`, whose results are tuples) whose result is ONE
+# layer of a stacked integer weight leaf — rank 3, leading 1. K/V and
+# activations are rank 4-5 or not integers; scales and norms are floats.
+_WEIGHT_COPY = re.compile(r" = (s8|u8|s4)\[1,(\d+),(\d+)\]\S* copy\(")
+_WEIGHT_COPY_MIN_BYTES = 64 * 1024  # below this it is no projection's weight
 
 
 @dataclass
@@ -54,6 +60,11 @@ class CompileStats:
     # compiled into this executable (the opcode histogram cannot tell a
     # Mosaic call from XLA's own custom calls)
     mosaic_calls: int = 0
+    # bytes of per-layer integer weight slices the program copies (a
+    # transposition XLA put in front of a matmul: the weight is then read,
+    # written and read again every step); 0 = every weight is multiplied
+    # where it lies in its stack
+    weight_copy_bytes: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -68,6 +79,7 @@ class CompileStats:
             "generated_code_bytes": self.generated_code_bytes,
             "hlo_ops": dict(self.hlo_ops),
             "mosaic_calls": self.mosaic_calls,
+            "weight_copy_bytes": self.weight_copy_bytes,
         }
 
 
@@ -90,6 +102,23 @@ def hlo_op_histogram(hlo_text: str, top: int = _TOP_OPS) -> dict[str, int]:
     out = dict(ranked[:top])
     out["other"] = sum(c for _, c in ranked[top:])
     return out
+
+
+def weight_copy_bytes(hlo_text: str) -> int:
+    """Summed result bytes of the ``copy`` instructions in an optimized HLO
+    module that produce one layer's slice of a stacked integer weight
+    (``s8``/``u8``/``s4`` ``[1, in, out]``, at least 64 KiB). Each
+    instruction counts once, a loop body's too: the number says which
+    weights are copied, not how often. (The int8 activations of a
+    ``quant_mode="w8a8"`` prefill piece, ``s8[1, T, d]``, have a weight's
+    shape: read a non-zero there against the HLO text.)"""
+    total = 0
+    for dtype, rows, cols in _WEIGHT_COPY.findall(hlo_text):
+        n = int(rows) * int(cols)
+        n = n // 2 if dtype == "s4" else n
+        if n >= _WEIGHT_COPY_MIN_BYTES:
+            total += n
+    return total
 
 
 def extract_compile_stats(
@@ -119,6 +148,7 @@ def extract_compile_stats(
         generated_code_bytes=code,
         hlo_ops=hlo_op_histogram(hlo_text),
         mosaic_calls=hlo_text.count('custom_call_target="tpu_custom_call"'),
+        weight_copy_bytes=weight_copy_bytes(hlo_text),
     )
 
 

@@ -14,6 +14,7 @@ from kserve_vllm_mini_tpu.profiling.compile_stats import (
     capture_compile_stats,
     extract_compile_stats,
     hlo_op_histogram,
+    weight_copy_bytes,
 )
 from kserve_vllm_mini_tpu.profiling.devices import device_facts
 from kserve_vllm_mini_tpu.profiling.headroom import (
@@ -39,7 +40,8 @@ def test_capture_pins_stat_keys():
     d = cs.to_dict()
     for key in ("label", "compile_wall_s", "flops", "bytes_accessed",
                 "peak_bytes", "argument_bytes", "output_bytes",
-                "temp_bytes", "generated_code_bytes", "hlo_ops"):
+                "temp_bytes", "generated_code_bytes", "hlo_ops",
+                "mosaic_calls", "weight_copy_bytes"):
         assert key in d, key
     assert d["label"] == "t"
     assert d["compile_wall_s"] > 0
@@ -93,6 +95,33 @@ def test_hlo_op_histogram_parses_and_caps():
     many = "\n".join(f"  %x{i} = f32[] op{i}(%a)" for i in range(20))
     capped = hlo_op_histogram(many, top=4)
     assert len(capped) == 5 and capped["other"] == 16
+
+
+# optimized-HLO lines as a v5e compile prints them (layout, tiling, memory
+# space after the shape); the first is PR 30's %copy.69, mistral's wq
+_WQ = ("  %copy.69 = s8[1,4096,4096]{1,2,0:T(8,128)(4,1)S(1)} "
+       "copy(%constant_dynamic-slice_fusion.6), metadata={}")
+_WK = "  %copy.72 = s8[1,4096,1024]{1,2,0:T(8,128)(4,1)S(1)} copy(%constant_dynamic-slice_fusion.7)"
+_WV = "  %copy.73 = s8[1,4096,1024]{1,2,0:T(8,128)(4,1)S(1)} copy(%constant_dynamic-slice_fusion.8)"
+
+
+@pytest.mark.parametrize("lines,want", [
+    ([_WQ], 16_777_216),
+    # an asynchronous prefetch of a scale is no copy instruction, and no weight
+    (["  %copy-start.2 = (f32[32,14336]{1,0:T(8,128)S(1)}, f32[32,14336]{1,0:T(8,128)}, u32[]{:S(2)}) "
+      "copy-start(%get-tuple-element.598)",
+      "  %copy-done.2 = f32[32,14336]{1,0:T(8,128)S(1)} copy-done(%copy-start.2)"], 0),
+    (["  %copy.63 = bf16[16,8,1,128]{3,1,0,2:T(2,128)(2,1)S(1)} copy(%bitcast_multiply_fusion.9)"], 0),
+    (["  %copy.5 = s8[1,64,64]{1,2,0:T(8,128)(4,1)} copy(%fusion.2)"], 0),              # under 64 KiB
+    (["  %copy.7 = s8[1,8,512,128]{3,2,1,0:T(8,128)(4,1)} copy(%dynamic-slice.3)"], 0),  # int8 K/V: rank 4
+    ([_WQ, _WK, _WV], 25_165_824),
+    (["  %copy.1 = s4[1,4096,4096]{1,2,0} copy(%f.1)"], 8_388_608),   # two to a byte
+    (["  %copy.1 = u8[1,2048,4096]{1,2,0} copy(%f.1)"], 8_388_608),
+], ids=["wq", "copy-start-done", "activation", "small", "int8-kv", "wq+wk+wv",
+        "packed-int4", "uint8"])
+def test_weight_copy_bytes_counts_integer_weight_slices(lines, want):
+    text = "\n".join(["HloModule jit_decode, is_scheduled=true", *lines, "}"])
+    assert weight_copy_bytes(text) == want
 
 
 # -- InstrumentedJit ----------------------------------------------------------

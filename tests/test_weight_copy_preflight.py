@@ -1,0 +1,65 @@
+"""The q, k and v projections read their weights where they lie in the layer
+stack: checked in the programs the chip's own compiler builds, with no chip.
+
+``tests/tpu_preflight_worker.py`` compiles the decode step and a fresh
+prefill piece for a TPU v5e by the README's pre-flight recipe (libtpu
+compiles for a described chip), at the benchmark's three configurations
+(published widths, two layers or one period of the hybrid). Asked of each
+executable: ``weight_copy_bytes == 0`` (no per-layer slice of an integer
+weight stack is copied, i.e. transposed, before its matmul:
+profiling/compile_stats.py) and a Mosaic call is still compiled in.
+
+A transposition of a projection's OUTPUT (the head split) can reach back
+through the dot to its weight: with ``qkv_proj`` as it stood before PR 31
+every case here fails (mistral 25,165,824 bytes, qwen2 16,515,072, the
+hybrid 14,745,600). A model of d 512 with 4 / 2 heads of 128 shows the same
+three copies in a quarter of the compile time, should this file ever cost
+the suite too much.
+
+One file, one subprocess: libtpu is loaded in the child alone (``--dist
+loadfile`` keeps this file in one worker), and the child is held to a
+timeout, so nothing here can hang the suite. Skips, with the reason, where
+libtpu or the topology cannot be had.
+"""
+
+import json
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+from tests import env_guards
+
+MODELS = ("mistral-7b-int8", "qwen2-7b-int8", "olmo-hybrid-7b-int8")
+CASES = [f"{m}:{s}" for m in MODELS for s in ("decode", "prefill")]
+# six compiles take ~100 s here; under a loaded suite several times that
+TIMEOUT_S = 900
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    env_guards.require_child_jax()
+    worker = Path(__file__).parent / "tpu_preflight_worker.py"
+    try:
+        p = subprocess.run(
+            [sys.executable, str(worker), *CASES],
+            capture_output=True, text=True, timeout=TIMEOUT_S,
+            cwd=Path(__file__).parent.parent,
+        )
+    except subprocess.TimeoutExpired:
+        pytest.skip(f"the chipless compiles did not finish in {TIMEOUT_S} s here")
+    assert p.returncode == 0, f"rc={p.returncode}\n{p.stdout[-2000:]}\n{p.stderr[-4000:]}"
+    out = json.loads(p.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(out["skip"])
+    return out["cases"]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_projections_read_their_weights_in_place(compiled, case):
+    stats = compiled[case]
+    assert stats["weight_copy_bytes"] == 0, (
+        f"{case}: {stats['weight_copy_bytes']} bytes of per-layer integer "
+        "weight slices are copied before their matmul")
+    assert stats["mosaic_calls"] >= 1, f"{case}: no Pallas kernel compiled in"

@@ -1,0 +1,127 @@
+"""Worker for test_weight_copy_preflight.py: compile engine steps for a TPU
+v5e with no chip, by the README's pre-flight recipe.
+
+Runs in its OWN process: libtpu is loaded here and nowhere in the test
+workers, ``jax.default_backend`` is patched (the kernel dispatch asks it), and
+the caller can hold the whole thing to a timeout.
+
+Each case is ``<model>:<step>``: ``decode`` is ``steps.build_decode(cfg,
+forward, "auto", 1)``, ``prefill`` is ``steps.build_prefill(cfg, forward,
+"auto", "slot", True)`` at bucket 128, over abstract arguments (no weights are
+made). Models: the benchmark's configurations by name (``BENCHMARK.json``), at
+their published widths and two layers (the hybrid: one period of four).
+
+Prints one JSON line: ``{"skip": reason}`` where libtpu or the topology cannot
+be had, else ``{"cases": {case: CompileStats.to_dict()}}``. ``--dump DIR``
+also writes each case's optimized HLO there.
+"""
+
+import argparse
+import json
+import os
+import sys
+from pathlib import Path
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+os.environ.update(
+    JAX_PLATFORMS="cpu", TPU_SKIP_MDS_QUERY="1",
+    TPU_ACCELERATOR_TYPE="v5litepod-4", TPU_WORKER_HOSTNAMES="localhost",
+)
+
+SLOTS, CONTEXT, BUCKET = 16, 2048, 128
+
+
+def model_config(name: str):
+    """The configuration as a cell of the benchmark serves it
+    (``bench.config.load_cell``), cut to two layers or one period."""
+    from bench import config as bench_config
+
+    cell = next(w["name"] for w in bench_config.benchmark()["workloads"]
+                if w["config"] == name)
+    m = dict(bench_config.load_cell(cell)["config"]["model"])
+    m["num_hidden_layers"] = 2
+    kinds = m.get("model_extra", {}).get("layer_types")
+    if kinds:   # a hybrid stack: one period, its linear layers then the full one
+        period = kinds.index("full_attention") + 1
+        m["num_hidden_layers"] = period
+        m["model_extra"] = {**m["model_extra"], "layer_types": kinds[:period]}
+    return bench_config.load_module("systems", "engine").model_config(m)
+
+
+def compile_case(cfg, step: str, device):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from kserve_vllm_mini_tpu.models.llama import (
+        forward,
+        init_kv_cache,
+        init_params_quantized,
+    )
+    from kserve_vllm_mini_tpu.profiling.compile_stats import capture_compile_stats
+    from kserve_vllm_mini_tpu.runtime import steps
+
+    sh = SingleDeviceSharding(device)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    key = jax.random.PRNGKey(0)
+    params = on_chip(jax.eval_shape(lambda: init_params_quantized(key, cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: init_kv_cache(cfg, SLOTS, max_seq=CONTEXT)))
+    i32, f32 = jnp.int32, jnp.float32
+    if step == "decode":
+        fn = steps.build_decode(cfg, forward, "auto", 1)
+        row = (SLOTS,)
+        args = (params, cache, arg(row, i32), arg(row, i32), arg(row, f32),
+                arg(row, i32), arg(row, f32), on_chip(key),
+                arg((SLOTS, cfg.vocab_size), i32), arg(row, f32), arg(row, f32))
+        kw = {"live": arg(row, jnp.bool_)} if cfg.is_hybrid else {}
+    else:
+        fn = steps.build_prefill(cfg, forward, "auto", "slot", True)
+        args = (params, cache, arg((1, BUCKET), i32), arg((), i32), arg((), i32))
+        kw = {}
+    return capture_compile_stats(fn, *args, label=step, **kw)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("cases", nargs="+", help="<model>:<decode|prefill>")
+    ap.add_argument("--dump", default=None)
+    ns = ap.parse_args()
+
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    # an executable for a described chip can be written to the persistent
+    # cache but not read back: keep these compiles out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        from jax.experimental import topologies
+
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation, or no such topology
+        print(json.dumps({"skip": f"no chipless v5e topology: {type(e).__name__}: {e}"[:400]}))
+        return 0
+    jax.default_backend = lambda: "tpu"   # what the kernel dispatch asks
+
+    out = {}
+    for case in ns.cases:
+        model, step = case.split(":")
+        exe, stats = compile_case(model_config(model), step, topo.devices[0])
+        out[case] = stats.to_dict()
+        if ns.dump:
+            Path(ns.dump).mkdir(parents=True, exist_ok=True)
+            (Path(ns.dump) / f"{case.replace(':', '.')}.hlo.txt").write_text(exe.as_text())
+    print(json.dumps({"cases": out}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
