@@ -1,0 +1,8 @@
+"""Device milliseconds of one decode step spent in the operations that read or write the Mamba-2 state (found by the state's shape, bench/ssm_moe_roofline.py): their summed time in ``jit_decode`` over the traced window, over the steps it ran. Nothing for a model without state-space blocks."""
+from bench import ssm_moe_roofline
+
+
+def read(ctx):
+    found = ssm_moe_roofline.decode_seconds_and_steps(
+        ctx.trace, ctx.memory["model"], ssm_moe_roofline.touches_state)
+    return None if found is None else 1000.0 * found[0] / found[1]

@@ -25,7 +25,10 @@ three legs, set-up seconds only, no performance claim.
   its published widths, two of its eight periods (6 linear-attention + 2
   full-attention layers, int8 weights, prompts prefilled in pieces of 256):
   its full layers' prefill and decode executables must hold their Mosaic
-  calls beside the linear layers' recurrent state.
+  calls beside the linear layers' recurrent state. Then nemotron-3-super-120b
+  at its published widths as one of four chips' share, seven blocks
+  (``MEM*EME``: Mamba-2, attention without rotary embedding, 128 of the 512
+  latent experts): the same asks.
 
 With no TPU it exits non-zero before doing anything; it never carries on on
 the CPU. ``--cpu-dry-run`` is the explicit exception for debugging the
@@ -278,12 +281,13 @@ def sizes(dry: bool) -> dict:
     positions, so it keeps the three short phases and shrinks the rest."""
     if dry:
         return dict(main_model="mistral-tiny", small_model="llama-tiny",
-                    hybrid_model="olmo-hybrid-tiny",
+                    hybrid_model="olmo-hybrid-tiny", pattern_model="nemotron-h-tiny",
                     phases=MAIN_PHASES[:3], out_tokens=8, main_parity=(32,),
                     paged_block=16, paged_parity=(32,), engine_words=20,
                     chunk=32, int8_parity=(32, 32))
     return dict(main_model="mistral-7b", small_model="llama-1b",
                 hybrid_model=hybrid_preset(periods=2),
+                pattern_model=pattern_preset("MEM*EME"),
                 phases=MAIN_PHASES, out_tokens=32, main_parity=(128,),
                 paged_block=64, paged_parity=(128,), engine_words=160,
                 chunk=256, int8_parity=(256, 256))
@@ -527,6 +531,18 @@ def hybrid_preset(periods: int) -> str:
     return name
 
 
+def pattern_preset(blocks: str) -> str:
+    """nemotron-3-super-120b cut to ``blocks`` of its kinds of block (every
+    width as published), one of four chips' share of each layer: 128 of the
+    512 experts and a quarter of the vocabulary."""
+    from kserve_vllm_mini_tpu.models.config import PRESETS, get_config
+
+    name = f"nemotron-3-super-{len(blocks)}b-ep4"
+    PRESETS[name] = get_config("nemotron-3-super-120b").scaled(
+        name=name, hybrid_override_pattern=blocks, n_experts_held=128, vocab_size=32768)
+    return name
+
+
 def leg_engines(dry: bool) -> dict:
     from kserve_vllm_mini_tpu.runtime.local import start_local_server
 
@@ -548,6 +564,14 @@ def leg_engines(dry: bool) -> dict:
         # bf16 cache keep the eager read
         ("hybrid", {"model": sz["hybrid_model"], "quantization": "int8",
                     "prefill_chunk": sz["chunk"]},
+         ["prefill[", "decode[chunk=1]"],
+         dict(chunks=sz["int8_parity"], decode=True, block_size=None,
+              kv_int8=False)),
+        # Mamba-2, attention without rotary embedding (2 KV heads x 128: flash
+        # prefill and the dense decode kernel) and expert blocks, 128 of 512
+        # experts held; continuation pieces carry state and tail
+        ("ssm_moe", {"model": sz["pattern_model"], "quantization": "int8",
+                     "prefill_chunk": sz["chunk"]},
          ["prefill[", "decode[chunk=1]"],
          dict(chunks=sz["int8_parity"], decode=True, block_size=None,
               kv_int8=False)),

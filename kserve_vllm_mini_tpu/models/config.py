@@ -31,6 +31,17 @@ orthogonal architecture axes extend it to the other families:
   whole q and k projections (QK-norm) before RoPE. ``olmo2`` is the hybrid
   stack's block and no axis of its own: only a hybrid's parameter tree
   holds its gains, so without ``layer_types`` it is refused.
+- ``hybrid_override_pattern`` — a stack of single-mixer blocks (Nemotron-H):
+  one character a block, ``M`` a Mamba-2 state-space mixer
+  (models/mamba.py, sized by the ``mamba_*`` fields), ``*`` attention
+  without rotary embedding, ``E`` routed experts in a latent beside a
+  shared expert (models/moe.py, the ``moe_*`` fields), in any order. Each
+  block is ``x + Mixer(RMSNorm(x))``: no attention+MLP pair. Each kind's
+  weights are stacked on their own, and the cache holds K/V for the ``*``
+  blocks and a state and a convolution tail for the ``M`` blocks.
+  ``n_routed_experts`` is the router's width; ``n_experts_held`` and
+  ``expert_offset`` say which of them this device holds (expert
+  parallelism's share: it routes over all, computes its own).
 """
 
 from __future__ import annotations
@@ -64,12 +75,9 @@ class ModelConfig:
     # Qwen2-style biases on the q/k/v projections (o/mlp stay bias-free).
     attn_bias: bool = False
     # Mixtral-style sparse MoE: n_experts > 0 replaces the dense SwiGLU MLP
-    # with a top-k routed expert MLP (models/moe.py).
+    # with a top-k routed expert MLP (models/moe.py; no token is dropped).
     n_experts: int = 0
     n_experts_per_tok: int = 2
-    # Dispatch buffer head-room: each expert's token capacity per routed
-    # block is ceil(tokens * top_k / n_experts * capacity_factor).
-    expert_capacity_factor: float = 2.0
     # Block style: "llama" (pre-norm attn -> pre-norm SwiGLU, RMSNorm) or
     # "phi" (parallel attn+MLP off one LayerNorm, GELU MLP, all-bias).
     block: str = "llama"
@@ -117,8 +125,39 @@ class ModelConfig:
     # beta in (0, 2) instead of (0, 1): the state transition may have
     # negative eigenvalues
     linear_allow_neg_eigval: bool = False
+    # A stack of single-mixer blocks (Nemotron-H), under the published names.
+    # One character a block: "M" Mamba-2, "*" attention (no rotary
+    # embedding), "E" routed experts. The pattern IS the depth: n_layers
+    # follows it (the source's own rule), so a cut pattern cuts the stack.
+    hybrid_override_pattern: Optional[str] = None
+    # the Mamba-2 mixer's sizes (models/mamba.py)
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    # the expert block's (models/moe.py moe_latent_block): a sigmoid router
+    # n_routed_experts wide with a selection bias, num_experts_per_tok
+    # chosen, their weights normalised (norm_topk_prob) and scaled; un-gated
+    # ReLU^2 experts moe_intermediate_size wide in a latent of
+    # moe_latent_size; one shared expert at the model's width
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_latent_size: int = 0
+    moe_intermediate_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # which of the n_routed_experts this device holds: experts
+    # [expert_offset, expert_offset + n_experts_held); None = all of them
+    n_experts_held: Optional[int] = None
+    expert_offset: int = 0
 
     def __post_init__(self) -> None:
+        if self.hybrid_override_pattern is not None:
+            self._check_pattern()
+            return
         if self.layer_types is None:
             if self.block == "olmo2":
                 raise ValueError("block='olmo2' is the hybrid stack's block: "
@@ -154,19 +193,78 @@ class ModelConfig:
             raise ValueError("a hybrid stack takes block='olmo2' with a dense MLP "
                              "and one attention mask for every full layer")
 
+    def _check_pattern(self) -> None:
+        pat = self.hybrid_override_pattern
+        if not pat or set(pat) - set("M*E"):
+            raise ValueError(f"hybrid_override_pattern {pat!r}: one of 'M', '*', 'E' a block")
+        object.__setattr__(self, "n_layers", len(pat))
+        if self.layer_types is not None or self.is_moe or self.block != "llama":
+            raise ValueError("a hybrid_override_pattern stack takes no layer_types, "
+                             "n_experts or block: its blocks are named by the pattern")
+        if "M" in pat and (min(self.mamba_num_heads, self.mamba_head_dim,
+                               self.ssm_state_size, self.n_groups) < 1
+                           or self.mamba_num_heads % self.n_groups
+                           or self.conv_kernel < 2 or self.chunk_size < 1):
+            raise ValueError("'M' blocks need mamba_num_heads (a multiple of n_groups), "
+                             "mamba_head_dim, ssm_state_size >= 1 and conv_kernel >= 2")
+        if "E" in pat:
+            if min(self.n_routed_experts, self.num_experts_per_tok, self.moe_latent_size,
+                   self.moe_intermediate_size, self.moe_shared_expert_intermediate_size) < 1:
+                raise ValueError("'E' blocks need n_routed_experts, num_experts_per_tok, "
+                                 "moe_latent_size, moe_intermediate_size and "
+                                 "moe_shared_expert_intermediate_size >= 1")
+            if self.num_experts_per_tok > self.n_routed_experts:
+                raise ValueError("num_experts_per_tok exceeds n_routed_experts")
+            if not (0 <= self.expert_offset
+                    and self.expert_offset + self.experts_held <= self.n_routed_experts
+                    and self.experts_held >= 1):
+                raise ValueError(
+                    f"experts [{self.expert_offset}, {self.expert_offset + self.experts_held}) "
+                    f"are not among the router's {self.n_routed_experts}")
+
     @property
     def is_hybrid(self) -> bool:
-        return self.layer_types is not None
+        """A stack that keeps a recurrent state per slot beside K/V."""
+        return self.layer_types is not None or self.is_pattern_stack
+
+    @property
+    def is_pattern_stack(self) -> bool:
+        return self.hybrid_override_pattern is not None
 
     @property
     def layer_period(self) -> int:
-        """Layers in one period of a hybrid stack (k linear + 1 full)."""
-        return self.layer_types.index("full_attention") + 1 if self.is_hybrid else 1
+        """Layers in one period of a layer_types stack (k linear + 1 full)."""
+        return self.layer_types.index("full_attention") + 1 if self.layer_types else 1
 
     @property
     def n_full_layers(self) -> int:
-        """Layers that hold K/V: all of them, or a hybrid's full-attention ones."""
+        """Layers that hold K/V: all of them, a layer_types stack's
+        full-attention ones, or a pattern's '*' blocks."""
+        if self.is_pattern_stack:
+            return self.hybrid_override_pattern.count("*")
         return self.n_layers // self.layer_period
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return (self.hybrid_override_pattern or "").count("M")
+
+    @property
+    def n_expert_layers(self) -> int:
+        return (self.hybrid_override_pattern or "").count("E")
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.n_routed_experts if self.n_experts_held is None else self.n_experts_held
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """Channels of the Mamba-2 convolution: x, B and C side by side."""
+        return self.mamba_d_inner + 2 * self.n_groups * self.ssm_state_size
 
     @property
     def n_linear_layers(self) -> int:
@@ -190,7 +288,10 @@ class ModelConfig:
 
     @property
     def rotary_dim(self) -> int:
-        """Even number of head dims receiving RoPE (phi uses a prefix)."""
+        """Even number of head dims receiving RoPE (phi uses a prefix; the
+        attention blocks of a pattern stack carry no position: 0)."""
+        if self.is_pattern_stack:
+            return 0
         d = int(self.head_dim * self.partial_rotary_factor)
         return d - (d % 2)
 
@@ -202,6 +303,8 @@ class ModelConfig:
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + blocks + head)."""
         emb = self.vocab_size * self.d_model
+        if self.is_pattern_stack:
+            return 2 * emb + self.d_model + self._pattern_block_params()
         qo = self.d_model * self.n_heads * self.head_dim  # q and o projections
         attn = 2 * qo + 2 * self.d_model * (self.n_kv_heads * self.head_dim)
         mlp = (2 if self.block == "phi" else 3) * self.d_model * self.d_ff
@@ -226,6 +329,21 @@ class ModelConfig:
             )
             blocks += self.n_linear_layers * (mixer + mlp + norms)
         return emb + blocks + self.d_model + head
+
+    def _pattern_block_params(self) -> int:
+        """Parameters of a pattern stack's blocks, the experts held here."""
+        d = self.d_model
+        di, ch = self.mamba_d_inner, self.mamba_conv_channels
+        mamba = (d * (di + ch + self.mamba_num_heads) + di * d   # in_proj, out_proj
+                 + ch * (self.conv_kernel + 1)                    # taps and bias
+                 + 3 * self.mamba_num_heads + di)                 # A_log, D, dt_bias, gain
+        attn = 2 * d * self.n_heads * self.head_dim + 2 * d * self.n_kv_heads * self.head_dim
+        lat, ff, sh = (self.moe_latent_size, self.moe_intermediate_size,
+                       self.moe_shared_expert_intermediate_size)
+        experts = (d * self.n_routed_experts + self.n_routed_experts  # router, bias
+                   + 2 * d * lat + 2 * d * sh + self.experts_held * 2 * lat * ff)
+        return (self.n_ssm_layers * (mamba + d) + self.n_full_layers * (attn + d)
+                + self.n_expert_layers * (experts + d))
 
     def scaled(self, **kwargs) -> "ModelConfig":
         return replace(self, **kwargs)
@@ -422,6 +540,36 @@ PRESETS: dict[str, ModelConfig] = {
         linear_conv_kernel_dim=4,
         linear_allow_neg_eigval=True,
     ),
+    # Nemotron-3-Super-120B-A12B (huggingface.co/nvidia/NVIDIA-Nemotron-3-
+    # Super-120B-A12B-BF16 config.json): 40 Mamba-2, 8 attention and 40
+    # expert blocks in the published order; 512 latent experts, 22 a token,
+    # one shared expert. The multi-token-prediction module is not built.
+    "nemotron-3-super-120b": ModelConfig(
+        name="nemotron-3-super-120b",
+        vocab_size=131_072,
+        d_model=4096,
+        n_layers=88,
+        n_heads=32,
+        n_kv_heads=2,
+        d_ff=2688,
+        max_seq_len=8192,
+        rms_eps=1e-5,
+        hybrid_override_pattern=("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+                                 "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        mamba_num_heads=128,
+        mamba_head_dim=64,
+        ssm_state_size=128,
+        n_groups=8,
+        conv_kernel=4,
+        chunk_size=128,
+        n_routed_experts=512,
+        num_experts_per_tok=22,
+        moe_latent_size=1024,
+        moe_intermediate_size=2688,
+        moe_shared_expert_intermediate_size=5376,
+        routed_scaling_factor=5.0,
+        norm_topk_prob=True,
+    ),
     # -- tiny CI variants (CPU in <1s) exercising each architecture axis ----
     "mistral-tiny": ModelConfig(
         name="mistral-tiny",
@@ -501,6 +649,35 @@ PRESETS: dict[str, ModelConfig] = {
         linear_value_head_dim=48,
         linear_conv_kernel_dim=4,
         linear_allow_neg_eigval=True,
+    ),
+    # all three kinds of block in an irregular order; a quarter of the
+    # experts held, as one of four devices that share each layer would
+    "nemotron-h-tiny": ModelConfig(
+        name="nemotron-h-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=7,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=64,
+        max_seq_len=256,
+        rms_eps=1e-5,
+        hybrid_override_pattern="MEM*EME",
+        mamba_num_heads=8,
+        mamba_head_dim=16,
+        ssm_state_size=16,
+        n_groups=2,
+        conv_kernel=4,
+        chunk_size=32,
+        n_routed_experts=32,
+        num_experts_per_tok=4,
+        moe_latent_size=64,
+        moe_intermediate_size=96,
+        moe_shared_expert_intermediate_size=192,
+        routed_scaling_factor=2.5,
+        norm_topk_prob=True,
+        n_experts_held=8,
+        expert_offset=0,
     ),
     "mixtral-tiny": ModelConfig(
         name="mixtral-tiny",

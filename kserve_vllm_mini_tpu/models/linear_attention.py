@@ -68,17 +68,21 @@ def state_shapes(cfg: ModelConfig, batch: int) -> dict[str, tuple[tuple[int, ...
 
 
 def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, taps: jnp.ndarray,
-                valid: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Depthwise causal convolution then SiLU. ``u`` [B, T, C] are this
-    block's inputs, ``tail`` [B, K-1, C] the inputs before it, ``taps``
-    [K, C] with the last tap on the current input. Returns the float32
-    outputs [B, T, C] and the new tail: the K-1 inputs before position
-    ``valid`` (the old tail where ``valid`` is 0)."""
+                valid: jnp.ndarray, bias: jnp.ndarray | None = None,
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Depthwise causal convolution (plus ``bias`` [C] where the mixer has
+    one) then SiLU. ``u`` [B, T, C] are this block's inputs, ``tail``
+    [B, K-1, C] the inputs before it, ``taps`` [K, C] with the last tap on
+    the current input. Returns the float32 outputs [B, T, C] and the new
+    tail: the K-1 inputs before position ``valid`` (the old tail where
+    ``valid`` is 0)."""
     k = taps.shape[0]
     t = u.shape[1]
     ext = jnp.concatenate([tail.astype(u.dtype), u], axis=1)       # [B, T+K-1, C]
     w = taps.astype(jnp.float32)
     y = sum(ext[:, j:j + t].astype(jnp.float32) * w[j] for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     rows = valid[:, None] + jnp.arange(k - 1)[None, :]             # [B, K-1]
     new_tail = jnp.take_along_axis(ext, rows[:, :, None], axis=1)
     return jax.nn.silu(y), new_tail.astype(tail.dtype)

@@ -92,6 +92,8 @@ def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
     the equivalence oracle in tests/test_quant.py rests on)."""
     dt = cfg.jnp_dtype
     keys = _init_keys(rng, cfg)
+    if cfg.is_pattern_stack:
+        return _init_pattern(keys, cfg, leaf_fn)
     if cfg.is_hybrid:
         return _init_hybrid(keys, cfg, leaf_fn)
     L = cfg.n_layers
@@ -204,6 +206,78 @@ def _init_hybrid(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Param
     return params
 
 
+# a hybrid_override_pattern's characters -> the stack of ``layers`` each kind lives in
+PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "experts"}
+
+
+def pattern_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
+    """Per-layer shape of every matmul of a ``hybrid_override_pattern``
+    stack, by kind of block; every one is quantized in the int8 tree
+    (ops/quant.py PATTERN_QUANTIZABLE)."""
+    from kserve_vllm_mini_tpu.models.mamba import mixer_weight_shapes
+
+    d, hd = cfg.d_model, cfg.head_dim
+    lat, ff = cfg.moe_latent_size, cfg.moe_intermediate_size
+    sh, held = cfg.moe_shared_expert_intermediate_size, cfg.experts_held
+    return {
+        "mamba": mixer_weight_shapes(cfg),
+        "attn": {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
+                 "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)},
+        "experts": {"w_in": (d, lat), "w_out": (lat, d), "w1": (held, lat, ff),
+                    "w2": (held, ff, lat), "s1": (d, sh), "s2": (sh, d)},
+    }
+
+
+def _init_pattern(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
+    """A pattern stack's tree: ``layers`` holds each kind of block stacked
+    on its own, ``{"mamba": [n_M, ...], "attn": [n_*, ...], "experts":
+    [n_E, ...]}``, in stack order within the kind; a kind the pattern lacks
+    has no entry. Small leaves get values that show when they are left out."""
+    dt = cfg.jnp_dtype
+    counts = {kind: cfg.hybrid_override_pattern.count(ch) for ch, kind in PATTERN_KINDS.items()}
+    shapes = pattern_weight_shapes(cfg)
+
+    def stack(key, n, shape, fn=leaf_fn):
+        def body(_, k):
+            return None, fn(_nrm(k, shape, dt))
+
+        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
+
+    def gains(key, n, width):
+        return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
+
+    layers: Params = {}
+    for kind, key in zip(counts, (keys["wq"], keys["wk"], keys["wv"])):
+        n = counts[kind]
+        if not n:
+            continue
+        ks = jax.random.split(key, 16)
+        sub: Params = {name: stack(k, n, shape)
+                       for (name, shape), k in zip(shapes[kind].items(), ks)}
+        sub["norm_in"] = gains(ks[8], n, cfg.d_model)
+        if kind == "mamba":
+            h, ch = cfg.mamba_num_heads, cfg.mamba_conv_channels
+            sub["conv"] = (0.3 * jax.random.normal(
+                ks[9], (n, cfg.conv_kernel, ch), jnp.float32)).astype(dt)
+            sub["conv_b"] = (0.1 * jax.random.normal(ks[10], (n, ch), jnp.float32)).astype(dt)
+            sub["A_log"] = jnp.log(jax.random.uniform(ks[11], (n, h), jnp.float32, 1.0, 16.0))
+            sub["dt_bias"] = jax.random.uniform(ks[12], (n, h), jnp.float32, -5.0, -1.0)
+            sub["D"] = jax.random.uniform(ks[13], (n, h), jnp.float32, 0.5, 1.5)
+            sub["norm"] = gains(ks[14], n, cfg.mamba_d_inner)
+        elif kind == "experts":
+            sub["router"] = stack(ks[9], n, (cfg.d_model, cfg.n_routed_experts),
+                                  lambda w: w.astype(jnp.float32))
+            sub["router_bias"] = 0.05 * jax.random.normal(
+                ks[10], (n, cfg.n_routed_experts), jnp.float32)
+        layers[kind] = sub
+    return {
+        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
+        "layers": layers,
+        "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
+        "lm_head": _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt),
+    }
+
+
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Random-normal init (0.02 std), bf16 — for tests, benches, and as the
     target pytree structure for checkpoint loading.
@@ -256,7 +330,10 @@ def init_kv_cache(
     shape = (cfg.n_full_layers, batch, cfg.n_kv_heads, s, cfg.head_dim)
     extra: KVCache = {}
     if cfg.is_hybrid:
-        from kserve_vllm_mini_tpu.models.linear_attention import state_shapes
+        if cfg.is_pattern_stack:
+            from kserve_vllm_mini_tpu.models.mamba import state_shapes
+        else:
+            from kserve_vllm_mini_tpu.models.linear_attention import state_shapes
 
         extra = {name: jnp.zeros(shp, dtype=dt)
                  for name, (shp, dt) in state_shapes(cfg, batch).items()}
@@ -388,6 +465,8 @@ def qkv_proj(
     k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     rd = cfg.rotary_dim
+    if rd == 0:
+        return q, k, v           # attention that carries no position
     if rd < cfg.head_dim:
         # phi-style partial rotary: RoPE on the first rotary_dim dims, the
         # rest pass through (cos/sin tables are built at rotary_dim width)
@@ -660,6 +739,10 @@ def run_cached_layers(
                                  # its recurrent state and convolution tail
                                  # untouched (models/linear_attention.py);
                                  # None = all T
+    with_stats: bool = False,    # a pattern stack with expert blocks: also
+                                 # return their routing counts, int32 [3]
+                                 # summed over the blocks (models/moe.py
+                                 # moe_latent_block), over the real positions
 ) -> tuple[jnp.ndarray, KVCache]:
     """The cached transformer stack: scan over stacked layers, writing this
     block's K/V at ``cache_offsets`` and attending with positional masking
@@ -817,6 +900,16 @@ def run_cached_layers(
 
     def full_layer(y0, cache, p, lora_p, lidx):
         h = block_norm(p, cfg, y0, "attn_norm")
+        o, cache = attend(h, cache, p, lora_p, lidx)
+        return (
+            attn_out_and_mlp(p, cfg, y0, o, h, lora=lora_p, lora_ids=lora_ids),
+            cache,
+        )
+
+    def attend(h, cache, p, lora_p, lidx):
+        """The attention mixer on the normed ``h``: project, write this
+        block's K/V into layer ``lidx`` of the cache, attend -> (o
+        [B,H,T,hd], cache)."""
         q, k, v = qkv_proj(p, cfg, h, positions, cos, sin,
                            lora=lora_p, lora_ids=lora_ids)
         cache = dict(cache)
@@ -933,10 +1026,52 @@ def run_cached_layers(
                 m = jnp.where(glidx % 2 == 0, mask, mask_global)
             o = attention(q, k_layer, v_layer, m,
                           scale=attn_scale, softcap=attn_cap)
-        return (
-            attn_out_and_mlp(p, cfg, y0, o, h, lora=lora_p, lora_ids=lora_ids),
-            cache,
+        return o, cache
+
+    if cfg.is_hybrid and (lora is not None or paged or write_gate is not None
+                          or slot_base is not None):
+        raise ValueError(
+            "a hybrid stack serves from the dense cache on one device, "
+            "without adapters (no block table, pipeline stage or LoRA bank)"
         )
+    if cfg.is_pattern_stack:
+        # One mixer a block, in the pattern's (irregular) order; each kind's
+        # weights are stacked on their own. The walk is unrolled with static
+        # indices: every block reads its slice of its stack where it lies
+        # (a scan over a [periods, k, ...] view, or a switch on the kind
+        # inside one, makes XLA copy the slice out every step), and state,
+        # tail and K/V are updated in place in the donated cache.
+        from kserve_vllm_mini_tpu.models.mamba import mamba_mixer
+        from kserve_vllm_mini_tpu.models.moe import moe_latent_block
+
+        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
+                 else valid_len.astype(jnp.int32))
+        real = jnp.arange(T)[None, :] < valid[:, None]
+        cache = dict(kv_cache)
+        seen = dict.fromkeys(PATTERN_KINDS, 0)
+        stats = jnp.zeros((3,), jnp.int32)
+        for ch in cfg.hybrid_override_pattern:
+            i = seen[ch]
+            seen[ch] += 1
+            p = jax.tree_util.tree_map(lambda a: a[i], layers[PATTERN_KINDS[ch]])
+            h = rms_norm(x, p["norm_in"], cfg.rms_eps)
+            if ch == "M":
+                state, tail = cache["ssm_state"][i], cache["ssm_conv"][i]
+                if fresh_prefill:
+                    # a new sequence starts from S_0 = 0 whatever the slot held
+                    state, tail = jnp.zeros_like(state), jnp.zeros_like(tail)
+                out, state, tail = mamba_mixer(p, cfg, h, state, tail, valid)
+                cache["ssm_state"] = cache["ssm_state"].at[i].set(state)
+                cache["ssm_conv"] = cache["ssm_conv"].at[i].set(tail)
+            elif ch == "*":
+                o, cache = attend(h, cache, p, None, jnp.int32(i))
+                o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
+                out = linear(o, p["wo"], mode=cfg.quant_mode)
+            else:
+                out, counts = moe_latent_block(p, cfg, h, real)
+                stats = stats + counts
+            x = x + out
+        return (x, cache, stats) if with_stats else (x, cache)
 
     if cfg.is_hybrid:
         # one period = k linear layers then one full layer; each kind's
@@ -947,12 +1082,6 @@ def run_cached_layers(
         # weights out of the stack every iteration, which doubles the
         # weight stream of a decode step. Both kinds of state ride the
         # carry (invariant 1 above holds for them too).
-        if (lora is not None or paged or write_gate is not None
-                or slot_base is not None):
-            raise ValueError(
-                "a hybrid stack serves from the dense cache on one device, "
-                "without adapters (no block table, pipeline stage or LoRA bank)"
-            )
         from kserve_vllm_mini_tpu.models.linear_attention import linear_mixer
 
         n_lin = cfg.layer_period - 1
@@ -1040,6 +1169,9 @@ def forward(
                         # slot is parked). Padding is harmless in a KV stripe
                         # and corrupts a recurrent state, so a hybrid stack
                         # needs it; every other model ignores it
+    with_stats: bool = False,  # static; cached calls of a pattern stack with
+                        # expert blocks: a third result, the routing counts
+                        # of run_cached_layers
 ) -> tuple[jnp.ndarray, Optional[KVCache]]:
     """Returns (logits [B, T, V] float32, updated cache).
 
@@ -1079,10 +1211,11 @@ def forward(
         )
         new_cache_dict = None
     elif use_cache:
-        x, new_cache_dict = run_cached_layers(
+        x, new_cache_dict, *stats = run_cached_layers(
             layers, cfg, x, positions, cos, sin, kv_cache, cache_offsets,
             fresh_prefill=fresh_prefill, block_table=block_table,
             lora=lora, lora_ids=lora_ids, kernels=kernels, valid_len=valid_len,
+            with_stats=with_stats,
         )
     else:
         def scan_body_nocache(carry, xs):
@@ -1101,4 +1234,6 @@ def forward(
     if logit_index is not None:
         x = x[jnp.arange(B)[:, None], logit_index[:, None]]  # [B, 1, D]
     logits = final_logits(params, cfg, x)
+    if with_stats:
+        return logits, new_cache_dict, stats[0]
     return logits, new_cache_dict

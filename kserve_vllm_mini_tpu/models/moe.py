@@ -1,112 +1,171 @@
-"""Sparse mixture-of-experts MLP (Mixtral-style top-k routing), TPU-first.
+"""Sparse mixture-of-experts layers, drop-free, for a device that holds some
+or all of the experts.
 
-The reference has no MoE support at all (SURVEY.md §2.2: EP absent); this
-module adds the family the TPU build owns end-to-end. Design:
+Two layers share one dispatch:
 
-- **Routing**: per-token softmax router, ``lax.top_k`` selection of
-  ``n_experts_per_tok`` experts, gates renormalized over the chosen k
-  (Mixtral's convention).
-- **Dispatch**: capacity-bounded scatter into a per-expert token buffer
-  ``[E, C, D]`` — O(tokens · k) memory, unlike the GShard one-hot einsum
-  whose ``[S, E, C]`` dispatch tensor is quadratic in tokens. Position
-  within each expert comes from a cumulative sum over a choice-major
-  flattening, so every token's FIRST choice beats any token's second choice
-  when an expert overflows (GShard priority). Overflowed assignments drop
-  (their gate weight is simply not added — the residual passes through),
-  which is the standard capacity-factor contract.
-- **Expert compute**: one batched SwiGLU over ``[E, C, D]`` — three
-  ``einsum('ecd,edf->ecf')`` matmuls the MXU tiles per expert. Expert
-  weights are stacked ``[L, E, d, ff]`` so the layer scan treats MoE layers
-  exactly like dense ones.
-- **EP sharding**: the expert axis shards over the mesh's ``ep`` axis
-  (parallel/sharding.py): expert weights are P(..., "ep", ...), and XLA
-  lowers the dispatch/return movement to all-to-alls over ICI — the
-  scaling-book recipe, not hand-written collectives.
+- ``moe_mlp`` (Mixtral): a softmax router, the top ``n_experts_per_tok``
+  renormalised, SwiGLU experts at the model's width, every expert held. The
+  expert axis shards over the mesh's ``ep`` axis (parallel/sharding.py).
+- ``moe_latent_block`` (Nemotron-H's ``E`` block): a float32 sigmoid router
+  over ``n_routed_experts`` with a selection bias, ``num_experts_per_tok``
+  chosen by ``score + bias`` and weighted by their scores, normalised over
+  all the chosen and scaled by ``routed_scaling_factor``; un-gated ReLU^2
+  experts in a latent of ``moe_latent_size`` between a down- and an
+  up-projection; one shared expert at the model's width. The device is TOLD
+  which experts it holds (``cfg.expert_offset``, ``cfg.experts_held``): it
+  routes over all of them, weights as the whole model would, and computes
+  the part of the result its own experts give. What the absent experts would
+  add is left out; on one device nothing stands in for their exchange.
 
-Quantization: expert matmul weights quantize per-output-channel like dense
-weights (ops/quant.py works on any [..., in, out] stack); the router stays
-bf16 (it is d_model x E — noise-level bytes, accuracy-critical).
+**Dispatch: every held expert runs every token of the block, and the routing
+weight (zero where the token did not choose the expert) is applied at the
+combine.** No token can be dropped at any routing, because nothing is
+bounded by a capacity: there is no sort, scatter, buffer or gather, so no
+latency-bound operation between the router and the matmuls, and the int8
+expert stacks are read where they lie (one batched matmul over the expert
+axis). The price is arithmetic: held experts / chosen-and-held experts times
+what the chosen tokens need. A decode step is bound by the experts' weight
+stream, which a sweep over 32 slots x 22 choices touches three quarters of
+anyway, so there the arithmetic is free; a prefill piece of 128 positions
+touches every expert and its arithmetic still hides under the weight stream;
+longer pieces pay it (ROADMAP: a grouped matmul over tokens sorted by
+expert, which would also skip the experts a decode step does not touch).
+Pieces longer than ``TOKEN_BLOCK`` run block by block so that the
+``[experts, tokens, width]`` intermediates stay bounded.
+
+Quantization: expert stacks quantize per output channel like dense weights
+(ops/quant.py works on any [..., in, out] stack); routers and the selection
+bias stay unquantized (noise-level bytes, and they decide the routing).
 """
 
 from __future__ import annotations
 
-import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
-from kserve_vllm_mini_tpu.ops.quant import is_quantized, unpacked_q
+from kserve_vllm_mini_tpu.ops.quant import is_quantized, linear, unpacked_q
 
-
-def expert_capacity(cfg: ModelConfig, n_tokens: int) -> int:
-    """Per-expert token capacity for a routed block of ``n_tokens``."""
-    ideal = n_tokens * cfg.n_experts_per_tok / cfg.n_experts
-    return max(int(math.ceil(ideal * cfg.expert_capacity_factor)), cfg.n_experts_per_tok)
+TOKEN_BLOCK = 256   # tokens the held experts run at once
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _expert_linear(x: jnp.ndarray, w: Any, mode: str = "dequant") -> jnp.ndarray:
-    """Batched per-expert matmul ``[E, C, in] @ [E, in, out]``; ``w`` may be
-    a plain array or an int8 dict (scale applied as a fused epilogue, same
-    contract as ops.quant.linear). ``mode="w8a8"`` contracts in int8 with
-    the expert axis as the batch dim (ops/qmatmul.py qdot)."""
-    if is_quantized(w):
-        if mode == "w8a8":
-            from kserve_vllm_mini_tpu.ops.qmatmul import qdot
+    """``[E, S, in] @ [E, in, out]`` (``x`` [S, in] is every expert's
+    input alike); ``w`` is a plain stack or an int8 leaf whose scale is a
+    fused epilogue (the contract of ops.quant.linear). ``mode="w8a8"``
+    contracts in int8 with the expert axis as the batch dimension."""
+    eq = "sd,edf->esf" if x.ndim == 2 else "esd,edf->esf"
+    if not is_quantized(w):
+        return jnp.einsum(eq, x, w)
+    if mode == "w8a8":
+        from kserve_vllm_mini_tpu.ops.qmatmul import qdot
 
-            return qdot(x, w, batch_dims=1)
-        y = jnp.einsum("ecd,edf->ecf", x, unpacked_q(w).astype(x.dtype))
-        return y * w["s"].astype(x.dtype)[:, None, :]
-    return jnp.einsum("ecd,edf->ecf", x, w)
+        n_e = w["s"].shape[0]
+        return qdot(jnp.broadcast_to(x, (n_e, *x.shape)) if x.ndim == 2 else x,
+                    w, batch_dims=1)
+    y = jnp.einsum(eq, x, unpacked_q(w).astype(x.dtype))
+    return y * w["s"].astype(x.dtype)[:, None, :]
+
+
+def held_weights(weights: jnp.ndarray, chosen: jnp.ndarray, offset: int,
+                 held: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``[S, K]`` routing weights and expert ids -> ``[S, held]`` float32,
+    the weight with which each token takes each expert held here (experts
+    ``offset .. offset + held``; zero where it did not choose it), and
+    ``[S, held]`` bool, whether it chose it."""
+    hit = (chosen[:, :, None] - offset) == jnp.arange(held)[None, None, :]
+    take = jnp.sum(jnp.where(hit, weights[:, :, None].astype(jnp.float32), 0.0), axis=1)
+    return take, jnp.any(hit, axis=1)
+
+
+def _run_held(x: jnp.ndarray, take: jnp.ndarray, body) -> jnp.ndarray:
+    """``sum_e take[s, e] * body(x)[e, s]`` -> [S, out] float32, the tokens
+    in blocks of ``TOKEN_BLOCK``."""
+    def block(args):
+        xb, tb = args
+        return jnp.einsum("se,esd->sd", tb, body(xb),
+                          preferred_element_type=jnp.float32)
+
+    n = x.shape[0]
+    if n <= TOKEN_BLOCK:
+        return block((x, take))
+    pad = -n % TOKEN_BLOCK
+    xs = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, TOKEN_BLOCK, x.shape[1])
+    ts = jnp.pad(take, ((0, pad), (0, 0))).reshape(-1, TOKEN_BLOCK, take.shape[1])
+    return jax.lax.map(block, (xs, ts)).reshape(n + pad, -1)[:n]
 
 
 def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
-    """Routed SwiGLU MLP. ``h`` is the normed hidden [B, T, D]; returns the
-    MLP delta [B, T, D] (caller adds the residual, mirroring the dense path).
-
-    ``p`` holds this layer's ``router`` [D, E] plus expert-stacked
-    ``w_gate``/``w_up`` [E, D, F] and ``w_down`` [E, F, D].
-    """
+    """Mixtral's routed SwiGLU MLP. ``h`` is the normed hidden [B, T, D];
+    returns the MLP delta [B, T, D] (the caller adds the residual).
+    ``p`` holds this layer's ``router`` [D, E] and the expert stacks
+    ``w_gate``/``w_up`` [E, D, F], ``w_down`` [E, F, D]."""
     B, T, D = h.shape
-    S = B * T
-    E, K = cfg.n_experts, cfg.n_experts_per_tok
-    C = expert_capacity(cfg, S)
-    dt = h.dtype
-    x = h.reshape(S, D)
-
-    # -- route (f32 softmax; the router matmul is tiny) ---------------------
-    router_logits = (x.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(router_logits, axis=-1)            # [S, E]
-    gates, expert_idx = jax.lax.top_k(probs, K)               # [S, K]
+    dt, qm = h.dtype, cfg.quant_mode
+    x = h.reshape(B * T, D)
+    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
     gates = gates / jnp.maximum(gates.sum(axis=-1, keepdims=True), 1e-9)
 
-    # -- capacity positions: choice-major cumsum so first choices win -------
-    flat_e = expert_idx.T.reshape(-1)                         # [K*S] choice-major
-    onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)       # [K*S, E]
-    pos = jnp.cumsum(onehot, axis=0) - onehot                 # entries start at 0
-    pos_in_expert = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-    keep = pos_in_expert < C                                  # [K*S]
-    # dropped assignments scatter to a sentinel row past every expert buffer
-    slot = jnp.where(keep, flat_e * C + jnp.minimum(pos_in_expert, C - 1), E * C)
+    def experts(xb):
+        gated = jax.nn.silu(
+            _expert_linear(xb, p["w_gate"], qm).astype(jnp.float32)
+        ).astype(dt) * _expert_linear(xb, p["w_up"], qm)
+        return _expert_linear(gated, p["w_down"], qm)
 
-    # -- dispatch: scatter tokens into [E*C(+1), D] expert buffers ----------
-    x_rep = jnp.broadcast_to(x[None], (K, S, D)).reshape(K * S, D)
-    buf = jnp.zeros((E * C + 1, D), dtype=dt).at[slot].add(x_rep)
-    expert_in = buf[: E * C].reshape(E, C, D)
+    out = _run_held(x, held_weights(gates, chosen, 0, cfg.n_experts)[0], experts)
+    return out.astype(dt).reshape(B, T, D)
 
-    # -- batched SwiGLU over experts ----------------------------------------
-    qm = cfg.quant_mode
-    gated = jax.nn.silu(
-        _expert_linear(expert_in, p["w_gate"], mode=qm).astype(jnp.float32)
-    ).astype(dt) * _expert_linear(expert_in, p["w_up"], mode=qm)
-    expert_out = _expert_linear(gated, p["w_down"], mode=qm)  # [E, C, D]
 
-    # -- return + combine: gather each kept assignment, weight by its gate --
-    out_flat = expert_out.reshape(E * C, D)
-    picked = jnp.where(
-        keep[:, None], jnp.take(out_flat, jnp.minimum(slot, E * C - 1), axis=0), 0.0
-    )                                                         # [K*S, D]
-    gates_flat = gates.T.reshape(-1).astype(dt)               # choice-major [K*S]
-    combined = (picked * gates_flat[:, None]).reshape(K, S, D).sum(axis=0)
-    return combined.reshape(B, T, D)
+def route_sigmoid(x: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray,
+                  cfg: ModelConfig) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The ``E`` block's router on x [S, D] -> (weights [S, K] float32,
+    expert ids [S, K]) over ALL ``n_routed_experts``: chosen by score +
+    bias, weighted by score, normalised over all the chosen, scaled. Float32
+    at the highest precision: a rounded score picks another expert."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HI)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.num_experts_per_tok)
+    w = jnp.take_along_axis(scores, chosen, axis=-1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * cfg.routed_scaling_factor, chosen
+
+
+def relu2(x: jnp.ndarray) -> jnp.ndarray:
+    return jnp.square(jax.nn.relu(x.astype(jnp.float32))).astype(x.dtype)
+
+
+def moe_latent_block(
+    p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
+    real: Optional[jnp.ndarray] = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The ``E`` block's mixer on the normed hidden ``h`` [B, T, D] ->
+    (delta [B, T, D], stats). ``p``: ``router`` [D, n_routed] and
+    ``router_bias`` [n_routed] float32; ``w_in`` [D, latent], ``w_out``
+    [latent, D]; the held experts' stacks ``w1`` [held, latent, F], ``w2``
+    [held, F, latent]; the shared expert ``s1`` [D, Fs], ``s2`` [Fs, D].
+
+    ``stats`` is int32 [3] over the positions ``real`` [B, T] marks (all of
+    them when None): assignments that landed on an expert held here, held
+    experts with at least one token, and the fullest held expert's tokens."""
+    B, T, D = h.shape
+    dt, qm = h.dtype, cfg.quant_mode
+    x = h.reshape(B * T, D)
+    w, chosen = route_sigmoid(x, p["router"], p["router_bias"], cfg)
+    take, landed = held_weights(w, chosen, cfg.expert_offset, cfg.experts_held)  # [S, held]
+    u = linear(x, p["w_in"], mode=qm)                                      # [S, latent]
+
+    def experts(ub):
+        return _expert_linear(relu2(_expert_linear(ub, p["w1"], qm)), p["w2"], qm)
+
+    routed = linear(_run_held(u, take, experts).astype(dt), p["w_out"], mode=qm)
+    shared = linear(relu2(linear(x, p["s1"], mode=qm)), p["s2"], mode=qm)
+    if real is not None:
+        landed = landed & real.reshape(B * T, 1)
+    load = jnp.sum(landed, axis=0, dtype=jnp.int32)                        # [held]
+    stats = jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load)])
+    return (routed + shared).reshape(B, T, D), stats

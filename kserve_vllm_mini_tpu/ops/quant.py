@@ -149,6 +149,13 @@ QUANTIZABLE = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 # [d, heads], the convolution taps, A_log, dt_bias, gains) stay as they are:
 # noise-level bytes that set the state's decay and write strength.
 HYBRID_QUANTIZABLE = QUANTIZABLE + ("wg",)
+# a hybrid_override_pattern stack (models/llama.py pattern_weight_shapes):
+# the Mamba-2 projections, the attention projections, and in an expert block
+# the latent projections, the held experts' stacks and the shared expert.
+# The router and its selection bias, convolution taps and bias, A_log, D,
+# dt_bias and every gain stay as they are.
+PATTERN_QUANTIZABLE = ("in_proj", "out_proj", "wq", "wk", "wv", "wo",
+                       "w_in", "w_out", "w1", "w2", "s1", "s2")
 
 
 def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
@@ -162,6 +169,8 @@ def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
     layers = params["layers"]
     if "linear" in layers and "full" in layers:
         out["layers"] = {kind: stack(sub, HYBRID_QUANTIZABLE) for kind, sub in layers.items()}
+    elif set(layers) <= {"mamba", "attn", "experts"}:
+        out["layers"] = {kind: stack(sub, PATTERN_QUANTIZABLE) for kind, sub in layers.items()}
     else:
         out["layers"] = stack(layers, QUANTIZABLE)
     return out

@@ -642,10 +642,16 @@ class Engine:
         else:
             self._cache = make_cache()
         # what the cache tree reserves, by kind of state (gauge, fixed at build)
-        self._cache_bytes = {"kv": 0, "linear_state": 0, "conv_state": 0}
+        kinds = {"lin_state": "linear_state", "lin_conv": "conv_state",
+                 "ssm_state": "ssm_state", "ssm_conv": "ssm_conv_state"}
+        self._cache_bytes = dict.fromkeys(("kv", *kinds.values()), 0)
         for name, leaf in self._cache.items():
-            kind = {"lin_state": "linear_state", "lin_conv": "conv_state"}.get(name, "kv")
-            self._cache_bytes[kind] += int(leaf.nbytes)
+            self._cache_bytes[kinds.get(name, "kv")] += int(leaf.nbytes)
+        # positions a prefill piece's scan solves at once, and what the span
+        # of a piece calls its count: the delta rule's or the Mamba-2 mixer's
+        self._scan_chunk, self._scan_arg = (
+            (cfg.chunk_size, "ssm_chunks") if cfg.is_pattern_stack
+            else (LINEAR_CHUNK, "linear_chunks"))
         # which rows of a decode sweep may move a hybrid's recurrent state:
         # the device mask of the last dispatch, keyed by its active set
         self._live_dev: Optional[tuple[tuple[int, ...], jnp.ndarray]] = None
@@ -955,6 +961,17 @@ class Engine:
             "linear_state_slot_steps": 0,
             "linear_state_held_slot_steps": 0,
             "linear_state_resets": 0,
+            # routed expert blocks (models/moe.py moe_latent_block; all 0 for
+            # every other model), over the decode steps retired, summed over
+            # the expert layers: assignments the live rows made (rows x
+            # experts a token), those that landed on an expert held here,
+            # held experts with at least one token, and the fullest held
+            # expert's tokens; the last three counted on the device and
+            # read back with the step's tokens
+            "moe_assignments": 0,
+            "moe_local_assignments": 0,
+            "moe_expert_touches": 0,
+            "moe_expert_load_max": 0,
             "requests_completed": 0,
             "busy_s": 0.0,        # exported: busy_seconds_total + duty_cycle
             "started_at": _now(),  # kvmini: metrics-ok — raw input; exposed as duty_cycle
@@ -1087,8 +1104,9 @@ class Engine:
         self._kv_admin_snapshot()
 
     def _refuse_for_hybrid(self, mesh, drafter, lora, prefill_mesh) -> None:
-        """A hybrid stack (cfg.layer_types) keeps a recurrent state per
-        slot beside its K/V. A state has no positions: it cannot be rewound
+        """A hybrid stack (cfg.layer_types, cfg.hybrid_override_pattern)
+        keeps a recurrent state per slot beside its K/V. A state has no
+        positions: it cannot be rewound
         to a shared prefix, cut into blocks, verified-and-rolled-back by a
         drafter, or handed over as a stripe, and nothing here shards it.
         Each such setting is an error at construction, never a fallback."""
@@ -1117,8 +1135,8 @@ class Engine:
                    "or splits the period walk over pipeline stages")
         if why is not None:
             raise ValueError(
-                f"model {self.cfg.name!r} is a hybrid stack (linear-attention "
-                f"layers beside full attention) and cannot be served with {why}"
+                f"model {self.cfg.name!r} is a hybrid stack (layers that keep a "
+                f"recurrent state beside full attention) and cannot be served with {why}"
             )
 
     # -- paged-KV block accounting ----------------------------------------
@@ -2273,14 +2291,19 @@ class Engine:
         bucket = steps.prefill_bucket(len(piece), self.ecfg)
         span_args = {}
         if self._hybrid:
-            # 64-position chunks this piece runs through each linear layer's
-            # scan, and those of them that hold only padding
-            chunks = -(-bucket // LINEAR_CHUNK)
-            span_args["linear_chunks"] = chunks
+            # chunks this piece runs through each recurrent layer's scan,
+            # and those of them that hold only padding
+            chunks = -(-bucket // self._scan_chunk)
+            span_args[self._scan_arg] = chunks
             self.stats["linear_scan_chunks"] += chunks
-            self.stats["linear_scan_pad_chunks"] += chunks - -(-len(piece) // LINEAR_CHUNK)
+            self.stats["linear_scan_pad_chunks"] += chunks - -(-len(piece) // self._scan_chunk)
             if off == 0:
                 self.stats["linear_state_resets"] += 1
+            if self.cfg.n_expert_layers:
+                # what the piece offers its expert blocks; how many land on
+                # an expert held here is known only once the program has run
+                span_args["moe_assignments"] = (
+                    len(piece) * self.cfg.num_experts_per_tok * self.cfg.n_expert_layers)
         with self._sched.phase(
             "prefill", request_id=handle.request.request_id,
             prompt_tokens=len(piece), bucket=bucket, offset=off, **span_args,
@@ -3386,7 +3409,7 @@ class Engine:
             # int(row[i]) costs a separate device readback each (chunk x
             # slots round-trips per sweep; this line was the serving
             # bottleneck, not the decode math)
-            toks_h, lps_h, tids_h, tlps_h = (
+            toks_h, lps_h, tids_h, tlps_h, *routed = (
                 np.asarray(a) for a in jax.device_get(rec["ys"])
             )
         t_ready = ph.t_end
@@ -3400,6 +3423,7 @@ class Engine:
             self.stats["decode_steps"] += rec["chunk"]
             if rec["filters"]:
                 self.stats["sampler_filter_steps"] += rec["chunk"]
+            self._book_routed(routed, len(rec["active"]), rec["chunk"])
             overlapped = bool(self._inflight)  # device still computing N+1
             for step in range(toks_h.shape[0]):
                 for i in rec["active"]:
@@ -3445,6 +3469,19 @@ class Engine:
         self._bubble_anchor = (
             t_ready if (any_active and not self._inflight) else 0.0
         )
+
+    def _book_routed(self, routed: list, live: int, steps: int) -> None:
+        """A retired sweep's expert-block counts: ``routed`` holds the
+        decode program's [steps, 3] read-back (landed, touched, fullest,
+        over its ``live`` rows), or nothing for a model without such blocks."""
+        if not routed:
+            return
+        landed, touched, fullest = routed[0].sum(axis=0).tolist()
+        self.stats["moe_assignments"] += (
+            live * steps * self.cfg.num_experts_per_tok * self.cfg.n_expert_layers)
+        self.stats["moe_local_assignments"] += landed
+        self.stats["moe_expert_touches"] += touched
+        self.stats["moe_expert_load_max"] += fullest
 
     def _note_sweep(self, t_dispatch: float, t_ready: float) -> None:
         """Watchdog food (docs/RESILIENCE.md): a sweep read back IS scheduler
@@ -3544,7 +3581,7 @@ class Engine:
         self._tokens_dev = next_toks
         self._tokens_dev_slots = frozenset(active)
         with self._sched.phase("decode_retire") as ph:
-            toks_h, lps_h, tids_h, tlps_h = (
+            toks_h, lps_h, tids_h, tlps_h, *routed = (
                 # the constrained path is synchronous by design: the next
                 # mask depends on the byte just emitted  # kvmini: sync-ok
                 np.asarray(a) for a in jax.device_get(ys)
@@ -3556,6 +3593,7 @@ class Engine:
         self.stats["decode_steps"] += 1
         if self._sampler_filters:
             self.stats["sampler_filter_steps"] += 1
+        self._book_routed(routed, len(active), 1)
         with self._sched.phase("emit") as ph:
             for step in range(toks_h.shape[0]):
                 for i in active:

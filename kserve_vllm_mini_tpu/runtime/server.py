@@ -1378,6 +1378,14 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"{s['linear_state_held_slot_steps']}",
             "# TYPE kvmini_tpu_linear_state_resets_total counter",
             f"kvmini_tpu_linear_state_resets_total {s['linear_state_resets']}",
+            "# TYPE kvmini_tpu_moe_assignments_total counter",
+            f"kvmini_tpu_moe_assignments_total {s['moe_assignments']}",
+            "# TYPE kvmini_tpu_moe_local_assignments_total counter",
+            f"kvmini_tpu_moe_local_assignments_total {s['moe_local_assignments']}",
+            "# TYPE kvmini_tpu_moe_expert_touches_total counter",
+            f"kvmini_tpu_moe_expert_touches_total {s['moe_expert_touches']}",
+            "# TYPE kvmini_tpu_moe_expert_load_max_total counter",
+            f"kvmini_tpu_moe_expert_load_max_total {s['moe_expert_load_max']}",
             # decode-pipeline telemetry (docs/DECODE_PIPELINE.md): depth >= 2
             # + low bubble = the double-buffered steady state is engaged
             "# TYPE kvmini_tpu_dispatch_depth gauge",

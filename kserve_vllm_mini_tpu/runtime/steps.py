@@ -39,7 +39,7 @@ from kserve_vllm_mini_tpu.runtime.sampling import (
 
 
 def forward_kwargs(cfg: ModelConfig, kernels: str, *, table=None, lora=None,
-                   ids=None, length=None, live=None) -> dict[str, Any]:
+                   ids=None, length=None, live=None, stats=False) -> dict[str, Any]:
     """The keyword arguments every step hands ``forward``.
 
     ``table``: block-table rows of a paged pool (None = dense stripes).
@@ -48,9 +48,13 @@ def forward_kwargs(cfg: ModelConfig, kernels: str, *, table=None, lora=None,
     tokens, scalar) or ``live`` (a decode sweep's decoding rows, [S] bool):
     what a hybrid stack must know so that a bucket's padding and a parked
     slot's row leave the recurrent state alone — harmless in a K/V stripe,
-    so every other model is handed neither. A new kind of cache leaf or
-    ``forward`` argument is added here."""
+    so every other model is handed neither. ``stats``: the caller takes a
+    third result from ``forward`` where the model has expert blocks that
+    count their routing (``models/moe.py moe_latent_block``). A new kind of
+    cache leaf or ``forward`` argument is added here."""
     kw: dict[str, Any] = {"kernels": kernels}
+    if stats and cfg.n_expert_layers:
+        kw["with_stats"] = True
     if cfg.is_hybrid and length is not None:
         kw["valid_len"] = length[None]
     if cfg.is_hybrid and live is not None:
@@ -202,8 +206,10 @@ def build_decode(cfg: ModelConfig, fwd, kernels: str, n_steps: int = 1,
         # the sweep (its K/V write is harmless, as for any model)
         def one(c, toks, lens, r, cnt):
             kw = forward_kwargs(cfg, kernels, table=table, lora=lora,
-                                ids=ids, live=live)
-            logits, nc = fwd(
+                                ids=ids, live=live, stats=True)
+            # routed: the expert blocks' counts over the live rows, int32 [3]
+            # (nothing for a model without such blocks)
+            logits, nc, *routed = fwd(
                 params, cfg, toks[:, None], lens[:, None], c, lens, **kw,
             )
             lg = apply_penalties(logits[:, 0, :], cnt, pres, freqs)
@@ -213,7 +219,7 @@ def build_decode(cfg: ModelConfig, fwd, kernels: str, n_steps: int = 1,
                 lg_masked = jnp.where(allowed, lg, -jnp.inf)
                 lg = jnp.where(use_mask[:, None], lg_masked, lg)
             nxt = sample_tokens(lg, r, temps, topks, topps)
-            return nc, nxt, (nxt, *token_logprobs(lg, nxt))
+            return nc, nxt, (nxt, *token_logprobs(lg, nxt), *routed)
 
         if masked:
             nc, nxt, ys = one(cache, tokens, lengths, rng, counts)
@@ -238,7 +244,8 @@ def build_decode(cfg: ModelConfig, fwd, kernels: str, n_steps: int = 1,
         # toks is the final carry: the last sampled token per slot,
         # returned ON DEVICE so the next dispatch can feed it without a
         # host round-trip (the double-buffered pipeline's token path)
-        return c, cnt, toks, ys  # ys: ([n,S], [n,S], [n,S,K], [n,S,K])
+        # ys: ([n,S], [n,S], [n,S,K], [n,S,K]) and, with expert blocks, [n,3]
+        return c, cnt, toks, ys
 
     step.__name__ = "decode_masked" if masked else "decode"
     return jax.jit(step, donate_argnums=(1, 8))

@@ -169,27 +169,47 @@ def test_moe_identical_experts_equals_dense():
     )
 
 
-def test_moe_capacity_drop_is_graceful():
-    """A starved capacity factor must drop tokens (output changes) but stay
-    finite — the residual passes through for dropped assignments."""
-    cfg = get_config("mixtral-tiny")
-    p = init_params(jax.random.PRNGKey(0), cfg)
-    toks, pos = _tok_pos(cfg, 2, 32)
-    lg_ample, _ = forward(p, cfg.scaled(expert_capacity_factor=8.0), toks, pos)
-    lg_tight, _ = forward(p, cfg.scaled(expert_capacity_factor=0.25), toks, pos)
-    assert bool(jnp.isfinite(lg_tight).all())
-    assert not np.allclose(np.asarray(lg_ample), np.asarray(lg_tight), atol=1e-5)
+def _moe_layer(cfg, seed=0):
+    """One layer's MoE leaves of the preset, float32."""
+    p = init_params(jax.random.PRNGKey(seed), cfg.scaled(dtype="float32"))
+    return {k: p["layers"][k][0] for k in ("router", "w_gate", "w_up", "w_down")}
 
 
-def test_moe_ample_capacity_invariant():
-    """Raising an already-ample capacity must not change the result (no
-    token is ever dropped, so buffers only gain unused rows)."""
-    cfg = get_config("mixtral-tiny")
-    p = init_params(jax.random.PRNGKey(0), cfg)
-    toks, pos = _tok_pos(cfg, 2, 16)
-    a, _ = forward(p, cfg.scaled(expert_capacity_factor=4.0), toks, pos)
-    b, _ = forward(p, cfg.scaled(expert_capacity_factor=9.0), toks, pos)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+def _token_by_token(p, cfg, x):
+    """The layer as its equations read: each token through its chosen
+    experts alone, weighted by its renormalised gates."""
+    probs = jax.nn.softmax(x @ p["router"], axis=-1)
+    out = np.zeros(x.shape, np.float32)
+    for s in range(x.shape[0]):
+        top = np.argsort(-np.asarray(probs[s]))[: cfg.n_experts_per_tok]
+        gates = np.asarray(probs[s])[top] / np.asarray(probs[s])[top].sum()
+        for g, e in zip(gates, top):
+            h = jax.nn.silu(x[s] @ p["w_gate"][e]) * (x[s] @ p["w_up"][e])
+            out[s] += g * np.asarray(h @ p["w_down"][e])
+    return out
+
+
+@pytest.mark.parametrize("routing", ["random", "all_to_two_experts", "one_token"])
+def test_moe_drops_no_token_at_any_routing(routing):
+    """No capacity bounds an expert: with every token of the block sent to
+    the same two experts (a capacity-factor dispatch would keep the first
+    few and drop the rest) the layer is still each token through its own
+    chosen experts."""
+    from kserve_vllm_mini_tpu.models.moe import moe_mlp
+
+    cfg = get_config("mixtral-tiny").scaled(dtype="float32")
+    p = _moe_layer(cfg)
+    x = jnp.asarray(np.random.default_rng(0).normal(size=(1 if routing == "one_token" else 48,
+                                                          cfg.d_model)), jnp.float32)
+    if routing == "all_to_two_experts":
+        x = x + 2.0                   # a component every token shares, for the router to read
+        p["router"] = jnp.zeros((cfg.d_model, cfg.n_experts)).at[:, 1].set(0.05).at[:, 3].set(0.04)
+    got = moe_mlp(p, cfg, x[None])[0]
+    want = _token_by_token(p, cfg, x)
+    if routing == "all_to_two_experts":
+        chosen = np.argsort(-np.asarray(x @ p["router"]), axis=-1)[:, :2]
+        assert (np.sort(chosen, axis=-1) == [1, 3]).all()
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
 
 
 def test_moe_ep_sharded_matches_unsharded():

@@ -3,8 +3,9 @@ stack: checked in the programs the chip's own compiler builds, with no chip.
 
 ``tests/tpu_preflight_worker.py`` compiles the decode step and a fresh
 prefill piece for a TPU v5e by the README's pre-flight recipe (libtpu
-compiles for a described chip), at the benchmark's three configurations
-(published widths, two layers or one period of the hybrid). Asked of each
+compiles for a described chip), at the benchmark's four configurations
+(published widths, two layers, one period of the hybrid, or one block of
+each kind of the pattern stack with 8 experts held). Asked of each
 executable: ``weight_copy_bytes == 0`` (no per-layer slice of an integer
 weight stack is copied, i.e. transposed, before its matmul:
 profiling/compile_stats.py) and a Mosaic call is still compiled in.
@@ -31,9 +32,10 @@ import pytest
 
 from tests import env_guards
 
-MODELS = ("mistral-7b-int8", "qwen2-7b-int8", "olmo-hybrid-7b-int8")
+MODELS = ("mistral-7b-int8", "qwen2-7b-int8", "olmo-hybrid-7b-int8",
+          "nemotron-3-super-ep4-int8")
 CASES = [f"{m}:{s}" for m in MODELS for s in ("decode", "prefill")]
-# six compiles take ~100 s here; under a loaded suite several times that
+# eight compiles take ~130 s here; under a loaded suite several times that
 TIMEOUT_S = 900
 
 
