@@ -25,7 +25,9 @@ three legs, set-up seconds only, no performance claim.
   its published widths, two of its eight periods (6 linear-attention + 2
   full-attention layers, int8 weights, prompts prefilled in pieces of 256):
   its full layers' prefill and decode executables must hold their Mosaic
-  calls beside the linear layers' recurrent state. Then nemotron-3-super-120b
+  calls, and its decode step must update the linear layers' recurrent state
+  in place through ``kvmini_linear_decode`` (``state_copy_bytes`` 0, the
+  gauge 1, logits as the jnp recurrence's). Then nemotron-3-super-120b
   at its published widths as one of four chips' share, seven blocks
   (``MEM*EME``: Mamba-2, attention without rotary embedding, 128 of the 512
   latent experts): the same asks.
@@ -203,6 +205,22 @@ def require_weights_in_place(exes: dict[str, dict], dry: bool) -> None:
         say(f"  {lab}: weight_copy_bytes={n}")
         if not dry and (lab.startswith("decode[") or "prefill[" in lab):
             check(n == 0, f"{lab} copies {n} bytes of weight slices a layer")
+
+
+def require_state_in_place(engine, exes: dict[str, dict], dry: bool) -> None:
+    """``state_copy_bytes`` of every executable (profiling/compile_stats.py:
+    layers of a recurrent state sliced out of their stack, written back or
+    copied outside a Mosaic call). Where the engine says its decode program
+    took ``kvmini_linear_decode`` (the hybrid's gauge), that program holds
+    none; the Mamba-2 update of a pattern stack is jnp and reads its own."""
+    took = engine.snapshot_stats()["linear_state_kernel"]
+    say(f"  kvmini_tpu_linear_state_kernel={took}")
+    for lab in sorted(exes):
+        say(f"  {lab}: state_copy_bytes={exes[lab]['state_copy_bytes']}")
+    if not dry and engine.cfg.is_hybrid and not engine.cfg.is_pattern_stack:
+        check(took == 1, "the hybrid's decode program did not take kvmini_linear_decode")
+        n = exes["decode[chunk=1]"]["state_copy_bytes"]
+        check(n == 0, f"decode[chunk=1] moves {n} bytes of state layers outside the kernel")
 
 
 # -- model-level parity: kernel path vs jnp path on the engine's own weights ----
@@ -596,6 +614,7 @@ def leg_engines(dry: bool) -> dict:
             for prefix in need:
                 require_mosaic(exes, prefix, dry)
             require_weights_in_place(exes, dry)
+            require_state_in_place(server.engine, exes, dry)
             forward_parity(server.engine, name, dry=dry, **parity)
             hbm_line(f"engines:{name}")
             summary[name] = {"boot_s": round(server.boot_seconds, 1),

@@ -25,14 +25,24 @@ two pieces of its prefill.
 
 The state is float32 whatever the model's dtype: it is summed into for the
 whole life of a sequence.
+
+A decode step (``T == 1``) on a TPU takes ``kvmini_linear_decode``
+(ops/linear_decode.py): ``recurrent_step`` with a slot's heads held in VMEM,
+so the state is read once and written once, inside the cache's layer stack
+(``linear_decode_kernel_block`` is the gate). ``recurrent_step`` runs
+everywhere else and is the oracle.
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
+from kserve_vllm_mini_tpu.ops.flash_attention import pallas_interpret
+from kserve_vllm_mini_tpu.ops.linear_decode import linear_decode_block, linear_decode_update
 from kserve_vllm_mini_tpu.ops.quant import linear
 from kserve_vllm_mini_tpu.ops.rmsnorm import rms_norm
 
@@ -103,6 +113,19 @@ def recurrent_step(state, q, k, v, g, beta):
     u = beta[..., None] * (v - jnp.sum(s * k[..., None], axis=-2))
     o = jnp.sum(s * q[..., None], axis=-2) + u * jnp.sum(k * q, axis=-1, keepdims=True)
     return s + k[..., None] * u[..., None, :], o
+
+
+def linear_decode_kernel_block(kernels: str, lin_state: jnp.ndarray, t: int) -> Optional[int]:
+    """Heads a grid step of ``kvmini_linear_decode`` holds where a block of
+    ``t`` positions over the cache's state stack ``lin_state``
+    [L, B, H, dk, dv] takes the kernel, or None where it keeps
+    ``recurrent_step`` / ``chunked_scan``. THE gate, read from what the call
+    can see: the kernels mode and backend, one position a row, and the
+    stack's dtype and tile (``linear_decode_block``: float32, whole sublane
+    tiles, a head block that fits VMEM)."""
+    if t != 1 or pallas_interpret(kernels) is None:
+        return None
+    return linear_decode_block(*lin_state.shape[2:], lin_state.dtype)
 
 
 def recurrent_scan(state, q, k, v, g, beta):
@@ -202,14 +225,22 @@ def linear_mixer(
     p: dict,                      # one layer's mixer leaves
     cfg: ModelConfig,
     x: jnp.ndarray,               # [B, T, D]
-    state: jnp.ndarray,           # [B, H, dk, dv] float32
+    state: jnp.ndarray,           # [B, H, dk, dv] float32; with ``layer``,
+                                  # the cache's whole stack [L, B, H, dk, dv]
     tail: jnp.ndarray,            # [B, K-1, C]
     valid: jnp.ndarray,           # [B] int32: leading positions that are real
+    kernels: str = "auto",        # Pallas kernel selection, as ``forward``'s
+    layer: Optional[jnp.ndarray] = None,  # scalar int32: ``state`` is the
+                                  # stack and this layer of it is updated in
+                                  # place by the decode kernel; the caller
+                                  # asked ``linear_decode_kernel_block``
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The mixer over one block -> (y [B,T,D], new state, new tail).
     ``T == 1`` takes the one-token recurrence, anything longer the
     chunk-parallel form. Rows of the batch with ``valid == 0`` come back
-    with state and tail untouched, bit for bit."""
+    with state and tail untouched, bit for bit. With ``layer`` the state
+    goes in and comes back as the whole stack: slicing a layer out for the
+    kernel and writing it back would cost the two passes the kernel saves."""
     b, t, _ = x.shape
     h, dk, dv = cfg.linear_num_key_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
     qm = cfg.quant_mode
@@ -228,12 +259,18 @@ def linear_mixer(
         xf @ p["wa"].astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
     real = (jnp.arange(t)[None, :] < valid[:, None])[..., None]    # [B, T, 1]
     beta, g = jnp.where(real, beta, 0.0), jnp.where(real, g, 0.0)
-    if t == 1:
-        s, o = recurrent_step(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
-        o = o[:, None]
-    else:
+    if t > 1:
         s, o = chunked_scan(state, q, k, v, g, beta)
-    new_state = jnp.where((valid > 0)[:, None, None, None], s, state)
+    else:
+        one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+        if layer is None:
+            s, o = recurrent_step(state, *one)
+        else:       # the kernel holds the rows with valid == 0 itself
+            new_state, o = linear_decode_update(
+                state, layer, *one, valid, interpret=pallas_interpret(kernels))
+        o = o[:, None]
+    if layer is None:
+        new_state = jnp.where((valid > 0)[:, None, None, None], s, state)
     o = rms_norm(o, p["o_norm"], cfg.rms_eps)
     gate = jax.nn.silu(linear(x, p["wg"], mode=qm).astype(jnp.float32))
     gated = (o.reshape(b, t, h * dv) * gate).astype(x.dtype)

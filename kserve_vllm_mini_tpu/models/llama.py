@@ -1082,9 +1082,14 @@ def run_cached_layers(
         # weights out of the stack every iteration, which doubles the
         # weight stream of a decode step. Both kinds of state ride the
         # carry (invariant 1 above holds for them too).
-        from kserve_vllm_mini_tpu.models.linear_attention import linear_mixer
+        from kserve_vllm_mini_tpu.models.linear_attention import (
+            linear_decode_kernel_block,
+            linear_mixer,
+        )
 
         n_lin = cfg.layer_period - 1
+        in_place = not fresh_prefill and linear_decode_kernel_block(
+            kernels, kv_cache["lin_state"], T) is not None
         valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
                  else valid_len.astype(jnp.int32))
         lin_layers = layers["linear"]
@@ -1093,14 +1098,19 @@ def run_cached_layers(
             p = jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, lidx, 0, keepdims=False),
                 lin_layers)
-            state = jax.lax.dynamic_index_in_dim(cache["lin_state"], lidx, 0, keepdims=False)
+            # a decode step on a TPU hands the mixer the whole stack: the
+            # kernel updates this layer's state inside it (no slice out, no
+            # write back); in_place is never a fresh prefill
+            state, layer = (cache["lin_state"], lidx) if in_place else (
+                jax.lax.dynamic_index_in_dim(cache["lin_state"], lidx, 0, keepdims=False), None)
             tail = jax.lax.dynamic_index_in_dim(cache["lin_conv"], lidx, 0, keepdims=False)
             if fresh_prefill:
                 # a new sequence starts from S_0 = 0 whatever the slot held
                 state, tail = jnp.zeros_like(state), jnp.zeros_like(tail)
-            out, state, tail = linear_mixer(p, cfg, y0, state, tail, valid)
+            out, state, tail = linear_mixer(
+                p, cfg, y0, state, tail, valid, kernels, layer=layer)
             cache = dict(cache)
-            cache["lin_state"] = jax.lax.dynamic_update_index_in_dim(
+            cache["lin_state"] = state if in_place else jax.lax.dynamic_update_index_in_dim(
                 cache["lin_state"], state, lidx, 0)
             cache["lin_conv"] = jax.lax.dynamic_update_index_in_dim(
                 cache["lin_conv"], tail, lidx, 0)

@@ -23,6 +23,7 @@ set-up time, never a device metric (docs/PROFILING.md).
 
 from __future__ import annotations
 
+import math
 import re
 import threading
 import time
@@ -40,6 +41,16 @@ _TOP_OPS = 16  # histogram cap: top-N opcodes, remainder folded into "other"
 # activations are rank 4-5 or not integers; scales and norms are floats.
 _WEIGHT_COPY = re.compile(r" = (s8|u8|s4)\[1,(\d+),(\d+)\]\S* copy\(")
 _WEIGHT_COPY_MIN_BYTES = 64 * 1024  # below this it is no projection's weight
+# `%cache__lin_state__.1 = f32[24,16,30,96,192]{4,3,2,1,0:T(8,128)} parameter(47)`
+# in the entry computation: a float32 leaf of rank 5 is a recurrent state's
+# layer stack (K/V stacks are bf16 or int8, their scales rank 4). What is then
+# looked for is that stack, or one layer of it (`f32[1,16,30,96,192]`), as the
+# result of a copy, a slice (static where the walk is unrolled, dynamic in a
+# loop, `slice-done` where it runs asynchronously) or a dynamic-update-slice,
+# fused or not.
+_F32_RANK5 = re.compile(
+    r" = f32\[(\d+),(\d+),(\d+),(\d+),(\d+)\]\S* "
+    r"(parameter|copy|slice|slice-done|dynamic-slice|dynamic-update-slice)\(")
 
 
 @dataclass
@@ -65,6 +76,11 @@ class CompileStats:
     # written and read again every step); 0 = every weight is multiplied
     # where it lies in its stack
     weight_copy_bytes: int = 0
+    # bytes of a recurrent state's layers the program slices out of their
+    # float32 stack, writes back into it or copies, outside a Mosaic call:
+    # each is a pass over a layer's state beyond the kernel's read and
+    # write; 0 = the state is updated where it lies, by a kernel or not at all
+    state_copy_bytes: int = 0
 
     def to_dict(self) -> dict[str, Any]:
         return {
@@ -80,6 +96,7 @@ class CompileStats:
             "hlo_ops": dict(self.hlo_ops),
             "mosaic_calls": self.mosaic_calls,
             "weight_copy_bytes": self.weight_copy_bytes,
+            "state_copy_bytes": self.state_copy_bytes,
         }
 
 
@@ -121,6 +138,32 @@ def weight_copy_bytes(hlo_text: str) -> int:
     return total
 
 
+def state_copy_bytes(hlo_text: str) -> int:
+    """Summed bytes of the layers of a recurrent-state stack that an
+    optimized HLO module slices out (``slice``, ``dynamic-slice``), writes back
+    (``dynamic-update-slice``: one layer counted) or copies (``copy``: its
+    result) outside a Mosaic call. The stacks are the entry computation's
+    float32 parameters of rank 5 (``lin_state``, ``ssm_state``). Each
+    instruction counts once, a loop body's and a fusion's too, like
+    ``weight_copy_bytes``: the number says which state is moved an extra
+    time, not how often. The jnp update of models/linear_attention.py
+    reads 9 x one layer over a period's three linear layers (each layer's
+    two fusions slice it out, one writes it back); ``kvmini_linear_decode``
+    (ops/linear_decode.py) updates the stack in place and reads 0."""
+    found = [(tuple(int(d) for d in m.groups()[:5]), m.group(6), m.start())
+             for m in _F32_RANK5.finditer(hlo_text)]
+    entry = hlo_text.find("\nENTRY ")
+    stacks = {dims for dims, op, at in found if op == "parameter" and 0 <= entry < at}
+    total = 0
+    for dims, op, _ in found:
+        if op == "parameter" or not any(
+                dims[1:] == st[1:] and dims[0] in (1, st[0]) for st in stacks):
+            continue
+        layer = 4 * math.prod(dims[1:])
+        total += layer * dims[0] if op == "copy" else layer
+    return total
+
+
 def extract_compile_stats(
     compiled: Any, compile_wall_s: float, label: str = ""
 ) -> CompileStats:
@@ -149,6 +192,7 @@ def extract_compile_stats(
         hlo_ops=hlo_op_histogram(hlo_text),
         mosaic_calls=hlo_text.count('custom_call_target="tpu_custom_call"'),
         weight_copy_bytes=weight_copy_bytes(hlo_text),
+        state_copy_bytes=state_copy_bytes(hlo_text),
     )
 
 

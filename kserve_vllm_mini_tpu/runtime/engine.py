@@ -35,6 +35,7 @@ import numpy as np
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
 from kserve_vllm_mini_tpu.models.linear_attention import CHUNK as LINEAR_CHUNK
+from kserve_vllm_mini_tpu.models.linear_attention import linear_decode_kernel_block
 from kserve_vllm_mini_tpu.models.llama import dense_decode_kernel_block, forward
 from kserve_vllm_mini_tpu.profiling.compile_stats import (
     CompileRecorder,
@@ -420,6 +421,10 @@ class Engine:
         # it (models/llama.py dense_decode_kernel_block), resolved in
         # start(): what _count_decode_kv_blocks counts by
         self._dense_decode_blk: Optional[int] = None
+        # whether decode steps update a hybrid's delta-rule state through
+        # kvmini_linear_decode (models/linear_attention.py
+        # linear_decode_kernel_block), resolved in start(): the gauge
+        self._linear_state_kernel = False
         # model executor: plain forward, or the pp-sharded drop-in when the
         # mesh pipelines layers (parallel/serving_pp.py — same signature, so
         # every compiled step below is executor-agnostic)
@@ -2071,6 +2076,9 @@ class Engine:
         self._dense_decode_blk = dense_decode_kernel_block(
             self.cfg, self._kernels, self._cache, self.paged
         )
+        self._linear_state_kernel = "lin_state" in self._cache and (
+            linear_decode_kernel_block(self._kernels, self._cache["lin_state"], 1)
+            is not None)
         if self._disagg is not None:
             self._disagg.start()
         self._thread = threading.Thread(target=self._loop, daemon=True, name="engine-loop")
@@ -4127,6 +4135,7 @@ class Engine:
         s["kv_prefix_hit_depth_p95"] = kv.get("kv_prefix_hit_depth_p95", 0)
         s["kv_bytes_per_token"] = self.kv_bytes_per_token()
         s["cache_bytes"] = dict(self._cache_bytes)
+        s["linear_state_kernel"] = int(self._linear_state_kernel)
         # physical bytes the reused prompt tokens did NOT re-write — the
         # byte-denominated view of prefix_tokens_reused_total
         s["kv_reused_bytes"] = s["prefix_tokens_reused"] * s["kv_bytes_per_token"]

@@ -1378,6 +1378,10 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"{s['linear_state_held_slot_steps']}",
             "# TYPE kvmini_tpu_linear_state_resets_total counter",
             f"kvmini_tpu_linear_state_resets_total {s['linear_state_resets']}",
+            # 1 where the decode program updates that state in place through
+            # kvmini_linear_decode, 0 where it runs the jnp recurrence
+            "# TYPE kvmini_tpu_linear_state_kernel gauge",
+            f"kvmini_tpu_linear_state_kernel {s['linear_state_kernel']}",
             "# TYPE kvmini_tpu_moe_assignments_total counter",
             f"kvmini_tpu_moe_assignments_total {s['moe_assignments']}",
             "# TYPE kvmini_tpu_moe_local_assignments_total counter",

@@ -80,6 +80,34 @@ def test_engine_streams_are_the_models_own_under_batching_and_slot_reuse(ecfg):
     assert s["cache_bytes"]["conv_state"] > 0 and s["cache_bytes"]["kv"] > 0
 
 
+@pytest.mark.parametrize("ecfg", [{}, {"prefill_chunk": 32}, {"decode_chunk": 4}],
+                         ids=["plain", "pieces32", "fused4"])
+def test_engine_streams_with_the_state_updated_by_the_decode_kernel(ecfg):
+    """The same six requests with the engine's decode steps through
+    ``kvmini_linear_decode`` (interpreted: what a TPU backend makes of the
+    engine's "auto"; nothing in the program picks the interpreter). The state
+    is updated inside the cache's stack, parked and mid-prefill rows held by
+    the kernel's select: every stream is still the cache-free forward's, and
+    the gauge says which program ran."""
+    params, eng = make_engine(**ecfg)
+    eng._kernels = "interpret"
+    eng.start()
+    rng = np.random.default_rng(0)
+    prompts = [[int(t) for t in rng.integers(3, 500, n)] for n in (70, 23, 100, 45, 17, 90)]
+    try:
+        outs = run_requests(eng, prompts)
+    finally:
+        eng.stop()
+    assert all(o == greedy(params, p + o, len(p)) for p, o in zip(prompts, outs))
+    s = eng.snapshot_stats()
+    assert s["linear_state_kernel"] == 1
+    assert 0 < s["linear_state_held_slot_steps"] < s["linear_state_slot_steps"]
+    _, eager = make_engine(**ecfg)
+    eager.start()
+    eager.stop()
+    assert eager.snapshot_stats()["linear_state_kernel"] == 0     # "auto" on a CPU
+
+
 def test_a_parked_or_mid_prefill_slot_keeps_its_state_through_neighbours_sweeps():
     params, eng = make_engine(prefill_chunk=32)
     from kserve_vllm_mini_tpu.runtime.tracing import SchedLedger

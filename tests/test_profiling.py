@@ -14,6 +14,7 @@ from kserve_vllm_mini_tpu.profiling.compile_stats import (
     capture_compile_stats,
     extract_compile_stats,
     hlo_op_histogram,
+    state_copy_bytes,
     weight_copy_bytes,
 )
 from kserve_vllm_mini_tpu.profiling.devices import device_facts
@@ -41,7 +42,7 @@ def test_capture_pins_stat_keys():
     for key in ("label", "compile_wall_s", "flops", "bytes_accessed",
                 "peak_bytes", "argument_bytes", "output_bytes",
                 "temp_bytes", "generated_code_bytes", "hlo_ops",
-                "mosaic_calls", "weight_copy_bytes"):
+                "mosaic_calls", "weight_copy_bytes", "state_copy_bytes"):
         assert key in d, key
     assert d["label"] == "t"
     assert d["compile_wall_s"] > 0
@@ -122,6 +123,38 @@ _WV = "  %copy.73 = s8[1,4096,1024]{1,2,0:T(8,128)(4,1)S(1)} copy(%constant_dyna
 def test_weight_copy_bytes_counts_integer_weight_slices(lines, want):
     text = "\n".join(["HloModule jit_decode, is_scheduled=true", *lines, "}"])
     assert weight_copy_bytes(text) == want
+
+
+_STATE = "f32[3,16,30,96,192]{4,3,2,1,0:T(8,128)}"
+_LAYER = "f32[1,16,30,96,192]{4,3,2,1,0:T(8,128)S(1)}"
+_ENTRY = ["ENTRY %main.1 (cache__lin_state__.1: f32[3,16,30,96,192]) -> f32[3,16,30,96,192] {",
+          f"  %cache__lin_state__.1 = {_STATE} parameter(47)"]
+_ONE = 16 * 30 * 96 * 192 * 4
+
+
+@pytest.mark.parametrize("body,entry,want", [
+    ([f"  ROOT %dynamic_update_slice.26 = {_STATE} dynamic-update-slice(%p, %s, %c, %c)"], [], _ONE),
+    ([f"  %dynamic-slice.3 = {_LAYER} dynamic-slice(%p, %i, %c, %c, %c, %c)",
+      f"  %slice.2 = {_LAYER} slice(%p), slice={{[1:2], [0:16]}}"], [], 2 * _ONE),
+    ([], [f"  %slice-done = {_LAYER} slice-done(%slice-start)",
+          f"  %copy.9 = {_STATE} copy(%cache__lin_state__.1)"], 4 * _ONE),
+    ([], [f"  %k.9 = ({_STATE}, f32[16,30,192]{{2,1,0}}) custom-call(%c, %v, %cache__lin_state__.1), "
+          'custom_call_target="tpu_custom_call"',
+          f"  %g.1 = {_STATE} get-tuple-element(%k.9), index=0"], 0),
+    ([f"  %param_0.1 = f32[1,30,16,64,96]{{4,3,2,1,0}} parameter(0)",
+      f"  %copy.4 = f32[1,30,16,64,96]{{4,3,2,1,0}} copy(%param_0.1)",
+      f"  %dus.1 = bf16[3,16,30,2048,128]{{4,3,2,1,0}} dynamic-update-slice(%kv, %u, %c)"], [], 0),
+], ids=["write-back", "slices-out", "async-slice+whole-copy", "kernel-in-place", "not-a-state"])
+def test_state_copy_bytes_counts_layers_of_a_float32_rank5_parameter(body, entry, want):
+    """The stack is known by the entry computation's parameter; a fusion's
+    own rank-5 float32 parameter (a prefill's chunk blocks) and a K/V stack
+    are not states."""
+    text = "\n".join(["HloModule jit_decode, is_scheduled=true",
+                      "%fused_computation.38 (param_0.123: f32[3,16,30,96,192]) -> f32[3,16,30,96,192] {",
+                      f"  %param_0.123 = {_STATE} parameter(0)", *body, "}", "",
+                      *_ENTRY, *entry, "}"])
+    assert state_copy_bytes(text) == want
+    assert state_copy_bytes(text.replace("ENTRY ", "")) == 0       # no entry, no stack
 
 
 # -- InstrumentedJit ----------------------------------------------------------

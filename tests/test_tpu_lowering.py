@@ -136,6 +136,22 @@ def test_cached_prefill_lowers(d, quantized):
     )
 
 
+@pytest.mark.parametrize("stack", [(24, 16, 30, 96, 192), (6, 4, 4, 24, 48)],
+                         ids=["published", "tiny"])
+def test_linear_decode_lowers(stack):
+    """The hybrid's state stack at the benchmark's size (15 of 30 heads a
+    grid step) and the tiny preset's: the aliased stack, the SMEM scalars,
+    the transposed k | q columns and the per-slot v / output rows."""
+    from kserve_vllm_mini_tpu.ops.linear_decode import linear_decode_update
+
+    _, b, h, dk, dv = stack
+    _lowers_for_tpu(
+        linear_decode_update, _sds(stack, F32), _sds((), I32),
+        _sds((b, h, dk), F32), _sds((b, h, dk), F32), _sds((b, h, dv), F32),
+        _sds((b, h), F32), _sds((b, h), F32), _sds((b,), I32),
+    )
+
+
 # -- which decode steps take the dense kernel ----------------------------------
 
 

@@ -17,6 +17,11 @@ hybrid 14,745,600). A model of d 512 with 4 / 2 heads of 128 shows the same
 three copies in a quarter of the compile time, should this file ever cost
 the suite too much.
 
+The hybrid's decode step also holds ``state_copy_bytes == 0``: its delta
+rule's state is updated inside the cache's layer stack by
+``kvmini_linear_decode`` (ops/linear_decode.py), one call a linear layer of
+the period beside the one ``kvmini_dense_decode``.
+
 One file, one subprocess: libtpu is loaded in the child alone (``--dist
 loadfile`` keeps this file in one worker), and the child is held to a
 timeout, so nothing here can hang the suite. Skips, with the reason, where
@@ -65,3 +70,22 @@ def test_projections_read_their_weights_in_place(compiled, case):
         f"{case}: {stats['weight_copy_bytes']} bytes of per-layer integer "
         "weight slices are copied before their matmul")
     assert stats["mosaic_calls"] >= 1, f"{case}: no Pallas kernel compiled in"
+
+
+def test_the_hybrids_decode_step_updates_its_state_in_place(compiled):
+    """No layer of the float32 state stack is sliced out, written back or
+    copied around the kernel's calls, and the calls are there (the parent's
+    step held ``kvmini_dense_decode`` alone: ``mosaic_calls`` 1, and read
+    318,504,960 bytes here: per linear layer two fused slices and a
+    write-back of 35,389,440). With the call handed the layer's slice
+    (``dynamic_index_in_dim``, the call, ``dynamic_update_index_in_dim``)
+    this case read ``mosaic_calls`` 4 and ``state_copy_bytes`` 212,336,640:
+    three slices out and three write-backs, a copy each way around an opaque
+    call, four passes over the state where the jnp path makes three."""
+    stats = compiled["olmo-hybrid-7b-int8:decode"]
+    assert stats["state_copy_bytes"] == 0, (
+        f"{stats['state_copy_bytes']} bytes of the recurrent state's layers are "
+        "sliced out of their stack or written back outside the kernel")
+    assert stats["mosaic_calls"] >= 4, (
+        f"mosaic_calls {stats['mosaic_calls']}: the period's three linear "
+        "layers do not each hold kvmini_linear_decode")

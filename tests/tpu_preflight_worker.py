@@ -13,7 +13,8 @@ their published widths and two layers (the hybrid: one period of four; the
 pattern stack: one block of each kind, ``M*E``, with 8 of its experts held).
 
 Prints one JSON line: ``{"skip": reason}`` where libtpu or the topology cannot
-be had, else ``{"cases": {case: CompileStats.to_dict()}}``. ``--dump DIR``
+be had, else ``{"cases": {case: CompileStats.to_dict()}}`` (``mosaic_calls``,
+``weight_copy_bytes``, ``state_copy_bytes`` among its keys). ``--dump DIR``
 also writes each case's optimized HLO there.
 """
 
