@@ -75,14 +75,24 @@ def limits_for(config_name: str) -> dict:
     return json.loads((LIMITS / f"{config_name}.json").read_text())["limits"]
 
 
+def beside(nums: dict, limits: dict) -> dict:
+    """Each limited number beside its limit: ``{name: {"value", "limit"}}``."""
+    return {name: {"value": nums[name], "limit": lim} for name, lim in limits.items()}
+
+
+def holds(c: dict) -> bool:
+    """One compared number: at most its ``limit``, or at least its ``limit_at_least``."""
+    return c["value"] >= c["limit_at_least"] if "limit_at_least" in c else c["value"] <= c["limit"]
+
+
+def lines_of(compared: dict) -> list[str]:
+    """The one rendering of compared numbers: ``check <name>=<value> limit=<limit> ok|OVER``."""
+    return [f"check {name}={c['value']} "
+            + " ".join(f"{k}={v}" for k, v in c.items() if k != "value")
+            + (" ok" if holds(c) else " OVER") for name, c in compared.items()]
+
+
 def within(nums: dict, limits: dict) -> tuple[bool, list[str]]:
     """Every number beside its limit, and whether all hold."""
-    lines, ok = [], True
-    for name, lim in limits.items():
-        good = nums[name] <= lim
-        ok = ok and good
-        lines.append(f"check {name}={nums[name]:.6g} limit={lim:.6g} "
-                     f"{'ok' if good else 'OVER'}")
-    lines.append(f"info differ_share={nums['differ_share']:.6g} (share of positions where the "
-                 "served token is not the reference's best; no limit)")
-    return ok, lines
+    compared = beside(nums, limits)
+    return all(map(holds, compared.values())), lines_of(compared)

@@ -8,10 +8,13 @@ step through the load generator (``bench/client.py``, a process of its own),
 offers the cell's traffic for ``--seconds`` seconds after a short lead-in,
 reads the counters, frees the engine, decides ``correct`` against the plain
 reference (``bench/check.py``) and prints one JSON object as its last line:
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, with
-``--trace 1``, ``breakdown``. ``--trace 0`` reports the cell's end-to-end
-metrics, ``--trace 1`` its per-layer metrics from a profiler trace of about
-three seconds in the middle of the window.
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
+``--trace 1`` ``breakdown``, and last ``checks``: every number ``correct``
+compared, beside its limit (``bench/check.py lines_of`` renders the same as
+the last lines of stderr; ``correct`` is that every one of them holds).
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics from a profiler trace of about three seconds in the middle
+of the window.
 
 With no TPU, or fewer chips than the cell asks for, it exits with code 2 and
 prints nothing to stdout; it never carries on on the CPU. ``--rehearse``
@@ -135,22 +138,24 @@ def collect(specs: list[dict], ctx: Context) -> dict:
 
 
 def decide_correct(args, cell, records, plan, tokenizer, guards, window_compiles):
-    """``correct`` and every number compared beside its limit: the sampled
-    requests against the plain reference, exact token counts of every
-    completed request, and the guards on compiles and kernels."""
+    """Every number ``correct`` compares, beside its limit, under a short name
+    (``bench/check.py holds`` judges each, ``lines_of`` prints them): the sampled
+    requests against the plain reference, exact token counts of every completed
+    request, and the guards on compiles and kernels. With it, lines that say
+    what was sampled and which request or executable was at fault."""
     from bench import check
 
     system = bench_config.load_module("systems", cell["config"]["model"].get("system", "engine"))
-    model, lines, correct = cell["config"]["model"], [], True
+    model, info, compared, miscounted = cell["config"]["model"], [], {}, 0
     for r in records:
         if metrics.completed(r):
             n_out = len(system.served_token_ids(r["text"]))
             usage = r["usage"] or {}
             if (n_out != r["max_tokens"] or usage.get("completion_tokens") != r["max_tokens"]
                     or usage.get("prompt_tokens") != r["prompt_tokens"]):
-                correct = False
-                lines.append(f"check request {r['id']}: served {n_out} tokens "
-                             f"(usage {usage}), asked {r['prompt_tokens']}+{r['max_tokens']}")
+                miscounted += 1
+                info.append(f"info request {r['id']}: served {n_out} tokens "
+                            f"(usage {usage}), asked {r['prompt_tokens']}+{r['max_tokens']}")
     picked = check.sample(records, args.seed, cell["traffic"]["check"])
     t_ref = time.monotonic()
     if picked:
@@ -162,20 +167,19 @@ def decide_correct(args, cell, records, plan, tokenizer, guards, window_compiles
             first.append(len(prompt))
         nums, positions = check.served_numbers(args.seed, model, seqs, first,
                                                cell["traffic"]["check"])
-        ok, num_lines = check.within(nums, check.limits_for(cell["config"]["name"]))
-        correct = correct and ok
-        lines += num_lines
-        lines.append(f"check sampled requests={len(picked)} positions={positions} "
-                     f"longest={len(seqs[0])} reference_s={time.monotonic() - t_ref:.2f}")
-    else:
-        correct = False
-        lines.append("check: the window finished no request to compare")
+        compared.update(check.beside(nums, check.limits_for(cell["config"]["name"])))
+        info.append(f"info sampled requests={len(picked)} positions={positions} "
+                    f"longest={len(seqs[0])} reference_s={time.monotonic() - t_ref:.2f} "
+                    f"differ_share={nums['differ_share']:.6g} (positions where the served "
+                    "token is not the reference's best; no limit)")
+    compared["sampled_requests"] = {"value": len(picked), "limit_at_least": 1}
+    compared["miscounted_requests"] = {"value": miscounted, "limit": 0}
     for what, bad in guards:
-        lines.append(f"check {what}={bad} limit=[] {'ok' if not bad else 'OVER'}")
-        correct = correct and not bad
-    lines.append(f"check backend compiles inside the window={window_compiles} limit=0 "
-                 f"{'ok' if window_compiles == 0 else 'OVER'}")
-    return correct and window_compiles == 0, lines
+        compared[what.replace(" ", "_")] = {"value": len(bad), "limit": 0}
+        if bad:
+            info.append(f"info {what}: {bad}")
+    compared["backend_compiles_inside_the_window"] = {"value": window_compiles, "limit": 0}
+    return all(map(check.holds, compared.values())), compared, info
 
 
 def run_cell(args):
@@ -313,12 +317,12 @@ def run_cell(args):
     counters = {k: after[k] - before.get(k, 0.0) for k in after}
 
     # -- correct: the served tokens against the plain reference, after the engine is freed
-    correct, lines = decide_correct(
+    correct, compared, info = decide_correct(
         args, cell, records, plan, tokenizer,
         guards=(("compiled inside the window", compiled_in_window),
                 ("prefill executables without a Mosaic call", no_mosaic)),
         window_compiles=window_compiles)
-    for line in lines:
+    for line in info:
         say(line)
     in_window = metrics.due_in_window(records, window_s)
 
@@ -355,6 +359,8 @@ def run_cell(args):
     if trace is not None:
         device["busy_s"], device["window_s"] = trace["busy_s"], trace["window_s"]
         result["breakdown"] = trace["breakdown"]
+    # every number compared beside its limit: last in the line, and (main) last on stderr
+    result["checks"] = compared
     (out_dir / f"result.trace{args.trace}.json").write_text(json.dumps(result))
     return result
 
@@ -371,6 +377,11 @@ def main(argv=None) -> int:
             + json.dumps(result["metrics"]))
         result.update(correct=False, metrics={})
     say(json.dumps(result))
+    from bench import check
+
+    for line in check.lines_of(result["checks"]):
+        note(line)
+    note(f"correct={result['correct']}")
     return 0
 
 

@@ -44,8 +44,14 @@ def served_token_ids(text: str) -> list[int]:
 def model_config(m: dict):
     from kserve_vllm_mini_tpu.models.config import ModelConfig
 
-    if m["hidden_size"] // m["num_attention_heads"] != m["head_dim"]:
-        raise ValueError("head_dim must be hidden_size / num_attention_heads")
+    if "head_dim" not in m:
+        raise KeyError("the configuration's file gives no head_dim: write the head's width "
+                       "there (and under `assumed` where the source leaves it out)")
+    # the head's width is the file's; where it is not hidden_size / num_attention_heads
+    # (attention narrower or wider than the residual stream) the program is told so
+    extra = dict(m.get("model_extra", {}))
+    if m["head_dim"] != m["hidden_size"] // m["num_attention_heads"]:
+        extra["explicit_head_dim"] = m["head_dim"]
     return ModelConfig(
         name=m["name"], vocab_size=m["vocab_size"], d_model=m["hidden_size"],
         n_layers=m["num_hidden_layers"], n_heads=m["num_attention_heads"],
@@ -56,7 +62,7 @@ def model_config(m: dict):
         sliding_window=m.get("sliding_window"),
         attn_bias=bool(m.get("attention_bias")),
         # what only the program names (a block type, expert counts): by field name
-        **m.get("model_extra", {}),
+        **extra,
     )
 
 

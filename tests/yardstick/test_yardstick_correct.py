@@ -273,7 +273,17 @@ def test_last_line_and_no_chip():
                           timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
     last = json.loads(done.stdout.strip().splitlines()[-1])
-    assert set(last) == {"correct", "attempted", "failed", "metrics", "device", "breakdown"}
+    assert list(last) == ["correct", "attempted", "failed", "metrics", "device", "breakdown",
+                          "checks"]
+    # every number compared beside its limit: last in the line, and again last on stderr
+    assert {"gap_mean", "gap_max", "backend_compiles_inside_the_window"} <= set(last["checks"])
+    assert all("value" in c and len(c) == 2 for c in last["checks"].values())
+    tail = done.stderr.strip().splitlines()[-len(last["checks"]) - 1:]
+    assert tail[-1] == "correct=False" and all(line.startswith("check ") for line in tail[:-1])
+    assert any(line.startswith("check gap_mean=") and "limit=" in line for line in tail)
+    # one rendering (bench/check.py lines_of): each says whether its number holds
+    assert tail[:-1] == check.lines_of(last["checks"])
+    assert all(line.endswith((" ok", " OVER")) for line in tail[:-1])
     assert last["correct"] is False and last["metrics"] == {}
     assert last["device"]["platform"] == "cpu" and last["attempted"] > 0
     assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}

@@ -61,7 +61,10 @@ def test_cell_loads_and_reports(cell):
     names = [m["name"] for m in loaded["end_to_end"]]
     assert "setup_s" in names and len(names) >= 2 and loaded["per_layer"]
     model = loaded["config"]["model"]
-    assert model["head_dim"] * model["num_attention_heads"] == model["hidden_size"]
+    # what any decoder's attention needs; the heads' width together need not be the hidden size
+    for key in ("head_dim", "num_attention_heads", "num_key_value_heads", "hidden_size"):
+        assert isinstance(model[key], int) and not isinstance(model[key], bool) and model[key] > 0
+    assert model["num_attention_heads"] % model["num_key_value_heads"] == 0
     longest = (loaded["traffic"]["prompt_tokens"]["max"]
                + loaded["traffic"]["output_tokens"]["max"])
     assert longest < loaded["config"]["engine"]["max_seq_len"]
@@ -75,8 +78,61 @@ def test_config_entry(cfg):
     raw = json.loads((ROOT / cfg["file"]).read_text())
     assert raw["source"] == cfg["source"] and set(cfg["reduced"]) == set(raw["reduced"])
     for key in cfg["reduced"]:
-        assert not re.search(r"(_dim|_rank|hidden|intermediate|head|experts_per)", key)
+        assert NAME.match(key) and COUNT.search(key), key
+    assert states_the_depth_it_was_cut_from(raw)
     assert any(w["config"] == cfg["name"] for w in BM["workloads"])
+
+
+# ``reduced`` may name only what is COUNTED and held here: layers (a depth, a layer pattern),
+# heads, routed experts, vocabulary rows, positions. One pattern admits those and every other
+# key is refused, so a width can never be listed: a hidden, intermediate, latent, state or
+# projection size, a key that ends in ``_dim`` or ``_rank``, a head size, a window, an
+# expansion factor, the number of experts a token takes (shared experts among them: every
+# token runs each), nor anything that is no size at all.
+COUNT = re.compile(r"^num_hidden_layers$|^n_layers?$|pattern$|^layer_types$|^layers_block_type$"
+                   r"|^(\w+_)?(n|num)_(?!shared_)(\w+_)?(heads|experts)$"
+                   r"|^vocab_size$|^max_position_embeddings$")
+REFUSED = ["hidden_size", "head_dim", "intermediate_size", "moe_intermediate_size",
+           "moe_shared_expert_intermediate_size", "shared_expert_intermediate_size",
+           "mamba_d_state", "mamba_d_ssm", "mamba_head_dim", "mamba_expand", "expand",
+           "ssm_state_size", "moe_latent_size", "sliding_window", "window_size",
+           "num_experts_per_tok", "moe_num_experts_per_tok", "kv_lora_rank", "q_lora_rank",
+           "qk_rope_head_dim", "v_head_dim", "linear_key_head_dim", "linear_value_head_dim",
+           "linear_conv_kernel_dim", "d_head", "d_state", "d_ssm", "d_model", "d_ff",
+           "attention_head_size",
+           # what the parent's rule refused and a list of widths alone would let through
+           "ffn_hidden_size", "headdim", "d_inner", "kv_channels", "n_embd", "n_inner",
+           "mamba_d_conv", "n_shared_experts", "num_shared_experts",
+           # no size at all
+           "rope_theta", "rms_norm_eps", "torch_dtype", "tie_word_embeddings", "hidden_act"]
+ADMITTED = ["num_hidden_layers", "n_layer", "hybrid_override_pattern", "layer_types",
+            "layers_block_type", "num_attention_heads", "num_key_value_heads", "mamba_n_heads",
+            "mamba_num_heads", "linear_num_key_heads", "linear_num_value_heads",
+            "n_routed_experts", "num_local_experts", "num_experts", "vocab_size",
+            "max_position_embeddings"]
+
+
+def states_the_depth_it_was_cut_from(raw: dict) -> bool:
+    """A file that lists ``num_hidden_layers`` under ``reduced`` carries the
+    published depth beside its own, under ``published``."""
+    if "num_hidden_layers" not in raw["reduced"]:
+        return True
+    published = raw.get("published", {}).get("num_hidden_layers")
+    return isinstance(published, int) and published >= raw["num_hidden_layers"] >= 1
+
+
+def test_a_depth_cut_states_the_depth_it_was_cut_from():
+    cut = {"num_hidden_layers": 12, "reduced": {"num_hidden_layers": "12 of the published 72"}}
+    assert not states_the_depth_it_was_cut_from(cut)
+    assert states_the_depth_it_was_cut_from({**cut, "published": {"num_hidden_layers": 72}})
+    assert not states_the_depth_it_was_cut_from({**cut, "published": {"num_hidden_layers": 8}})
+    assert states_the_depth_it_was_cut_from({"num_hidden_layers": 32, "reduced": {}})
+
+
+@pytest.mark.parametrize("key,admitted",
+                         [(k, False) for k in REFUSED] + [(k, True) for k in ADMITTED])
+def test_reduced_refuses_a_width_and_admits_a_count(key, admitted):
+    assert bool(COUNT.search(key)) is admitted
 
 
 def test_paths_and_command():

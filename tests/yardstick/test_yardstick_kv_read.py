@@ -48,7 +48,8 @@ def test_reader_finds_nothing_without_the_kernel(name, counters):
 def test_entry_is_a_program_counter_of_its_cells(name):
     entry = {m["name"]: m for m in BM["per_layer"]}[name]
     assert entry["source"] == "program_counter" and entry["better"] == "lower"
-    assert entry["unit"] == "%" and entry["workloads"] == CELLS[name]
+    # the cells it was accepted with, and whichever later cells its reader reads a number in
+    assert entry["unit"] == "%" and set(CELLS[name]) <= set(entry["workloads"])
     assert entry["moves"] == ("out_tokens_per_s" if name.endswith(".sat") else "itl_p50_ms")
     layers = {m["layer"] for m in BM["per_layer"] if m["name"] == "flash_prefill_roofline"}
     assert entry["layer"] in layers

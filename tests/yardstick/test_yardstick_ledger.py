@@ -121,7 +121,8 @@ def test_collect_leaves_out_what_reads_nothing():
 def test_entry_is_a_program_counter_of_its_cells(name):
     entry = {m["name"]: m for m in BM["per_layer"]}[name]
     assert entry["source"] == "program_counter" and entry["better"] == "lower"
-    assert entry["workloads"] == NEW[name]
+    # the cells it was accepted with, and whichever later cells its reader reads a number in
+    assert set(NEW[name]) <= set(entry["workloads"])
     moves = "out_tokens_per_s" if name.endswith(".sat") else (
         "itl_p50_ms" if name == "host_exposed_share" else "ttft_p50_ms")
     assert entry["moves"] == moves
