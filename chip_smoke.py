@@ -30,7 +30,10 @@ three legs, set-up seconds only, no performance claim.
   gauge 1, logits as the jnp recurrence's). Then nemotron-3-super-120b
   at its published widths as one of four chips' share, seven blocks
   (``MEM*EME``: Mamba-2, attention without rotary embedding, 128 of the 512
-  latent experts): the same asks.
+  latent experts): the same asks. Then falcon-h1-34b at its published
+  widths and multipliers, two of its two-mixer blocks (a Mamba-2 mixer of
+  state 256 and rotary attention side by side on one normed input) and a
+  quarter of its vocabulary's rows: the same asks.
 
 With no TPU it exits non-zero before doing anything; it never carries on on
 the CPU. ``--cpu-dry-run`` is the explicit exception for debugging the
@@ -300,12 +303,14 @@ def sizes(dry: bool) -> dict:
     if dry:
         return dict(main_model="mistral-tiny", small_model="llama-tiny",
                     hybrid_model="olmo-hybrid-tiny", pattern_model="nemotron-h-tiny",
+                    parallel_model="falcon-h1-tiny",
                     phases=MAIN_PHASES[:3], out_tokens=8, main_parity=(32,),
                     paged_block=16, paged_parity=(32,), engine_words=20,
                     chunk=32, int8_parity=(32, 32))
     return dict(main_model="mistral-7b", small_model="llama-1b",
                 hybrid_model=hybrid_preset(periods=2),
                 pattern_model=pattern_preset("MEM*EME"),
+                parallel_model=parallel_preset(blocks=2),
                 phases=MAIN_PHASES, out_tokens=32, main_parity=(128,),
                 paged_block=64, paged_parity=(128,), engine_words=160,
                 chunk=256, int8_parity=(256, 256))
@@ -561,6 +566,17 @@ def pattern_preset(blocks: str) -> str:
     return name
 
 
+def parallel_preset(blocks: int) -> str:
+    """falcon-h1-34b cut to ``blocks`` of its two-mixer blocks (every width
+    and multiplier as published) and a quarter of its vocabulary's rows."""
+    from kserve_vllm_mini_tpu.models.config import PRESETS, get_config
+
+    name = f"falcon-h1-34b-{blocks}b"
+    PRESETS[name] = get_config("falcon-h1-34b").scaled(
+        name=name, hybrid_override_pattern="P" * blocks, vocab_size=65280)
+    return name
+
+
 def leg_engines(dry: bool) -> dict:
     from kserve_vllm_mini_tpu.runtime.local import start_local_server
 
@@ -590,6 +606,14 @@ def leg_engines(dry: bool) -> dict:
         # experts held; continuation pieces carry state and tail
         ("ssm_moe", {"model": sz["pattern_model"], "quantization": "int8",
                      "prefill_chunk": sz["chunk"]},
+         ["prefill[", "decode[chunk=1]"],
+         dict(chunks=sz["int8_parity"], decode=True, block_size=None,
+              kv_int8=False)),
+        # a Mamba-2 mixer (state 256) and rotary attention (20 / 4 heads x 128:
+        # flash prefill, the dense decode kernel at G = 5) side by side in every
+        # block, the muP multipliers; K/V, state and tail for every layer
+        ("two_mixer", {"model": sz["parallel_model"], "quantization": "int8",
+                       "prefill_chunk": sz["chunk"]},
          ["prefill[", "decode[chunk=1]"],
          dict(chunks=sz["int8_parity"], decode=True, block_size=None,
               kv_int8=False)),

@@ -42,6 +42,14 @@ orthogonal architecture axes extend it to the other families:
   ``n_routed_experts`` is the router's width; ``n_experts_held`` and
   ``expert_offset`` say which of them this device holds (expert
   parallelism's share: it routes over all, computes its own).
+  A fourth character, ``P``, is a block that holds TWO mixers and an MLP
+  (Falcon-H1): one RMSNorm feeds a Mamba-2 mixer and rotary attention side
+  by side, both outputs join the residual stream in one add, then a
+  pre-normed SwiGLU MLP (``d_ff``). Such a block keeps K/V AND a state and a
+  convolution tail, and its attention carries positions (``rotary_dim``
+  follows the block kind). The ``*_multiplier(s)`` fields are the family's
+  fixed muP scalars, applied to activations where the equations place them;
+  1.0 / None leave a program as it is.
 """
 
 from __future__ import annotations
@@ -153,8 +161,30 @@ class ModelConfig:
     # [expert_offset, expert_offset + n_experts_held); None = all of them
     n_experts_held: Optional[int] = None
     expert_offset: int = 0
+    # Fixed scalars of a muP-parametrised family (Falcon-H1), under the
+    # published names: configuration, not weights. Each scales an activation
+    # where the equations place it (models/llama.py, models/mamba.py); at
+    # 1.0 / None nothing is traced, so every other model's program is as it was.
+    embedding_multiplier: float = 1.0        # embed[tokens] * m
+    lm_head_multiplier: float = 1.0          # lm_head(norm(x)) * m
+    attention_in_multiplier: float = 1.0     # the normed input of wq, wk, wv
+    attention_out_multiplier: float = 1.0    # wo's output
+    key_multiplier: float = 1.0              # wk's output
+    ssm_in_multiplier: float = 1.0           # the normed input of in_proj
+    ssm_out_multiplier: float = 1.0          # out_proj's output
+    # in_proj's output by segment: z | x | B | C | dt, in that order
+    ssm_multipliers: Optional[tuple[float, ...]] = None
+    # (on w_gate's output inside the SiLU, on w_down's output)
+    mlp_multipliers: Optional[tuple[float, ...]] = None
 
     def __post_init__(self) -> None:
+        for name, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
+            given = getattr(self, name)
+            if given is not None:
+                # a list (from JSON) becomes a tuple: the config must hash
+                object.__setattr__(self, name, tuple(float(v) for v in given))
+                if len(given) != n:
+                    raise ValueError(f"{name} takes {n} values, got {len(given)}")
         if self.hybrid_override_pattern is not None:
             self._check_pattern()
             return
@@ -195,17 +225,21 @@ class ModelConfig:
 
     def _check_pattern(self) -> None:
         pat = self.hybrid_override_pattern
-        if not pat or set(pat) - set("M*E"):
-            raise ValueError(f"hybrid_override_pattern {pat!r}: one of 'M', '*', 'E' a block")
+        if not pat or set(pat) - set("M*EP"):
+            raise ValueError(f"hybrid_override_pattern {pat!r}: one of 'M', '*', 'E', 'P' a block")
+        if "P" in pat and "*" in pat:
+            raise ValueError("a pattern takes 'P' (rotary attention beside a Mamba-2 mixer) "
+                             "or '*' (attention without positions), not both: the stack "
+                             "has one rotary_dim")
         object.__setattr__(self, "n_layers", len(pat))
         if self.layer_types is not None or self.is_moe or self.block != "llama":
             raise ValueError("a hybrid_override_pattern stack takes no layer_types, "
                              "n_experts or block: its blocks are named by the pattern")
-        if "M" in pat and (min(self.mamba_num_heads, self.mamba_head_dim,
-                               self.ssm_state_size, self.n_groups) < 1
-                           or self.mamba_num_heads % self.n_groups
-                           or self.conv_kernel < 2 or self.chunk_size < 1):
-            raise ValueError("'M' blocks need mamba_num_heads (a multiple of n_groups), "
+        if self.n_ssm_layers and (min(self.mamba_num_heads, self.mamba_head_dim,
+                                      self.ssm_state_size, self.n_groups) < 1
+                                  or self.mamba_num_heads % self.n_groups
+                                  or self.conv_kernel < 2 or self.chunk_size < 1):
+            raise ValueError("'M' and 'P' blocks need mamba_num_heads (a multiple of n_groups), "
                              "mamba_head_dim, ssm_state_size >= 1 and conv_kernel >= 2")
         if "E" in pat:
             if min(self.n_routed_experts, self.num_experts_per_tok, self.moe_latent_size,
@@ -239,14 +273,25 @@ class ModelConfig:
     @property
     def n_full_layers(self) -> int:
         """Layers that hold K/V: all of them, a layer_types stack's
-        full-attention ones, or a pattern's '*' blocks."""
+        full-attention ones, or a pattern's '*' and 'P' blocks."""
         if self.is_pattern_stack:
-            return self.hybrid_override_pattern.count("*")
+            return sum(map(self.hybrid_override_pattern.count, "*P"))
         return self.n_layers // self.layer_period
 
     @property
     def n_ssm_layers(self) -> int:
-        return (self.hybrid_override_pattern or "").count("M")
+        """Blocks that keep a Mamba-2 state and tail: a pattern's 'M' and 'P'."""
+        return sum(map((self.hybrid_override_pattern or "").count, "MP"))
+
+    @property
+    def n_parallel_layers(self) -> int:
+        return (self.hybrid_override_pattern or "").count("P")
+
+    @property
+    def block_mixers(self) -> int:
+        """Sequence mixers in one block: 2 where a Mamba-2 mixer and
+        attention read one normed input side by side, else 1."""
+        return 2 if self.n_parallel_layers else 1
 
     @property
     def n_expert_layers(self) -> int:
@@ -288,9 +333,10 @@ class ModelConfig:
 
     @property
     def rotary_dim(self) -> int:
-        """Even number of head dims receiving RoPE (phi uses a prefix; the
-        attention blocks of a pattern stack carry no position: 0)."""
-        if self.is_pattern_stack:
+        """Even number of head dims receiving RoPE (phi uses a prefix). It
+        follows the block kind: a pattern's '*' blocks carry no position (0),
+        its 'P' blocks do."""
+        if self.is_pattern_stack and not self.n_parallel_layers:
             return 0
         d = int(self.head_dim * self.partial_rotary_factor)
         return d - (d % 2)
@@ -342,8 +388,11 @@ class ModelConfig:
                        self.moe_shared_expert_intermediate_size)
         experts = (d * self.n_routed_experts + self.n_routed_experts  # router, bias
                    + 2 * d * lat + 2 * d * sh + self.experts_held * 2 * lat * ff)
-        return (self.n_ssm_layers * (mamba + d) + self.n_full_layers * (attn + d)
-                + self.n_expert_layers * (experts + d))
+        n_p = self.n_parallel_layers
+        # a 'P' block: both mixers off one norm, then a pre-normed SwiGLU MLP
+        parallel = mamba + attn + 3 * d * self.d_ff + 2 * d
+        return ((self.n_ssm_layers - n_p) * (mamba + d) + (self.n_full_layers - n_p) * (attn + d)
+                + self.n_expert_layers * (experts + d) + n_p * parallel)
 
     def scaled(self, **kwargs) -> "ModelConfig":
         return replace(self, **kwargs)
@@ -570,6 +619,41 @@ PRESETS: dict[str, ModelConfig] = {
         routed_scaling_factor=5.0,
         norm_topk_prob=True,
     ),
+    # Falcon-H1-34B-Instruct (huggingface.co/tiiuae/Falcon-H1-34B-Instruct
+    # config.json): 72 blocks, each a Mamba-2 mixer (32 heads x 128, state
+    # 256, 2 groups; d_inner 4096 is NOT expand x hidden) and rotary
+    # attention (20 / 4 heads x 128, theta 1e11) side by side on one normed
+    # input, then a SwiGLU MLP; the muP multipliers as published.
+    "falcon-h1-34b": ModelConfig(
+        name="falcon-h1-34b",
+        vocab_size=261_120,
+        d_model=5120,
+        n_layers=72,
+        n_heads=20,
+        n_kv_heads=4,
+        d_ff=21_504,
+        max_seq_len=8192,
+        rope_theta=1e11,
+        rms_eps=1e-5,
+        explicit_head_dim=128,
+        hybrid_override_pattern="P" * 72,
+        mamba_num_heads=32,
+        mamba_head_dim=128,
+        ssm_state_size=256,
+        n_groups=2,
+        conv_kernel=4,
+        chunk_size=128,
+        embedding_multiplier=5.656854249492381,
+        lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0,
+        attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804,
+        ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ),
     # -- tiny CI variants (CPU in <1s) exercising each architecture axis ----
     "mistral-tiny": ModelConfig(
         name="mistral-tiny",
@@ -678,6 +762,37 @@ PRESETS: dict[str, ModelConfig] = {
         norm_topk_prob=True,
         n_experts_held=8,
         expert_offset=0,
+    ),
+    # three two-mixer blocks: attention narrower than the hidden size, a
+    # d_inner that is not expand x hidden, every multiplier away from 1
+    "falcon-h1-tiny": ModelConfig(
+        name="falcon-h1-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=256,
+        max_seq_len=256,
+        rope_theta=1e11,
+        rms_eps=1e-5,
+        explicit_head_dim=16,
+        hybrid_override_pattern="PPP",
+        mamba_num_heads=8,
+        mamba_head_dim=12,
+        ssm_state_size=32,
+        n_groups=2,
+        conv_kernel=4,
+        chunk_size=32,
+        embedding_multiplier=5.656854249492381,
+        lm_head_multiplier=0.0625,
+        attention_in_multiplier=0.8,
+        attention_out_multiplier=0.3,
+        key_multiplier=0.25,
+        ssm_in_multiplier=0.5,
+        ssm_out_multiplier=0.35,
+        ssm_multipliers=(0.7, 0.5, 0.35, 1.4, 0.7),
+        mlp_multipliers=(0.35, 0.2),
     ),
     "mixtral-tiny": ModelConfig(
         name="mixtral-tiny",

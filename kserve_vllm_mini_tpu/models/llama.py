@@ -207,7 +207,14 @@ def _init_hybrid(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Param
 
 
 # a hybrid_override_pattern's characters -> the stack of ``layers`` each kind lives in
-PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "experts"}
+PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "experts", "P": "parallel"}
+
+
+def scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
+    """``x`` times a fixed scalar of the configuration (a muP multiplier),
+    formed in float32 and returned in ``x``'s type; ``x`` itself at 1.0, so a
+    model without multipliers traces nothing."""
+    return x if by == 1.0 else (x.astype(jnp.float32) * by).astype(x.dtype)
 
 
 def pattern_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
@@ -219,12 +226,16 @@ def pattern_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ..
     d, hd = cfg.d_model, cfg.head_dim
     lat, ff = cfg.moe_latent_size, cfg.moe_intermediate_size
     sh, held = cfg.moe_shared_expert_intermediate_size, cfg.experts_held
+    attn = {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
+            "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)}
     return {
         "mamba": mixer_weight_shapes(cfg),
-        "attn": {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
-                 "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)},
+        "attn": attn,
         "experts": {"w_in": (d, lat), "w_out": (lat, d), "w1": (held, lat, ff),
                     "w2": (held, ff, lat), "s1": (d, sh), "s2": (sh, d)},
+        # both mixers and the MLP of a two-mixer block, in one stack
+        "parallel": {**mixer_weight_shapes(cfg), **attn, "w_gate": (d, cfg.d_ff),
+                     "w_up": (d, cfg.d_ff), "w_down": (cfg.d_ff, d)},
     }
 
 
@@ -247,28 +258,34 @@ def _init_pattern(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Para
         return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
 
     layers: Params = {}
-    for kind, key in zip(counts, (keys["wq"], keys["wk"], keys["wv"])):
+    for kind, key in zip(counts, (keys["wq"], keys["wk"], keys["wv"], keys["wo"])):
         n = counts[kind]
         if not n:
             continue
-        ks = jax.random.split(key, 16)
+        # the matmuls' keys first, then the small leaves' (from the ninth on,
+        # where the three single-mixer kinds have always had them)
+        n_mat = max(len(shapes[kind]), 8)
+        ks = jax.random.split(key, n_mat + 8)
         sub: Params = {name: stack(k, n, shape)
                        for (name, shape), k in zip(shapes[kind].items(), ks)}
-        sub["norm_in"] = gains(ks[8], n, cfg.d_model)
-        if kind == "mamba":
+        ks = ks[n_mat:]
+        sub["norm_in"] = gains(ks[0], n, cfg.d_model)
+        if kind in ("mamba", "parallel"):
             h, ch = cfg.mamba_num_heads, cfg.mamba_conv_channels
             sub["conv"] = (0.3 * jax.random.normal(
-                ks[9], (n, cfg.conv_kernel, ch), jnp.float32)).astype(dt)
-            sub["conv_b"] = (0.1 * jax.random.normal(ks[10], (n, ch), jnp.float32)).astype(dt)
-            sub["A_log"] = jnp.log(jax.random.uniform(ks[11], (n, h), jnp.float32, 1.0, 16.0))
-            sub["dt_bias"] = jax.random.uniform(ks[12], (n, h), jnp.float32, -5.0, -1.0)
-            sub["D"] = jax.random.uniform(ks[13], (n, h), jnp.float32, 0.5, 1.5)
-            sub["norm"] = gains(ks[14], n, cfg.mamba_d_inner)
+                ks[1], (n, cfg.conv_kernel, ch), jnp.float32)).astype(dt)
+            sub["conv_b"] = (0.1 * jax.random.normal(ks[2], (n, ch), jnp.float32)).astype(dt)
+            sub["A_log"] = jnp.log(jax.random.uniform(ks[3], (n, h), jnp.float32, 1.0, 16.0))
+            sub["dt_bias"] = jax.random.uniform(ks[4], (n, h), jnp.float32, -5.0, -1.0)
+            sub["D"] = jax.random.uniform(ks[5], (n, h), jnp.float32, 0.5, 1.5)
+            sub["norm"] = gains(ks[6], n, cfg.mamba_d_inner)
+            if kind == "parallel":
+                sub["mlp_norm"] = gains(ks[7], n, cfg.d_model)
         elif kind == "experts":
-            sub["router"] = stack(ks[9], n, (cfg.d_model, cfg.n_routed_experts),
+            sub["router"] = stack(ks[1], n, (cfg.d_model, cfg.n_routed_experts),
                                   lambda w: w.astype(jnp.float32))
             sub["router_bias"] = 0.05 * jax.random.normal(
-                ks[10], (n, cfg.n_routed_experts), jnp.float32)
+                ks[2], (n, cfg.n_routed_experts), jnp.float32)
         layers[kind] = sub
     return {
         "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
@@ -443,8 +460,9 @@ def qkv_proj(
 
     B, T, _ = h.shape
     qm = cfg.quant_mode
+    h = scaled(h, cfg.attention_in_multiplier)
     q = adapted_linear(h, p["wq"], lora, "wq", lora_ids, mode=qm)
-    k = adapted_linear(h, p["wk"], lora, "wk", lora_ids, mode=qm)
+    k = scaled(adapted_linear(h, p["wk"], lora, "wk", lora_ids, mode=qm), cfg.key_multiplier)
     v = adapted_linear(h, p["wv"], lora, "wv", lora_ids, mode=qm)
     if cfg.attn_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
@@ -486,7 +504,7 @@ def embed_tokens(params: Params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.n
     layer stack through this helper — gemma scales embeddings by
     sqrt(d_model), and an executor that skips it produces silently-wrong
     activations ~sqrt(d_model)x too small."""
-    x = params["embed"][tokens]
+    x = scaled(params["embed"][tokens], cfg.embedding_multiplier)
     if cfg.block == "gemma2":
         # computed in the model dtype, matching the published
         # implementation's bf16 rounding
@@ -505,7 +523,7 @@ def final_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarra
     else:
         x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head.T).astype(jnp.float32)
+    logits = scaled((x @ head.T).astype(jnp.float32), cfg.lm_head_multiplier)
     if cfg.block == "phi":
         logits = logits + params["lm_head_b"].astype(jnp.float32)
     if cfg.final_softcap is not None:
@@ -599,16 +617,19 @@ def swiglu_mlp(
     lora_ids: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """``W_down(silu(W_gate h) * W_up h)``: the dense MLP of the llama and
-    olmo2 blocks, residual and norms left to the caller."""
+    olmo2 blocks and of a two-mixer block, residual and norms left to the
+    caller. With ``cfg.mlp_multipliers`` (g, d):
+    ``W_down(silu(g W_gate h) * W_up h) d``."""
     from functools import partial
 
     from kserve_vllm_mini_tpu.ops.lora import adapted_linear
 
     _al = partial(adapted_linear, mode=cfg.quant_mode)
+    by_gate, by_down = cfg.mlp_multipliers or (1.0, 1.0)
     gated = jax.nn.silu(
-        _al(h, p["w_gate"], lora, "w_gate", lora_ids).astype(jnp.float32)
+        scaled(_al(h, p["w_gate"], lora, "w_gate", lora_ids).astype(jnp.float32), by_gate)
     ).astype(cfg.jnp_dtype) * _al(h, p["w_up"], lora, "w_up", lora_ids)
-    return _al(gated, p["w_down"], lora, "w_down", lora_ids)
+    return scaled(_al(gated, p["w_down"], lora, "w_down", lora_ids), by_down)
 
 
 def branch_tail(p: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -1050,23 +1071,44 @@ def run_cached_layers(
         cache = dict(kv_cache)
         seen = dict.fromkeys(PATTERN_KINDS, 0)
         stats = jnp.zeros((3,), jnp.int32)
+
+        def ssm(p, h, cache, i):
+            """The Mamba-2 mixer on the normed ``h``, its state and tail
+            layer ``i`` of the cache's two state-space leaves."""
+            state, tail = cache["ssm_state"][i], cache["ssm_conv"][i]
+            if fresh_prefill:
+                # a new sequence starts from S_0 = 0 whatever the slot held
+                state, tail = jnp.zeros_like(state), jnp.zeros_like(tail)
+            out, state, tail = mamba_mixer(p, cfg, h, state, tail, valid)
+            cache["ssm_state"] = cache["ssm_state"].at[i].set(state)
+            cache["ssm_conv"] = cache["ssm_conv"].at[i].set(tail)
+            return out, cache
+
+        def attn(p, h, cache, i):
+            """Attention on the normed ``h``, its K/V layer ``i`` of the cache."""
+            o, cache = attend(h, cache, p, None, jnp.int32(i))
+            o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
+            return linear(o, p["wo"], mode=cfg.quant_mode), cache
+
         for ch in cfg.hybrid_override_pattern:
-            i = seen[ch]
+            # a block's place in its kind's weight stack, and in the cache's
+            # state-space and K/V leaves (a 'P' block holds a layer of each)
+            i, i_ssm, i_kv = seen[ch], seen["M"] + seen["P"], seen["*"] + seen["P"]
             seen[ch] += 1
             p = jax.tree_util.tree_map(lambda a: a[i], layers[PATTERN_KINDS[ch]])
             h = rms_norm(x, p["norm_in"], cfg.rms_eps)
             if ch == "M":
-                state, tail = cache["ssm_state"][i], cache["ssm_conv"][i]
-                if fresh_prefill:
-                    # a new sequence starts from S_0 = 0 whatever the slot held
-                    state, tail = jnp.zeros_like(state), jnp.zeros_like(tail)
-                out, state, tail = mamba_mixer(p, cfg, h, state, tail, valid)
-                cache["ssm_state"] = cache["ssm_state"].at[i].set(state)
-                cache["ssm_conv"] = cache["ssm_conv"].at[i].set(tail)
+                out, cache = ssm(p, h, cache, i_ssm)
             elif ch == "*":
-                o, cache = attend(h, cache, p, None, jnp.int32(i))
-                o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
-                out = linear(o, p["wo"], mode=cfg.quant_mode)
+                out, cache = attn(p, h, cache, i_kv)
+            elif ch == "P":
+                # two mixers side by side on ONE normed input, one residual
+                # add for both; then the block's own pre-normed MLP
+                mixed, cache = ssm(p, h, cache, i_ssm)
+                attended, cache = attn(p, h, cache, i_kv)
+                x = x + (scaled(mixed, cfg.ssm_out_multiplier)
+                         + scaled(attended, cfg.attention_out_multiplier))
+                out = swiglu_mlp(p, cfg, rms_norm(x, p["mlp_norm"], cfg.rms_eps))
             else:
                 out, counts = moe_latent_block(p, cfg, h, real)
                 stats = stats + counts

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
 from kserve_vllm_mini_tpu.models.linear_attention import causal_conv
+from kserve_vllm_mini_tpu.models.llama import scaled
 from kserve_vllm_mini_tpu.ops.quant import linear
 
 _HI = jax.lax.Precision.HIGHEST
@@ -136,6 +137,12 @@ def grouped_rms_norm(y: jnp.ndarray, gain: jnp.ndarray, groups: int, eps: float)
     return yg.reshape(shape) * gain.astype(jnp.float32)
 
 
+def gated_norm(y: jnp.ndarray, z: jnp.ndarray, gain: jnp.ndarray, groups: int, eps: float):
+    """The mixer's output norm, gate FIRST (``norm_before_gate`` false in the
+    published configs): ``RMSNorm_group(y * silu(z))``."""
+    return grouped_rms_norm(y * jax.nn.silu(z.astype(jnp.float32)), gain, groups, eps)
+
+
 def mamba_mixer(
     p: dict,                      # one layer's mixer leaves
     cfg: ModelConfig,
@@ -150,7 +157,12 @@ def mamba_mixer(
     bsz, t, _ = x.shape
     h, hp, n, g = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size, cfg.n_groups
     di = cfg.mamba_d_inner
-    zxbcdt = linear(x, p["in_proj"], mode=cfg.quant_mode)
+    zxbcdt = linear(scaled(x, cfg.ssm_in_multiplier), p["in_proj"], mode=cfg.quant_mode)
+    if cfg.ssm_multipliers is not None:
+        # one fixed scalar a segment of in_proj's output: z | x | B | C | dt
+        by = jnp.concatenate([jnp.full((width,), m, jnp.float32) for width, m in zip(
+            (di, di, g * n, g * n, h), cfg.ssm_multipliers)])
+        zxbcdt = (zxbcdt.astype(jnp.float32) * by).astype(zxbcdt.dtype)
     z, xbc, dt = jnp.split(zxbcdt, [di, di + cfg.mamba_conv_channels], axis=-1)
     xbc, new_tail = causal_conv(xbc, tail, p["conv"], valid, bias=p["conv_b"])
     xs = xbc[..., :di].reshape(bsz, t, h, hp)
@@ -169,6 +181,5 @@ def mamba_mixer(
         s, y = chunked_scan(state, xs, b, c, dt, a, cfg.chunk_size)
     new_state = jnp.where((valid > 0)[:, None, None, None], s, state)
     y = y + p["D"].astype(jnp.float32)[:, None] * xs
-    gated = y.reshape(bsz, t, di) * jax.nn.silu(z.astype(jnp.float32))
-    normed = grouped_rms_norm(gated, p["norm"], g, cfg.rms_eps).astype(x.dtype)
+    normed = gated_norm(y.reshape(bsz, t, di), z, p["norm"], g, cfg.rms_eps).astype(x.dtype)
     return linear(normed, p["out_proj"], mode=cfg.quant_mode), new_state, new_tail

@@ -155,7 +155,9 @@ HYBRID_QUANTIZABLE = QUANTIZABLE + ("wg",)
 # The router and its selection bias, convolution taps and bias, A_log, D,
 # dt_bias and every gain stay as they are.
 PATTERN_QUANTIZABLE = ("in_proj", "out_proj", "wq", "wk", "wv", "wo",
-                       "w_in", "w_out", "w1", "w2", "s1", "s2")
+                       "w_in", "w_out", "w1", "w2", "s1", "s2",
+                       # a two-mixer block's MLP, in the same stack as its mixers
+                       "w_gate", "w_up", "w_down")
 
 
 def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
@@ -169,7 +171,7 @@ def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
     layers = params["layers"]
     if "linear" in layers and "full" in layers:
         out["layers"] = {kind: stack(sub, HYBRID_QUANTIZABLE) for kind, sub in layers.items()}
-    elif set(layers) <= {"mamba", "attn", "experts"}:
+    elif set(layers) <= {"mamba", "attn", "experts", "parallel"}:
         out["layers"] = {kind: stack(sub, PATTERN_QUANTIZABLE) for kind, sub in layers.items()}
     else:
         out["layers"] = stack(layers, QUANTIZABLE)

@@ -4136,6 +4136,7 @@ class Engine:
         s["kv_bytes_per_token"] = self.kv_bytes_per_token()
         s["cache_bytes"] = dict(self._cache_bytes)
         s["linear_state_kernel"] = int(self._linear_state_kernel)
+        s["block_mixers"] = self.cfg.block_mixers
         # physical bytes the reused prompt tokens did NOT re-write — the
         # byte-denominated view of prefix_tokens_reused_total
         s["kv_reused_bytes"] = s["prefix_tokens_reused"] * s["kv_bytes_per_token"]

@@ -3,9 +3,10 @@ stack: checked in the programs the chip's own compiler builds, with no chip.
 
 ``tests/tpu_preflight_worker.py`` compiles the decode step and a fresh
 prefill piece for a TPU v5e by the README's pre-flight recipe (libtpu
-compiles for a described chip), at the benchmark's four configurations
-(published widths, two layers, one period of the hybrid, or one block of
-each kind of the pattern stack with 8 experts held). Asked of each
+compiles for a described chip), at the benchmark's five configurations
+(published widths, two layers, one period of the hybrid, one block of
+each kind of the pattern stack with 8 experts held, or two blocks of the
+two-mixer stack). Asked of each
 executable: ``weight_copy_bytes == 0`` (no per-layer slice of an integer
 weight stack is copied, i.e. transposed, before its matmul:
 profiling/compile_stats.py) and a Mosaic call is still compiled in.
@@ -38,9 +39,9 @@ import pytest
 from tests import env_guards
 
 MODELS = ("mistral-7b-int8", "qwen2-7b-int8", "olmo-hybrid-7b-int8",
-          "nemotron-3-super-ep4-int8")
+          "nemotron-3-super-ep4-int8", "falcon-h1-34b-int8")
 CASES = [f"{m}:{s}" for m in MODELS for s in ("decode", "prefill")]
-# eight compiles take ~130 s here; under a loaded suite several times that
+# ten compiles take ~170 s here; under a loaded suite several times that
 TIMEOUT_S = 900
 
 
@@ -89,3 +90,21 @@ def test_the_hybrids_decode_step_updates_its_state_in_place(compiled):
     assert stats["mosaic_calls"] >= 4, (
         f"mosaic_calls {stats['mosaic_calls']}: the period's three linear "
         "layers do not each hold kvmini_linear_decode")
+
+
+def test_the_two_mixer_stack_holds_a_kernel_a_block_and_its_state_traffic_is_recorded(compiled):
+    """Two blocks at the published widths (hidden 5120, attention 20 / 4 heads
+    x 128, state 32 x 128 x 256): one ``kvmini_dense_decode`` a block in the
+    decode step (G = 5), one flash prefill a block in ``prefill[128]``.
+    ``state_copy_bytes`` is a number to record here, not a bar: the jnp
+    update of models/mamba.py slices each layer's state out of the stack and
+    writes it back (402,653,184 in the decode step of two blocks: 16 slots x
+    4.19 MB x 3 a layer; 67,108,864 in the prefill piece), and a kernel that
+    updates it in place, as ``kvmini_linear_decode`` does for the delta rule,
+    is a later PR's."""
+    decode, prefill = (compiled[f"falcon-h1-34b-int8:{s}"] for s in ("decode", "prefill"))
+    assert decode["mosaic_calls"] >= 2 and prefill["mosaic_calls"] >= 2
+    for stats in (decode, prefill):
+        assert isinstance(stats["state_copy_bytes"], int) and stats["state_copy_bytes"] >= 0
+    print("falcon-h1-34b-int8 state_copy_bytes:", decode["state_copy_bytes"],
+          prefill["state_copy_bytes"])

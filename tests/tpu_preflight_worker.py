@@ -10,7 +10,8 @@ forward, "auto", 1)``, ``prefill`` is ``steps.build_prefill(cfg, forward,
 "auto", "slot", True)`` at bucket 128, over abstract arguments (no weights are
 made). Models: the benchmark's configurations by name (``BENCHMARK.json``), at
 their published widths and two layers (the hybrid: one period of four; the
-pattern stack: one block of each kind, ``M*E``, with 8 of its experts held).
+pattern stack: one block of each kind, ``M*E``, with 8 of its experts held;
+the two-mixer stack: two blocks, ``PP``).
 
 Prints one JSON line: ``{"skip": reason}`` where libtpu or the topology cannot
 be had, else ``{"cases": {case: CompileStats.to_dict()}}`` (``mosaic_calls``,
@@ -48,7 +49,11 @@ def model_config(name: str):
         period = kinds.index("full_attention") + 1
         m["num_hidden_layers"] = period
         m["model_extra"] = {**m["model_extra"], "layer_types": kinds[:period]}
-    if "hybrid_override_pattern" in m.get("model_extra", {}):
+    pattern = m.get("model_extra", {}).get("hybrid_override_pattern", "")
+    if "P" in pattern:
+        # a stack of two-mixer blocks: two of them
+        m["model_extra"] = {**m["model_extra"], "hybrid_override_pattern": "PP"}
+    elif pattern:
         # a stack of single-mixer blocks: one of each kind, a few experts held
         m["model_extra"] = {**m["model_extra"], "hybrid_override_pattern": "M*E",
                             "n_experts_held": 8}
