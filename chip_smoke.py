@@ -213,17 +213,24 @@ def require_weights_in_place(exes: dict[str, dict], dry: bool) -> None:
 def require_state_in_place(engine, exes: dict[str, dict], dry: bool) -> None:
     """``state_copy_bytes`` of every executable (profiling/compile_stats.py:
     layers of a recurrent state sliced out of their stack, written back or
-    copied outside a Mosaic call). Where the engine says its decode program
-    took ``kvmini_linear_decode`` (the hybrid's gauge), that program holds
-    none; the Mamba-2 update of a pattern stack is jnp and reads its own."""
-    took = engine.snapshot_stats()["linear_state_kernel"]
-    say(f"  kvmini_tpu_linear_state_kernel={took}")
+    copied outside a Mosaic call), beside the two gauges that say which
+    decode program the engine runs. Where a stack keeps a recurrent state,
+    the decode program on the chip took its kernel (``kvmini_linear_decode``
+    for the delta rule, ``kvmini_ssm_decode`` for Mamba-2) and holds no such
+    copy: a silent fall-back to the jnp recurrence, which the parity check
+    below would pass, fails here."""
+    stats = engine.snapshot_stats()
+    took = {"linear": stats["linear_state_kernel"], "ssm": stats["ssm_state_kernel"]}
+    say(f"  kvmini_tpu_linear_state_kernel={took['linear']} "
+        f"kvmini_tpu_ssm_state_kernel={took['ssm']}")
     for lab in sorted(exes):
         say(f"  {lab}: state_copy_bytes={exes[lab]['state_copy_bytes']}")
-    if not dry and engine.cfg.is_hybrid and not engine.cfg.is_pattern_stack:
-        check(took == 1, "the hybrid's decode program did not take kvmini_linear_decode")
-        n = exes["decode[chunk=1]"]["state_copy_bytes"]
-        check(n == 0, f"decode[chunk=1] moves {n} bytes of state layers outside the kernel")
+    if dry or not engine.cfg.is_hybrid:
+        return
+    kind = "ssm" if engine.cfg.is_pattern_stack else "linear"
+    check(took[kind] == 1, f"the decode program did not take the {kind} state's kernel")
+    n = exes["decode[chunk=1]"]["state_copy_bytes"]
+    check(n == 0, f"decode[chunk=1] moves {n} bytes of state layers outside the kernel")
 
 
 # -- model-level parity: kernel path vs jnp path on the engine's own weights ----

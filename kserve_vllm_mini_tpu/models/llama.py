@@ -1062,7 +1062,7 @@ def run_cached_layers(
         # (a scan over a [periods, k, ...] view, or a switch on the kind
         # inside one, makes XLA copy the slice out every step), and state,
         # tail and K/V are updated in place in the donated cache.
-        from kserve_vllm_mini_tpu.models.mamba import mamba_mixer
+        from kserve_vllm_mini_tpu.models.mamba import mamba_mixer, ssm_decode_kernel_block
         from kserve_vllm_mini_tpu.models.moe import moe_latent_block
 
         valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
@@ -1072,15 +1072,23 @@ def run_cached_layers(
         seen = dict.fromkeys(PATTERN_KINDS, 0)
         stats = jnp.zeros((3,), jnp.int32)
 
+        # a decode step on a TPU hands the mixer the whole state stack: the
+        # kernel updates the block's layer inside it (no slice out, no write
+        # back); never a fresh prefill
+        in_place = not fresh_prefill and ssm_decode_kernel_block(
+            kernels, kv_cache["ssm_state"], T) is not None
+
         def ssm(p, h, cache, i):
             """The Mamba-2 mixer on the normed ``h``, its state and tail
             layer ``i`` of the cache's two state-space leaves."""
-            state, tail = cache["ssm_state"][i], cache["ssm_conv"][i]
+            state, layer = (cache["ssm_state"], jnp.int32(i)) if in_place else (
+                cache["ssm_state"][i], None)
+            tail = cache["ssm_conv"][i]
             if fresh_prefill:
                 # a new sequence starts from S_0 = 0 whatever the slot held
                 state, tail = jnp.zeros_like(state), jnp.zeros_like(tail)
-            out, state, tail = mamba_mixer(p, cfg, h, state, tail, valid)
-            cache["ssm_state"] = cache["ssm_state"].at[i].set(state)
+            out, state, tail = mamba_mixer(p, cfg, h, state, tail, valid, kernels, layer=layer)
+            cache["ssm_state"] = state if in_place else cache["ssm_state"].at[i].set(state)
             cache["ssm_conv"] = cache["ssm_conv"].at[i].set(tail)
             return out, cache
 

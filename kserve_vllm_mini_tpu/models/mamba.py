@@ -14,10 +14,16 @@ convolution with bias and SiLU. The output is
 ``out_proj(RMSNorm_group(y * silu(z)))``, the norm over each of the
 ``n_groups`` groups of ``d_inner / n_groups`` channels (gate first).
 
-Decode (``T == 1``) is the recurrence as written: one pass that reads and
-writes the state. Prefill is the chunked (state-space-dual) form,
-``chunk_size`` positions at a time: inside a chunk the outputs are a masked
-``C B^T`` product, between chunks one pass carries the state.
+Decode (``T == 1``) is the recurrence as written (``recurrent_step``). As
+jnp it is three passes over a layer's state (XLA's update reads and writes
+it, a second fusion reads the new state again for ``S C``); on a TPU a
+decode step takes ``kvmini_ssm_decode`` (ops/ssm_decode.py): the same update
+with a slot's heads held in VMEM, so the state is read once and written
+once, inside the cache's layer stack (``ssm_decode_kernel_block`` is the
+gate). ``recurrent_step`` runs everywhere else and is the oracle. Prefill is
+the chunked (state-space-dual) form, ``chunk_size`` positions at a time:
+inside a chunk the outputs are a masked ``C B^T`` product, between chunks
+one pass carries the state.
 
 As for the delta rule (models/linear_attention.py, whose ``causal_conv``
 this shares): the state has no positions, so every entry point takes
@@ -28,13 +34,17 @@ this shares): the state has no positions, so every entry point takes
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
 from kserve_vllm_mini_tpu.models.linear_attention import causal_conv
 from kserve_vllm_mini_tpu.models.llama import scaled
+from kserve_vllm_mini_tpu.ops.flash_attention import pallas_interpret
 from kserve_vllm_mini_tpu.ops.quant import linear
+from kserve_vllm_mini_tpu.ops.ssm_decode import ssm_decode_block, ssm_decode_update
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -64,6 +74,20 @@ def recurrent_step(state, x, b, c, dt, a_log_neg):
     decay = jnp.exp(dt * a_log_neg)[..., None, None]
     s = state * decay + (dt[..., None] * x)[..., None] * b[..., None, :]
     return s, jnp.sum(s * c[..., None, :], axis=-1)
+
+
+def ssm_decode_kernel_block(kernels: str, ssm_state: jnp.ndarray, t: int) -> Optional[int]:
+    """Heads a grid step of ``kvmini_ssm_decode`` holds where a block of
+    ``t`` positions over the cache's state stack ``ssm_state``
+    [L, B, H, P, N] takes the kernel, or None where it keeps
+    ``recurrent_step`` / ``chunked_scan``. THE gate (the twin of
+    ``linear_attention.linear_decode_kernel_block``), read from what the
+    call can see: the kernels mode and backend, one position a row, and the
+    stack's dtype and tile (``ssm_decode_block``: float32, ``P`` whole
+    sublane tiles, ``N`` whole lane tiles, a head block that fits VMEM)."""
+    if t != 1 or pallas_interpret(kernels) is None:
+        return None
+    return ssm_decode_block(*ssm_state.shape[2:], ssm_state.dtype)
 
 
 def recurrent_scan(state, x, b, c, dt, a):
@@ -147,13 +171,22 @@ def mamba_mixer(
     p: dict,                      # one layer's mixer leaves
     cfg: ModelConfig,
     x: jnp.ndarray,               # [B, T, D] (already normed)
-    state: jnp.ndarray,           # [B, H, P, N] float32
+    state: jnp.ndarray,           # [B, H, P, N] float32; with ``layer``,
+                                  # the cache's whole stack [L, B, H, P, N]
     tail: jnp.ndarray,            # [B, K-1, C]
     valid: jnp.ndarray,           # [B] int32: leading positions that are real
+    kernels: str = "auto",        # Pallas kernel selection, as ``forward``'s
+    layer: Optional[jnp.ndarray] = None,  # scalar int32: ``state`` is the
+                                  # stack and this layer of it is updated in
+                                  # place by the decode kernel; the caller
+                                  # asked ``ssm_decode_kernel_block``
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The mixer over one block -> (y [B,T,D], new state, new tail). ``T ==
     1`` takes the one-token recurrence, anything longer the chunked form.
-    Rows with ``valid == 0`` come back with state and tail untouched."""
+    Rows with ``valid == 0`` come back with state and tail untouched. With
+    ``layer`` the state goes in and comes back as the whole stack: slicing a
+    layer out for the kernel and writing it back would cost the two passes
+    the kernel saves."""
     bsz, t, _ = x.shape
     h, hp, n, g = cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size, cfg.n_groups
     di = cfg.mamba_d_inner
@@ -172,14 +205,20 @@ def mamba_mixer(
     real = (jnp.arange(t)[None, :] < valid[:, None])[..., None]    # [B, T, 1]
     dt = jnp.where(real, dt, 0.0)
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
-    if t == 1:
+    if layer is not None:           # the kernel holds the rows with valid == 0 itself
+        new_state, y = ssm_decode_update(
+            state, layer, xs[:, 0], b[:, 0], c[:, 0], dt[:, 0], a, valid,
+            interpret=pallas_interpret(kernels))
+        y = y[:, None]
+    elif t == 1:
         per = h // g
         s, y = recurrent_step(state, xs[:, 0], jnp.repeat(b[:, 0], per, axis=1),
                               jnp.repeat(c[:, 0], per, axis=1), dt[:, 0], a)
         y = y[:, None]
     else:
         s, y = chunked_scan(state, xs, b, c, dt, a, cfg.chunk_size)
-    new_state = jnp.where((valid > 0)[:, None, None, None], s, state)
+    if layer is None:
+        new_state = jnp.where((valid > 0)[:, None, None, None], s, state)
     y = y + p["D"].astype(jnp.float32)[:, None] * xs
     normed = gated_norm(y.reshape(bsz, t, di), z, p["norm"], g, cfg.rms_eps).astype(x.dtype)
     return linear(normed, p["out_proj"], mode=cfg.quant_mode), new_state, new_tail

@@ -37,6 +37,7 @@ from kserve_vllm_mini_tpu.models.config import ModelConfig
 from kserve_vllm_mini_tpu.models.linear_attention import CHUNK as LINEAR_CHUNK
 from kserve_vllm_mini_tpu.models.linear_attention import linear_decode_kernel_block
 from kserve_vllm_mini_tpu.models.llama import dense_decode_kernel_block, forward
+from kserve_vllm_mini_tpu.models.mamba import ssm_decode_kernel_block
 from kserve_vllm_mini_tpu.profiling.compile_stats import (
     CompileRecorder,
     InstrumentedJit,
@@ -425,6 +426,9 @@ class Engine:
         # kvmini_linear_decode (models/linear_attention.py
         # linear_decode_kernel_block), resolved in start(): the gauge
         self._linear_state_kernel = False
+        # the same for a pattern stack's Mamba-2 state and kvmini_ssm_decode
+        # (models/mamba.py ssm_decode_kernel_block)
+        self._ssm_state_kernel = False
         # model executor: plain forward, or the pp-sharded drop-in when the
         # mesh pipelines layers (parallel/serving_pp.py — same signature, so
         # every compiled step below is executor-agnostic)
@@ -2078,6 +2082,9 @@ class Engine:
         )
         self._linear_state_kernel = "lin_state" in self._cache and (
             linear_decode_kernel_block(self._kernels, self._cache["lin_state"], 1)
+            is not None)
+        self._ssm_state_kernel = "ssm_state" in self._cache and (
+            ssm_decode_kernel_block(self._kernels, self._cache["ssm_state"], 1)
             is not None)
         if self._disagg is not None:
             self._disagg.start()
@@ -4136,6 +4143,7 @@ class Engine:
         s["kv_bytes_per_token"] = self.kv_bytes_per_token()
         s["cache_bytes"] = dict(self._cache_bytes)
         s["linear_state_kernel"] = int(self._linear_state_kernel)
+        s["ssm_state_kernel"] = int(self._ssm_state_kernel)
         s["block_mixers"] = self.cfg.block_mixers
         # physical bytes the reused prompt tokens did NOT re-write — the
         # byte-denominated view of prefix_tokens_reused_total

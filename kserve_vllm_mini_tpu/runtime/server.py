@@ -1382,6 +1382,9 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             # kvmini_linear_decode, 0 where it runs the jnp recurrence
             "# TYPE kvmini_tpu_linear_state_kernel gauge",
             f"kvmini_tpu_linear_state_kernel {s['linear_state_kernel']}",
+            # the same for a pattern stack's Mamba-2 state and kvmini_ssm_decode
+            "# TYPE kvmini_tpu_ssm_state_kernel gauge",
+            f"kvmini_tpu_ssm_state_kernel {s['ssm_state_kernel']}",
             # mixers a block runs on one normed input: 2 where a state-space
             # mixer and attention sit side by side, 1 for every other stack
             "# TYPE kvmini_tpu_block_mixers gauge",

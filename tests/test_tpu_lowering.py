@@ -152,6 +152,24 @@ def test_linear_decode_lowers(stack):
     )
 
 
+@pytest.mark.parametrize("stack,groups", [((10, 32, 128, 64, 128), 8), ((12, 16, 32, 128, 256), 2),
+                                          ((3, 4, 8, 16, 128), 2)],
+                         ids=["single-mixer", "two-mixer", "small"])
+def test_ssm_decode_lowers(stack, groups):
+    """The two pattern stacks' state stacks at the benchmark's sizes (64 of
+    128 heads and 16 of 32 a grid step) and a small one: the aliased stack,
+    the SMEM decays, the slot's transposed ``dt x`` columns and ``B | C``
+    rows, the bfloat16 matmul that sums over the lanes."""
+    from kserve_vllm_mini_tpu.ops.ssm_decode import ssm_decode_update
+
+    _, b, h, p, n = stack
+    _lowers_for_tpu(
+        ssm_decode_update, _sds(stack, F32), _sds((), I32), _sds((b, h, p), F32),
+        _sds((b, groups, n), F32), _sds((b, groups, n), F32), _sds((b, h), F32),
+        _sds((h,), F32), _sds((b,), I32),
+    )
+
+
 # -- which decode steps take the dense kernel ----------------------------------
 
 

@@ -21,7 +21,8 @@ the suite too much.
 The hybrid's decode step also holds ``state_copy_bytes == 0``: its delta
 rule's state is updated inside the cache's layer stack by
 ``kvmini_linear_decode`` (ops/linear_decode.py), one call a linear layer of
-the period beside the one ``kvmini_dense_decode``.
+the period beside the one ``kvmini_dense_decode``; the two pattern stacks'
+the same for their Mamba-2 state and ``kvmini_ssm_decode`` (ops/ssm_decode.py).
 
 One file, one subprocess: libtpu is loaded in the child alone (``--dist
 loadfile`` keeps this file in one worker), and the child is held to a
@@ -92,19 +93,43 @@ def test_the_hybrids_decode_step_updates_its_state_in_place(compiled):
         "layers do not each hold kvmini_linear_decode")
 
 
-def test_the_two_mixer_stack_holds_a_kernel_a_block_and_its_state_traffic_is_recorded(compiled):
+# state-space blocks and attention calls of each pattern stack as the worker
+# cuts it: "M*E" (one Mamba-2 block, one attention block) and "PP" (two blocks
+# of both mixers)
+PATTERN_STACKS = {"nemotron-3-super-ep4-int8": (1, 1), "falcon-h1-34b-int8": (2, 2)}
+
+
+@pytest.mark.parametrize("model", sorted(PATTERN_STACKS))
+def test_the_pattern_stacks_decode_step_updates_its_state_in_place(compiled, model):
+    """No layer of the float32 Mamba-2 state stack is sliced out, written
+    back or copied around ``kvmini_ssm_decode`` (ops/ssm_decode.py), and the
+    calls are there: one a state-space block beside one
+    ``kvmini_dense_decode`` an attention block. Until PR 37 the update was
+    jnp (models/mamba.py ``recurrent_step``) and these cases only recorded
+    its traffic: the two-mixer stack's decode step read ``mosaic_calls`` 2
+    and ``state_copy_bytes`` 402,653,184 (two blocks at the published widths,
+    state 32 x 128 x 256: 16 slots x 4.19 MB x 3 a layer, the slice out, the
+    update and the write-back); the single-mixer stack's read ``mosaic_calls``
+    1 and ``state_copy_bytes`` 0 (its one state-space block is the whole
+    stack, so nothing was sliced: there the calls are the bar)."""
+    ssm_blocks, attention_calls = PATTERN_STACKS[model]
+    stats = compiled[f"{model}:decode"]
+    assert stats["state_copy_bytes"] == 0, (
+        f"{stats['state_copy_bytes']} bytes of the state-space state's layers are "
+        "sliced out of their stack or written back outside the kernel")
+    assert stats["mosaic_calls"] >= ssm_blocks + attention_calls, (
+        f"mosaic_calls {stats['mosaic_calls']}: the {ssm_blocks} state-space "
+        "blocks do not each hold kvmini_ssm_decode")
+
+
+def test_the_two_mixer_stack_holds_a_kernel_a_block_and_its_prefill_traffic_is_recorded(compiled):
     """Two blocks at the published widths (hidden 5120, attention 20 / 4 heads
     x 128, state 32 x 128 x 256): one ``kvmini_dense_decode`` a block in the
-    decode step (G = 5), one flash prefill a block in ``prefill[128]``.
-    ``state_copy_bytes`` is a number to record here, not a bar: the jnp
-    update of models/mamba.py slices each layer's state out of the stack and
-    writes it back (402,653,184 in the decode step of two blocks: 16 slots x
-    4.19 MB x 3 a layer; 67,108,864 in the prefill piece), and a kernel that
-    updates it in place, as ``kvmini_linear_decode`` does for the delta rule,
-    is a later PR's."""
+    decode step (G = 5), one flash prefill a block in ``prefill[128]``. The
+    prefill piece's ``state_copy_bytes`` is a number to record, not a bar: a
+    fresh prefill starts from a zero state and ``chunked_scan`` writes each
+    layer's new state into the stack once (67,108,864 for two blocks)."""
     decode, prefill = (compiled[f"falcon-h1-34b-int8:{s}"] for s in ("decode", "prefill"))
     assert decode["mosaic_calls"] >= 2 and prefill["mosaic_calls"] >= 2
-    for stats in (decode, prefill):
-        assert isinstance(stats["state_copy_bytes"], int) and stats["state_copy_bytes"] >= 0
-    print("falcon-h1-34b-int8 state_copy_bytes:", decode["state_copy_bytes"],
-          prefill["state_copy_bytes"])
+    assert isinstance(prefill["state_copy_bytes"], int) and prefill["state_copy_bytes"] >= 0
+    print("falcon-h1-34b-int8 prefill state_copy_bytes:", prefill["state_copy_bytes"])
