@@ -805,7 +805,9 @@ class Engine:
         # the host-visible _slot_len (chunk per unretired sweep)
         self._pending_steps = 0
         self._t_last_ready = 0.0   # when the device last finished a sweep
-        self._bubble_anchor = 0.0  # device went idle here with work queued
+        # a synchronous sweep (masked, speculative) between its dispatch and
+        # the end of its readback: in flight, though never in _inflight
+        self._sync_inflight = False
         # multihost lockstep mode (set by runtime/multihost.py drivers):
         # disables the retire-time cancelled-handle emission skip, whose
         # trigger is a host-local race the follower cannot observe —
@@ -1001,7 +1003,6 @@ class Engine:
             "dispatch_depth": 0,    # high-water concurrently in-flight sweeps
             "pipelined_sweeps": 0,  # sweeps dispatched ahead of a retire
             "host_overlap_s": 0.0,  # host emit/bookkeeping under device compute
-            "bubble_s": 0.0,        # device idle between sweeps with work live
             "pipeline_fallback_constrained": 0,  # grammar mask forced sync
             "pipeline_fallback_spec": 0,         # spec partition forced sync
             "pipeline_fallback_active_set": 0,   # admission/cancel forced retire
@@ -1074,6 +1075,7 @@ class Engine:
             exposed=self._host_exposed,
             # asked on the scheduler thread, which is the one that writes it
             sweep_s=lambda: self._sweep_ema_s,
+            on_gap=self._trace_gap if self._engine_tracer is not None else None,
         )
 
         # Per-device analytic HBM footprint for headroom-model validation
@@ -2045,6 +2047,18 @@ class Engine:
         self._engine_tracer.record(
             name, self._engine_trace_id, self._unix_ns(t0), self._unix_ns(t1),
             attrs=attrs,
+        )
+
+    def _trace_gap(self, cause: str, t0_ns: int, t1_ns: int, waiting: int,
+                   admissions: int, request_ids: list) -> None:
+        """The ledger's ``on_gap``: each decode gap it books is one completed
+        ``engine.decode.gap`` span of the engine lane, beside the windows."""
+        self._engine_tracer.record(
+            "engine.decode.gap", self._engine_trace_id,
+            t0_ns + self._unix_offset_ns, t1_ns + self._unix_offset_ns,
+            attrs={"cause": cause, "waiting_slots": waiting,
+                   "admissions": admissions,
+                   "request_ids": ",".join(str(r) for r in request_ids)},
         )
 
     def _unix_ns(self, t: float) -> int:
@@ -3171,11 +3185,14 @@ class Engine:
                     tokens, lengths, temps, topks, topps, sub,
                 )
         t0 = ph.t_start
+        self._sync_inflight = True
         with self._sched.phase("decode_retire") as ph:
             # one transfer for the whole [S, k] block (same rationale as
             # decode); a spec round is synchronous  # kvmini: sync-ok
             emit_host = np.asarray(jax.device_get(emit))
+            self._sync_inflight = False
         now = ph.t_end
+        self._sched.gap_open()
         self.stats["busy_s"] += now - t0
         self._note_sweep(t0, now)
         self.stats["spec_rounds"] += 1
@@ -3193,6 +3210,7 @@ class Engine:
                 # accepted drafts = emitted minus the bonus token
                 self.stats["spec_accepted"] += max(n_emitted - 1, 0)
         self._observe_phase("emit", ph.seconds)
+        self._sched.gap_waiting(len(self._decode_active()))
         self._trace_engine_span(
             "engine.decode.window", t0, now,
             attrs={"chunk": k, "slots": len(active), "mode": "spec"},
@@ -3382,9 +3400,6 @@ class Engine:
                         self._counts, pres, freqs, **lkw,
                     )
             t0 = ph.t_start
-            if self._bubble_anchor:
-                self.stats["bubble_s"] += max(t0 - self._bubble_anchor, 0.0)
-                self._bubble_anchor = 0.0
             self._tokens_dev = next_toks
             self._tokens_dev_slots = frozenset(active)
             self._inflight.append({
@@ -3417,7 +3432,7 @@ class Engine:
         in-flight results of a cancelled request must never reach its
         stream. When every slot finished, any younger in-flight sweep is
         pure garbage: drop it and rewind the rng split it consumed."""
-        rec = self._inflight.pop(0)
+        rec = self._inflight[0]
         # the device_get alone: what bench/trace_reduce.py names gaps by
         with self._sched.phase("decode_retire") as ph:
             # ONE host transfer for the whole chunk block — per-element
@@ -3427,7 +3442,15 @@ class Engine:
             toks_h, lps_h, tids_h, tlps_h, *routed = (
                 np.asarray(a) for a in jax.device_get(rec["ys"])
             )
+            # in flight until its tokens are on the host: the wait for them
+            # is never the ledger's ``exposed`` time
+            self._inflight.pop(0)
         t_ready = ph.t_end
+        # the last sweep in flight is read back: the slots that go on
+        # decoding once it is emitted wait from here to the next dispatch
+        drained = not self._inflight
+        if drained:
+            self._sched.gap_open()
         with self._sched.phase("emit") as ph:
             self.stats["busy_s"] += t_ready - max(
                 rec["t_dispatch"], self._t_last_ready
@@ -3472,8 +3495,10 @@ class Engine:
             # emission ran while the device computed the next sweep — the
             # host time the synchronous loop would have serialized
             self.stats["host_overlap_s"] += ph.seconds
-        any_active = bool(self._decode_active())
-        if not any_active and self._inflight:
+        active = self._decode_active()
+        if drained:
+            self._sched.gap_waiting(len(active))
+        if not active and self._inflight:
             # every decode slot finished: younger sweeps computed only garbage.
             # Rewind to the oldest dropped sweep's pre-dispatch rng (their
             # counts/KV pollution sits in freed rows, reset at admission).
@@ -3481,9 +3506,6 @@ class Engine:
             self._inflight.clear()
             self._pending_steps = 0
             self._tokens_dev = None
-        self._bubble_anchor = (
-            t_ready if (any_active and not self._inflight) else 0.0
-        )
 
     def _book_routed(self, routed: list, live: int, steps: int) -> None:
         """A retired sweep's expert-block counts: ``routed`` holds the
@@ -3590,18 +3612,18 @@ class Engine:
                     jnp.asarray(mask), jnp.asarray(use_mask), **lkw,
                 )
         t0 = ph.t_start
-        if self._bubble_anchor:
-            self.stats["bubble_s"] += max(t0 - self._bubble_anchor, 0.0)
-            self._bubble_anchor = 0.0
         self._tokens_dev = next_toks
         self._tokens_dev_slots = frozenset(active)
+        self._sync_inflight = True
         with self._sched.phase("decode_retire") as ph:
             toks_h, lps_h, tids_h, tlps_h, *routed = (
                 # the constrained path is synchronous by design: the next
                 # mask depends on the byte just emitted  # kvmini: sync-ok
                 np.asarray(a) for a in jax.device_get(ys)
             )
+            self._sync_inflight = False
         now = ph.t_end
+        self._sched.gap_open()
         self.stats["busy_s"] += now - t0
         self._t_last_ready = now
         self._note_sweep(t0, now)
@@ -3625,8 +3647,7 @@ class Engine:
             "engine.decode.window", t0, now,
             attrs={"chunk": 1, "slots": len(active), "mode": "masked"},
         )
-        if self._decode_active():
-            self._bubble_anchor = now
+        self._sched.gap_waiting(len(self._decode_active()))
 
     def _fail_all(self, exc: BaseException) -> None:
         """Push an error 'done' to every live/pending handle so no client
@@ -3768,7 +3789,11 @@ class Engine:
         self._advance_prefills(on_decision)
         if self._decode_active():
             self._sweep_phase(on_decision)
-        elif not admitted:
+            return
+        # whoever waited in an open decode gap is gone (finished at its
+        # first token, cancelled): no dispatch will end it
+        self._sched.gap_close()
+        if not admitted:
             if self._inflight:
                 # every live slot was cancelled this iteration: whatever is
                 # still in flight is garbage — retire (emissions all skip
@@ -3982,6 +4007,9 @@ class Engine:
 
         print(f"engine: recovering from fault: {reason}", file=sys.stderr)
         self._inflight.clear()
+        self._sync_inflight = False
+        # every slot fails below: a wedge is nobody's decode gap
+        self._sched.gap_waiting(0)
         self._pending_steps = 0
         self._tokens_dev = None
         self._tokens_dev_slots = frozenset()
@@ -4132,6 +4160,15 @@ class Engine:
         s["sched_exposed_s"] = sched["exposed"]
         s["sched_slow_s"] = sched["slow"]
         s["sched_spans"] = sched["spans"]
+        # the decode gaps (docs/TRACING.md): what the slots that were
+        # decoding lost between a drained readback and the next dispatch
+        s["sched_gaps"] = sched["gaps"]
+        s["sched_gap_s"] = sched["gap_seconds"]
+        s["sched_gap_slot_s"] = sched["gap_slot_seconds"]
+        s["sched_admit_gap_s"] = sched["admit_gap_seconds"]
+        s["sched_admit_gap_admissions"] = sched["admit_gap_admissions"]
+        # the older name of the same time: every gap, whatever caused it
+        s["bubble_s"] = sum(sched["gap_seconds"].values())
         # Derived KV gauges (occupancy, fragmentation, retained fraction,
         # hit-depth percentiles) come from ONE consistent scheduler-thread
         # pass (_kv_admin_snapshot): a ratio built from independent
@@ -4238,7 +4275,8 @@ class Engine:
         whatever the engine thread does now, the device waits for it.
         O(1): the ledger asks at every phase boundary — so from inside a
         phase, on the scheduler thread that owns both lists."""
-        return not self._inflight and (
+        # kvmini: thread-ok — asked by the ledger, on the scheduler thread
+        return not self._inflight and not self._sync_inflight and (
             len(self._free) < self.ecfg.max_slots
             or not self._pending.empty()
         )

@@ -1645,6 +1645,35 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"kvmini_tpu_sched_spans_total{{phase=\"{p}\"}} {s['sched_spans'][p]}"
             for p in s["sched_spans"]
         ]
+        # the decode gaps the ledger booked (docs/TRACING.md "The decode
+        # gap"): by cause; the slot-time they cost; an admission gap's time
+        # by phase (a partition of gap_seconds{cause="admission"}) and the
+        # admissions inside. kvmini_tpu_bubble_seconds_total above is the
+        # sum of gap_seconds over the causes
+        lines.append("# TYPE kvmini_tpu_sched_gaps_total counter")
+        lines += [
+            f"kvmini_tpu_sched_gaps_total{{cause=\"{c}\"}} {s['sched_gaps'][c]}"
+            for c in s["sched_gaps"]
+        ]
+        lines.append("# TYPE kvmini_tpu_sched_gap_seconds_total counter")
+        lines += [
+            f"kvmini_tpu_sched_gap_seconds_total{{cause=\"{c}\"}} "
+            f"{s['sched_gap_s'][c]:.6f}"
+            for c in s["sched_gap_s"]
+        ]
+        lines.append("# TYPE kvmini_tpu_sched_admit_gap_seconds_total counter")
+        lines += [
+            f"kvmini_tpu_sched_admit_gap_seconds_total{{phase=\"{p}\"}} "
+            f"{s['sched_admit_gap_s'][p]:.6f}"
+            for p in s["sched_admit_gap_s"]
+        ]
+        lines += [
+            "# TYPE kvmini_tpu_sched_gap_slot_seconds_total counter",
+            f"kvmini_tpu_sched_gap_slot_seconds_total {s['sched_gap_slot_s']:.6f}",
+            "# TYPE kvmini_tpu_sched_admit_gap_admissions_total counter",
+            "kvmini_tpu_sched_admit_gap_admissions_total "
+            f"{s['sched_admit_gap_admissions']}",
+        ]
         # per-phase latency histograms (docs/TRACING.md): queue / prefill /
         # decode / emit durations the engine observes at phase transitions
         from kserve_vllm_mini_tpu.runtime.tracing import render_phase_histograms

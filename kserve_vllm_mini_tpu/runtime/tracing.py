@@ -292,6 +292,10 @@ SCHED_PHASES = (
 
 _SPAN_NAMES = {p: "kvmini." + p for p in SCHED_PHASES}
 
+# what a decode gap is booked under when it closes (docs/TRACING.md "The
+# decode gap"): by the phases entered inside it
+SCHED_GAP_CAUSES = ("admission", "prefill_piece", "sweep")
+
 # an entry is a stall, not work, once its own time passes twice the wall of
 # a decode sweep (``sweep_s``: the engine's rolling dispatch -> retire time,
 # which scales with the model, ``decode_chunk`` and the pipeline's depth; the
@@ -341,6 +345,70 @@ class _SchedPhase:
         return self._t1_ns * 1e-9
 
 
+class _Gap:
+    """One open decode gap: the slots that wait through it (0 until the
+    engine has emitted the sweep whose readback opened it; a gap nobody
+    waits through is not booked), the self time and the entries of each
+    phase inside it, the requests admitted, and where it ended if the engine
+    said so and no dispatch did."""
+
+    __slots__ = ("t0_ns", "end_ns", "waiting", "ns", "entered", "request_ids")
+
+    def __init__(self, t0_ns: int) -> None:
+        self.t0_ns = t0_ns
+        self.end_ns: Optional[int] = None
+        self.waiting = 0
+        self.ns: dict[str, int] = {}
+        self.entered: dict[str, int] = {}
+        self.request_ids: list[Any] = []
+
+    @property
+    def cause(self) -> str:
+        # an admission began (``admit``) or was completed (``first_token``:
+        # the last piece of a chunked prompt, a handoff) inside the gap
+        if "admit" in self.entered or "first_token" in self.entered:
+            return "admission"
+        return "prefill_piece" if "prefill" in self.entered else "sweep"
+
+    @property
+    def admissions(self) -> int:
+        return self.entered.get("first_token", 0)
+
+
+class _GapBook:
+    """The decode gaps booked so far: their number and wall time by cause,
+    the slot-time they cost, and of the ``admission`` gaps the self time by
+    phase and the first tokens sampled inside."""
+
+    __slots__ = ("gaps", "ns", "slot_ns", "admit_ns", "admissions")
+
+    def __init__(self) -> None:
+        self.gaps = dict.fromkeys(SCHED_GAP_CAUSES, 0)
+        self.ns = dict.fromkeys(SCHED_GAP_CAUSES, 0)
+        self.slot_ns = 0
+        self.admit_ns = dict.fromkeys(SCHED_PHASES, 0)
+        self.admissions = 0
+
+    def add(self, gap: _Gap) -> None:
+        wall, cause = sum(gap.ns.values()), gap.cause
+        self.gaps[cause] += 1
+        self.ns[cause] += wall
+        self.slot_ns += wall * gap.waiting
+        if cause == "admission":
+            for name, took in gap.ns.items():
+                self.admit_ns[name] += took
+            self.admissions += gap.admissions
+
+    def snapshot(self) -> dict[str, Any]:
+        return {
+            "gaps": dict(self.gaps),
+            "gap_seconds": {c: v * 1e-9 for c, v in self.ns.items()},
+            "gap_slot_seconds": self.slot_ns * 1e-9,
+            "admit_gap_seconds": {p: v * 1e-9 for p, v in self.admit_ns.items()},
+            "admit_gap_admissions": self.admissions,
+        }
+
+
 class SchedLedger:
     """Where the scheduler thread's time goes, kept in one place.
 
@@ -360,6 +428,24 @@ class SchedLedger:
     decode sweep in flight, so the device had nothing queued), ``spans``
     (entries) and ``slow`` (self time of the entries whose own time passed
     ``SCHED_SLOW_SWEEPS`` x ``sweep_s()``, at least ``SCHED_SLOW_FLOOR_NS``).
+
+    **The decode gap**: what the slots that were decoding lose between the
+    readback of the last sweep in flight and the next dispatch. The engine
+    says ``gap_open()`` where such a readback ends, ``gap_waiting(n)`` once
+    that sweep's tokens are emitted (0: nobody waits, no gap) and
+    ``gap_close()`` where the waiting slots are gone; the next
+    ``decode_dispatch`` entered closes the gap by itself. While one is open
+    every stretch charged to a phase is charged to the gap too, so a gap's
+    wall time is the sum of its phases'. It is booked once, under the cause
+    its phases give it: ``admission`` if an admission began (``admit``) or
+    was completed (``first_token``) inside it, else ``prefill_piece`` if a
+    ``prefill`` was entered, else ``sweep``. An ``admission`` gap also books
+    its phases' self times and its ``first_token`` entries. The dispatch
+    that closes a gap carries ``gap_ms``, ``cause``, ``waiting`` and
+    ``admissions`` as its span's arguments; ``on_gap`` is handed each booked
+    gap (cause, start and end in ``now_ns``, waiting, admissions, the
+    ``request_id`` of every ``admit`` entered).
+
     Written by the one thread that enters phases; a snapshot from another
     thread is at worst one stretch stale."""
 
@@ -368,10 +454,12 @@ class SchedLedger:
         annotate: Optional[Callable[..., Any]] = None,
         exposed: Optional[Callable[[], bool]] = None,
         sweep_s: Optional[Callable[[], float]] = None,
+        on_gap: Optional[Callable[..., None]] = None,
     ) -> None:
         self._annotate = annotate or _no_annotation
         self._exposed = exposed or (lambda: False)
         self._sweep_s = sweep_s or (lambda: 0.0)
+        self._on_gap = on_gap
         self._stack: list[_SchedPhase] = []
         self._mark_ns = 0
         self._mark_exposed = False
@@ -379,15 +467,61 @@ class SchedLedger:
         self._exposed_ns = dict.fromkeys(SCHED_PHASES, 0)
         self._slow_ns = dict.fromkeys(SCHED_PHASES, 0)
         self._spans = dict.fromkeys(SCHED_PHASES, 0)
+        self._gap: Optional[_Gap] = None
+        self._gap_book = _GapBook()
 
     def phase(self, name: str, **args: Any) -> _SchedPhase:
         """``args`` become the span's arguments: only for the rare phases
         (a few a second) — the annotation formats them whether or not a
-        profiler is listening."""
+        profiler is listening. The ``decode_dispatch`` that closes a gap is
+        such a one, and is given the gap's."""
+        gap = self._gap
+        if gap is not None:
+            if name == "decode_dispatch" and gap.waiting:
+                args = {"gap_ms": round((now_ns() - gap.t0_ns) * 1e-6, 3),
+                        "cause": gap.cause, "waiting": gap.waiting,
+                        "admissions": gap.admissions}
+            elif name == "admit":
+                gap.request_ids.append(args.get("request_id"))
         return _SchedPhase(self, name, self._annotate(_SPAN_NAMES[name], **args))
+
+    def gap_open(self) -> None:
+        """A sweep's tokens are on the host and no other sweep is in flight:
+        a gap begins where that readback ended (the stretch in progress
+        began there), if ``gap_waiting`` then says somebody waits. None is
+        open at such a moment: the dispatch of the sweep just read back
+        closed the one before."""
+        # kvmini: thread-ok — the engine asks from inside a phase, on the one thread that enters them
+        self._gap = _Gap(self._mark_ns)
+
+    def gap_waiting(self, slots: int) -> None:
+        """The sweep's tokens are emitted and ``slots`` slots go on decoding:
+        they wait through the gap. With none there is no gap."""
+        if self._gap is not None:
+            self._gap.waiting = slots
+
+    def gap_close(self) -> None:
+        """The slots that waited are gone (finished, cancelled): the gap
+        ends where the last stretch did, with no dispatch; the next phase
+        boundary books it."""
+        if self._gap is not None and self._gap.end_ns is None:
+            self._gap.end_ns = self._mark_ns
+
+    def _book_gap(self, gap: _Gap, end_ns: int) -> None:
+        self._gap = None
+        if not gap.waiting:
+            return      # nobody waited through it: no gap
+        self._gap_book.add(gap)
+        if self._on_gap is not None:
+            self._on_gap(gap.cause, gap.t0_ns, end_ns, gap.waiting,
+                         gap.admissions, gap.request_ids)
 
     def _switch(self, ph: _SchedPhase, entering: bool) -> int:
         now = now_ns()
+        gap = self._gap
+        if gap is not None and gap.end_ns is not None:
+            self._book_gap(gap, gap.end_ns)
+            gap = None
         if self._stack:
             top = self._stack[-1]
             took = now - self._mark_ns
@@ -395,9 +529,16 @@ class SchedLedger:
             self._self_ns[top.name] += took
             if self._mark_exposed:
                 self._exposed_ns[top.name] += took
+            if gap is not None:
+                gap.ns[top.name] = gap.ns.get(top.name, 0) + took
         if entering:
             self._stack.append(ph)
             self._spans[ph.name] += 1
+            if gap is not None:
+                if ph.name == "decode_dispatch":
+                    self._book_gap(gap, now)
+                else:
+                    gap.entered[ph.name] = gap.entered.get(ph.name, 0) + 1
         else:
             self._stack.pop()
             # the sweep wall is asked only about the few entries over the floor
@@ -410,12 +551,16 @@ class SchedLedger:
 
     def snapshot(self) -> dict[str, dict[str, float]]:
         """``seconds`` / ``exposed`` / ``slow`` in seconds and ``spans`` as
-        counts, each by phase, as /metrics exports them."""
+        counts, each by phase, and the decode gaps booked so far (``gaps``
+        and ``gap_seconds`` by cause, ``gap_slot_seconds``, an admission
+        gap's ``admit_gap_seconds`` by phase and ``admit_gap_admissions``),
+        as /metrics exports them."""
         return {
             "seconds": {p: v * 1e-9 for p, v in self._self_ns.items()},
             "exposed": {p: v * 1e-9 for p, v in self._exposed_ns.items()},
             "slow": {p: v * 1e-9 for p, v in self._slow_ns.items()},
             "spans": dict(self._spans),
+            **self._gap_book.snapshot(),
         }
 
 

@@ -23,6 +23,7 @@ from aiohttp import web
 
 from kserve_vllm_mini_tpu.runtime.tracing import (
     PHASES,
+    SCHED_PHASES,
     PhaseHistogram,
     SpanRecorder,
     parse_traceparent,
@@ -368,6 +369,21 @@ def make_app(
         "kvmini_tpu_pipelined_sweeps_total": 40.0,
         "kvmini_tpu_host_overlap_seconds_total": 0.25,
         "kvmini_tpu_bubble_seconds_total": 0.01,
+        # the scheduler ledger's decode gaps (docs/TRACING.md): the bubble
+        # above is gap_seconds summed over the causes, an admission gap's
+        # phases add up to its cause's seconds
+        'kvmini_tpu_sched_gaps_total{cause="admission"}': 2.0,
+        'kvmini_tpu_sched_gaps_total{cause="prefill_piece"}': 0.0,
+        'kvmini_tpu_sched_gaps_total{cause="sweep"}': 1.0,
+        'kvmini_tpu_sched_gap_seconds_total{cause="admission"}': 0.008,
+        'kvmini_tpu_sched_gap_seconds_total{cause="prefill_piece"}': 0.0,
+        'kvmini_tpu_sched_gap_seconds_total{cause="sweep"}': 0.002,
+        "kvmini_tpu_sched_gap_slot_seconds_total": 0.012,
+        **{f'kvmini_tpu_sched_admit_gap_seconds_total{{phase="{p}"}}': v
+           for p, v in {**dict.fromkeys(SCHED_PHASES, 0.0), "prefill": 0.004,
+                        "first_token": 0.002, "decode_prepare": 0.0008,
+                        "emit": 0.0005, "admit": 0.0004, "loop": 0.0003}.items()},
+        "kvmini_tpu_sched_admit_gap_admissions_total": 2.0,
         # chunked-prefill rail (docs/TROUBLESHOOTING.md)
         "kvmini_tpu_prefills_total": 4.0,
         "kvmini_tpu_prefill_chunks_total": 6.0,
@@ -430,10 +446,13 @@ def make_app(
         vals = dict(pipe)
         if metrics_script is not None:
             vals.update(metrics_script(time.time() - t_app_start))
-        lines = []
+        lines, typed = [], set()
         for name, value in vals.items():
-            kind = "counter" if name.endswith("_total") else "gauge"
-            lines.append(f"# TYPE {name} {kind}")
+            family = name.split("{", 1)[0]      # one TYPE line a labelled family
+            if family not in typed:
+                typed.add(family)
+                kind = "counter" if family.endswith("_total") else "gauge"
+                lines.append(f"# TYPE {family} {kind}")
             lines.append(f"{name} {value}")
         # phase-latency histograms, same renderer as runtime/server.py
         lines += render_phase_histograms(phase_hist)

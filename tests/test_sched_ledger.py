@@ -223,6 +223,145 @@ def test_an_exception_closes_the_phase(clock):
     assert s["seconds"]["decode_prepare"] == pytest.approx(0.001)
 
 
+# -- the decode gap: the arithmetic ------------------------------------------
+
+
+class Recorded:
+    """An annotation factory that keeps what it was handed."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **args):
+        self.seen.append((name, args))
+        return rt_tracing._NO_SPAN
+
+
+def sweep(led, clock, retire_ms=10.0, waiting=None, emit_ms=0.25):
+    """One synchronous sweep as the engine enters it: dispatch, readback,
+    then (``waiting`` given) the gap's opening around the emission."""
+    with led.phase("decode_prepare"):
+        clock.tick(0.5)
+        with led.phase("decode_dispatch"):
+            clock.tick(0.7)
+    with led.phase("decode_retire"):
+        clock.tick(retire_ms)
+    if waiting is not None:
+        led.gap_open()
+    with led.phase("emit"):
+        clock.tick(emit_ms)
+    if waiting is not None:
+        led.gap_waiting(waiting)
+
+
+def test_an_admission_gap_is_partitioned_by_phase(clock):
+    """From the readback's end to the next dispatch's entry: every stretch
+    inside is charged to the gap and to its phase, so the phases of an
+    admission gap add up to its wall time, and nothing outside it is counted."""
+    note, spans = [], Recorded()
+    led = SchedLedger(annotate=spans, on_gap=lambda *a: note.append(a))
+    with led.phase("loop"):
+        clock.tick(1)
+        sweep(led, clock, waiting=3)            # the gap opens: emit 0.25
+        t_open = clock.ns - int(0.25 * MS)
+        clock.tick(0.1)                         # loop
+    with led.phase("loop"):
+        clock.tick(0.4)
+        with led.phase("admit", request_id="r7", prompt_tokens=40):
+            clock.tick(0.2)
+            with led.phase("prefill", request_id="r7", bucket=64):
+                clock.tick(21)
+            with led.phase("first_token"):
+                clock.tick(6)
+        with led.phase("decode_prepare"):
+            clock.tick(2.5)
+            t_close = clock.ns
+            with led.phase("decode_dispatch"):  # the gap closes at its entry
+                clock.tick(0.7)
+        clock.tick(3)
+    s = led.snapshot()
+    assert s["gaps"] == {"admission": 1, "prefill_piece": 0, "sweep": 0}
+    assert s["gap_seconds"]["admission"] == pytest.approx(0.03045)
+    assert s["gap_seconds"]["admission"] == pytest.approx((t_close - t_open) * 1e-9)
+    assert s["gap_slot_seconds"] == pytest.approx(3 * 0.03045)
+    assert s["admit_gap_admissions"] == 1
+    want = {"emit": 0.25, "loop": 0.5, "admit": 0.2, "prefill": 21.0,
+            "first_token": 6.0, "decode_prepare": 2.5}
+    for p in SCHED_PHASES:
+        assert s["admit_gap_seconds"][p] == pytest.approx(want.get(p, 0.0) / 1e3)
+    assert sum(s["admit_gap_seconds"].values()) == pytest.approx(
+        s["gap_seconds"]["admission"], rel=1e-12)
+    assert note == [("admission", t_open, t_close, 3, 1, ["r7"])]
+    # the dispatch that closed the gap says so; the steady one is bare
+    dispatches = [a for n, a in spans.seen if n == "kvmini.decode_dispatch"]
+    assert dispatches == [{}, {"gap_ms": 30.45, "cause": "admission",
+                               "waiting": 3, "admissions": 1}]
+    assert all(not a for n, a in spans.seen
+               if n in ("kvmini.decode_retire", "kvmini.emit", "kvmini.loop"))
+
+
+@pytest.mark.parametrize("inside,cause,admissions", [
+    ((), "sweep", 0),
+    (("prefill",), "prefill_piece", 0),
+    (("admit", "prefill", "first_token"), "admission", 1),
+    (("admit", "prefill"), "admission", 0),         # a chunked prompt's first piece
+    (("prefill", "first_token"), "admission", 1),   # and its last: the admission completes
+    (("first_token",), "admission", 1),
+    (("admit", "first_token", "admit", "first_token"), "admission", 2),
+])
+def test_a_gap_is_booked_under_the_cause_its_phases_give(clock, inside, cause, admissions):
+    led = SchedLedger()
+    with led.phase("loop"):
+        sweep(led, clock, waiting=2)
+        for name in inside:
+            with led.phase(name):
+                clock.tick(1)
+        sweep(led, clock)
+    s = led.snapshot()
+    assert s["gaps"] == {c: int(c == cause) for c in rt_tracing.SCHED_GAP_CAUSES}
+    assert s["gap_seconds"][cause] == pytest.approx((0.25 + len(inside) + 0.5) / 1e3)
+    assert s["admit_gap_admissions"] == admissions
+    # only an admission gap is split by phase
+    in_phases = sum(s["admit_gap_seconds"].values())
+    assert in_phases == pytest.approx(s["gap_seconds"]["admission"])
+
+
+def test_no_gap_where_nobody_waits_and_none_while_a_sweep_is_in_flight(clock):
+    spans = Recorded()
+    led = SchedLedger(annotate=spans)
+    with led.phase("loop"):
+        sweep(led, clock, waiting=0)        # the last slot finished in this emission
+        clock.tick(5)
+        sweep(led, clock)                   # pipelined: the engine opens nothing
+        sweep(led, clock)
+        led.gap_open()                      # the engine never says who waited
+        sweep(led, clock)
+    s = led.snapshot()
+    assert sum(s["gaps"].values()) == 0 and s["gap_slot_seconds"] == 0.0
+    assert all(not a for _, a in spans.seen)
+
+
+def test_a_gap_whose_slots_are_gone_closes_without_a_dispatch(clock):
+    note, spans = [], Recorded()
+    led = SchedLedger(annotate=spans, on_gap=lambda *a: note.append(a))
+    with led.phase("loop"):
+        sweep(led, clock, waiting=1)
+        clock.tick(0.3)
+    with led.phase("loop"):
+        clock.tick(0.2)                     # the cancel pass finishes the slot
+        led.gap_close()
+        led.gap_close()                     # nothing open: nothing booked
+        clock.tick(4)
+        with led.phase("idle"):
+            clock.tick(20)
+    s = led.snapshot()
+    assert s["gaps"]["sweep"] == 1
+    # it ends where the last stretch did: the emission and the first loop's tail
+    assert s["gap_seconds"]["sweep"] == pytest.approx(0.00055)
+    assert len(note) == 1 and note[0][0] == "sweep" and note[0][3:] == (1, 0, [])
+    assert all(not a for _, a in spans.seen)
+
+
 # -- the books of a serving engine --------------------------------------------
 
 jax = pytest.importorskip("jax")
@@ -269,11 +408,13 @@ def params():
     return init_params(jax.random.PRNGKey(0), CFG)
 
 
-def serve(params, requests, drafter=False, **ecfg):
+def serve(params, requests, drafter=False, drive=None, **ecfg):
     """A tiny engine, its ledger handed a recording annotation factory,
     serving ``requests`` to the end. Returns the engine (stopped), the spans
     the factory saw as (name, args), and the engine thread's wall time as
-    the spans' own clock reads it: first ``loop`` entered -> last one left."""
+    the spans' own clock reads it: first ``loop`` entered -> last one left.
+    ``drive(engine, handles)`` runs while it serves (more callers, a cancel)
+    and waits for the requests itself."""
     eng = Engine(
         params, CFG,
         EngineConfig(max_slots=2, max_seq_len=128, max_prefill_len=64,
@@ -296,19 +437,27 @@ def serve(params, requests, drafter=False, **ecfg):
                 loops.append(time.perf_counter())
 
     eng._sched = SchedLedger(annotate=Span, exposed=eng._host_exposed,
-                             sweep_s=lambda: eng._sweep_ema_s)
+                             sweep_s=lambda: eng._sweep_ema_s, on_gap=eng._trace_gap)
     # queued before the thread starts: every admission finds work live (a
     # request waiting or a slot occupied), whatever the threads' timing
     handles = [eng.submit(r) for r in requests]
     eng.start()
     try:
-        for h in handles:
-            while h.events.get(timeout=120)[0] != "done":
-                pass
+        if drive is not None:
+            drive(eng, handles)     # it waits for what it drives
+        else:
+            for h in handles:
+                wait_done(h)
         time.sleep(0.05)            # a few idle waits
     finally:
         eng.stop()
+    eng.handles = handles
     return eng, spans, loops[-1] - loops[0]
+
+
+def wait_done(handle):
+    while handle.events.get(timeout=120)[0] != "done":
+        pass
 
 
 @pytest.fixture(scope="module")
@@ -355,9 +504,14 @@ def test_span_args_only_on_the_rare_phases(plain):
     by_name = {}
     for name, args in plain["spans"]:
         by_name.setdefault(name, []).append(args)
-    assert all(not a for n in ("loop", "idle", "decode_prepare", "decode_dispatch",
+    assert all(not a for n in ("loop", "idle", "decode_prepare",
                                "decode_retire", "emit", "first_token")
                for a in by_name[f"kvmini.{n}"])
+    # a dispatch is bare unless it closes a decode gap (here: after the
+    # admissions beside a decoding slot and between the masked sweeps)
+    closing = [a for a in by_name["kvmini.decode_dispatch"] if a]
+    assert 0 < len(closing) < len(by_name["kvmini.decode_dispatch"])
+    assert all(set(a) == {"gap_ms", "cause", "waiting", "admissions"} for a in closing)
     prefill = {a["request_id"]: a for a in by_name["kvmini.prefill"]}
     assert prefill["b"] == {"request_id": "b", "prompt_tokens": 37, "bucket": 64, "offset": 0}
     assert prefill["a"]["bucket"] == 16 and prefill["a"]["prompt_tokens"] == 5
@@ -507,6 +661,197 @@ def test_multihost_drivers_keep_the_partition(params, monkeypatch):
     assert follower.stats["decode_tokens"] == primary.stats["decode_tokens"] > 0
 
 
+# -- the decode gap on a serving engine ---------------------------------------
+
+
+def gap_spans(eng):
+    """The ``engine.decode.gap`` spans of /traces as (t0, t1, attributes), in
+    seconds on the engine's monotonic clock."""
+    out = []
+    for scope in eng.traces_otlp()["resourceSpans"][0]["scopeSpans"]:
+        for sp in scope["spans"]:
+            if sp["name"] == "engine.decode.gap":
+                attrs = {a["key"]: next(iter(a["value"].values())) for a in sp["attributes"]}
+                out.append(((int(sp["startTimeUnixNano"]) - eng._unix_offset_ns) * 1e-9,
+                            (int(sp["endTimeUnixNano"]) - eng._unix_offset_ns) * 1e-9, attrs))
+    return out
+
+
+def closing_dispatches(spans):
+    return [a for n, a in spans if n == "kvmini.decode_dispatch" and a]
+
+
+@pytest.fixture(scope="module", params=[True, False], ids=["pipelined", "synchronous"])
+def closed_loop(params, request):
+    """2 x slots callers, each sending its next request when the last one is
+    answered: a freed slot always has a request waiting, as in the
+    benchmark's saturated cells."""
+    import threading
+
+    lengths = [(6, 9, 5), (20, 7, 11), (9, 12, 6), (12, 5, 8)]
+    later = []
+
+    def caller(eng, first, mine):
+        wait_done(first)
+        for k, n in enumerate(mine[1:]):
+            h = eng.submit(GenRequest(prompt_tokens=[5, 3, 2 + k], max_new_tokens=n,
+                                      request_id=f"{first.request.request_id}.{k + 1}"))
+            later.append(h)
+            wait_done(h)
+
+    def drive(eng, handles):
+        threads = [threading.Thread(target=caller, args=(eng, h, mine))
+                   for h, mine in zip(handles, lengths)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=240)
+
+    eng, spans, wall = serve(
+        params, [GenRequest(prompt_tokens=[3, 1, 4, 1 + i], max_new_tokens=mine[0],
+                            request_id=f"c{i}") for i, mine in enumerate(lengths)],
+        drive=drive, decode_pipeline=request.param)
+    return {"eng": eng, "spans": spans, "stats": eng.snapshot_stats(),
+            "handles": eng.handles + later}
+
+
+def test_admission_gaps_are_partitioned_and_the_bubble_is_their_sum(closed_loop):
+    s = closed_loop["stats"]
+    assert s["sched_gaps"]["admission"] >= 4
+    in_phases = sum(s["sched_admit_gap_s"].values())
+    assert in_phases == pytest.approx(s["sched_gap_s"]["admission"], rel=0.02)
+    assert s["sched_admit_gap_s"]["prefill"] > 0 and s["sched_admit_gap_s"]["first_token"] > 0
+    # the wait for a sweep's tokens is never part of a gap, an idle wait neither
+    assert s["sched_admit_gap_s"]["decode_retire"] == s["sched_admit_gap_s"]["idle"] == 0.0
+    assert s["bubble_s"] == pytest.approx(sum(s["sched_gap_s"].values()), rel=1e-12)
+    assert 0 < s["bubble_s"] < sum(s["sched_s"].values())
+    # 2 slots: one waits while the other is admitted into, both between two
+    # synchronous sweeps
+    assert s["bubble_s"] * (1 - 1e-9) <= s["sched_gap_slot_s"] <= 2 * s["bubble_s"]
+    if closed_loop["eng"].ecfg.decode_pipeline:
+        assert s["sched_gap_slot_s"] == pytest.approx(s["bubble_s"], rel=1e-9)
+    for p in SCHED_PHASES:
+        assert s["sched_admit_gap_s"][p] <= s["sched_s"][p] + 1e-9
+
+
+def test_one_admission_gap_an_admission_or_one_for_two_admitted_together(closed_loop):
+    s, eng = closed_loop["stats"], closed_loop["eng"]
+    from kserve_vllm_mini_tpu.core.schema import validate_traces
+
+    assert validate_traces(eng.traces_otlp()) == []
+    gaps = gap_spans(eng)
+    assert len(gaps) == sum(s["sched_gaps"].values())
+    admission = [g for g in gaps if g[2]["cause"] == "admission"]
+    assert len(admission) == s["sched_gaps"]["admission"] <= s["sched_admit_gap_admissions"]
+    assert s["sched_admit_gap_admissions"] < s["sched_spans"]["first_token"] == 12
+    # the first tokens sampled inside a gap, counted from the requests' own
+    # stamps, are the admissions the ledger booked, and the spans name them
+    inside = {h.request.request_id for h in closed_loop["handles"]
+              if any(t0 <= h.t_first_token <= t1 for t0, t1, _ in admission)}
+    assert len(inside) == s["sched_admit_gap_admissions"]
+    named = [r for g in admission for r in g[2]["request_ids"].split(",")]
+    assert sorted(named) == sorted(inside)
+    for _, _, attrs in admission:
+        assert int(attrs["admissions"]) == len(attrs["request_ids"].split(",")) >= 1
+        assert int(attrs["waiting_slots"]) == 1
+    # every gap but one closed without a dispatch was said on the dispatch that closed it
+    said = closing_dispatches(closed_loop["spans"])
+    assert sum(a["admissions"] for a in said) == s["sched_admit_gap_admissions"]
+    assert [a["cause"] for a in said].count("admission") == s["sched_gaps"]["admission"]
+    assert all(set(a) == {"gap_ms", "cause", "waiting", "admissions"} for a in said)
+    assert sum(a["gap_ms"] for a in said) == pytest.approx(1e3 * s["bubble_s"], rel=0.02)
+
+
+def test_pipelined_sweeps_book_no_gap_and_synchronous_ones_each_their_own(closed_loop):
+    s = closed_loop["stats"]
+    if closed_loop["eng"].ecfg.decode_pipeline:
+        assert s["sched_gaps"]["sweep"] == 0
+        assert len(closing_dispatches(closed_loop["spans"])) < s["sched_spans"]["decode_dispatch"] / 3
+    else:
+        assert s["sched_gaps"]["sweep"] > s["sched_gaps"]["admission"]
+    assert s["sched_gaps"]["prefill_piece"] == 0
+
+
+def test_the_wait_for_a_sweeps_tokens_is_never_exposed(closed_loop, plain):
+    """A sweep is in flight until its tokens are on the host: the readback of
+    a drain's last sweep (before every admission) and of a synchronous sweep
+    is a wait for the device, while the host phases around it read as before."""
+    for s in (closed_loop["stats"], plain["stats"]):
+        assert s["sched_s"]["decode_retire"] > 0
+        assert s["sched_exposed_s"]["decode_retire"] == 0.0
+        # the emission after a drained readback, the admission, the next
+        # dispatch: exposed, as they were
+        for p in ("emit", "admit", "first_token", "decode_prepare", "decode_dispatch", "loop"):
+            assert s["sched_exposed_s"][p] > 0
+        assert s["sched_exposed_s"]["admit"] == pytest.approx(s["sched_s"]["admit"])
+        assert s["sched_exposed_s"]["first_token"] == pytest.approx(s["sched_s"]["first_token"])
+    # the masked sweeps of the plain fixture are synchronous: none exposed either
+    assert plain["stats"]["sched_spans"]["masked_sweep"] >= 2
+
+
+def test_every_synchronous_sweep_books_a_sweep_gap(params):
+    """``decode_pipeline`` off: each readback leaves nothing in flight, so each
+    sweep but the one that finishes the request opens a gap the next dispatch closes."""
+    eng, spans, _ = serve(params, [GenRequest(prompt_tokens=[3, 1, 4], max_new_tokens=9)],
+                          decode_pipeline=False)
+    s = eng.snapshot_stats()
+    assert s["sched_spans"]["decode_retire"] == 8
+    assert s["sched_gaps"] == {"admission": 0, "prefill_piece": 0, "sweep": 7}
+    said = closing_dispatches(spans)
+    assert [a["cause"] for a in said] == ["sweep"] * 7
+    assert all(a["waiting"] == 1 and a["admissions"] == 0 for a in said)
+    # gap + sweep + gap + ...: the thread's time between the first readback and the last
+    assert s["bubble_s"] < s["sched_s"]["decode_prepare"] + s["sched_s"]["emit"] + s["sched_s"]["loop"]
+
+
+def test_a_speculative_round_follows_the_same_rule(params):
+    eng, spans, _ = serve(params, [GenRequest(prompt_tokens=[3, 1, 4], max_new_tokens=12)],
+                          drafter=True, spec_tokens=2)
+    s = eng.snapshot_stats()
+    assert s["sched_gaps"]["sweep"] == s["spec_rounds"] - 1 > 0
+    assert s["sched_exposed_s"]["decode_retire"] == 0.0
+    assert [a["cause"] for a in closing_dispatches(spans)] == ["sweep"] * s["sched_gaps"]["sweep"]
+
+
+def test_a_chunked_prompts_later_pieces_book_prefill_piece(params):
+    """Synchronous sweeps, a 59-token prompt in pieces of 16 beside a slot that
+    decodes: the first piece runs before any sweep, the second and third each
+    in a gap of their own, the last one's gap completes the admission."""
+    eng, spans, _ = serve(params, [
+        GenRequest(prompt_tokens=[3, 1, 4, 1, 5], max_new_tokens=30, request_id="a"),
+        GenRequest(prompt_tokens=list(range(1, 60)), max_new_tokens=5, request_id="b"),
+    ], decode_pipeline=False, prefill_chunk=16)
+    s = eng.snapshot_stats()
+    assert s["sched_spans"]["prefill"] == 5
+    assert s["sched_gaps"]["prefill_piece"] == 2 and s["sched_gaps"]["admission"] == 1
+    assert s["sched_admit_gap_admissions"] == 1
+    assert s["sched_gap_s"]["prefill_piece"] > 0
+    pieces = [a for a in closing_dispatches(spans) if a["cause"] != "sweep"]
+    assert [(a["cause"], a["admissions"]) for a in pieces] == [
+        ("prefill_piece", 0), ("prefill_piece", 0), ("admission", 1)]
+
+
+def test_a_gap_whose_slots_all_finish_closes_without_a_dispatch(params):
+    """A cancelled request is finished by the next iteration's cancel pass,
+    inside the gap its last readback opened: the gap is booked though no
+    dispatch follows it."""
+    def cancel_after_a_few_tokens(eng, handles):
+        seen = 0
+        while seen < 4:
+            seen += handles[0].events.get(timeout=120)[0] == "token"
+        eng.cancel(handles[0])
+        wait_done(handles[0])
+
+    eng, spans, _ = serve(params, [GenRequest(prompt_tokens=[3, 1, 4], max_new_tokens=100)],
+                          drive=cancel_after_a_few_tokens, decode_pipeline=False)
+    s = eng.snapshot_stats()
+    assert eng.handles[0].finish_reason == "stop" and len(eng.handles[0].tokens) < 100
+    sweeps = s["sched_spans"]["decode_retire"]
+    assert s["sched_spans"]["decode_dispatch"] == sweeps
+    assert s["sched_gaps"]["sweep"] == sweeps == len(gap_spans(eng))
+    assert len(closing_dispatches(spans)) == sweeps - 1
+
+
 # -- what the benchmark reads by name -----------------------------------------
 
 
@@ -556,3 +901,19 @@ def test_names_the_benchmark_reads(plain):
     for phase in ("queue", "prefill"):
         assert values[f'kvmini_tpu_phase_seconds_count{{phase="{phase}"}}'] == 4
         assert f'kvmini_tpu_phase_seconds_sum{{phase="{phase}"}}' in values
+    # the decode gap's series (bench/readers/decode_gap_share.py, admit_gap_*.py),
+    # and the older name of their sum
+    for c in rt_tracing.SCHED_GAP_CAUSES:
+        assert values[f'kvmini_tpu_sched_gaps_total{{cause="{c}"}}'] == s["sched_gaps"][c]
+        assert values[f'kvmini_tpu_sched_gap_seconds_total{{cause="{c}"}}'] == pytest.approx(
+            s["sched_gap_s"][c], abs=1e-6)
+    for p in SCHED_PHASES:
+        assert values[f'kvmini_tpu_sched_admit_gap_seconds_total{{phase="{p}"}}'] == pytest.approx(
+            s["sched_admit_gap_s"][p], abs=1e-6)
+    assert values["kvmini_tpu_sched_gap_slot_seconds_total"] == pytest.approx(
+        s["sched_gap_slot_s"], abs=1e-6)
+    assert values["kvmini_tpu_sched_admit_gap_admissions_total"] == s["sched_admit_gap_admissions"]
+    assert sum(s["sched_gaps"].values()) > 0
+    assert values["kvmini_tpu_bubble_seconds_total"] == pytest.approx(
+        sum(values[f'kvmini_tpu_sched_gap_seconds_total{{cause="{c}"}}']
+            for c in rt_tracing.SCHED_GAP_CAUSES), abs=2e-6)
