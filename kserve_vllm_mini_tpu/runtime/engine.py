@@ -49,6 +49,15 @@ from kserve_vllm_mini_tpu.runtime import tracing as rt_tracing
 # one monotonic clock (runtime/tracing.py); only /traces converts to Unix time
 _now = rt_tracing.now_s
 
+# What makes an admission drain the decode pipeline instead of being
+# dispatched behind the sweep in flight (Engine._behind_refusal;
+# docs/DECODE_PIPELINE.md), the ``reason`` label of
+# ``kvmini_tpu_admissions_drained_total``.
+ADMIT_DRAIN_REASONS = (
+    "lockstep", "paged", "disagg", "drafter", "lora", "prefix_cache",
+    "prefill_chunk", "constrained", "sweep",
+)
+
 # Constrained decoding speaks the TOKEN protocol (runtime/token_grammar.py):
 # machines expose token_mask(budget) -> bool[V] / advance_token(id). Raw
 # byte automata (runtime/constrain.py) passed as GenRequest.constraint are
@@ -804,6 +813,17 @@ class Engine:
         # decode positions the in-flight sweeps have already written past
         # the host-visible _slot_len (chunk per unretired sweep)
         self._pending_steps = 0
+        # of those, the steps dispatched BEFORE the slot's occupant was
+        # admitted behind them (_admit_behind): its first decode write
+        # lands at its prompt's length, not past sweeps it was never part
+        # of. Zero everywhere but between such an admission and the retire
+        # of the sweep it was taken behind.
+        self._slot_behind = [0] * S
+        # callers admitted behind the sweep in flight whose first token is
+        # still on the device: read back at the end of the same iteration
+        # (_read_first_tokens), after that sweep is retired
+        self._first_pending: list[dict] = []
+        self._no_mask_dev: Optional[jnp.ndarray] = None
         self._t_last_ready = 0.0   # when the device last finished a sweep
         # a synchronous sweep (masked, speculative) between its dispatch and
         # the end of its readback: in flight, though never in _inflight
@@ -1005,8 +1025,12 @@ class Engine:
             "host_overlap_s": 0.0,  # host emit/bookkeeping under device compute
             "pipeline_fallback_constrained": 0,  # grammar mask forced sync
             "pipeline_fallback_spec": 0,         # spec partition forced sync
-            "pipeline_fallback_active_set": 0,   # admission/cancel forced retire
+            "pipeline_fallback_active_set": 0,   # admission forced retire
             "pipeline_fallback_headroom": 0,     # cache window forced sync
+            # admissions dispatched behind the sweep in flight, and those
+            # that drained it instead (active_set above), by what refused
+            "admissions_inflight": 0,
+            **{f"admissions_drained_{why}": 0 for why in ADMIT_DRAIN_REASONS},
         }
         if self.paged:
             # KV-block economy rail (ISSUE 16), paged engines only (same
@@ -1867,7 +1891,8 @@ class Engine:
         compile-stats wrapper under the label that ``bench/run.py``,
         ``chip_smoke.py`` and ``/metrics`` read it by. Kinds and keys:
         ``prefill`` (bucket, fresh), ``decode`` (n_steps), ``decode_masked``,
-        ``spec``, ``first``, ``reset``, ``inject``, ``kv_block_write``.
+        ``spec``, ``first``, ``reset`` (carry: the variant that merges the
+        token into the decode carry), ``inject``, ``kv_block_write``.
         ``draft``: the drafter's shadow of a prefill step, over its own
         config and plain ``forward``."""
         k = (kind, *key, draft)
@@ -1902,7 +1927,7 @@ class Engine:
         elif kind == "first":
             fn = steps.build_first(cfg)
         elif kind == "reset":
-            fn = steps.build_reset()
+            fn = steps.build_reset(*key)
         elif kind == "inject":
             fn = steps.build_cache_write(kind)
             label = "disagg_inject"
@@ -2302,15 +2327,19 @@ class Engine:
         jax.block_until_ready(last_logits)
         return last_logits, chunks
 
-    def _prefill_step(self, slot: int, st: dict, budget: int) -> bool:
+    def _prefill_step(self, slot: int, st: dict, budget: int,
+                      wait: bool = True) -> bool:
         """Advance one prefill piece for ``st`` (the per-slot chunked-
         prefill state): target pieces first, then — once the target cache
         holds the whole prompt — the drafter's shadow pieces. Blocks until
         the dispatch completes so the stall accounting is honest wall
         time, and counts the piece into ``prefill_chunks`` (and into
         ``prefill_chunk_stall_s`` when decode work was live — the decode
-        tail this piece's execution stood in front of). Returns True when
-        every piece (target and draft) has run."""
+        tail this piece's execution stood in front of). ``wait=False``
+        (an admission behind the sweep in flight) only dispatches: the
+        wall time is booked where the first token is read back
+        (_read_first_tokens). Returns True when every piece (target and
+        draft) has run."""
         handle = st["handle"]
         prompt = handle.request.prompt_tokens
         n = len(prompt)
@@ -2340,12 +2369,16 @@ class Engine:
             last_logits = self._prefill_piece(
                 piece, slot, off, draft=draft, adapter_idx=st["adapter_idx"]
             )
-            jax.block_until_ready(last_logits)
-        wall = ph.seconds
-        self.stats["busy_s"] += wall
+            if wait:
+                jax.block_until_ready(last_logits)
         self.stats["prefill_chunks"] += 1
-        if self._inflight or self._decode_active():
-            self.stats["prefill_chunk_stall_s"] += wall
+        if wait:
+            wall = ph.seconds
+            self.stats["busy_s"] += wall
+            if self._inflight or self._decode_active():
+                self.stats["prefill_chunk_stall_s"] += wall
+        else:
+            st.setdefault("t_dispatch", ph.t_start)
         if draft:
             st["draft_off"] = off + len(piece)
             st["draft_chunks"] += 1
@@ -2633,15 +2666,18 @@ class Engine:
         is aborted at the scheduler's next iteration (_abort_prefill)."""
         handle.cancelled = reason
 
-    def _admit_one(self, handle: RequestHandle) -> None:
+    def _admit_one(self, handle: RequestHandle, behind: bool = False) -> None:
+        """``behind``: a sweep is in flight and nothing refuses
+        (_behind_refusal): the admission is dispatched behind it instead
+        of after a drain (_admit_behind)."""
         req = handle.request
         with self._sched.phase(
             "admit", request_id=req.request_id,
-            prompt_tokens=len(req.prompt_tokens),
+            prompt_tokens=len(req.prompt_tokens), inflight=behind,
         ):
-            self._admit(handle)
+            self._admit(handle, behind)
 
-    def _admit(self, handle: RequestHandle) -> None:
+    def _admit(self, handle: RequestHandle, behind: bool = False) -> None:
         req = handle.request
         if handle.cancelled is not None:
             # cancelled while queued: report done without spending a
@@ -2807,6 +2843,9 @@ class Engine:
             return
         # monolithic admission: every piece back-to-back (budget =
         # max_prefill_len), then the drafter's shadow pieces, then sample
+        if behind:
+            self._admit_behind(slot, st)
+            return
         while not self._prefill_step(slot, st, self.ecfg.max_prefill_len):
             pass
         self._activate_slot(slot, st)
@@ -2817,52 +2856,62 @@ class Engine:
         The shared tail of monolithic admission (_admit_one) and chunked-
         prefill completion (_advance_prefills). Callers settle the
         in-flight pipeline first: _schedule_once retires before admitting
-        and _advance_prefills retires before activating."""
+        (unless the admission goes behind the sweep, _admit_behind) and
+        _advance_prefills retires before activating."""
         with self._sched.phase("first_token") as ph:
             self._sample_first(slot, st)
         # the phase holds jit_first's device time (the readback waits for
         # it), which is what makes it busy time
         self.stats["busy_s"] += ph.seconds
 
-    def _sample_first(self, slot: int, st: dict) -> None:
+    def _dispatch_first(self, st: dict, mask, constrained: bool):
+        """Sample the first token from the last piece's logits, on the
+        device: (token, its logprob, top ids, top logprobs). Consumes one
+        rng split, like every decode dispatch."""
+        req = st["handle"].request
+        self._rng, sub = jax.random.split(self._rng)
+        return self._step("first")(
+            st["logits"], sub,
+            jnp.float32(req.temperature),
+            jnp.int32(req.top_k),
+            jnp.float32(req.top_p),
+            mask,
+            jnp.bool_(constrained),
+        )
+
+    def _occupy_slot(self, slot: int, st: dict, machine) -> None:
+        """The slot's host state once its prompt is in the cache: what an
+        admission knows without the first token's value."""
+        req = st["handle"].request
+        self._slot_req[slot] = st["handle"]
+        self._slot_len[slot] = len(req.prompt_tokens)
+        self._slot_remaining[slot] = req.max_new_tokens - 1
+        self._slot_machine[slot] = machine
+        self._slot_adapter[slot] = st["adapter_idx"]
+        self._adapter_ids_dev = None
+        self._retained[slot] = []
+        self._sampling_arrays = None  # slot population changed
+
+    def _first_token_out(self, slot: int, st: dict, first_id: int, first_lp,
+                         first_tids, first_tlps, t_read: Optional[float] = None) -> None:
+        """The first token is on the host (since ``t_read``; now, if not
+        given): counters, the prefill phase, the stream's first event and
+        the slot's token lists."""
         handle: RequestHandle = st["handle"]
         req = handle.request
         n = len(req.prompt_tokens)
         reused = st["reused"]
-        last_logits = st["logits"]
-        # first token: sampled from the prompt's last-position logits,
-        # grammar-masked when the request is constrained
-        # multihost submit refuses constrained requests (req_payload has no
-        # constraint field), dead on both sides there (kvmini: protocol-ok)
-        machine = req.constraint
-        if machine is not None:
-            # budget = tokens the slot can actually emit: the grammar must
-            # close before max_new_tokens AND before the KV window fills,
-            # else out_of_space cuts the structure mid-emission
-            budget = min(req.max_new_tokens, self.ecfg.max_seq_len - 1 - n)
-            mask = self._constraint_mask(machine, budget)
-        else:
-            mask = np.zeros(((self.cfg.vocab_size + 7) // 8,), dtype=np.uint8)
-        self._rng, sub = jax.random.split(self._rng)
-        first_tok, first_lp, first_tids, first_tlps = self._step("first")(
-            last_logits, sub,
-            jnp.float32(req.temperature),
-            jnp.int32(req.top_k),
-            jnp.float32(req.top_p),
-            jnp.asarray(mask),
-            jnp.bool_(machine is not None),
-        )
-        first_id = int(first_tok)
         self.stats["prefills"] += 1
         # only tokens actually prefilled: reused prefix tokens are counted
         # in prefix_tokens_reused, not here (throughput math stays honest)
         self.stats["prefill_tokens"] += n - reused
 
-        handle.t_first_token = _now()
+        handle.t_first_token = _now() if t_read is None else t_read
         # prefill phase: admission -> first sampled token (chunked prefill
         # and the drafter's shadow prefill included; for interleaved
         # chunking this span also contains the decode sweeps that rode
-        # between pieces — the request's real TTFT anatomy)
+        # between pieces, for an admission behind a sweep the rest of that
+        # sweep — the request's real TTFT anatomy)
         self._observe_phase("prefill", handle.t_first_token - handle.t_admit)
         self._trace_span(
             handle, "server.prefill", handle.t_admit, handle.t_first_token,
@@ -2879,20 +2928,43 @@ class Engine:
             )
             handle.logprobs.append(lp_info)
         handle.events.put(("token", first_id, handle.t_first_token, lp_info))
-
-        self._slot_req[slot] = handle
-        self._slot_len[slot] = n
-        self._slot_remaining[slot] = req.max_new_tokens - 1
         self._last_tokens[slot] = first_id
-        self._tokens_dev = None  # host mutation: device token carry is stale
-        self._slot_machine[slot] = machine
-        self._slot_adapter[slot] = st["adapter_idx"]
-        self._adapter_ids_dev = None
         # rows 0..n-1 now hold the prompt's KV; emitted tokens append as
         # their KV lands (fed on the next step)
         self._slot_tokens[slot] = list(req.prompt_tokens) + [first_id]
-        self._retained[slot] = []
-        self._sampling_arrays = None  # slot population changed
+
+    def _finish_at_first(self, slot: int, first_id: int) -> bool:
+        """A first token that ends the request (EOS, a budget of one)
+        finishes the slot; True if it did."""
+        req = self._slot_req[slot].request
+        hit_eos = req.eos_id is not None and first_id == req.eos_id
+        if self._slot_remaining[slot] <= 0 or hit_eos:
+            self._finish_slot(slot, "stop" if hit_eos else "length")
+            return True
+        return False
+
+    def _sample_first(self, slot: int, st: dict) -> None:
+        req = st["handle"].request
+        # first token: sampled from the prompt's last-position logits,
+        # grammar-masked when the request is constrained
+        # multihost submit refuses constrained requests (req_payload has no
+        # constraint field), dead on both sides there (kvmini: protocol-ok)
+        machine = req.constraint
+        if machine is not None:
+            # budget = tokens the slot can actually emit: the grammar must
+            # close before max_new_tokens AND before the KV window fills,
+            # else out_of_space cuts the structure mid-emission
+            budget = min(req.max_new_tokens,
+                         self.ecfg.max_seq_len - 1 - len(req.prompt_tokens))
+            mask = self._constraint_mask(machine, budget)
+        else:
+            mask = np.zeros(((self.cfg.vocab_size + 7) // 8,), dtype=np.uint8)
+        first_tok, first_lp, first_tids, first_tlps = self._dispatch_first(
+            st, jnp.asarray(mask), machine is not None)
+        first_id = int(first_tok)
+        self._first_token_out(slot, st, first_id, first_lp, first_tids, first_tlps)
+        self._occupy_slot(slot, st, machine)
+        self._tokens_dev = None  # host mutation: device token carry is stale
         # penalty state: clear the previous occupant's generated-token
         # counts and record the first token (it IS a generated token — the
         # next step's penalty must already see it)
@@ -2904,9 +2976,138 @@ class Engine:
             if machine.done:
                 self._finish_slot(slot, "stop")
                 return
-        hit_eos = req.eos_id is not None and first_id == req.eos_id
-        if self._slot_remaining[slot] <= 0 or hit_eos:
-            self._finish_slot(slot, "stop" if hit_eos else "length")
+        self._finish_at_first(slot, first_id)
+
+    # -- admission behind the sweep in flight (docs/DECODE_PIPELINE.md) -----
+
+    def _behind_refusal(self, handle: RequestHandle) -> Optional[str]:
+        """Why ``handle`` cannot be admitted behind the sweep in flight
+        (one of ADMIT_DRAIN_REASONS), or None if it can. It can when the
+        next sweep may be dispatched ahead with its slot in it
+        (_pipeline_eligible) and the admission is dense, colocated,
+        monolithic and unconstrained: everything its dispatches need is
+        known here, and every cache leaf it writes is safe behind a sweep
+        that was dispatched under the slot's old occupant. Everything
+        else drains the pipeline first, as the scheduler always did."""
+        req = handle.request
+        if self._lockstep:
+            return "lockstep"   # followers replay settled decisions
+        built = self._behind_refused_as_built()
+        if built is not None:
+            return built
+        if req.constraint is not None:
+            return "constrained"    # the mask needs the token on the host
+        ok, _ = self._pipeline_eligible(self._decode_active())
+        if (not ok or self.ecfg.max_seq_len - 1 - len(req.prompt_tokens)
+                < self._full_chunk()):
+            return "sweep"      # the next sweep cannot go ahead of a retire
+        return None
+
+    def _behind_refused_as_built(self) -> Optional[str]:
+        """The refusals that follow from how the engine was built: no
+        admission of such an engine ever goes behind a sweep."""
+        if self.paged:
+            return "paged"      # the block table is rebuilt on the host
+        if self._disagg is not None:
+            return "disagg"
+        if self._drafter_params is not None:
+            return "drafter"    # its shadow prefill and rounds are synchronous
+        if self._lora is not None:
+            return "lora"
+        if self.ecfg.prefix_cache:
+            return "prefix_cache"   # a sweep in flight may write a retained row
+        if self.ecfg.prefill_chunk is not None:
+            return "prefill_chunk"
+        return None
+
+    def _warm_behind(self) -> None:
+        """Compile what only an admission behind a sweep runs (the count
+        reset that also merges the token into the decode carry) before the
+        first request is looked at (the scheduler thread's first act), not
+        inside somebody's first such admission: every other program of the
+        path is the drained admission's own. Run on a free slot's count
+        row, which every admission resets first."""
+        if (not self.ecfg.decode_pipeline or not self._free
+                or self._behind_refused_as_built() is not None):
+            return
+        self._counts, _ = self._step("reset", True)(
+            self._counts, jnp.int32(self._free[-1]), jnp.asarray(0, jnp.int32),
+            jnp.asarray(np.zeros((self.ecfg.max_slots,), dtype=np.int32)),
+        )
+
+    def _admit_behind(self, slot: int, st: dict) -> None:
+        """Monolithic admission without a drain: the prompt's pieces, the
+        first token's sampler and the slot's count reset are dispatched
+        behind the sweep in flight (the device orders them after it by the
+        cache and count dependencies), the token goes into the decode
+        carry ON the device, and the slot joins the decode set at once so
+        that _sweep_phase dispatches the next sweep with it. The host sees
+        the token at the end of this iteration (_read_first_tokens)."""
+        handle: RequestHandle = st["handle"]
+        # the carry the next sweep would feed, before this slot joins
+        carry = self._feed_tokens(self._decode_active())
+        while not self._prefill_step(
+                slot, st, self.ecfg.max_prefill_len, wait=False):
+            pass
+        if self._no_mask_dev is None:      # an upload, kept: nothing compiles
+            self._no_mask_dev = jnp.asarray(
+                np.zeros(((self.cfg.vocab_size + 7) // 8,), dtype=np.uint8))
+        out = self._dispatch_first(st, self._no_mask_dev, False)
+        self._counts, self._tokens_dev = self._step("reset", True)(
+            self._counts, jnp.int32(slot), out[0], carry
+        )
+        self._tokens_dev_slots = self._tokens_dev_slots | {slot}
+        self._occupy_slot(slot, st, None)
+        self._slot_tokens[slot] = list(handle.request.prompt_tokens)
+        # none of the steps in flight are this occupant's
+        self._slot_behind[slot] = self._pending_steps
+        self._first_pending.append({"slot": slot, "st": st, "out": out})
+        self.stats["admissions_inflight"] += 1
+
+    def _read_first_tokens(self) -> None:
+        """Read back the first tokens of the callers admitted behind a
+        sweep this iteration, one transfer a caller in the order of their
+        dispatch (each returns when that caller's pieces have run, while
+        the device goes on with the next caller's: nobody's token waits for
+        another's prompt), and do with each what _sample_first does once it
+        has the value. The wait is the ``prefill`` phase's: it is the
+        prompt's pieces the host waits on. If the next sweep went out
+        behind them the decoding slots' gap ends where the last readback
+        does (the device has begun that sweep); after a drain the next
+        dispatch ends it, as ever."""
+        pending, self._first_pending = self._first_pending, []
+        for left, p in zip(range(len(pending), 0, -1), pending):
+            slot, st = p["slot"], p["st"]
+            handle: RequestHandle = st["handle"]
+            request_id = handle.request.request_id
+            with self._sched.phase("prefill", first_tokens=left) as ph:
+                # (the token alone unless the caller asked for logprobs)
+                tok, lp, tids, tlps = jax.device_get(
+                    p["out"] if handle.request.logprobs
+                    else (p["out"][0], None, None, None))
+            # what the blocking waits of a drained admission feed
+            # (_prefill_step, _activate_slot): the device ran the pieces and
+            # the sampler from the later of their dispatch and the end of
+            # what it ran before them, in front of whoever was decoding
+            wall = ph.t_end - max(st["t_dispatch"], self._t_last_ready)
+            self.stats["busy_s"] += wall
+            self.stats["prefill_chunk_stall_s"] += wall
+            self._t_last_ready = ph.t_end
+            if left == 1 and self._inflight:
+                # (the callers before this one entered their first_token
+                # inside the gap; this one's follows its end)
+                self._sched.gap_close(admitted=[request_id])
+            with self._sched.phase("first_token", request_id=request_id):
+                if handle.cancelled is not None:
+                    # cancelled between the dispatch and this readback: the
+                    # token is dropped, as a sweep's are (_retire_one)
+                    self._abort_prefill(slot, handle.cancelled)
+                    continue
+                first_id = int(tok)
+                self._first_token_out(slot, st, first_id, lp, tids, tlps, ph.t_end)
+                self._finish_at_first(slot, first_id)
+        if pending:
+            self._drop_idle_inflight()
 
     def _abort_prefill(self, slot: int, reason: str) -> None:
         """Finish a slot that was cancelled (or drained) MID-chunked-
@@ -3076,6 +3277,7 @@ class Engine:
         # discarded KV there, and the dense decode kernel fetches only the
         # blocks that reach that row instead of the slot's stale length
         self._slot_len[slot] = len(self._retained[slot])
+        self._slot_behind[slot] = 0
         self._free.append(slot)
         self._sampling_arrays = None  # slot population changed
 
@@ -3280,6 +3482,20 @@ class Engine:
             self._live_dev = (key, jnp.asarray(mask))
         return self._live_dev[1]
 
+    def _window(self, active: list[int]) -> int:
+        """Positions left in the tightest active slot's cache window past
+        those the in-flight sweeps have already claimed of it (a slot
+        admitted behind a sweep has none of that sweep's: _slot_behind)."""
+        return min(
+            self.ecfg.max_seq_len - 1 - self._slot_len[i] + self._slot_behind[i]
+            for i in active
+        ) - self._pending_steps
+
+    def _full_chunk(self) -> int:
+        """decode_chunk rounded down to a power of two: what a sweep fuses
+        when no cache window clamps it."""
+        return 1 << (max(1, self.ecfg.decode_chunk).bit_length() - 1)
+
     def _chunk_for(self, active: list[int]) -> int:
         """Fused-step count for the next plain dispatch: decode_chunk
         clamped into every active slot's REMAINING cache window (minus
@@ -3287,10 +3503,7 @@ class Engine:
         a power of two so at most log2(decode_chunk)+1 scan variants ever
         compile. Requests finishing mid-chunk surplus-discard on the host
         — shrinking instead would recompile per remaining-budget value."""
-        window = min(
-            self.ecfg.max_seq_len - 1 - self._slot_len[i] for i in active
-        ) - self._pending_steps
-        chunk = max(1, min(self.ecfg.decode_chunk, window))
+        chunk = max(1, min(self.ecfg.decode_chunk, self._window(active)))
         return 1 << (chunk.bit_length() - 1)
 
     def _pipeline_eligible(self, active: list[int]) -> tuple[bool, Optional[str]]:
@@ -3312,9 +3525,10 @@ class Engine:
           decode_chunk of window past the in-flight positions guarantees
           both.
 
-        The fourth condition — ``active_set`` (admission/cancellation
-        landing mid-flight) — is enforced by _schedule_once retiring all
-        in-flight sweeps before mutating the slot population."""
+        The fourth condition — ``active_set`` (an admission landing
+        mid-flight that cannot go behind the sweep, _behind_refusal) — is
+        enforced by _schedule_once retiring all in-flight sweeps before
+        such an admission."""
         if not self.ecfg.decode_pipeline or not active:
             return False, None
         if any(self._slot_machine[i] is not None for i in active):
@@ -3333,11 +3547,7 @@ class Engine:
             and any(self._spec_capable(i) for i in active)
         ):
             return False, "spec"
-        full = 1 << (max(1, self.ecfg.decode_chunk).bit_length() - 1)
-        window = min(
-            self.ecfg.max_seq_len - 1 - self._slot_len[i] for i in active
-        ) - self._pending_steps
-        if window < full:
+        if self._window(active) < self._full_chunk():
             return False, "headroom"
         return True, None
 
@@ -3376,12 +3586,15 @@ class Engine:
                 raise DeviceFault("injected dispatch-time device error")
             chunk = self._chunk_for(active)
             tokens = self._feed_tokens(active)
-            # The fed token occupies absolute position slot_len + already-in-
-            # flight steps; forward writes its KV there and attends <=. The cap
-            # only ever binds on inactive rows (eligibility guarantees active
-            # windows), whose writes are masked-garbage either way.
+            # The fed token occupies absolute position slot_len + the steps
+            # in flight that are the slot's own (all of them, but for a slot
+            # admitted behind a sweep); forward writes its KV there and
+            # attends <=. The cap only ever binds on inactive rows
+            # (eligibility guarantees active windows), whose writes are
+            # masked-garbage either way.
             lengths = np.minimum(
-                np.asarray(self._slot_len, dtype=np.int32) + self._pending_steps,
+                np.asarray(self._slot_len, dtype=np.int32) + self._pending_steps
+                - np.asarray(self._slot_behind, dtype=np.int32),
                 self.ecfg.max_seq_len - 1,
             )
             self._count_decode_kv_blocks(lengths, chunk)
@@ -3447,9 +3660,12 @@ class Engine:
             self._inflight.pop(0)
         t_ready = ph.t_end
         # the last sweep in flight is read back: the slots that go on
-        # decoding once it is emitted wait from here to the next dispatch
-        drained = not self._inflight
-        if drained:
+        # decoding once it is emitted wait from here to the next dispatch.
+        # Or callers were admitted behind this sweep: the device runs their
+        # prefills next, and the slots wait from here until the first
+        # tokens are read back (_read_first_tokens ends that gap)
+        gap = not self._inflight or bool(self._first_pending)
+        if gap:
             self._sched.gap_open()
         with self._sched.phase("emit") as ph:
             self.stats["busy_s"] += t_ready - max(
@@ -3458,6 +3674,11 @@ class Engine:
             self._t_last_ready = t_ready
             self._note_sweep(rec["t_dispatch"], t_ready)
             self._pending_steps -= rec["chunk"]
+            if any(self._slot_behind):
+                # sweeps retire oldest first: a slot admitted behind this
+                # one has that much less in flight that is not its own
+                self._slot_behind = [
+                    max(b - rec["chunk"], 0) for b in self._slot_behind]
             self.stats["decode_steps"] += rec["chunk"]
             if rec["filters"]:
                 self.stats["sampler_filter_steps"] += rec["chunk"]
@@ -3495,17 +3716,29 @@ class Engine:
             # emission ran while the device computed the next sweep — the
             # host time the synchronous loop would have serialized
             self.stats["host_overlap_s"] += ph.seconds
-        active = self._decode_active()
-        if drained:
-            self._sched.gap_waiting(len(active))
-        if not active and self._inflight:
-            # every decode slot finished: younger sweeps computed only garbage.
-            # Rewind to the oldest dropped sweep's pre-dispatch rng (their
-            # counts/KV pollution sits in freed rows, reset at admission).
+        if gap:
+            # the slots that go on decoding, not those whose first token is
+            # still to come
+            self._sched.gap_waiting(
+                len(self._decode_active()) - len(self._first_pending))
+        self._drop_idle_inflight()
+
+    def _drop_idle_inflight(self) -> None:
+        """Every decode slot finished: the sweeps still in flight computed
+        only garbage. Rewind to the oldest dropped sweep's pre-dispatch rng
+        (their counts/KV pollution sits in freed rows, reset at admission)."""
+        if self._inflight and not self._decode_active():
             self._rng = self._inflight[0]["rng_prev"]
-            self._inflight.clear()
-            self._pending_steps = 0
-            self._tokens_dev = None
+            self._settle_pipeline()
+
+    def _settle_pipeline(self) -> None:
+        """Nothing is in flight any more, by retire, drop or fault: the
+        bookkeeping of what was."""
+        self._inflight.clear()
+        self._pending_steps = 0
+        self._slot_behind = [0] * self.ecfg.max_slots
+        self._first_pending = []
+        self._tokens_dev = None
 
     def _book_routed(self, routed: list, live: int, steps: int) -> None:
         """A retired sweep's expert-block counts: ``routed`` holds the
@@ -3655,9 +3888,7 @@ class Engine:
         info = {"finish_reason": "error", "error": f"{type(exc).__name__}: {exc}"}
         # in-flight sweeps die with the scheduler; drop their bookkeeping so
         # a post-mortem snapshot_stats doesn't report phantom depth
-        self._inflight.clear()
-        self._pending_steps = 0
-        self._tokens_dev = None
+        self._settle_pipeline()
         # half-prefilled slots die with it too (their handles error below
         # through the same _slot_req sweep), and so do slots awaiting a
         # prefill-lane handoff (their payloads orphan at consume)
@@ -3758,17 +3989,26 @@ class Engine:
                 # hold at the head of the line until decode frees blocks
                 self._deferred = handle
                 break
+            behind = False
             if self._inflight:
-                # admission mutates the active set and cache bookkeeping
-                # the in-flight sweep was dispatched under — retire first,
-                # admit against settled state (a newly admitted slot must
-                # never receive a stale token from a sweep dispatched
-                # before its admission)
-                self.stats["pipeline_fallback_active_set"] += 1
-                self._retire_all(on_decision)
+                why = self._behind_refusal(handle)
+                behind = why is None
+                if not behind:
+                    # this admission mutates the active set and cache
+                    # bookkeeping the in-flight sweep was dispatched under
+                    # in a way the sweep is not safe against — retire
+                    # first, admit against settled state (a newly admitted
+                    # slot must never receive a stale token from a sweep
+                    # dispatched before its admission)
+                    self.stats["pipeline_fallback_active_set"] += 1
+                    self.stats[f"admissions_drained_{why}"] += 1
+                    self._retire_all(on_decision)
+                    # (first tokens of callers taken behind what was just
+                    # drained: the host state must be whole again)
+                    self._read_first_tokens()
             if on_decision is not None:
                 on_decision(("admit", handle.request))
-            self._admit_one(handle)
+            self._admit_one(handle, behind)
             admitted = True
         self.stats["queue_depth"] = self._queue_depth()
         # republish the live-handle snapshot (docs/RESILIENCE.md): the
@@ -3789,6 +4029,7 @@ class Engine:
         self._advance_prefills(on_decision)
         if self._decode_active():
             self._sweep_phase(on_decision)
+            self._read_first_tokens()
             return
         # whoever waited in an open decode gap is gone (finished at its
         # first token, cancelled): no dispatch will end it
@@ -3832,8 +4073,14 @@ class Engine:
     def _loop(self) -> None:
         from kserve_vllm_mini_tpu.runtime.faults import DeviceFault
 
+        warm = False
         while self._running:
             try:
+                if not warm:
+                    # (inside the try: a failure must reach the waiting
+                    # clients like any other of this thread's)
+                    warm = True
+                    self._warm_behind()
                 # one iteration, gauge republish included: the ledger's
                 # outermost phase, so the phases' self times add up
                 # to this thread's wall time
@@ -3891,9 +4138,7 @@ class Engine:
         their cancel reason (default "cancelled"), release blocks, and
         fail queued/deferred handles — exactly one terminal event per
         handle, no slot or block leak."""
-        self._inflight.clear()
-        self._pending_steps = 0
-        self._tokens_dev = None
+        self._settle_pipeline()
         self._tokens_dev_slots = frozenset()
         with self._res_lock:
             faulted = set(self._faulted_ids)
@@ -4006,12 +4251,10 @@ class Engine:
         import sys
 
         print(f"engine: recovering from fault: {reason}", file=sys.stderr)
-        self._inflight.clear()
+        self._settle_pipeline()
         self._sync_inflight = False
         # every slot fails below: a wedge is nobody's decode gap
         self._sched.gap_waiting(0)
-        self._pending_steps = 0
-        self._tokens_dev = None
         self._tokens_dev_slots = frozenset()
         now = _now()
         with self._res_lock:

@@ -28,7 +28,12 @@ import uuid
 from pathlib import Path
 from typing import Any, Optional
 
-from kserve_vllm_mini_tpu.runtime.engine import Engine, EngineConfig, GenRequest
+from kserve_vllm_mini_tpu.runtime.engine import (
+    ADMIT_DRAIN_REASONS,
+    Engine,
+    EngineConfig,
+    GenRequest,
+)
 from kserve_vllm_mini_tpu.runtime.tokenizer import Tokenizer, load_tokenizer
 
 
@@ -1421,6 +1426,13 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"{{reason=\"active_set\"}} {s['pipeline_fallback_active_set']}",
             "kvmini_tpu_pipeline_fallback_total"
             f"{{reason=\"headroom\"}} {s['pipeline_fallback_headroom']}",
+            # admissions dispatched behind the sweep in flight, and why the
+            # others drained it (each of those is an active_set fallback)
+            "# TYPE kvmini_tpu_admissions_inflight_total counter",
+            f"kvmini_tpu_admissions_inflight_total {s['admissions_inflight']}",
+            "# TYPE kvmini_tpu_admissions_drained_total counter",
+            *(f"kvmini_tpu_admissions_drained_total{{reason=\"{why}\"}} "
+              f"{s['admissions_drained_' + why]}" for why in ADMIT_DRAIN_REASONS),
             "# TYPE kvmini_tpu_spec_rounds_total counter",
             f"kvmini_tpu_spec_rounds_total {s['spec_rounds']}",
             "# TYPE kvmini_tpu_spec_accepted_total counter",

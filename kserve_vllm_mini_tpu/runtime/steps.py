@@ -373,13 +373,23 @@ def build_first(cfg: ModelConfig):
     return jax.jit(first)
 
 
-def build_reset():
+def build_reset(carry: bool = False):
     """Admission-time reset of one slot's penalty-count row: zero it, then
-    record the first generated token."""
+    record the first generated token.
 
-    def reset(counts, slot, tok):
+    ``carry``: the admission is dispatched behind a sweep in flight
+    (docs/DECODE_PIPELINE.md), so ``tok`` is still on the device: a fourth
+    operand, the decode token carry [S], comes back with ``tok`` in the
+    slot's row, and the next sweep feeds it without the host having seen
+    the token."""
+
+    def reset(counts, slot, tok, *tokens):
         row = jnp.zeros((counts.shape[1],), counts.dtype).at[tok].add(1)
-        return jax.lax.dynamic_update_index_in_dim(counts, row, slot, 0)
+        counts = jax.lax.dynamic_update_index_in_dim(counts, row, slot, 0)
+        if not carry:
+            return counts
+        (tokens,) = tokens
+        return counts, tokens.at[slot].set(tok.astype(tokens.dtype))
 
     return jax.jit(reset, donate_argnums=(0,))
 

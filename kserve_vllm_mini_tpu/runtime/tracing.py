@@ -430,11 +430,15 @@ class SchedLedger:
     ``SCHED_SLOW_SWEEPS`` x ``sweep_s()``, at least ``SCHED_SLOW_FLOOR_NS``).
 
     **The decode gap**: what the slots that were decoding lose between the
-    readback of the last sweep in flight and the next dispatch. The engine
-    says ``gap_open()`` where such a readback ends, ``gap_waiting(n)`` once
-    that sweep's tokens are emitted (0: nobody waits, no gap) and
-    ``gap_close()`` where the waiting slots are gone; the next
-    ``decode_dispatch`` entered closes the gap by itself. While one is open
+    readback of the last sweep in flight and the next dispatch, or, when an
+    admission was dispatched behind the sweep in flight, between that
+    sweep's readback and the readback of the admission's first token (the
+    device runs the prefill in between, then the sweep that was dispatched
+    behind it). The engine says ``gap_open()`` where such a readback ends,
+    ``gap_waiting(n)`` once that sweep's tokens are emitted (0: nobody
+    waits, no gap) and ``gap_close()`` where the waiting slots are gone or
+    the first tokens are read back; the next ``decode_dispatch`` entered
+    closes the gap by itself. While one is open
     every stretch charged to a phase is charged to the gap too, so a gap's
     wall time is the sum of its phases'. It is booked once, under the cause
     its phases give it: ``admission`` if an admission began (``admit``) or
@@ -476,12 +480,14 @@ class SchedLedger:
         profiler is listening. The ``decode_dispatch`` that closes a gap is
         such a one, and is given the gap's."""
         gap = self._gap
-        if gap is not None:
+        if gap is not None and gap.end_ns is None:
             if name == "decode_dispatch" and gap.waiting:
                 args = {"gap_ms": round((now_ns() - gap.t0_ns) * 1e-6, 3),
                         "cause": gap.cause, "waiting": gap.waiting,
                         "admissions": gap.admissions}
-            elif name == "admit":
+            elif name == "admit" or (name == "first_token" and "request_id" in args):
+                # (a first token names its caller when the admission began
+                # before the gap: one dispatched behind a sweep)
                 gap.request_ids.append(args.get("request_id"))
         return _SchedPhase(self, name, self._annotate(_SPAN_NAMES[name], **args))
 
@@ -500,12 +506,23 @@ class SchedLedger:
         if self._gap is not None:
             self._gap.waiting = slots
 
-    def gap_close(self) -> None:
-        """The slots that waited are gone (finished, cancelled): the gap
-        ends where the last stretch did, with no dispatch; the next phase
-        boundary books it."""
-        if self._gap is not None and self._gap.end_ns is None:
-            self._gap.end_ns = self._mark_ns
+    def gap_close(self, admitted: Iterable[Any] = ()) -> None:
+        """The gap ends where the last stretch did, with no dispatch, and
+        the next phase boundary books it: the slots that waited are gone
+        (finished, cancelled), or they waited for callers admitted BEHIND
+        the sweep whose readback opened the gap and the stretch that just
+        ended was the readback of the last of their first tokens (the
+        device has begun the sweep dispatched behind them). ``admitted``:
+        the ``request_id``s of the callers whose ``admit`` was entered
+        before the gap and whose ``first_token`` follows it, so they are
+        counted here (one whose token was read back earlier entered its
+        ``first_token`` inside the gap, with its ``request_id``)."""
+        gap = self._gap
+        if gap is not None and gap.end_ns is None:
+            gap.end_ns = self._mark_ns
+            for request_id in admitted:
+                gap.request_ids.append(request_id)
+                gap.entered["first_token"] = gap.entered.get("first_token", 0) + 1
 
     def _book_gap(self, gap: _Gap, end_ns: int) -> None:
         self._gap = None

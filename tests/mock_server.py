@@ -384,6 +384,10 @@ def make_app(
                         "first_token": 0.002, "decode_prepare": 0.0008,
                         "emit": 0.0005, "admit": 0.0004, "loop": 0.0003}.items()},
         "kvmini_tpu_sched_admit_gap_admissions_total": 2.0,
+        # admissions behind the sweep in flight, and those that drained it
+        # (docs/DECODE_PIPELINE.md)
+        "kvmini_tpu_admissions_inflight_total": 3.0,
+        'kvmini_tpu_pipeline_fallback_total{reason="active_set"}': 1.0,
         # chunked-prefill rail (docs/TROUBLESHOOTING.md)
         "kvmini_tpu_prefills_total": 4.0,
         "kvmini_tpu_prefill_chunks_total": 6.0,

@@ -222,8 +222,44 @@ def test_cancel_during_inflight_sweep_emits_no_token(params):
 
 def test_admission_during_inflight_gets_no_stale_token(params):
     """Satellite: a newly admitted request must never receive a token from
-    a sweep dispatched before its admission — the scheduler retires all
-    in-flight sweeps (active_set fallback) before admitting."""
+    a sweep dispatched before its admission. Since PR 39 the scheduler no
+    longer retires the sweep in flight for that (docs/DECODE_PIPELINE.md
+    "Admission behind the sweep in flight"): B is dispatched behind the
+    sweep that was dispatched with the cancelled A live in the very slot B
+    takes, and the retire's handle-identity check drops A's row."""
+    from tests.oracle import greedy_reference
+
+    eng = make_engine(params, pipeline=True, slots=2)
+    ha = eng.submit(GenRequest(prompt_tokens=[3, 1, 4], max_new_tokens=60))
+    hc = eng.submit(GenRequest(prompt_tokens=[2, 7, 1], max_new_tokens=30))
+    eng._schedule_once()  # admit A and C + dispatch sweep 1
+    eng._schedule_once()  # dispatch sweep 2 + retire sweep 1
+    assert eng.snapshot_stats()["inflight_sweeps"] == 1
+    slot_a = eng._slot_req.index(ha)
+    # B arrives while the sweep with A in it is in flight; the slot frees via cancel
+    hb = eng.submit(GenRequest(prompt_tokens=[8, 6, 7, 5], max_new_tokens=6))
+    eng.cancel(ha, "stop")
+    n_a = len(ha.tokens)
+    eng._schedule_once()
+    assert eng._slot_req[slot_a] is hb and len(ha.tokens) == n_a
+    assert len(hb.tokens) == 1          # its own first token, read back this iteration
+    for _ in range(64):
+        eng._schedule_once()
+        if hb.finish_reason and hc.finish_reason:
+            break
+    assert eng.stats["admissions_inflight"] == 1
+    assert eng.stats["pipeline_fallback_active_set"] == 0
+    tokens_b, _ = _drain(hb)
+    assert tokens_b == greedy_reference(params, CFG, [8, 6, 7, 5], 6)
+    tokens_c, _ = _drain(hc)
+    assert tokens_c == greedy_reference(params, CFG, [2, 7, 1], 30)
+
+
+def test_admission_with_nobody_decoding_still_drains(params):
+    """One slot: the cancel leaves no slot decoding, so there is no next
+    sweep to dispatch behind the prefill and the admission retires the
+    (garbage) sweep in flight first, as it always did: counted as an
+    ``active_set`` fallback, refused as ``sweep``."""
     from tests.oracle import greedy_reference
 
     eng = make_engine(params, pipeline=True, slots=1)
@@ -231,7 +267,6 @@ def test_admission_during_inflight_gets_no_stale_token(params):
     eng._schedule_once()  # admit A + dispatch sweep 1
     eng._schedule_once()  # dispatch sweep 2 + retire sweep 1
     assert eng.snapshot_stats()["inflight_sweeps"] == 1
-    # B arrives while A's sweep is in flight; the slot frees via cancel
     hb = eng.submit(GenRequest(prompt_tokens=[8, 6, 7, 5], max_new_tokens=6))
     eng.cancel(ha, "stop")
     for _ in range(32):
@@ -239,6 +274,7 @@ def test_admission_during_inflight_gets_no_stale_token(params):
         if hb.finish_reason:
             break
     assert eng.stats["pipeline_fallback_active_set"] >= 1
+    assert eng.stats["admissions_drained_sweep"] >= 1 and eng.stats["admissions_inflight"] == 0
     tokens_b, _ = _drain(hb)
     assert tokens_b == greedy_reference(params, CFG, [8, 6, 7, 5], 6)
 

@@ -240,6 +240,8 @@ def _harness(slots=2):
     eng._free = []
     eng._inflight = []
     eng._pending_steps = 0
+    eng._slot_behind = [0] * slots
+    eng._first_pending = []
     eng._tokens_dev = None
     eng._tokens_dev_slots = frozenset()
     eng._sampling_arrays = None

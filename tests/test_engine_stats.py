@@ -130,3 +130,11 @@ def test_snapshot_stats_exposes_pipeline_counters(params):
     assert s["inflight_sweeps"] == 0
     for reason in ("constrained", "spec", "active_set", "headroom"):
         assert s[f"pipeline_fallback_{reason}"] == 0
+    # the admissions that went behind the sweep in flight, and those that
+    # drained it (the active_set fallback) by what refused them
+    assert s["admissions_inflight"] == 0
+    from kserve_vllm_mini_tpu.runtime.engine import ADMIT_DRAIN_REASONS
+
+    assert "paged" in ADMIT_DRAIN_REASONS and eng._behind_refused_as_built() == "paged"
+    for reason in ADMIT_DRAIN_REASONS:
+        assert s[f"admissions_drained_{reason}"] == 0

@@ -108,6 +108,20 @@ def test_engine_streams_with_the_state_updated_by_the_decode_kernel(ecfg):
     assert eager.snapshot_stats()["linear_state_kernel"] == 0     # "auto" on a CPU
 
 
+@pytest.mark.parametrize("sampled,kernels", [(False, ""), (True, ""), (False, "interpret")],
+                         ids=["greedy", "sampled", "greedy_decode_kernel"])
+def test_admissions_behind_the_sweep_in_flight_leave_every_state_its_own(sampled, kernels):
+    """Callers admitted while a sweep is in flight, without a drain
+    (docs/DECODE_PIPELINE.md): the sweep holds a parked row's state and conv
+    tail and moves those of the caller it was dispatched for; the fresh piece,
+    later on the device, starts both from zero. Token for token the
+    synchronous scheduler's streams, through the jnp update and through
+    ``kvmini_linear_decode`` in the cache's stack."""
+    from tests.admit_behind import check_stack
+
+    check_stack(make_engine, greedy, sampled, kernels)
+
+
 def test_a_parked_or_mid_prefill_slot_keeps_its_state_through_neighbours_sweeps():
     params, eng = make_engine(prefill_chunk=32)
     from kserve_vllm_mini_tpu.runtime.tracing import SchedLedger

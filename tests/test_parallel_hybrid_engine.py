@@ -76,6 +76,19 @@ def test_engine_streams_are_the_models_own_under_batching_and_slot_reuse(ecfg):
     assert s["block_mixers"] == 2
 
 
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+def test_admissions_behind_the_sweep_in_flight_leave_every_state_its_own(sampled):
+    """Callers admitted while a sweep is in flight, without a drain
+    (docs/DECODE_PIPELINE.md): a ``P`` block holds a layer of Mamba-2 state and tail AND a layer of K/V.
+    The sweep holds a parked row and moves the row of the caller it was
+    dispatched for; the fresh piece, later on the device, starts the state
+    from zero and overwrites the K/V rows from 0. Token for token the
+    synchronous scheduler's streams."""
+    from tests.admit_behind import check_stack
+
+    check_stack(make_engine, greedy, sampled)
+
+
 def test_a_parked_or_mid_prefill_slot_keeps_its_state_through_neighbours_sweeps():
     params, eng = make_engine(prefill_chunk=32)
     from kserve_vllm_mini_tpu.runtime.tracing import SchedLedger

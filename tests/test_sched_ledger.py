@@ -300,6 +300,119 @@ def test_an_admission_gap_is_partitioned_by_phase(clock):
                if n in ("kvmini.decode_retire", "kvmini.emit", "kvmini.loop"))
 
 
+def test_an_admission_behind_the_sweep_books_readback_to_readback(clock):
+    """An admission dispatched behind the sweep in flight drains nothing, and
+    the slots still wait for its prefill: ONE gap of cause ``admission`` from
+    the end of that sweep's readback to the end of the first token's. The
+    wait is the ``prefill`` phase's, the rest (the sweep's emission, the loop)
+    the host's, the two add up to the gap's wall; what the host did for the
+    admission before (``admit``, the pieces' dispatch, the next sweep's) and
+    does with the token after (``first_token``) is in no gap; and the
+    admission is counted, by name, where the gap closes."""
+    note, spans = [], Recorded()
+    led = SchedLedger(annotate=spans, on_gap=lambda *a: note.append(a))
+    with led.phase("loop"):
+        sweep(led, clock)                       # the steady state: nothing opens
+    with led.phase("loop"):
+        clock.tick(0.4)
+        with led.phase("admit", request_id="r9", prompt_tokens=40, inflight=True):
+            clock.tick(1.0)
+            with led.phase("prefill", request_id="r9", bucket=64):
+                clock.tick(2.2)                 # the dispatch alone
+            clock.tick(4.5)                     # first, reset: dispatched, not waited for
+        with led.phase("decode_prepare"):
+            clock.tick(3.5)
+            with led.phase("decode_dispatch"):  # sweep N+1, behind the prefill
+                clock.tick(0.7)
+        with led.phase("decode_retire"):        # sweep N
+            clock.tick(5)
+        led.gap_open()
+        t_open = clock.ns
+        with led.phase("emit"):
+            clock.tick(0.4)
+        led.gap_waiting(15)
+        clock.tick(0.1)
+        with led.phase("prefill", first_tokens=1):
+            clock.tick(16)                      # the device runs the prompt's pieces
+        t_close = clock.ns
+        led.gap_close(admitted=["r9"])
+        with led.phase("first_token"):
+            clock.tick(1.2)
+        clock.tick(0.3)
+    with led.phase("loop"):
+        sweep(led, clock)                       # sweep N+2: the gap is long closed
+    s = led.snapshot()
+    assert s["gaps"] == {"admission": 1, "prefill_piece": 0, "sweep": 0}
+    assert s["gap_seconds"]["admission"] == pytest.approx(0.0165)
+    assert s["gap_seconds"]["admission"] == pytest.approx((t_close - t_open) * 1e-9)
+    assert s["gap_slot_seconds"] == pytest.approx(15 * 0.0165)
+    assert s["admit_gap_admissions"] == 1 == s["spans"]["first_token"]
+    want = {"emit": 0.4, "loop": 0.1, "prefill": 16.0}
+    for p in SCHED_PHASES:
+        assert s["admit_gap_seconds"][p] == pytest.approx(want.get(p, 0.0) / 1e3)
+    # the two parts the benchmark reads: prefill + the rest = the gap's wall
+    rest = sum(v for p, v in s["admit_gap_seconds"].items() if p != "prefill")
+    assert s["admit_gap_seconds"]["prefill"] + rest == pytest.approx(
+        s["gap_seconds"]["admission"], rel=1e-12)
+    assert note == [("admission", t_open, t_close, 15, 1, ["r9"])]
+    # no dispatch closed it: none says so
+    assert all(not a for n, a in spans.seen if n == "kvmini.decode_dispatch")
+    # the phases' own books are whole: both prefill entries, one first token
+    assert s["spans"]["prefill"] == 2 and s["seconds"]["prefill"] == pytest.approx(0.0182)
+    assert s["seconds"]["first_token"] == pytest.approx(0.0012)
+
+
+def test_two_callers_behind_one_sweep_share_a_gap_and_a_drain_after_it_keeps_it_open(clock):
+    """Two callers behind one sweep: a readback each, in the order of their
+    dispatch, and ONE gap with two admissions that ends with the second
+    readback. The first caller's ``first_token`` is entered inside the gap
+    (the device is at the second caller's prompt) and names it; the second's
+    follows the gap's end and is counted where the gap closes.
+    If the pipeline was drained meanwhile (a refused caller in the same
+    iteration) the engine closes nothing: the first tokens are counted by
+    their ``first_token`` entries and the next dispatch ends the gap."""
+    note = []
+    led = SchedLedger(on_gap=lambda *a: note.append(a))
+    with led.phase("loop"):
+        with led.phase("decode_retire"):
+            clock.tick(5)
+        led.gap_open()
+        with led.phase("emit"):
+            clock.tick(0.5)
+        led.gap_waiting(14)
+        with led.phase("prefill", first_tokens=2):
+            clock.tick(16)
+        with led.phase("first_token", request_id="r1"):
+            clock.tick(1)
+        with led.phase("prefill", first_tokens=1):
+            clock.tick(13)
+        led.gap_close(admitted=["r2"])
+        with led.phase("first_token", request_id="r2"):
+            clock.tick(1)
+    s = led.snapshot()
+    assert s["gaps"]["admission"] == 1 and s["admit_gap_admissions"] == 2
+    assert s["gap_seconds"]["admission"] == pytest.approx(0.0305)
+    assert s["admit_gap_seconds"]["prefill"] == pytest.approx(0.029)
+    assert s["admit_gap_seconds"]["first_token"] == pytest.approx(0.001)
+    assert [(g[0], g[3], g[4], g[5]) for g in note] == [("admission", 14, 2, ["r1", "r2"])]
+
+    led = SchedLedger()
+    with led.phase("loop"):
+        sweep(led, clock, waiting=3)            # drained for the refused caller
+        with led.phase("prefill", first_tokens=1):
+            clock.tick(12)
+        with led.phase("first_token"):          # no gap_close: nothing in flight
+            clock.tick(1)
+        with led.phase("admit", request_id="r3"):
+            clock.tick(20)
+            with led.phase("first_token"):
+                clock.tick(6)
+        sweep(led, clock)
+    s = led.snapshot()
+    assert s["gaps"]["admission"] == 1 and s["admit_gap_admissions"] == 2
+    assert s["gap_seconds"]["admission"] == pytest.approx((0.25 + 12 + 1 + 20 + 6 + 0.5) / 1e3)
+
+
 @pytest.mark.parametrize("inside,cause,admissions", [
     ((), "sweep", 0),
     (("prefill",), "prefill_piece", 0),
@@ -516,7 +629,8 @@ def test_span_args_only_on_the_rare_phases(plain):
     assert prefill["b"] == {"request_id": "b", "prompt_tokens": 37, "bucket": 64, "offset": 0}
     assert prefill["a"]["bucket"] == 16 and prefill["a"]["prompt_tokens"] == 5
     admit = {a["request_id"]: a for a in by_name["kvmini.admit"]}
-    assert admit["b"] == {"request_id": "b", "prompt_tokens": 37}
+    # (admitted into an idle engine: no sweep in flight to go behind)
+    assert admit["b"] == {"request_id": "b", "prompt_tokens": 37, "inflight": False}
 
 
 def test_bucket_tokens_count_the_padding(plain):
@@ -720,7 +834,14 @@ def test_admission_gaps_are_partitioned_and_the_bubble_is_their_sum(closed_loop)
     assert s["sched_gaps"]["admission"] >= 4
     in_phases = sum(s["sched_admit_gap_s"].values())
     assert in_phases == pytest.approx(s["sched_gap_s"]["admission"], rel=0.02)
-    assert s["sched_admit_gap_s"]["prefill"] > 0 and s["sched_admit_gap_s"]["first_token"] > 0
+    assert s["sched_admit_gap_s"]["prefill"] > 0
+    if s["admissions_inflight"] == s["sched_admit_gap_admissions"]:
+        # every gap was an admission's behind the sweep in flight: it holds
+        # that sweep's emission and the wait for the first token, and ends
+        # where the token is read back, before the host's work on it
+        assert {p for p, v in s["sched_admit_gap_s"].items() if v} <= {"emit", "loop", "prefill"}
+    elif not s["admissions_inflight"]:
+        assert s["sched_admit_gap_s"]["first_token"] > 0
     # the wait for a sweep's tokens is never part of a gap, an idle wait neither
     assert s["sched_admit_gap_s"]["decode_retire"] == s["sched_admit_gap_s"]["idle"] == 0.0
     assert s["bubble_s"] == pytest.approx(sum(s["sched_gap_s"].values()), rel=1e-12)
@@ -754,12 +875,74 @@ def test_one_admission_gap_an_admission_or_one_for_two_admitted_together(closed_
     for _, _, attrs in admission:
         assert int(attrs["admissions"]) == len(attrs["request_ids"].split(",")) >= 1
         assert int(attrs["waiting_slots"]) == 1
-    # every gap but one closed without a dispatch was said on the dispatch that closed it
+    # every gap but one closed without a dispatch was said on the dispatch
+    # that closed it; the gap of an admission behind the sweep in flight is
+    # closed by its first token's readback, and no dispatch says it
     said = closing_dispatches(closed_loop["spans"])
-    assert sum(a["admissions"] for a in said) == s["sched_admit_gap_admissions"]
-    assert [a["cause"] for a in said].count("admission") == s["sched_gaps"]["admission"]
     assert all(set(a) == {"gap_ms", "cause", "waiting", "admissions"} for a in said)
-    assert sum(a["gap_ms"] for a in said) == pytest.approx(1e3 * s["bubble_s"], rel=0.02)
+    if s["admissions_inflight"]:
+        assert eng.ecfg.decode_pipeline
+        assert sum(a["admissions"] for a in said) < s["sched_admit_gap_admissions"]
+        assert sum(a["gap_ms"] for a in said) < 1e3 * s["bubble_s"]
+    else:
+        assert sum(a["admissions"] for a in said) == s["sched_admit_gap_admissions"]
+        assert [a["cause"] for a in said].count("admission") == s["sched_gaps"]["admission"]
+        assert sum(a["gap_ms"] for a in said) == pytest.approx(1e3 * s["bubble_s"], rel=0.02)
+
+
+def test_an_engines_admission_behind_a_sweep_books_one_gap_and_a_drained_one_as_before(params):
+    """Driven iteration by iteration, two slots, one caller decoding: the
+    second caller is admitted behind the sweep in flight and the ledger books
+    ONE admission gap from that sweep's readback to the first token's, the
+    token stamped where the gap ends; with ``decode_pipeline`` off the same
+    admission books the drained gap that the next dispatch closes."""
+    for pipeline in (True, False):
+        eng = Engine(params, CFG, EngineConfig(max_slots=2, max_seq_len=128, max_prefill_len=64,
+                                               min_prefill_bucket=16, decode_pipeline=pipeline))
+        spans = []
+        eng._sched = SchedLedger(annotate=lambda n, **a: spans.append((n, a)) or rt_tracing._NO_SPAN,
+                                 exposed=eng._host_exposed, on_gap=eng._trace_gap)
+
+        def iterate():
+            with eng._sched.phase("loop"):      # as Engine._loop does around every iteration
+                eng._schedule_once()
+
+        eng.submit(GenRequest(prompt_tokens=[3, 1, 4], max_new_tokens=20, request_id="a"))
+        iterate()
+        iterate()
+        before = eng.snapshot_stats()
+        b = eng.submit(GenRequest(prompt_tokens=[8, 6, 7, 5], max_new_tokens=5, request_id="b"))
+        iterate()
+        iterate()
+        s = eng.snapshot_stats()
+        assert len(b.tokens) >= 1
+        gaps = {c: s["sched_gaps"][c] - before["sched_gaps"][c] for c in s["sched_gaps"]}
+        assert gaps["admission"] == 1 and gaps["prefill_piece"] == 0
+        assert s["sched_admit_gap_admissions"] == 1 == s["admissions_inflight"] + (not pipeline)
+        in_phases = s["sched_admit_gap_s"]
+        assert sum(in_phases.values()) == pytest.approx(s["sched_gap_s"]["admission"], rel=1e-9)
+        (t0, t1, attrs), = [g for g in gap_spans(eng) if g[2]["cause"] == "admission"]
+        assert attrs["request_ids"] == "b" and int(attrs["admissions"]) == 1
+        assert int(attrs["waiting_slots"]) == 1
+        # (the drained gap spans two iterations, and this test's time between
+        # them is nobody's; behind a sweep the gap lies inside one iteration)
+        assert t1 - t0 >= s["sched_gap_s"]["admission"] * (1 - 1e-6)
+        if pipeline:
+            assert t1 - t0 == pytest.approx(s["sched_gap_s"]["admission"], rel=1e-6)
+        closing = [x for n, x in spans if n == "kvmini.decode_dispatch" and x.get("cause") == "admission"]
+        admit = [x for n, x in spans if n == "kvmini.admit" and x["request_id"] == "b"]
+        assert admit == [{"request_id": "b", "prompt_tokens": 4, "inflight": pipeline}]
+        if pipeline:
+            # the first token is stamped where its readback, and the gap, ends
+            assert b.t_first_token == pytest.approx(t1, abs=1e-9)
+            assert {p for p, v in in_phases.items() if v} <= {"emit", "loop", "prefill"}
+            assert in_phases["prefill"] > 0 and not closing
+            assert s["busy_s"] > before["busy_s"] and s["prefill_chunk_stall_s"] > 0
+        else:
+            assert t0 < b.t_first_token < t1
+            assert in_phases["first_token"] > 0 and in_phases["admit"] > 0
+            assert [x["admissions"] for x in closing] == [1]
+        assert s["bubble_s"] == pytest.approx(sum(s["sched_gap_s"].values()), rel=1e-12)
 
 
 def test_pipelined_sweeps_book_no_gap_and_synchronous_ones_each_their_own(closed_loop):
@@ -779,6 +962,12 @@ def test_the_wait_for_a_sweeps_tokens_is_never_exposed(closed_loop, plain):
     for s in (closed_loop["stats"], plain["stats"]):
         assert s["sched_s"]["decode_retire"] > 0
         assert s["sched_exposed_s"]["decode_retire"] == 0.0
+        if s["admissions_inflight"]:
+            # admissions behind the sweep in flight: the device has that
+            # sweep and then their prefill queued while the host works
+            assert s["sched_exposed_s"]["admit"] < s["sched_s"]["admit"]
+            assert s["sched_exposed_s"]["first_token"] < s["sched_s"]["first_token"]
+            continue
         # the emission after a drained readback, the admission, the next
         # dispatch: exposed, as they were
         for p in ("emit", "admit", "first_token", "decode_prepare", "decode_dispatch", "loop"):

@@ -235,6 +235,7 @@ NAMES = [
     ("drafter", "spec", (), False, "spec_step", "spec[k=3]"),
     ("dense", "first", (), False, "first", None),
     ("dense", "reset", (), False, "reset", None),
+    ("dense", "reset", (True,), False, "reset", None),     # the variant that merges the token into the carry
     ("disagg", "inject", (), False, "inject", "disagg_inject"),
     ("paged", "kv_block_write", (), False, "kv_block_write", "kv_block_write"),
     ("disagg", "lane", (True,), False, "fresh", "disagg_prefill[16]"),
@@ -255,3 +256,22 @@ def test_every_kind_of_step_keeps_its_jit_name_and_its_label(eng, kind, key, dra
         assert engine(eng)._step(kind, *key, draft=draft) is fn  # built once
     assert getattr(fn, "_fn", fn).__name__ == jit_name
     assert getattr(fn, "_label", None) == label
+
+
+@pytest.mark.parametrize("carry", [False, True], ids=["drained", "behind_a_sweep"])
+def test_reset_zeroes_the_row_records_the_token_and_merges_it_into_the_carry(carry):
+    """``build_reset``: the slot's count row holds the first token alone, every
+    other row is left; the ``carry`` variant (an admission dispatched behind
+    the sweep in flight) also returns the decode carry with the token in the
+    slot's row, all on the device."""
+    counts = jnp.arange(4 * 6, dtype=jnp.int32).reshape(4, 6)
+    want = np.arange(4 * 6, dtype=np.int32).reshape(4, 6)
+    want[2] = [0, 0, 0, 1, 0, 0]
+    fn = steps.build_reset(carry)
+    if not carry:
+        out = fn(counts, jnp.int32(2), jnp.int32(3))
+        assert np.array_equal(np.asarray(out), want)
+        return
+    out, tokens = fn(counts, jnp.int32(2), jnp.int32(3), jnp.asarray([7, 8, 9, 10], jnp.int32))
+    assert np.array_equal(np.asarray(out), want)
+    assert np.asarray(tokens).tolist() == [7, 8, 3, 10] and tokens.dtype == jnp.int32
