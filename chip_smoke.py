@@ -33,7 +33,11 @@ three legs, set-up seconds only, no performance claim.
   latent experts): the same asks. Then falcon-h1-34b at its published
   widths and multipliers, two of its two-mixer blocks (a Mamba-2 mixer of
   state 256 and rotary attention side by side on one normed input) and a
-  quarter of its vocabulary's rows: the same asks.
+  quarter of its vocabulary's rows: the same asks. Then lfm2-8b-a1b at its
+  published widths, six layers (convolution, convolution, attention,
+  convolution, attention, convolution; a dense MLP then five layers of all
+  32 SwiGLU experts): the flash prefill takes its 64-wide heads, the decode
+  step keeps the eager read, every weight stack is read where it lies.
 
 With no TPU it exits non-zero before doing anything; it never carries on on
 the CPU. ``--cpu-dry-run`` is the explicit exception for debugging the
@@ -225,8 +229,8 @@ def require_state_in_place(engine, exes: dict[str, dict], dry: bool) -> None:
         f"kvmini_tpu_ssm_state_kernel={took['ssm']}")
     for lab in sorted(exes):
         say(f"  {lab}: state_copy_bytes={exes[lab]['state_copy_bytes']}")
-    if dry or not engine.cfg.is_hybrid:
-        return
+    if dry or not engine.cfg.is_hybrid or engine.cfg.is_conv_stack:
+        return          # a short-convolution stack keeps a tail and no recurrent state
     kind = "ssm" if engine.cfg.is_pattern_stack else "linear"
     check(took[kind] == 1, f"the decode program did not take the {kind} state's kernel")
     n = exes["decode[chunk=1]"]["state_copy_bytes"]
@@ -310,7 +314,7 @@ def sizes(dry: bool) -> dict:
     if dry:
         return dict(main_model="mistral-tiny", small_model="llama-tiny",
                     hybrid_model="olmo-hybrid-tiny", pattern_model="nemotron-h-tiny",
-                    parallel_model="falcon-h1-tiny",
+                    parallel_model="falcon-h1-tiny", conv_model="lfm2-moe-tiny",
                     phases=MAIN_PHASES[:3], out_tokens=8, main_parity=(32,),
                     paged_block=16, paged_parity=(32,), engine_words=20,
                     chunk=32, int8_parity=(32, 32))
@@ -318,6 +322,8 @@ def sizes(dry: bool) -> dict:
                 hybrid_model=hybrid_preset(periods=2),
                 pattern_model=pattern_preset("MEM*EME"),
                 parallel_model=parallel_preset(blocks=2),
+                conv_model=conv_preset(("conv", "conv", "full_attention", "conv",
+                                        "full_attention", "conv")),
                 phases=MAIN_PHASES, out_tokens=32, main_parity=(128,),
                 paged_block=64, paged_parity=(128,), engine_words=160,
                 chunk=256, int8_parity=(256, 256))
@@ -584,6 +590,17 @@ def parallel_preset(blocks: int) -> str:
     return name
 
 
+def conv_preset(kinds: tuple[str, ...]) -> str:
+    """lfm2-8b-a1b cut to layers of these ``kinds`` (every width as
+    published, all 32 experts held), the dense MLP under the first alone."""
+    from kserve_vllm_mini_tpu.models.config import PRESETS, get_config
+
+    name = f"lfm2-8b-a1b-{len(kinds)}l"
+    PRESETS[name] = get_config("lfm2-8b-a1b").scaled(
+        name=name, n_layers=len(kinds), layer_types=kinds, num_dense_layers=1)
+    return name
+
+
 def leg_engines(dry: bool) -> dict:
     from kserve_vllm_mini_tpu.runtime.local import start_local_server
 
@@ -622,6 +639,15 @@ def leg_engines(dry: bool) -> dict:
         ("two_mixer", {"model": sz["parallel_model"], "quantization": "int8",
                        "prefill_chunk": sz["chunk"]},
          ["prefill[", "decode[chunk=1]"],
+         dict(chunks=sz["int8_parity"], decode=True, block_size=None,
+              kv_int8=False)),
+        # gated short convolutions (a two-position tail, no state) and rotary
+        # attention with a per-head QK-norm (32 / 8 heads x 64: flash prefill;
+        # no "decode[", 64-wide heads keep the eager decode read), a dense MLP
+        # then SwiGLU experts, all 32 held; continuation pieces carry the tail
+        ("conv_moe", {"model": sz["conv_model"], "quantization": "int8",
+                      "prefill_chunk": sz["chunk"]},
+         ["prefill["],
          dict(chunks=sz["int8_parity"], decode=True, block_size=None,
               kv_int8=False)),
     ]

@@ -50,6 +50,16 @@ orthogonal architecture axes extend it to the other families:
   follows the block kind). The ``*_multiplier(s)`` fields are the family's
   fixed muP scalars, applied to activations where the equations place them;
   1.0 / None leave a program as it is.
+- ``layer_types`` with ``"conv"`` — a stack of operator + feed-forward pairs
+  (LFM2-MoE): each layer's sequence operator is a gated short convolution
+  (models/short_conv.py; ``conv_L_cache`` taps) or rotary attention with an
+  RMSNorm over each head of q and k, in ANY order (the published one is
+  irregular); the first ``num_dense_layers`` layers' feed-forward is a dense
+  SwiGLU MLP (``d_ff``), every later layer's is ``n_routed_experts`` SwiGLU
+  experts ``moe_intermediate_size`` wide behind a biased sigmoid router
+  (models/moe.py moe_glu_block), no shared expert. Four weight stacks, one a
+  kind; the cache holds K/V for the attention layers and a convolution tail
+  of ``conv_L_cache - 1`` positions for the others, and no recurrent state.
 """
 
 from __future__ import annotations
@@ -123,7 +133,14 @@ class ModelConfig:
     # repeating period of k linear layers then one full layer. None = every
     # layer is full attention. A list (from JSON) becomes a tuple: the
     # config is a static jit argument and must hash.
+    # A stack whose kinds are "conv" and "full_attention" (LFM2-MoE) is the
+    # other stack this key spells: any order, sized by the three keys below
+    # and the expert block's (n_routed_experts is the source's num_experts).
     layer_types: Optional[tuple[str, ...]] = None
+    # taps of the short convolution (its tail keeps conv_L_cache - 1 inputs)
+    conv_L_cache: int = 3
+    # leading layers whose feed-forward is the dense MLP; experts after them
+    num_dense_layers: int = 0
     # the gated-delta-rule mixer's sizes (models/linear_attention.py)
     linear_num_key_heads: int = 0
     linear_num_value_heads: int = 0
@@ -199,6 +216,9 @@ class ModelConfig:
             raise ValueError(
                 f"layer_types names {len(types)} layers, n_layers={self.n_layers}"
             )
+        if "conv" in types:
+            self._check_conv_stack()
+            return
         if "linear_attention" not in types:
             raise ValueError("layer_types without a linear_attention layer: "
                              "leave it None for a full-attention stack")
@@ -222,6 +242,33 @@ class ModelConfig:
         if self.block != "olmo2" or self.is_moe or self.alt_sliding_window:
             raise ValueError("a hybrid stack takes block='olmo2' with a dense MLP "
                              "and one attention mask for every full layer")
+
+    def _check_conv_stack(self) -> None:
+        if set(self.layer_types) - {"conv", "full_attention"}:
+            raise ValueError("a stack with 'conv' layers takes 'conv' and 'full_attention' "
+                             f"layers only; got {self.layer_types}")
+        if self.block != "llama" or self.is_moe or self.sliding_window is not None:
+            raise ValueError("a 'conv' stack takes no block, n_experts or sliding_window: "
+                             "its layers are named by layer_types and num_dense_layers")
+        if self.conv_L_cache < 2 or not 0 <= self.num_dense_layers <= self.n_layers:
+            raise ValueError("a 'conv' stack needs conv_L_cache >= 2 and "
+                             "0 <= num_dense_layers <= n_layers")
+        if self.n_expert_layers:
+            if min(self.n_routed_experts, self.num_experts_per_tok,
+                   self.moe_intermediate_size) < 1:
+                raise ValueError("the layers past num_dense_layers need n_routed_experts, "
+                                 "num_experts_per_tok and moe_intermediate_size >= 1")
+            self._check_experts_held()
+
+    def _check_experts_held(self) -> None:
+        if self.num_experts_per_tok > self.n_routed_experts:
+            raise ValueError("num_experts_per_tok exceeds n_routed_experts")
+        if not (0 <= self.expert_offset
+                and self.expert_offset + self.experts_held <= self.n_routed_experts
+                and self.experts_held >= 1):
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset + self.experts_held}) "
+                f"are not among the router's {self.n_routed_experts}")
 
     def _check_pattern(self) -> None:
         pat = self.hybrid_override_pattern
@@ -247,18 +294,12 @@ class ModelConfig:
                 raise ValueError("'E' blocks need n_routed_experts, num_experts_per_tok, "
                                  "moe_latent_size, moe_intermediate_size and "
                                  "moe_shared_expert_intermediate_size >= 1")
-            if self.num_experts_per_tok > self.n_routed_experts:
-                raise ValueError("num_experts_per_tok exceeds n_routed_experts")
-            if not (0 <= self.expert_offset
-                    and self.expert_offset + self.experts_held <= self.n_routed_experts
-                    and self.experts_held >= 1):
-                raise ValueError(
-                    f"experts [{self.expert_offset}, {self.expert_offset + self.experts_held}) "
-                    f"are not among the router's {self.n_routed_experts}")
+            self._check_experts_held()
 
     @property
     def is_hybrid(self) -> bool:
-        """A stack that keeps a recurrent state per slot beside K/V."""
+        """A stack that keeps, per slot and beside K/V, something that has no
+        positions: a recurrent state, a convolution tail, or both."""
         return self.layer_types is not None or self.is_pattern_stack
 
     @property
@@ -266,9 +307,16 @@ class ModelConfig:
         return self.hybrid_override_pattern is not None
 
     @property
+    def is_conv_stack(self) -> bool:
+        """``layer_types`` of short-convolution and attention operators."""
+        return self.layer_types is not None and "conv" in self.layer_types
+
+    @property
     def layer_period(self) -> int:
-        """Layers in one period of a layer_types stack (k linear + 1 full)."""
-        return self.layer_types.index("full_attention") + 1 if self.layer_types else 1
+        """Layers in one period of a linear-attention stack (k linear + 1 full)."""
+        if self.layer_types is None or self.is_conv_stack:
+            return 1
+        return self.layer_types.index("full_attention") + 1
 
     @property
     def n_full_layers(self) -> int:
@@ -276,7 +324,14 @@ class ModelConfig:
         full-attention ones, or a pattern's '*' and 'P' blocks."""
         if self.is_pattern_stack:
             return sum(map(self.hybrid_override_pattern.count, "*P"))
+        if self.is_conv_stack:
+            return self.layer_types.count("full_attention")
         return self.n_layers // self.layer_period
+
+    @property
+    def n_conv_layers(self) -> int:
+        """Layers that keep a short convolution's tail."""
+        return self.layer_types.count("conv") if self.is_conv_stack else 0
 
     @property
     def n_ssm_layers(self) -> int:
@@ -295,6 +350,10 @@ class ModelConfig:
 
     @property
     def n_expert_layers(self) -> int:
+        """Layers with routed experts: a pattern's 'E' blocks, a 'conv'
+        stack's layers past ``num_dense_layers``."""
+        if self.is_conv_stack:
+            return self.n_layers - self.num_dense_layers
         return (self.hybrid_override_pattern or "").count("E")
 
     @property
@@ -313,7 +372,7 @@ class ModelConfig:
 
     @property
     def n_linear_layers(self) -> int:
-        return self.n_layers - self.n_full_layers
+        return 0 if self.is_conv_stack else self.n_layers - self.n_full_layers
 
     @property
     def linear_conv_channels(self) -> int:
@@ -351,6 +410,9 @@ class ModelConfig:
         emb = self.vocab_size * self.d_model
         if self.is_pattern_stack:
             return 2 * emb + self.d_model + self._pattern_block_params()
+        if self.is_conv_stack:
+            head = 0 if self.tie_embeddings else emb
+            return emb + head + self.d_model + self._conv_stack_params()
         qo = self.d_model * self.n_heads * self.head_dim  # q and o projections
         attn = 2 * qo + 2 * self.d_model * (self.n_kv_heads * self.head_dim)
         mlp = (2 if self.block == "phi" else 3) * self.d_model * self.d_ff
@@ -393,6 +455,17 @@ class ModelConfig:
         parallel = mamba + attn + 3 * d * self.d_ff + 2 * d
         return ((self.n_ssm_layers - n_p) * (mamba + d) + (self.n_full_layers - n_p) * (attn + d)
                 + self.n_expert_layers * (experts + d) + n_p * parallel)
+
+    def _conv_stack_params(self) -> int:
+        """Parameters of a 'conv' stack's layers, the experts held here."""
+        d, hd = self.d_model, self.head_dim
+        conv = 4 * d * d + self.conv_L_cache * d           # in_proj (3d), out_proj, taps
+        attn = 2 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + 2 * hd
+        experts = (d * self.n_routed_experts + self.n_routed_experts   # router, bias
+                   + self.experts_held * 3 * d * self.moe_intermediate_size)
+        return (self.n_conv_layers * conv + self.n_full_layers * attn
+                + self.num_dense_layers * 3 * d * self.d_ff
+                + self.n_expert_layers * experts + self.n_layers * 2 * d)
 
     def scaled(self, **kwargs) -> "ModelConfig":
         return replace(self, **kwargs)
@@ -654,6 +727,32 @@ PRESETS: dict[str, ModelConfig] = {
                          0.3535533905932738),
         mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
     ),
+    # LFM2-8B-A1B (huggingface.co/LiquidAI/LFM2-8B-A1B config.json): 18 gated
+    # short convolutions and 6 attention layers (per-head QK-norm) in the
+    # published irregular order; a dense MLP under the first two layers, 32
+    # SwiGLU experts (4 a token, biased sigmoid router) under the other 22.
+    "lfm2-8b-a1b": ModelConfig(
+        name="lfm2-8b-a1b",
+        vocab_size=65_536,
+        d_model=2048,
+        n_layers=24,
+        n_heads=32,
+        n_kv_heads=8,
+        d_ff=7168,
+        max_seq_len=8192,
+        rope_theta=1_000_000.0,
+        rms_eps=1e-5,
+        tie_embeddings=True,
+        layer_types=tuple("full_attention" if i in (2, 6, 10, 14, 18, 21) else "conv"
+                          for i in range(24)),
+        conv_L_cache=3,
+        num_dense_layers=2,
+        n_routed_experts=32,
+        num_experts_per_tok=4,
+        moe_intermediate_size=1792,
+        routed_scaling_factor=1.0,
+        norm_topk_prob=True,
+    ),
     # -- tiny CI variants (CPU in <1s) exercising each architecture axis ----
     "mistral-tiny": ModelConfig(
         name="mistral-tiny",
@@ -793,6 +892,29 @@ PRESETS: dict[str, ModelConfig] = {
         ssm_out_multiplier=0.35,
         ssm_multipliers=(0.7, 0.5, 0.35, 1.4, 0.7),
         mlp_multipliers=(0.35, 0.2),
+    ),
+    # a whole irregular order (gaps 2, 1 between the attention layers), one
+    # dense layer then experts, every expert held
+    "lfm2-moe-tiny": ModelConfig(
+        name="lfm2-moe-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=6,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=256,
+        max_seq_len=256,
+        rope_theta=1_000_000.0,
+        rms_eps=1e-5,
+        tie_embeddings=True,
+        layer_types=("conv", "conv", "full_attention", "conv", "full_attention", "conv"),
+        conv_L_cache=3,
+        num_dense_layers=1,
+        n_routed_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=96,
+        routed_scaling_factor=1.0,
+        norm_topk_prob=True,
     ),
     "mixtral-tiny": ModelConfig(
         name="mixtral-tiny",

@@ -79,9 +79,10 @@ def state_shapes(cfg: ModelConfig, batch: int) -> dict[str, tuple[tuple[int, ...
 
 def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, taps: jnp.ndarray,
                 valid: jnp.ndarray, bias: jnp.ndarray | None = None,
-                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+                activation=jax.nn.silu) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Depthwise causal convolution (plus ``bias`` [C] where the mixer has
-    one) then SiLU. ``u`` [B, T, C] are this block's inputs, ``tail``
+    one) then ``activation`` (SiLU for the delta rule and Mamba-2; None for
+    the short-convolution operator, which has none). ``u`` [B, T, C] are this block's inputs, ``tail``
     [B, K-1, C] the inputs before it, ``taps`` [K, C] with the last tap on
     the current input. Returns the float32 outputs [B, T, C] and the new
     tail: the K-1 inputs before position ``valid`` (the old tail where
@@ -95,7 +96,7 @@ def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, taps: jnp.ndarray,
         y = y + bias.astype(jnp.float32)
     rows = valid[:, None] + jnp.arange(k - 1)[None, :]             # [B, K-1]
     new_tail = jnp.take_along_axis(ext, rows[:, :, None], axis=1)
-    return jax.nn.silu(y), new_tail.astype(tail.dtype)
+    return (y if activation is None else activation(y)), new_tail.astype(tail.dtype)
 
 
 def _l2(x: jnp.ndarray) -> jnp.ndarray:

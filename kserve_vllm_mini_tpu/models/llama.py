@@ -94,6 +94,8 @@ def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
     keys = _init_keys(rng, cfg)
     if cfg.is_pattern_stack:
         return _init_pattern(keys, cfg, leaf_fn)
+    if cfg.is_conv_stack:
+        return _init_conv_stack(keys, cfg, leaf_fn)
     if cfg.is_hybrid:
         return _init_hybrid(keys, cfg, leaf_fn)
     L = cfg.n_layers
@@ -295,6 +297,84 @@ def _init_pattern(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Para
     }
 
 
+def conv_stack_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
+    """Per-layer shape of every matmul of a ``"conv"`` stack, by the four
+    stacks of ``layers``: the two kinds of sequence operator and the two
+    kinds of feed-forward. Every one is quantized in the int8 tree
+    (ops/quant.py CONV_STACK_QUANTIZABLE)."""
+    from kserve_vllm_mini_tpu.models.short_conv import mixer_weight_shapes
+
+    d, hd = cfg.d_model, cfg.head_dim
+    ff, held = cfg.moe_intermediate_size, cfg.experts_held
+    return {
+        "conv": mixer_weight_shapes(cfg),
+        "attn": {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
+                 "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)},
+        "mlp": {"w_gate": (d, cfg.d_ff), "w_up": (d, cfg.d_ff), "w_down": (cfg.d_ff, d)},
+        "experts": {"w_gate": (held, d, ff), "w_up": (held, d, ff), "w_down": (held, ff, d)},
+    }
+
+
+def conv_stack_counts(cfg: ModelConfig) -> dict[str, int]:
+    """Layers in each of a ``"conv"`` stack's four weight stacks."""
+    return {"conv": cfg.n_conv_layers, "attn": cfg.n_full_layers,
+            "mlp": cfg.num_dense_layers, "experts": cfg.n_expert_layers}
+
+
+def _init_conv_stack(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
+    """A ``"conv"`` stack's tree: ``layers`` holds ``{"conv": [n_conv, ...],
+    "attn": [n_attn, ...], "mlp": [num_dense_layers, ...], "experts":
+    [n_expert_layers, ...]}``, in stack order within the kind (a stack of
+    no layers has no entry). An operator's stack holds its layer's
+    ``norm_in``, a feed-forward's its ``ffn_norm``. Small leaves get values
+    that show when they are left out."""
+    dt = cfg.jnp_dtype
+    shapes = conv_stack_weight_shapes(cfg)
+
+    def stack(key, n, shape, fn=leaf_fn):
+        def body(_, k):
+            return None, fn(_nrm(k, shape, dt))
+
+        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
+
+    def gains(key, n, width):
+        return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
+
+    layers: Params = {}
+    for (kind, n), key in zip(conv_stack_counts(cfg).items(),
+                              (keys["wq"], keys["wk"], keys["wv"], keys["wo"])):
+        if not n:
+            continue
+        ks = jax.random.split(key, 8)
+        sub: Params = {name: stack(k, n, shape)
+                       for (name, shape), k in zip(shapes[kind].items(), ks)}
+        if kind == "conv":
+            sub["norm_in"] = gains(ks[4], n, cfg.d_model)
+            sub["conv"] = (0.5 * jax.random.normal(
+                ks[5], (n, cfg.conv_L_cache, cfg.d_model), jnp.float32)).astype(dt)
+        elif kind == "attn":
+            sub["norm_in"] = gains(ks[4], n, cfg.d_model)
+            # one gain a head dimension, shared by every head
+            sub["q_norm"] = gains(ks[5], n, cfg.head_dim)
+            sub["k_norm"] = gains(ks[6], n, cfg.head_dim)
+        else:
+            sub["ffn_norm"] = gains(ks[4], n, cfg.d_model)
+            if kind == "experts":
+                sub["router"] = stack(ks[5], n, (cfg.d_model, cfg.n_routed_experts),
+                                      lambda w: w.astype(jnp.float32))
+                sub["router_bias"] = 0.05 * jax.random.normal(
+                    ks[6], (n, cfg.n_routed_experts), jnp.float32)
+        layers[kind] = sub
+    params: Params = {
+        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
+        "layers": layers,
+        "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt)
+    return params
+
+
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Random-normal init (0.02 std), bf16 — for tests, benches, and as the
     target pytree structure for checkpoint loading.
@@ -349,6 +429,8 @@ def init_kv_cache(
     if cfg.is_hybrid:
         if cfg.is_pattern_stack:
             from kserve_vllm_mini_tpu.models.mamba import state_shapes
+        elif cfg.is_conv_stack:
+            from kserve_vllm_mini_tpu.models.short_conv import state_shapes
         else:
             from kserve_vllm_mini_tpu.models.linear_attention import state_shapes
 
@@ -482,6 +564,11 @@ def qkv_proj(
     q = q.reshape(B, T, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     k = k.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
     v = v.reshape(B, T, cfg.n_kv_heads, cfg.head_dim).transpose(0, 2, 1, 3)
+    if cfg.is_conv_stack:
+        # LFM2's QK-norm: over each head's head_dim, one gain for every head
+        # (olmo2's above is over the whole projection)
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
     rd = cfg.rotary_dim
     if rd == 0:
         return q, k, v           # attention that carries no position
@@ -1055,6 +1142,51 @@ def run_cached_layers(
             "a hybrid stack serves from the dense cache on one device, "
             "without adapters (no block table, pipeline stage or LoRA bank)"
         )
+    if cfg.is_conv_stack:
+        # An operator and a feed-forward a layer, in the published (irregular)
+        # order; four weight stacks, each read where it lies. Unrolled with
+        # static indices like the pattern walk below, for the same reason.
+        from kserve_vllm_mini_tpu.models.moe import moe_glu_block
+        from kserve_vllm_mini_tpu.models.short_conv import short_conv_mixer
+
+        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
+                 else valid_len.astype(jnp.int32))
+        real = jnp.arange(T)[None, :] < valid[:, None]
+        cache = dict(kv_cache)
+        seen = dict.fromkeys(("conv", "attn"), 0)
+        stats = jnp.zeros((3,), jnp.int32)
+
+        def at(kind, i):
+            return jax.tree_util.tree_map(lambda a: a[i], layers[kind])
+
+        for l, kind in enumerate(cfg.layer_types):
+            kind = "attn" if kind == "full_attention" else kind
+            i = seen[kind]
+            seen[kind] += 1
+            p = at(kind, i)
+            h = rms_norm(x, p["norm_in"], cfg.rms_eps)
+            if kind == "conv":
+                tail = cache["conv_tail"][i]
+                if fresh_prefill:
+                    # a new sequence starts from an empty tail whatever the slot held
+                    tail = jnp.zeros_like(tail)
+                out, tail = short_conv_mixer(p, cfg, h, tail, valid)
+                cache["conv_tail"] = cache["conv_tail"].at[i].set(tail)
+            else:
+                o, cache = attend(h, cache, p, None, jnp.int32(i))
+                o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
+                out = linear(o, p["wo"], mode=cfg.quant_mode)
+            x = x + out
+            if l < cfg.num_dense_layers:
+                p = at("mlp", l)
+                out = swiglu_mlp(p, cfg, rms_norm(x, p["ffn_norm"], cfg.rms_eps))
+            else:
+                p = at("experts", l - cfg.num_dense_layers)
+                out, counts = moe_glu_block(p, cfg, rms_norm(x, p["ffn_norm"], cfg.rms_eps), real)
+                stats = stats + counts
+            x = x + out
+        return (x, cache, stats) if with_stats else (x, cache)
+
     if cfg.is_pattern_stack:
         # One mixer a block, in the pattern's (irregular) order; each kind's
         # weights are stacked on their own. The walk is unrolled with static

@@ -1,7 +1,7 @@
 """Sparse mixture-of-experts layers, drop-free, for a device that holds some
 or all of the experts.
 
-Two layers share one dispatch:
+Three layers share one dispatch:
 
 - ``moe_mlp`` (Mixtral): a softmax router, the top ``n_experts_per_tok``
   renormalised, SwiGLU experts at the model's width, every expert held. The
@@ -16,6 +16,12 @@ Two layers share one dispatch:
   routes over all of them, weights as the whole model would, and computes
   the part of the result its own experts give. What the absent experts would
   add is left out; on one device nothing stands in for their exchange.
+- ``moe_glu_block`` (LFM2-MoE's feed-forward past ``num_dense_layers``): the
+  same float32 sigmoid router and selection bias, the chosen scores
+  normalised with the family's ``+ 1e-6`` and scaled; Mixtral's SwiGLU
+  experts (``glu_experts``, the one body both run) ``moe_intermediate_size``
+  wide at the model's width, no latent and no shared expert. The device is
+  told which experts it holds exactly as above.
 
 **Dispatch: every held expert runs every token of the block, and the routing
 weight (zero where the token did not choose the expert) is applied at the
@@ -30,8 +36,8 @@ anyway, so there the arithmetic is free; a prefill piece of 128 positions
 touches every expert and its arithmetic still hides under the weight stream;
 longer pieces pay it (ROADMAP: a grouped matmul over tokens sorted by
 expert, which would also skip the experts a decode step does not touch).
-Pieces longer than ``TOKEN_BLOCK`` run block by block so that the
-``[experts, tokens, width]`` intermediates stay bounded.
+Pieces longer than ``TOKEN_BLOCK`` run block by block (unrolled) so that
+the ``[experts, tokens, width]`` intermediates stay bounded.
 
 Quantization: expert stacks quantize per output channel like dense weights
 (ops/quant.py works on any [..., in, out] stack); routers and the selection
@@ -92,10 +98,28 @@ def _run_held(x: jnp.ndarray, take: jnp.ndarray, body) -> jnp.ndarray:
     n = x.shape[0]
     if n <= TOKEN_BLOCK:
         return block((x, take))
+    # Unrolled, not a ``lax.map``: a loop's operands must be buffers of their
+    # own, so XLA sliced every expert stack out of its layer stack and copied
+    # it (transposed) before the loop: 117 MB a stack, 6.9 GB of temporaries
+    # in a 1024-token prefill of 22 layers x 32 experts (chipless compile).
     pad = -n % TOKEN_BLOCK
-    xs = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, TOKEN_BLOCK, x.shape[1])
-    ts = jnp.pad(take, ((0, pad), (0, 0))).reshape(-1, TOKEN_BLOCK, take.shape[1])
-    return jax.lax.map(block, (xs, ts)).reshape(n + pad, -1)[:n]
+    xs = jnp.pad(x, ((0, pad), (0, 0)))
+    ts = jnp.pad(take, ((0, pad), (0, 0)))
+    return jnp.concatenate([block((xs[i:i + TOKEN_BLOCK], ts[i:i + TOKEN_BLOCK]))
+                            for i in range(0, n + pad, TOKEN_BLOCK)])[:n]
+
+
+def glu_experts(p: dict[str, Any], qm: str):
+    """The SwiGLU expert body over the stacks ``w_gate``/``w_up`` [E, D, F]
+    and ``w_down`` [E, F, D]: ``x`` [S, D] -> [E, S, D], every expert on
+    every token of the block."""
+    def experts(xb):
+        gated = jax.nn.silu(
+            _expert_linear(xb, p["w_gate"], qm).astype(jnp.float32)
+        ).astype(xb.dtype) * _expert_linear(xb, p["w_up"], qm)
+        return _expert_linear(gated, p["w_down"], qm)
+
+    return experts
 
 
 def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
@@ -104,35 +128,61 @@ def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     ``p`` holds this layer's ``router`` [D, E] and the expert stacks
     ``w_gate``/``w_up`` [E, D, F], ``w_down`` [E, F, D]."""
     B, T, D = h.shape
-    dt, qm = h.dtype, cfg.quant_mode
     x = h.reshape(B * T, D)
     logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
     gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
     gates = gates / jnp.maximum(gates.sum(axis=-1, keepdims=True), 1e-9)
+    out = _run_held(x, held_weights(gates, chosen, 0, cfg.n_experts)[0],
+                    glu_experts(p, cfg.quant_mode))
+    return out.astype(h.dtype).reshape(B, T, D)
 
-    def experts(xb):
-        gated = jax.nn.silu(
-            _expert_linear(xb, p["w_gate"], qm).astype(jnp.float32)
-        ).astype(dt) * _expert_linear(xb, p["w_up"], qm)
-        return _expert_linear(gated, p["w_down"], qm)
 
-    out = _run_held(x, held_weights(gates, chosen, 0, cfg.n_experts)[0], experts)
-    return out.astype(dt).reshape(B, T, D)
+NORM_EPS_LATENT = 1e-20   # Nemotron-H: sum(w) + 1e-20
+NORM_EPS_GLU = 1e-6       # LFM2-MoE: sum(w) + 1e-6
 
 
 def route_sigmoid(x: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray,
-                  cfg: ModelConfig) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The ``E`` block's router on x [S, D] -> (weights [S, K] float32,
+                  cfg: ModelConfig, norm_eps: float = NORM_EPS_LATENT,
+                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The biased sigmoid router on x [S, D] -> (weights [S, K] float32,
     expert ids [S, K]) over ALL ``n_routed_experts``: chosen by score +
-    bias, weighted by score, normalised over all the chosen, scaled. Float32
-    at the highest precision: a rounded score picks another expert."""
+    bias, weighted by score, normalised over all the chosen (``norm_eps`` is
+    the family's constant in that sum), scaled. Float32 at the highest
+    precision: a rounded score picks another expert."""
     logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HI)
     scores = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.num_experts_per_tok)
     w = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
     return w * cfg.routed_scaling_factor, chosen
+
+
+def routing_stats(landed: jnp.ndarray, real: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """int32 [3] from ``landed`` [S, held] (whether a token chose a held
+    expert), over the positions ``real`` [B, T] marks (all when None):
+    assignments that landed on an expert held here, held experts with at
+    least one token, and the fullest held expert's tokens."""
+    if real is not None:
+        landed = landed & real.reshape(-1, 1)
+    load = jnp.sum(landed, axis=0, dtype=jnp.int32)                        # [held]
+    return jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load)])
+
+
+def moe_glu_block(
+    p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
+    real: Optional[jnp.ndarray] = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """LFM2-MoE's routed feed-forward on the normed hidden ``h`` [B, T, D]
+    -> (delta [B, T, D], stats as ``routing_stats``). ``p``: ``router``
+    [D, n_routed] and ``router_bias`` [n_routed] float32; the held experts'
+    stacks ``w_gate``/``w_up`` [held, D, F], ``w_down`` [held, F, D]."""
+    B, T, D = h.shape
+    x = h.reshape(B * T, D)
+    w, chosen = route_sigmoid(x, p["router"], p["router_bias"], cfg, NORM_EPS_GLU)
+    take, landed = held_weights(w, chosen, cfg.expert_offset, cfg.experts_held)
+    out = _run_held(x, take, glu_experts(p, cfg.quant_mode))
+    return out.astype(h.dtype).reshape(B, T, D), routing_stats(landed, real)
 
 
 def relu2(x: jnp.ndarray) -> jnp.ndarray:
@@ -149,9 +199,7 @@ def moe_latent_block(
     [latent, D]; the held experts' stacks ``w1`` [held, latent, F], ``w2``
     [held, F, latent]; the shared expert ``s1`` [D, Fs], ``s2`` [Fs, D].
 
-    ``stats`` is int32 [3] over the positions ``real`` [B, T] marks (all of
-    them when None): assignments that landed on an expert held here, held
-    experts with at least one token, and the fullest held expert's tokens."""
+    ``stats`` is ``routing_stats`` over the positions ``real`` marks."""
     B, T, D = h.shape
     dt, qm = h.dtype, cfg.quant_mode
     x = h.reshape(B * T, D)
@@ -164,8 +212,5 @@ def moe_latent_block(
 
     routed = linear(_run_held(u, take, experts).astype(dt), p["w_out"], mode=qm)
     shared = linear(relu2(linear(x, p["s1"], mode=qm)), p["s2"], mode=qm)
-    if real is not None:
-        landed = landed & real.reshape(B * T, 1)
-    load = jnp.sum(landed, axis=0, dtype=jnp.int32)                        # [held]
-    stats = jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load)])
+    stats = routing_stats(landed, real)
     return (routed + shared).reshape(B, T, D), stats

@@ -158,6 +158,11 @@ PATTERN_QUANTIZABLE = ("in_proj", "out_proj", "wq", "wk", "wv", "wo",
                        "w_in", "w_out", "w1", "w2", "s1", "s2",
                        # a two-mixer block's MLP, in the same stack as its mixers
                        "w_gate", "w_up", "w_down")
+# a "conv" stack (models/llama.py conv_stack_weight_shapes): the short
+# convolution's two projections, the attention projections, the dense MLP and
+# the expert stacks (which carry the MLP's names). Routers, selection biases,
+# taps and gains stay as they are.
+CONV_STACK_QUANTIZABLE = ("in_proj", "out_proj") + QUANTIZABLE
 
 
 def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
@@ -171,6 +176,9 @@ def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
     layers = params["layers"]
     if "linear" in layers and "full" in layers:
         out["layers"] = {kind: stack(sub, HYBRID_QUANTIZABLE) for kind, sub in layers.items()}
+    elif "conv" in layers:
+        out["layers"] = {kind: stack(sub, CONV_STACK_QUANTIZABLE)
+                         for kind, sub in layers.items()}
     elif set(layers) <= {"mamba", "attn", "experts", "parallel"}:
         out["layers"] = {kind: stack(sub, PATTERN_QUANTIZABLE) for kind, sub in layers.items()}
     else:

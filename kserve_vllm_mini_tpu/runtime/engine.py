@@ -661,14 +661,17 @@ class Engine:
             self._cache = make_cache()
         # what the cache tree reserves, by kind of state (gauge, fixed at build)
         kinds = {"lin_state": "linear_state", "lin_conv": "conv_state",
-                 "ssm_state": "ssm_state", "ssm_conv": "ssm_conv_state"}
+                 "ssm_state": "ssm_state", "ssm_conv": "ssm_conv_state",
+                 "conv_tail": "conv_tail"}
         self._cache_bytes = dict.fromkeys(("kv", *kinds.values()), 0)
         for name, leaf in self._cache.items():
             self._cache_bytes[kinds.get(name, "kv")] += int(leaf.nbytes)
         # positions a prefill piece's scan solves at once, and what the span
         # of a piece calls its count: the delta rule's or the Mamba-2 mixer's
+        # (a short convolution scans nothing: 0)
         self._scan_chunk, self._scan_arg = (
             (cfg.chunk_size, "ssm_chunks") if cfg.is_pattern_stack
+            else (0, "") if cfg.is_conv_stack
             else (LINEAR_CHUNK, "linear_chunks"))
         # which rows of a decode sweep may move a hybrid's recurrent state:
         # the device mask of the last dispatch, keyed by its active set
@@ -992,6 +995,9 @@ class Engine:
             "linear_state_slot_steps": 0,
             "linear_state_held_slot_steps": 0,
             "linear_state_resets": 0,
+            # fresh prefills of a short-convolution stack, each of which
+            # starts its slot's tails from zero (models/short_conv.py)
+            "conv_tail_resets": 0,
             # routed expert blocks (models/moe.py moe_latent_block; all 0 for
             # every other model), over the decode steps retired, summed over
             # the expert layers: assignments the live rows made (rows x
@@ -1140,8 +1146,9 @@ class Engine:
 
     def _refuse_for_hybrid(self, mesh, drafter, lora, prefill_mesh) -> None:
         """A hybrid stack (cfg.layer_types, cfg.hybrid_override_pattern)
-        keeps a recurrent state per slot beside its K/V. A state has no
-        positions: it cannot be rewound
+        keeps, per slot and beside its K/V, a recurrent state, a convolution
+        tail or both (a short-convolution stack: the tail alone). Neither
+        has positions: it cannot be rewound
         to a shared prefix, cut into blocks, verified-and-rolled-back by a
         drafter, or handed over as a stripe, and nothing here shards it.
         Each such setting is an error at construction, never a fallback."""
@@ -1161,7 +1168,8 @@ class Engine:
             why = ("disagg: the handoff protocol ships K/V stripes or block "
                    "tables, not a recurrent state and convolution tail")
         elif lora is not None:
-            why = "a LoRA bank: the linear mixer's projections take no adapters"
+            why = ("a LoRA bank: the recurrent and convolution mixers' projections "
+                   "(in_proj, out_proj and their like) take no adapters")
         elif e.kv_host_tier_bytes:
             why = ("kv_host_tier_bytes: the host tier holds paged K/V blocks, "
                    "and a hybrid has none")
@@ -1171,7 +1179,8 @@ class Engine:
         if why is not None:
             raise ValueError(
                 f"model {self.cfg.name!r} is a hybrid stack (layers that keep a "
-                f"recurrent state beside full attention) and cannot be served with {why}"
+                "recurrent state or a convolution tail beside full attention) "
+                f"and cannot be served with {why}"
             )
 
     # -- paged-KV block accounting ----------------------------------------
@@ -2349,14 +2358,17 @@ class Engine:
         bucket = steps.prefill_bucket(len(piece), self.ecfg)
         span_args = {}
         if self._hybrid:
-            # chunks this piece runs through each recurrent layer's scan,
-            # and those of them that hold only padding
-            chunks = -(-bucket // self._scan_chunk)
-            span_args[self._scan_arg] = chunks
-            self.stats["linear_scan_chunks"] += chunks
-            self.stats["linear_scan_pad_chunks"] += chunks - -(-len(piece) // self._scan_chunk)
+            if self._scan_chunk:
+                # chunks this piece runs through each recurrent layer's scan,
+                # and those of them that hold only padding
+                chunks = -(-bucket // self._scan_chunk)
+                span_args[self._scan_arg] = chunks
+                self.stats["linear_scan_chunks"] += chunks
+                self.stats["linear_scan_pad_chunks"] += (
+                    chunks - -(-len(piece) // self._scan_chunk))
             if off == 0:
-                self.stats["linear_state_resets"] += 1
+                self.stats["linear_state_resets" if self._scan_chunk
+                           else "conv_tail_resets"] += 1
             if self.cfg.n_expert_layers:
                 # what the piece offers its expert blocks; how many land on
                 # an expert held here is known only once the program has run
@@ -3667,7 +3679,7 @@ class Engine:
         gap = not self._inflight or bool(self._first_pending)
         if gap:
             self._sched.gap_open()
-        with self._sched.phase("emit") as ph:
+        with self._sched.phase("emit", **self._routed_args(routed, rec["chunk"])) as ph:
             self.stats["busy_s"] += t_ready - max(
                 rec["t_dispatch"], self._t_last_ready
             )
@@ -3739,6 +3751,16 @@ class Engine:
         self._slot_behind = [0] * self.ecfg.max_slots
         self._first_pending = []
         self._tokens_dev = None
+
+    @staticmethod
+    def _routed_args(routed: list, steps: int) -> dict[str, int]:
+        """Arguments of the ``emit`` span of a sweep whose model has expert
+        layers: the held experts its ``moe_steps`` steps touched, summed over
+        the expert layers, so that a trace holds the count beside the device
+        time of the very steps it belongs to. Nothing for any other model."""
+        if not routed:
+            return {}
+        return {"moe_touched": int(routed[0][:, 1].sum()), "moe_steps": steps}
 
     def _book_routed(self, routed: list, live: int, steps: int) -> None:
         """A retired sweep's expert-block counts: ``routed`` holds the
@@ -3864,7 +3886,7 @@ class Engine:
         if self._sampler_filters:
             self.stats["sampler_filter_steps"] += 1
         self._book_routed(routed, len(active), 1)
-        with self._sched.phase("emit") as ph:
+        with self._sched.phase("emit", **self._routed_args(routed, 1)) as ph:
             for step in range(toks_h.shape[0]):
                 for i in active:
                     if self._slot_req[i] is None:

@@ -1383,6 +1383,10 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"{s['linear_state_held_slot_steps']}",
             "# TYPE kvmini_tpu_linear_state_resets_total counter",
             f"kvmini_tpu_linear_state_resets_total {s['linear_state_resets']}",
+            # fresh prefills of a short-convolution stack: each starts its
+            # slot's tails (kvmini_tpu_cache_bytes{kind="conv_tail"}) from zero
+            "# TYPE kvmini_tpu_conv_tail_resets_total counter",
+            f"kvmini_tpu_conv_tail_resets_total {s['conv_tail_resets']}",
             # 1 where the decode program updates that state in place through
             # kvmini_linear_decode, 0 where it runs the jnp recurrence
             "# TYPE kvmini_tpu_linear_state_kernel gauge",

@@ -69,14 +69,16 @@ def assert_went_behind(eng, admissions: int = 3):
     assert not eng._first_pending and not any(eng._slot_behind)
 
 
-def check_stack(make_engine, greedy, sampled: bool, kernels: str = ""):
+def check_stack(make_engine, greedy, sampled: bool, kernels: str = "",
+                resets: str = "linear_state_resets"):
     """The script on one of the hybrid stacks' tiny engines
     (``make_engine(**ecfg) -> (params, engine)``, four slots): a caller
     admitted behind a sweep lands on a parked row whose recurrent state the
     sweep held, or on the row of a caller that sweep still moved; either way
     its prefill starts the state from zero later on the device. Streams are
     the synchronous scheduler's, and the greedy ones the cache-free
-    forward's own (``greedy(params, sequence, n_prompt)``)."""
+    forward's own (``greedy(params, sequence, n_prompt)``). ``resets``: the
+    counter that books a fresh prefill's reset of the stack's per-slot state."""
     import numpy as np
 
     rng = np.random.default_rng(7)
@@ -90,7 +92,7 @@ def check_stack(make_engine, greedy, sampled: bool, kernels: str = ""):
     (eng, behind), (_, sync) = runs
     assert_same_streams(behind, sync)
     assert_went_behind(eng)
-    assert eng.stats["linear_state_resets"] == 5
+    assert eng.stats[resets] == 5
     if not sampled:
         for h, p in zip(behind[1:], prompts[1:]):
             assert h.tokens == greedy(params, p + h.tokens, len(p))

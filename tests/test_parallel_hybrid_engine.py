@@ -72,7 +72,7 @@ def test_engine_streams_are_the_models_own_under_batching_and_slot_reuse(ecfg):
     kv_elem = (16 + 4) if "kv_cache_dtype" in ecfg else 16 * 4
     assert s["cache_bytes"] == {"kv": 2 * 2 * 4 * 2 * 256 * kv_elem, "linear_state": 0,
                                 "conv_state": 0, "ssm_state": 2 * 4 * 8 * 12 * 32 * 4,
-                                "ssm_conv_state": 2 * 4 * 3 * 224 * 4}
+                                "ssm_conv_state": 2 * 4 * 3 * 224 * 4, "conv_tail": 0}
     assert s["block_mixers"] == 2
 
 

@@ -61,7 +61,7 @@ def test_engine_streams_are_the_models_own_under_batching_and_slot_reuse(ecfg):
     assert s["linear_scan_chunks"] >= 6
     assert s["cache_bytes"] == {"kv": 2 * 4 * 2 * 256 * 32 * 4, "linear_state": 0, "conv_state": 0,
                                 "ssm_state": 2 * 4 * 8 * 16 * 16 * 4,
-                                "ssm_conv_state": 2 * 4 * 3 * 192 * 4}
+                                "ssm_conv_state": 2 * 4 * 3 * 192 * 4, "conv_tail": 0}
     # the expert blocks' counters, booked with the retired decode steps: every live
     # row offers 4 choices to each of 2 expert layers; a quarter of the experts is held
     live_steps = s["linear_state_slot_steps"] - s["linear_state_held_slot_steps"]
@@ -120,7 +120,7 @@ def test_a_parked_or_mid_prefill_slot_keeps_its_state_through_neighbours_sweeps(
     # a fresh prefill into the marked slot starts from zero whatever it held
     eng._free = [parked]
     c = eng.submit(GenRequest(prompt_tokens=short_p, max_new_tokens=3, temperature=0.0, eos_id=None))
-    while c.finish_reason is None:
+    while not c.finish_reason:
         eng._schedule_once()
     assert c.tokens == greedy(params, short_p + c.tokens, len(short_p))
 

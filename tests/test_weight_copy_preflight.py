@@ -3,10 +3,11 @@ stack: checked in the programs the chip's own compiler builds, with no chip.
 
 ``tests/tpu_preflight_worker.py`` compiles the decode step and a fresh
 prefill piece for a TPU v5e by the README's pre-flight recipe (libtpu
-compiles for a described chip), at the benchmark's five configurations
+compiles for a described chip), at the benchmark's six configurations
 (published widths, two layers, one period of the hybrid, one block of
-each kind of the pattern stack with 8 experts held, or two blocks of the
-two-mixer stack). Asked of each
+each kind of the pattern stack with 8 experts held, two blocks of the
+two-mixer stack, or three layers of the short-convolution stack: both
+operators, the dense MLP and two layers of all 32 experts). Asked of each
 executable: ``weight_copy_bytes == 0`` (no per-layer slice of an integer
 weight stack is copied, i.e. transposed, before its matmul:
 profiling/compile_stats.py) and a Mosaic call is still compiled in.
@@ -40,9 +41,13 @@ import pytest
 from tests import env_guards
 
 MODELS = ("mistral-7b-int8", "qwen2-7b-int8", "olmo-hybrid-7b-int8",
-          "nemotron-3-super-ep4-int8", "falcon-h1-34b-int8")
+          "nemotron-3-super-ep4-int8", "falcon-h1-34b-int8", "lfm2-8b-a1b-int8")
 CASES = [f"{m}:{s}" for m in MODELS for s in ("decode", "prefill")]
-# ten compiles take ~170 s here; under a loaded suite several times that
+# A decode step with no attention kernel yet: 64-wide heads, which
+# ops/paged_attention.py dense_decode_block turns away (head_dim % 128), so the
+# six attention layers read K/V eagerly (ROADMAP A: a kernel for them)
+NO_DECODE_KERNEL = {"lfm2-8b-a1b-int8:decode"}
+# twelve compiles take ~200 s here; under a loaded suite several times that
 TIMEOUT_S = 900
 
 
@@ -71,7 +76,8 @@ def test_projections_read_their_weights_in_place(compiled, case):
     assert stats["weight_copy_bytes"] == 0, (
         f"{case}: {stats['weight_copy_bytes']} bytes of per-layer integer "
         "weight slices are copied before their matmul")
-    assert stats["mosaic_calls"] >= 1, f"{case}: no Pallas kernel compiled in"
+    if case not in NO_DECODE_KERNEL:
+        assert stats["mosaic_calls"] >= 1, f"{case}: no Pallas kernel compiled in"
 
 
 def test_the_hybrids_decode_step_updates_its_state_in_place(compiled):

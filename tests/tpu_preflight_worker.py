@@ -11,7 +11,9 @@ forward, "auto", 1)``, ``prefill`` is ``steps.build_prefill(cfg, forward,
 made). Models: the benchmark's configurations by name (``BENCHMARK.json``), at
 their published widths and two layers (the hybrid: one period of four; the
 pattern stack: one block of each kind, ``M*E``, with 8 of its experts held;
-the two-mixer stack: two blocks, ``PP``).
+the two-mixer stack: two blocks, ``PP``; the short-convolution stack: a
+convolution, an attention layer and a convolution, the dense MLP under the
+first and all 32 experts under the other two).
 
 Prints one JSON line: ``{"skip": reason}`` where libtpu or the topology cannot
 be had, else ``{"cases": {case: CompileStats.to_dict()}}`` (``mosaic_calls``,
@@ -45,7 +47,13 @@ def model_config(name: str):
     m = dict(bench_config.load_cell(cell)["config"]["model"])
     m["num_hidden_layers"] = 2
     kinds = m.get("model_extra", {}).get("layer_types")
-    if kinds:   # a hybrid stack: one period, its linear layers then the full one
+    if kinds and "conv" in kinds:
+        # operator + feed-forward pairs: each operator, each feed-forward, and
+        # a second layer in the convolution's and the experts' stacks
+        kinds = ["conv", "full_attention", "conv"]
+        m["num_hidden_layers"] = len(kinds)
+        m["model_extra"] = {**m["model_extra"], "layer_types": kinds, "num_dense_layers": 1}
+    elif kinds:   # a hybrid stack: one period, its linear layers then the full one
         period = kinds.index("full_attention") + 1
         m["num_hidden_layers"] = period
         m["model_extra"] = {**m["model_extra"], "layer_types": kinds[:period]}
