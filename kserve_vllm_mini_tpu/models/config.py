@@ -60,6 +60,22 @@ orthogonal architecture axes extend it to the other families:
   (models/moe.py moe_glu_block), no shared expert. Four weight stacks, one a
   kind; the cache holds K/V for the attention layers and a convolution tail
   of ``conv_L_cache - 1`` positions for the others, and no recurrent state.
+- ``sliding_window_layout`` / ``rope_layout`` — a window and a rotary switch
+  PER LAYER of a plain attention stack (SmallThinker), under the source's
+  names: layer ``l`` attends keys ``i - sliding_window_size < j <= i`` where
+  ``sliding_window_layout[l]`` is 1 and every ``j <= i`` where it is 0, and
+  rotates q and k where ``rope_layout[l]`` is 1 (0: no position enters the
+  layer at all). A stack of depth ``n`` reads the first ``n`` entries, so a
+  cut stack keeps the published lists. The cache tree holds TWO K/V leaves
+  of different lengths: ``k``/``v`` ``[n_global, B, KVH, max_seq, D]`` for the
+  global layers and ``k_win``/``v_win`` ``[n_window, B, KVH, R, D]`` for the
+  window layers, a ring addressed ``p mod R`` (``ring_len``). Every layer's
+  feed-forward is ``n_routed_experts`` ReLU-gated GLU experts
+  ``moe_intermediate_size`` wide behind a float32 softmax router that reads
+  the layer's INPUT, before the attention norm (models/moe.py
+  moe_glu_block). ``sliding_window`` (one window for every layer) and
+  ``alt_sliding_window`` (even layers) stay what they are for the stacks
+  that spell them so.
 """
 
 from __future__ import annotations
@@ -193,6 +209,14 @@ class ModelConfig:
     ssm_multipliers: Optional[tuple[float, ...]] = None
     # (on w_gate's output inside the SiLU, on w_down's output)
     mlp_multipliers: Optional[tuple[float, ...]] = None
+    # A window and a rotary switch per layer (SmallThinker), under the
+    # published names: 1 = the layer attends the last sliding_window_size
+    # positions / rotates q and k; 0 = every earlier position / no position
+    # enters. At least n_layers entries each; the stack reads the first
+    # n_layers. None = the stack has no such axis.
+    sliding_window_layout: Optional[tuple[int, ...]] = None
+    rope_layout: Optional[tuple[int, ...]] = None
+    sliding_window_size: Optional[int] = None
 
     def __post_init__(self) -> None:
         for name, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
@@ -202,6 +226,9 @@ class ModelConfig:
                 object.__setattr__(self, name, tuple(float(v) for v in given))
                 if len(given) != n:
                     raise ValueError(f"{name} takes {n} values, got {len(given)}")
+        if self.sliding_window_layout is not None or self.rope_layout is not None:
+            self._check_window_stack()
+            return
         if self.hybrid_override_pattern is not None:
             self._check_pattern()
             return
@@ -260,6 +287,36 @@ class ModelConfig:
                                  "num_experts_per_tok and moe_intermediate_size >= 1")
             self._check_experts_held()
 
+    def _check_window_stack(self) -> None:
+        if self.sliding_window_layout is None or self.rope_layout is None:
+            raise ValueError("sliding_window_layout and rope_layout come together: "
+                             "one entry a layer each")
+        for name in ("sliding_window_layout", "rope_layout"):
+            # a list (from JSON) becomes a tuple: the config must hash
+            given = tuple(int(v) for v in getattr(self, name))
+            object.__setattr__(self, name, given)
+            if set(given) - {0, 1}:
+                raise ValueError(f"{name} takes 0 or 1 a layer; got {given}")
+        n_win, n_rope = len(self.sliding_window_layout), len(self.rope_layout)
+        if n_win != n_rope or n_win < self.n_layers:
+            raise ValueError(
+                f"sliding_window_layout ({n_win}) and rope_layout ({n_rope}) must be "
+                f"equally long and name at least n_layers={self.n_layers} layers")
+        if self.n_window_layers and (self.sliding_window_size or 0) < 1:
+            raise ValueError("a layer with sliding_window_layout 1 needs sliding_window_size >= 1")
+        if (self.block != "llama" or self.layer_types is not None
+                or self.hybrid_override_pattern is not None or self.is_moe
+                or self.sliding_window is not None or self.alt_sliding_window):
+            raise ValueError(
+                "a sliding_window_layout stack takes block='llama' and no layer_types, "
+                "hybrid_override_pattern, n_experts, sliding_window or alt_sliding_window: "
+                "its layers are named by the two layouts")
+        if min(self.n_routed_experts, self.num_experts_per_tok, self.moe_intermediate_size) < 1:
+            raise ValueError("a sliding_window_layout stack's feed-forward is routed experts: "
+                             "it needs n_routed_experts, num_experts_per_tok and "
+                             "moe_intermediate_size >= 1")
+        self._check_experts_held()
+
     def _check_experts_held(self) -> None:
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError("num_experts_per_tok exceeds n_routed_experts")
@@ -300,7 +357,38 @@ class ModelConfig:
     def is_hybrid(self) -> bool:
         """A stack that keeps, per slot and beside K/V, something that has no
         positions: a recurrent state, a convolution tail, or both."""
-        return self.layer_types is not None or self.is_pattern_stack
+        return (self.layer_types is not None or self.is_pattern_stack
+                or self.is_window_stack)
+
+    @property
+    def is_window_stack(self) -> bool:
+        """``sliding_window_layout`` / ``rope_layout``: window layers, whose
+        K/V is a ring, beside global layers. The ring is what makes it a
+        hybrid for the engine: positions do not address it, so it cannot be
+        rewound, paged, shared or handed over as a stripe."""
+        return self.sliding_window_layout is not None
+
+    @property
+    def window_layers(self) -> tuple[bool, ...]:
+        """Per layer of the stack as it is cut: whether it is a window layer."""
+        return tuple(bool(v) for v in (self.sliding_window_layout or ())[: self.n_layers])
+
+    @property
+    def n_window_layers(self) -> int:
+        return sum(self.window_layers)
+
+    @property
+    def n_global_layers(self) -> int:
+        return self.n_layers - self.n_window_layers if self.is_window_stack else 0
+
+    def ring_len(self, max_seq: int) -> int:
+        """Positions of a window layer's ring in a cache ``max_seq`` long.
+        ``R = sliding_window_size``: the only cached read is a decode step
+        (one query at ``q``, written at ``q mod R`` before it is read), whose
+        window ``q - W < j <= q`` is exactly the R positions then in the ring;
+        a fresh prefill attends its own projections and leaves its last R
+        positions behind. A cache shorter than the window never wraps."""
+        return min(self.sliding_window_size or max_seq, max_seq)
 
     @property
     def is_pattern_stack(self) -> bool:
@@ -326,6 +414,9 @@ class ModelConfig:
             return sum(map(self.hybrid_override_pattern.count, "*P"))
         if self.is_conv_stack:
             return self.layer_types.count("full_attention")
+        if self.is_window_stack:
+            # the layers whose K/V is a stripe as long as the context
+            return self.n_global_layers
         return self.n_layers // self.layer_period
 
     @property
@@ -354,6 +445,8 @@ class ModelConfig:
         stack's layers past ``num_dense_layers``."""
         if self.is_conv_stack:
             return self.n_layers - self.num_dense_layers
+        if self.is_window_stack:
+            return self.n_layers
         return (self.hybrid_override_pattern or "").count("E")
 
     @property
@@ -372,7 +465,9 @@ class ModelConfig:
 
     @property
     def n_linear_layers(self) -> int:
-        return 0 if self.is_conv_stack else self.n_layers - self.n_full_layers
+        if self.is_conv_stack or self.is_window_stack:
+            return 0
+        return self.n_layers - self.n_full_layers
 
     @property
     def linear_conv_channels(self) -> int:
@@ -413,6 +508,14 @@ class ModelConfig:
         if self.is_conv_stack:
             head = 0 if self.tie_embeddings else emb
             return emb + head + self.d_model + self._conv_stack_params()
+        if self.is_window_stack:
+            head = 0 if self.tie_embeddings else emb
+            hd = self.head_dim
+            attn = 2 * self.d_model * self.n_heads * hd + 2 * self.d_model * self.n_kv_heads * hd
+            experts = (self.d_model * self.n_routed_experts
+                       + self.experts_held * 3 * self.d_model * self.moe_intermediate_size)
+            return (emb + head + self.d_model
+                    + self.n_layers * (attn + experts + 2 * self.d_model))
         qo = self.d_model * self.n_heads * self.head_dim  # q and o projections
         attn = 2 * qo + 2 * self.d_model * (self.n_kv_heads * self.head_dim)
         mlp = (2 if self.block == "phi" else 3) * self.d_model * self.d_ff
@@ -753,6 +856,31 @@ PRESETS: dict[str, ModelConfig] = {
         routed_scaling_factor=1.0,
         norm_topk_prob=True,
     ),
+    # SmallThinker-21BA3B-Instruct (huggingface.co/PowerInfer/
+    # SmallThinker-21BA3B-Instruct config.json): 52 layers in periods of one
+    # global layer without positions and three window-4096 layers with RoPE;
+    # 64 ReLU-gated GLU experts a layer, 6 a token, routed by a softmax router
+    # from the layer's input. No dense feed-forward: d_ff multiplies nothing.
+    "smallthinker-21b-a3b": ModelConfig(
+        name="smallthinker-21b-a3b",
+        vocab_size=151_936,
+        d_model=2560,
+        n_layers=52,
+        n_heads=28,
+        n_kv_heads=4,
+        d_ff=0,
+        explicit_head_dim=128,
+        max_seq_len=16_384,
+        rope_theta=1_500_000.0,
+        rms_eps=1e-6,
+        sliding_window_layout=(0, 1, 1, 1) * 13,
+        rope_layout=(0, 1, 1, 1) * 13,
+        sliding_window_size=4096,
+        n_routed_experts=64,
+        num_experts_per_tok=6,
+        moe_intermediate_size=768,
+        norm_topk_prob=True,
+    ),
     # -- tiny CI variants (CPU in <1s) exercising each architecture axis ----
     "mistral-tiny": ModelConfig(
         name="mistral-tiny",
@@ -914,6 +1042,27 @@ PRESETS: dict[str, ModelConfig] = {
         num_experts_per_tok=2,
         moe_intermediate_size=96,
         routed_scaling_factor=1.0,
+        norm_topk_prob=True,
+    ),
+    # two periods of global + three window layers; a window of 16 so that a
+    # short prompt wraps the ring; every expert held
+    "smallthinker-tiny": ModelConfig(
+        name="smallthinker-tiny",
+        vocab_size=512,
+        d_model=128,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=0,
+        max_seq_len=256,
+        rope_theta=1_500_000.0,
+        rms_eps=1e-6,
+        sliding_window_layout=(0, 1, 1, 1) * 2,
+        rope_layout=(0, 1, 1, 1) * 2,
+        sliding_window_size=16,
+        n_routed_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=96,
         norm_topk_prob=True,
     ),
     "mixtral-tiny": ModelConfig(

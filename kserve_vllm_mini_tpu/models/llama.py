@@ -96,6 +96,8 @@ def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
         return _init_pattern(keys, cfg, leaf_fn)
     if cfg.is_conv_stack:
         return _init_conv_stack(keys, cfg, leaf_fn)
+    if cfg.is_window_stack:
+        return _init_window_stack(keys, cfg, leaf_fn)
     if cfg.is_hybrid:
         return _init_hybrid(keys, cfg, leaf_fn)
     L = cfg.n_layers
@@ -375,6 +377,57 @@ def _init_conv_stack(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> P
     return params
 
 
+def window_stack_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
+    """Per-layer shape of every matmul of a ``sliding_window_layout`` stack,
+    by the two stacks of ``layers``: every layer's attention (window and
+    global layers share one stack: the layouts, not the weights, tell them
+    apart) and every layer's experts. Every one is quantized in the int8
+    tree (ops/quant.py QUANTIZABLE)."""
+    d, hd = cfg.d_model, cfg.head_dim
+    ff, held = cfg.moe_intermediate_size, cfg.experts_held
+    return {
+        "attn": {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
+                 "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)},
+        "experts": {"w_gate": (held, d, ff), "w_up": (held, d, ff), "w_down": (held, ff, d)},
+    }
+
+
+def _init_window_stack(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
+    """A ``sliding_window_layout`` stack's tree: ``layers`` holds ``{"attn":
+    [n_layers, ...], "experts": [n_layers, ...]}``, in stack order. The
+    attention stack holds its layer's ``norm_in``, the expert stack its
+    ``ffn_norm`` and the float32 ``router`` (no selection bias). Gains get
+    values that show when they are left out."""
+    dt, n = cfg.jnp_dtype, cfg.n_layers
+    shapes = window_stack_weight_shapes(cfg)
+
+    def stack(key, shape, fn=leaf_fn):
+        def body(_, k):
+            return None, fn(_nrm(k, shape, dt))
+
+        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
+
+    def gains(key):
+        return (1.0 + 0.1 * jax.random.normal(key, (n, cfg.d_model), jnp.float32)).astype(dt)
+
+    layers: Params = {}
+    for kind, key in (("attn", keys["wq"]), ("experts", keys["wk"])):
+        ks = jax.random.split(key, 8)
+        layers[kind] = {name: stack(k, shape)
+                        for (name, shape), k in zip(shapes[kind].items(), ks)}
+        layers[kind]["norm_in" if kind == "attn" else "ffn_norm"] = gains(ks[4])
+    layers["experts"]["router"] = stack(
+        keys["router"], (cfg.d_model, cfg.n_routed_experts), lambda w: w.astype(jnp.float32))
+    params: Params = {
+        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
+        "layers": layers,
+        "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
+    }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt)
+    return params
+
+
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Random-normal init (0.02 std), bf16 — for tests, benches, and as the
     target pytree structure for checkpoint loading.
@@ -426,7 +479,17 @@ def init_kv_cache(
     # them (same tree, slot axis still dim 1) what its linear layers keep
     shape = (cfg.n_full_layers, batch, cfg.n_kv_heads, s, cfg.head_dim)
     extra: KVCache = {}
-    if cfg.is_hybrid:
+    if cfg.is_window_stack:
+        # two K/V leaves of different lengths in the one tree: ``k``/``v``
+        # above are the global layers' stripes; the window layers keep a
+        # ring of ring_len positions, addressed ``p mod R``
+        if quantized:
+            raise ValueError("a sliding_window_layout stack keeps its K/V in the model's "
+                             "type: the ring has no int8 leaves")
+        ring = (cfg.n_window_layers, batch, cfg.n_kv_heads, cfg.ring_len(s), cfg.head_dim)
+        extra = {name: jnp.zeros(ring, dtype=dtype or cfg.jnp_dtype)
+                 for name in ("k_win", "v_win")}
+    elif cfg.is_hybrid:
         if cfg.is_pattern_stack:
             from kserve_vllm_mini_tpu.models.mamba import state_shapes
         elif cfg.is_conv_stack:
@@ -533,6 +596,8 @@ def qkv_proj(
     sin: jnp.ndarray,
     lora: Optional[Params] = None,       # one layer's adapter bank slices
     lora_ids: Optional[jnp.ndarray] = None,  # [B] adapter index per row
+    rope: bool = True,           # static: False = this LAYER carries no position
+                                 # (a rope_layout stack's 0 entries)
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """QKV projections + RoPE -> (q [B,H,T,hd], k [B,KVH,T,hd], v). The one
     implementation every execution path (scan-rolled, cached, pipelined)
@@ -569,7 +634,7 @@ def qkv_proj(
         # (olmo2's above is over the whole projection)
         q = rms_norm(q, p["q_norm"], cfg.rms_eps)
         k = rms_norm(k, p["k_norm"], cfg.rms_eps)
-    rd = cfg.rotary_dim
+    rd = cfg.rotary_dim if rope else 0
     if rd == 0:
         return q, k, v           # attention that carries no position
     if rd < cfg.head_dim:
@@ -792,6 +857,26 @@ def dense_decode_kernel_block(
         return None
     k = kv_cache["k"]                         # [L, B, KVH, S, D]
     return dense_decode_block(k.shape[3], k.shape[2], k.shape[4], k.dtype)
+
+
+def window_decode_kernel_blocks(
+    cfg: ModelConfig, kernels: str, kv_cache: KVCache
+) -> Optional[tuple[int, int]]:
+    """(global, ring) key-block sizes with which a ``sliding_window_layout``
+    stack's decode step reads BOTH K/V leaves through
+    ``kvmini_dense_decode``, or None where it keeps the eager reads: the
+    gate of ``dense_decode_kernel_block`` asked of each leaf's own length.
+    The engine asks it once, to count the blocks a step fetches by kind."""
+    from kserve_vllm_mini_tpu.ops.flash_attention import pallas_interpret
+    from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_block
+
+    if pallas_interpret(kernels) is None or cfg.attn_softcap is not None:
+        return None
+    found = tuple(
+        dense_decode_block(kv_cache[name].shape[3], kv_cache[name].shape[2],
+                           kv_cache[name].shape[4], kv_cache[name].dtype)
+        for name in ("k", "k_win"))
+    return None if None in found else found
 
 
 def run_cached_layers(
@@ -1142,6 +1227,105 @@ def run_cached_layers(
             "a hybrid stack serves from the dense cache on one device, "
             "without adapters (no block table, pipeline stage or LoRA bank)"
         )
+    if cfg.is_window_stack:
+        # Attention and routed experts in every layer; what differs by layer
+        # is static: the mask (window or global), the rotation, and the K/V
+        # leaf (a ring of R positions or a stripe of the context). Unrolled
+        # with static indices like the walks below: each layer reads its
+        # slice of the two weight stacks where it lies, and both leaves are
+        # updated in place in the donated cache.
+        from kserve_vllm_mini_tpu.models.moe import moe_glu_block
+        from kserve_vllm_mini_tpu.ops.flash_attention import prefill_attention
+        from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_attention
+
+        if quantized_kv or not (fresh_prefill or T == 1):
+            raise ValueError(
+                "a sliding_window_layout stack serves a fresh prefill piece or a decode "
+                "step from bf16 K/V: a continuation piece (T > 1 against the cache) "
+                "would attend ring + piece, which nothing here computes")
+        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
+                 else valid_len.astype(jnp.int32))
+        real = jnp.arange(T)[None, :] < valid[:, None]
+        cache = dict(kv_cache)
+        W, R = cfg.sliding_window_size, kv_cache["k_win"].shape[3]
+        G = cfg.n_heads // cfg.n_kv_heads
+        blocks = window_decode_kernel_blocks(cfg, kernels, kv_cache)
+        qpos = cache_offsets.astype(jnp.int32)                    # [B], a decode step's
+        if fresh_prefill and T > R:
+            # The prompt's last R REAL positions stay behind: ring index r
+            # gets the largest position p < valid with p = r (mod R). The
+            # bucket's padding past ``valid`` must not push real keys out.
+            last = valid[:, None] - 1                              # [B, 1]
+            ring_src = last - jnp.mod(last - jnp.arange(R)[None, :], R)   # [B, R]
+            ring_src = jnp.clip(ring_src, 0, T - 1)[:, None, :, None]     # [B, 1, R, 1]
+        seen = {True: 0, False: 0}
+        stats = jnp.zeros((3,), jnp.int32)
+
+        def keep(leaf, i, new, at):
+            """``new`` [B, KVH, T', D] into layer ``i`` of ``leaf`` at positions
+            ``at`` [B, 1, T'] of the slots' own axis."""
+            return cache[leaf].at[i, b_idx, h_idx, at].set(new.astype(cache[leaf].dtype))
+
+        for l, windowed in enumerate(cfg.window_layers):
+            i = seen[windowed]
+            seen[windowed] += 1
+            p = jax.tree_util.tree_map(lambda a: a[l], layers["attn"])
+            h = rms_norm(x, p["norm_in"], cfg.rms_eps)
+            q, k, v = qkv_proj(p, cfg, h, positions, cos, sin, rope=bool(cfg.rope_layout[l]))
+            kn, vn = ("k_win", "v_win") if windowed else ("k", "v")
+            if fresh_prefill:
+                # attention over the piece's own projections FIRST: the ring
+                # is written after, from them (a ring written first would
+                # have lost the keys the piece's first queries attend)
+                o = prefill_attention(q, k, v, kernels=kernels,
+                                      window=W if windowed else None)
+                if windowed and T > R:  # kvmini: static-shape
+                    at = jnp.arange(R)[None, None, :]
+                    k = jnp.take_along_axis(k, ring_src, axis=2)
+                    v = jnp.take_along_axis(v, ring_src, axis=2)
+                else:
+                    at = t_idx
+                cache[kn], cache[vn] = keep(kn, i, k, at), keep(vn, i, v, at)
+            else:
+                if windowed:  # kvmini: static-shape
+                    # position q lives at q mod R; a row that is not decoding
+                    # writes back what was there (its query position is not
+                    # its slot's next one: it would push a live key out)
+                    at = jnp.mod(t_idx, R)
+                    live = (valid > 0)[:, None, None, None]
+                    k = jnp.where(live, k.astype(cache[kn].dtype), cache[kn][i, b_idx, h_idx, at])
+                    v = jnp.where(live, v.astype(cache[vn].dtype), cache[vn][i, b_idx, h_idx, at])
+                    # Written before it is read, the ring holds exactly the
+                    # positions q - R < p <= q: with R = W every index that
+                    # holds a position >= 0 is visible, the indices 0 ..
+                    # min(q, R - 1); no position enters the scores after the
+                    # rotation, so their order in the ring does not matter
+                    # and the read is a causal one at min(q, R - 1)
+                    reach = jnp.minimum(qpos, R - 1)
+                else:
+                    at, reach = t_idx, qpos
+                cache[kn], cache[vn] = keep(kn, i, k, at), keep(vn, i, v, at)
+                if blocks is not None:
+                    qg = q[:, :, 0, :].reshape(B, cfg.n_kv_heads, G, cfg.head_dim)
+                    og = dense_decode_attention(
+                        qg, cache[kn], cache[vn], reach, layer=jnp.int32(i),
+                        scale=attn_scale, interpret=interpret)
+                    o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
+                else:
+                    span = cache[kn].shape[3]
+                    m = (jnp.arange(span)[None, None, :] <= reach[:, None, None])[:, None]
+                    o = attention(q, cache[kn][i].astype(dt), cache[vn][i].astype(dt), m,
+                                  scale=attn_scale, softcap=attn_cap)
+            o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
+            x_in, x = x, x + linear(o, p["wo"], mode=cfg.quant_mode)
+            p = jax.tree_util.tree_map(lambda a: a[l], layers["experts"])
+            # the router reads the layer's INPUT; the experts the post-attention norm
+            out, counts = moe_glu_block(p, cfg, rms_norm(x, p["ffn_norm"], cfg.rms_eps),
+                                        real, route_from=x_in)
+            stats = stats + counts
+            x = x + out
+        return (x, cache, stats) if with_stats else (x, cache)
+
     if cfg.is_conv_stack:
         # An operator and a feed-forward a layer, in the published (irregular)
         # order; four weight stacks, each read where it lies. Unrolled with

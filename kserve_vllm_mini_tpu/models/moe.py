@@ -1,7 +1,7 @@
 """Sparse mixture-of-experts layers, drop-free, for a device that holds some
 or all of the experts.
 
-Three layers share one dispatch:
+Four layers share one dispatch:
 
 - ``moe_mlp`` (Mixtral): a softmax router, the top ``n_experts_per_tok``
   renormalised, SwiGLU experts at the model's width, every expert held. The
@@ -22,6 +22,11 @@ Three layers share one dispatch:
   experts (``glu_experts``, the one body both run) ``moe_intermediate_size``
   wide at the model's width, no latent and no shared expert. The device is
   told which experts it holds exactly as above.
+- ``moe_glu_block`` again, for a ``sliding_window_layout`` stack
+  (SmallThinker): a float32 softmax router without bias that reads the
+  layer's INPUT (``route_from``, the residual stream before the attention
+  norm) while the experts read the post-attention norm; the chosen
+  probabilities renormalised; the same GLU body with ReLU on the gate.
 
 **Dispatch: every held expert runs every token of the block, and the routing
 weight (zero where the token did not choose the expert) is applied at the
@@ -37,7 +42,8 @@ touches every expert and its arithmetic still hides under the weight stream;
 longer pieces pay it (ROADMAP: a grouped matmul over tokens sorted by
 expert, which would also skip the experts a decode step does not touch).
 Pieces longer than ``TOKEN_BLOCK`` run block by block (unrolled) so that
-the ``[experts, tokens, width]`` intermediates stay bounded.
+the ``[experts, tokens, width]`` intermediates stay bounded; pieces longer
+than ``LONG_PIECE`` in blocks of ``LONG_TOKEN_BLOCK``.
 
 Quantization: expert stacks quantize per output channel like dense weights
 (ops/quant.py works on any [..., in, out] stack); routers and the selection
@@ -55,6 +61,12 @@ from kserve_vllm_mini_tpu.models.config import ModelConfig
 from kserve_vllm_mini_tpu.ops.quant import is_quantized, linear, unpacked_q
 
 TOKEN_BLOCK = 256   # tokens the held experts run at once
+# A piece of more than LONG_PIECE tokens (longer than any piece the programs
+# above were measured on) runs LONG_TOKEN_BLOCK at once: the unrolled blocks
+# are what a prefill program's compile time grows with (an 8192-token piece of
+# 12 expert layers is 384 of them at 256 and takes 146 s to compile, 96 at 1024)
+LONG_PIECE = 1024
+LONG_TOKEN_BLOCK = 1024
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -89,32 +101,34 @@ def held_weights(weights: jnp.ndarray, chosen: jnp.ndarray, offset: int,
 
 def _run_held(x: jnp.ndarray, take: jnp.ndarray, body) -> jnp.ndarray:
     """``sum_e take[s, e] * body(x)[e, s]`` -> [S, out] float32, the tokens
-    in blocks of ``TOKEN_BLOCK``."""
+    in blocks of ``TOKEN_BLOCK`` (``LONG_TOKEN_BLOCK`` past ``LONG_PIECE`` tokens)."""
     def block(args):
         xb, tb = args
         return jnp.einsum("se,esd->sd", tb, body(xb),
                           preferred_element_type=jnp.float32)
 
     n = x.shape[0]
-    if n <= TOKEN_BLOCK:
+    size = TOKEN_BLOCK if n <= LONG_PIECE else LONG_TOKEN_BLOCK
+    if n <= size:
         return block((x, take))
     # Unrolled, not a ``lax.map``: a loop's operands must be buffers of their
     # own, so XLA sliced every expert stack out of its layer stack and copied
     # it (transposed) before the loop: 117 MB a stack, 6.9 GB of temporaries
     # in a 1024-token prefill of 22 layers x 32 experts (chipless compile).
-    pad = -n % TOKEN_BLOCK
+    pad = -n % size
     xs = jnp.pad(x, ((0, pad), (0, 0)))
     ts = jnp.pad(take, ((0, pad), (0, 0)))
-    return jnp.concatenate([block((xs[i:i + TOKEN_BLOCK], ts[i:i + TOKEN_BLOCK]))
-                            for i in range(0, n + pad, TOKEN_BLOCK)])[:n]
+    return jnp.concatenate([block((xs[i:i + size], ts[i:i + size]))
+                            for i in range(0, n + pad, size)])[:n]
 
 
-def glu_experts(p: dict[str, Any], qm: str):
-    """The SwiGLU expert body over the stacks ``w_gate``/``w_up`` [E, D, F]
+def glu_experts(p: dict[str, Any], qm: str, act=jax.nn.silu):
+    """The gated expert body over the stacks ``w_gate``/``w_up`` [E, D, F]
     and ``w_down`` [E, F, D]: ``x`` [S, D] -> [E, S, D], every expert on
-    every token of the block."""
+    every token of the block. ``act`` is the gate's activation: SiLU
+    (Mixtral, LFM2-MoE) or ReLU (SmallThinker); one body."""
     def experts(xb):
-        gated = jax.nn.silu(
+        gated = act(
             _expert_linear(xb, p["w_gate"], qm).astype(jnp.float32)
         ).astype(xb.dtype) * _expert_linear(xb, p["w_up"], qm)
         return _expert_linear(gated, p["w_down"], qm)
@@ -158,6 +172,21 @@ def route_sigmoid(x: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray,
     return w * cfg.routed_scaling_factor, chosen
 
 
+def route_softmax(x: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig,
+                  ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The softmax router on x [S, D] -> (weights [S, K] float32, expert ids
+    [S, K]) over ALL ``n_routed_experts``, no bias: the top
+    ``num_experts_per_tok`` logits chosen and a softmax over the chosen,
+    which is the softmax over all, its top renormalised (``norm_topk_prob``;
+    without it the chosen keep their share of the whole). Float32 at the
+    highest precision, as ``route_sigmoid``."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32), precision=_HI)
+    top, chosen = jax.lax.top_k(logits, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        return jax.nn.softmax(top, axis=-1), chosen
+    return jnp.exp(top - jax.nn.logsumexp(logits, axis=-1, keepdims=True)), chosen
+
+
 def routing_stats(landed: jnp.ndarray, real: Optional[jnp.ndarray]) -> jnp.ndarray:
     """int32 [3] from ``landed`` [S, held] (whether a token chose a held
     expert), over the positions ``real`` [B, T] marks (all when None):
@@ -172,16 +201,27 @@ def routing_stats(landed: jnp.ndarray, real: Optional[jnp.ndarray]) -> jnp.ndarr
 def moe_glu_block(
     p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
     real: Optional[jnp.ndarray] = None,
+    route_from: Optional[jnp.ndarray] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """LFM2-MoE's routed feed-forward on the normed hidden ``h`` [B, T, D]
+    """A routed GLU feed-forward on the normed hidden ``h`` [B, T, D]
     -> (delta [B, T, D], stats as ``routing_stats``). ``p``: ``router``
-    [D, n_routed] and ``router_bias`` [n_routed] float32; the held experts'
-    stacks ``w_gate``/``w_up`` [held, D, F], ``w_down`` [held, F, D]."""
+    [D, n_routed] float32; the held experts' stacks ``w_gate``/``w_up``
+    [held, D, F], ``w_down`` [held, F, D].
+
+    LFM2-MoE's: the biased sigmoid router (``router_bias`` [n_routed]) on
+    ``h`` itself, SiLU on the gate. A ``sliding_window_layout`` stack's
+    (``route_from`` [B, T, D], the layer's input): the softmax router on
+    THAT tensor, ReLU on the gate."""
     B, T, D = h.shape
     x = h.reshape(B * T, D)
-    w, chosen = route_sigmoid(x, p["router"], p["router_bias"], cfg, NORM_EPS_GLU)
+    if route_from is None:
+        w, chosen = route_sigmoid(x, p["router"], p["router_bias"], cfg, NORM_EPS_GLU)
+        act = jax.nn.silu
+    else:
+        w, chosen = route_softmax(route_from.reshape(B * T, D), p["router"], cfg)
+        act = jax.nn.relu
     take, landed = held_weights(w, chosen, cfg.expert_offset, cfg.experts_held)
-    out = _run_held(x, take, glu_experts(p, cfg.quant_mode))
+    out = _run_held(x, take, glu_experts(p, cfg.quant_mode, act))
     return out.astype(h.dtype).reshape(B, T, D), routing_stats(landed, real)
 
 

@@ -36,12 +36,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+# a fresh piece of LONG_PIECE positions or more tiles by LONG_PIECE_BLOCK
+LONG_PIECE = 2048
+LONG_PIECE_BLOCK = 512
 _NEG_INF = -1e30
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale: float, block_q: int, block_k: int, causal: bool,
+    window: Optional[int] = None,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -55,6 +59,11 @@ def _flash_kernel(
 
     # Causal: kv block strictly after the q block contributes nothing.
     run = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    if window is not None:
+        # Window: key j is visible to query i iff i - window < j <= i. A kv
+        # block whose LAST key lies at or before the tile's FIRST query's
+        # lower bound is wholly outside every query's window.
+        run = run & (ki * block_k + block_k - 1 > qi * block_q - window)
 
     @pl.when(run)
     def _accumulate():
@@ -68,7 +77,10 @@ def _flash_kernel(
         if causal:
             qpos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            logits = jnp.where(kpos <= qpos, logits, _NEG_INF)
+            visible = kpos <= qpos
+            if window is not None:
+                visible = visible & (kpos > qpos - window)
+            logits = jnp.where(visible, logits, _NEG_INF)
         m_prev = m_ref[:]                    # [BQ, 1]... stored as [BQ, 128] lanes
         m_cur = jnp.max(logits, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -95,6 +107,10 @@ def flash_attention(
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,  # Pallas interpreter: tests only
+    window: Optional[int] = None,  # sliding window (causal only): key j is
+                              # visible to query i iff i - window < j <= i.
+                              # Key blocks wholly outside it are neither
+                              # computed nor fetched; the edge blocks are masked
 ) -> jnp.ndarray:
     """Causal flash attention. T and S must be multiples of the block sizes
     (the runtime pads sequences to bucket boundaries anyway)."""
@@ -107,17 +123,34 @@ def flash_attention(
     n_rep = H // KVH
     scale = scale if scale is not None else D ** -0.5
 
+    if window is not None and (not causal or window >= S):
+        window = None  # wider than the sequence: the lower bound never binds
+
     grid = (B, H, T // block_q, S // block_k)
     kernel = functools.partial(
-        _flash_kernel, scale=scale, block_q=block_q, block_k=block_k, causal=causal
+        _flash_kernel, scale=scale, block_q=block_q, block_k=block_k, causal=causal,
+        window=window,
     )
+
+    def kv_block(qi, ki):
+        if window is None:
+            return ki
+        # the kv blocks a q tile computes on, [first, last]: a step outside
+        # names the nearest of them again, and an unchanged block index
+        # issues no DMA (the rule of ops/paged_attention.py fetched_block)
+        first = jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+        last = (qi * block_q + block_q - 1) // block_k
+        return jnp.clip(ki, first, last)
+
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // n_rep, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // n_rep, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, qi, ki: (b, h // n_rep, kv_block(qi, ki), 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, qi, ki: (b, h // n_rep, kv_block(qi, ki), 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
@@ -360,6 +393,7 @@ def prefill_attention(
     k: jnp.ndarray,   # [B, KVH, T, D]
     v: jnp.ndarray,   # [B, KVH, T, D]
     kernels: str = "auto",
+    window: Optional[int] = None,  # a window layer's: i - window < j <= i
 ) -> jnp.ndarray:
     """Serving-prefill attention over a freshly projected block.
 
@@ -372,8 +406,12 @@ def prefill_attention(
     block-aligned), the jnp oracle elsewhere.
     """
     T = q.shape[2]
-    bq = min(DEFAULT_BLOCK_Q, T)
-    bk = min(DEFAULT_BLOCK_K, T)
+    # pieces longer than any 128-block program was tuned on run 512-wide
+    # tiles: a grid step costs ~0.35 us whatever it multiplies, and a
+    # [128, 128] x [128, D] tile is 8 MFLOP, 40 ns of a v5e
+    block = LONG_PIECE_BLOCK if T >= LONG_PIECE else DEFAULT_BLOCK_Q
+    bq = min(block, T)
+    bk = min(block, T)
     # tile-aligned block shapes only: T a power of two >= 16 (the engine's
     # bucket sizes) or a multiple of the full 128 block — anything else
     # (e.g. a clamped 99-wide bucket) takes the jnp path rather than handing
@@ -387,7 +425,11 @@ def prefill_attention(
     interpret = pallas_interpret(kernels)
     if interpret is not None and aligned:
         return flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
-                               interpret=interpret)
+                               interpret=interpret, window=window)
     from kserve_vllm_mini_tpu.ops.attention import attention, causal_mask
 
-    return attention(q, k, v, causal_mask(T, T)[None, None])
+    mask = causal_mask(T, T)
+    if window is not None:
+        pos = jnp.arange(T)
+        mask = mask & (pos[None, :] > pos[:, None] - window)
+    return attention(q, k, v, mask[None, None])

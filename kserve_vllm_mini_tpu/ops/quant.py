@@ -156,7 +156,10 @@ HYBRID_QUANTIZABLE = QUANTIZABLE + ("wg",)
 # dt_bias and every gain stay as they are.
 PATTERN_QUANTIZABLE = ("in_proj", "out_proj", "wq", "wk", "wv", "wo",
                        "w_in", "w_out", "w1", "w2", "s1", "s2",
-                       # a two-mixer block's MLP, in the same stack as its mixers
+                       # a two-mixer block's MLP, in the same stack as its mixers;
+                       # a sliding_window_layout stack's {"attn", "experts"}
+                       # (models/llama.py window_stack_weight_shapes) goes by
+                       # this list too: the expert stacks carry the MLP's names
                        "w_gate", "w_up", "w_down")
 # a "conv" stack (models/llama.py conv_stack_weight_shapes): the short
 # convolution's two projections, the attention projections, the dense MLP and

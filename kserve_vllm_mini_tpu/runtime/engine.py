@@ -36,7 +36,11 @@ import numpy as np
 from kserve_vllm_mini_tpu.models.config import ModelConfig
 from kserve_vllm_mini_tpu.models.linear_attention import CHUNK as LINEAR_CHUNK
 from kserve_vllm_mini_tpu.models.linear_attention import linear_decode_kernel_block
-from kserve_vllm_mini_tpu.models.llama import dense_decode_kernel_block, forward
+from kserve_vllm_mini_tpu.models.llama import (
+    dense_decode_kernel_block,
+    forward,
+    window_decode_kernel_blocks,
+)
 from kserve_vllm_mini_tpu.models.mamba import ssm_decode_kernel_block
 from kserve_vllm_mini_tpu.profiling.compile_stats import (
     CompileRecorder,
@@ -420,7 +424,9 @@ class Engine:
         self.params = params
         self._hybrid = cfg.is_hybrid
         if self._hybrid:
-            self._refuse_for_hybrid(mesh, drafter, lora, prefill_mesh)
+            # (a ring of K/V is refused in its own words)
+            refuse = self._refuse_for_ring if cfg.is_window_stack else self._refuse_for_hybrid
+            refuse(mesh, drafter, lora, prefill_mesh)
 
         # Pallas kernel selection for every compiled step (models/llama.py
         # forward ``kernels``): steps jitted over a mesh are partitioned by
@@ -431,6 +437,9 @@ class Engine:
         # it (models/llama.py dense_decode_kernel_block), resolved in
         # start(): what _count_decode_kv_blocks counts by
         self._dense_decode_blk: Optional[int] = None
+        # the same for a sliding_window_layout stack's two leaves, (global,
+        # ring) (models/llama.py window_decode_kernel_blocks)
+        self._window_decode_blks: Optional[tuple[int, int]] = None
         # whether decode steps update a hybrid's delta-rule state through
         # kvmini_linear_decode (models/linear_attention.py
         # linear_decode_kernel_block), resolved in start(): the gauge
@@ -664,6 +673,11 @@ class Engine:
                  "ssm_state": "ssm_state", "ssm_conv": "ssm_conv_state",
                  "conv_tail": "conv_tail"}
         self._cache_bytes = dict.fromkeys(("kv", *kinds.values()), 0)
+        if cfg.is_window_stack:
+            # two kinds of K/V of its own: the window layers' ring and, beside
+            # it, the stripes, which are the global layers' alone ("kv" stays 0)
+            kinds.update(k="kv_global", v="kv_global", k_win="kv_window", v_win="kv_window")
+            self._cache_bytes.update(kv_window=0, kv_global=0)
         for name, leaf in self._cache.items():
             self._cache_bytes[kinds.get(name, "kv")] += int(leaf.nbytes)
         # positions a prefill piece's scan solves at once, and what the span
@@ -671,7 +685,7 @@ class Engine:
         # (a short convolution scans nothing: 0)
         self._scan_chunk, self._scan_arg = (
             (cfg.chunk_size, "ssm_chunks") if cfg.is_pattern_stack
-            else (0, "") if cfg.is_conv_stack
+            else (0, "") if cfg.is_conv_stack or cfg.is_window_stack
             else (LINEAR_CHUNK, "linear_chunks"))
         # which rows of a decode sweep may move a hybrid's recurrent state:
         # the device mask of the last dispatch, keyed by its active set
@@ -977,6 +991,11 @@ class Engine:
             # is a constant). Both stay 0 where the eager read runs.
             "decode_kv_blocks_fetched": 0,
             "decode_kv_blocks": 0,
+            # the same by kind of layer, a sliding_window_layout stack's alone
+            # (a layer of each kind booked on its own; the two above hold
+            # their sums there)
+            **{f"decode_kv_blocks{f}_{kind}": 0
+               for f in ("_fetched", "") for kind in ("global", "window")},
             # of decode_steps (plain and masked sweeps, booked with them
             # at retire), those dispatched while some occupied slot sampled
             # with a top-k or top-p filter: the steps in which the
@@ -1143,6 +1162,47 @@ class Engine:
         # /metrics served before the first sweep must still carry the
         # paged pool gauges rather than an empty fallback dict
         self._kv_admin_snapshot()
+
+    def _refuse_for_ring(self, mesh, drafter, lora, prefill_mesh) -> None:
+        """A ``sliding_window_layout`` stack keeps its window layers' K/V in
+        a ring of R positions a slot, addressed ``p mod R``: once a slot has
+        wrapped, the keys of its first positions are gone. What needs them
+        back, or needs more than one query against the ring, is an error at
+        construction, each by the setting's name."""
+        e = self.ecfg
+        why = None
+        if e.kv_layout != "dense":
+            why = (f"kv_layout={e.kv_layout!r}: the ring is a per-slot leaf beside the "
+                   "global layers' stripes; nothing cuts it into pool blocks")
+        elif e.prefix_cache:
+            why = ("prefix_cache: a slot that has wrapped no longer holds its prefix's "
+                   "window keys, so a retained slot cannot be resumed from a shared prefix")
+        elif e.kv_host_tier_bytes:
+            why = ("kv_host_tier_bytes: the host tier holds paged K/V blocks, and the "
+                   "ring has none")
+        elif e.disagg or prefill_mesh is not None:
+            why = ("disagg: the handoff ships one K/V stripe per layer of one length, "
+                   "not a ring beside stripes")
+        elif drafter is not None or e.spec_tokens > 0:
+            why = ("a drafter / spec_tokens: a verify step is several queries against "
+                   "the ring, and a rejected token's write has pushed a live key out")
+        elif mesh is not None:
+            why = ("a mesh (tp/dp/sp/pp): nothing shards the two K/V leaves or splits "
+                   "the unrolled walk over pipeline stages")
+        elif lora is not None:
+            why = "a LoRA bank: the unrolled walk takes no adapters"
+        elif e.prefill_chunk is not None:
+            why = ("prefill_chunk: a continuation piece would attend ring + piece, "
+                   "which no program here computes (a prompt is one fresh piece of "
+                   "up to max_prefill_len)")
+        elif e.kv_cache_dtype == "int8":
+            why = "kv_cache_dtype='int8': the ring has no int8 leaves"
+        if why is not None:
+            raise ValueError(
+                f"model {self.cfg.name!r} is a sliding_window_layout stack (window "
+                "layers whose K/V is a ring beside global layers' stripes) and "
+                f"cannot be served with {why}"
+            )
 
     def _refuse_for_hybrid(self, mesh, drafter, lora, prefill_mesh) -> None:
         """A hybrid stack (cfg.layer_types, cfg.hybrid_override_pattern)
@@ -1963,6 +2023,17 @@ class Engine:
             req.truncated_tokens = len(req.prompt_tokens) - prompt_cap
             req.prompt_tokens = req.prompt_tokens[-prompt_cap:]
         handle = RequestHandle(req)
+        if self.cfg.is_window_stack and len(req.prompt_tokens) > self.ecfg.max_prefill_len:
+            # a longer prompt's continuation piece would attend ring + piece
+            handle.events.put(("done", {
+                "finish_reason": "error",
+                "error": (
+                    f"prompt of {len(req.prompt_tokens)} tokens exceeds max_prefill_len="
+                    f"{self.ecfg.max_prefill_len}: a sliding_window_layout stack "
+                    "prefills a prompt as one fresh piece"
+                ),
+            }))
+            return handle
         if req.constraint is not None and not hasattr(req.constraint, "token_mask"):
             # raw byte automaton -> ByteTokenizer token mapping
             from kserve_vllm_mini_tpu.runtime.token_grammar import ByteTokenMachine
@@ -2125,9 +2196,15 @@ class Engine:
         if self._running:
             return
         self._running = True
-        self._dense_decode_blk = dense_decode_kernel_block(
-            self.cfg, self._kernels, self._cache, self.paged
-        )
+        if self.cfg.is_window_stack:
+            # both leaves go through the kernel or neither does, each by the
+            # block of its own length
+            self._window_decode_blks = window_decode_kernel_blocks(
+                self.cfg, self._kernels, self._cache)
+        else:
+            self._dense_decode_blk = dense_decode_kernel_block(
+                self.cfg, self._kernels, self._cache, self.paged
+            )
         self._linear_state_kernel = "lin_state" in self._cache and (
             linear_decode_kernel_block(self._kernels, self._cache["lin_state"], 1)
             is not None)
@@ -2366,7 +2443,14 @@ class Engine:
                 self.stats["linear_scan_chunks"] += chunks
                 self.stats["linear_scan_pad_chunks"] += (
                     chunks - -(-len(piece) // self._scan_chunk))
-            if off == 0:
+            if self.cfg.is_window_stack:
+                # a piece longer than the ring leaves only its last R positions
+                # in the window layers' leaf (models/llama.py run_cached_layers)
+                span_args.update(
+                    window_layers=self.cfg.n_window_layers,
+                    global_layers=self.cfg.n_global_layers,
+                    ring_wrapped=int(len(piece) > self._cache["k_win"].shape[3]))
+            elif off == 0:
                 self.stats["linear_state_resets" if self._scan_chunk
                            else "conv_tail_resets"] += 1
             if self.cfg.n_expert_layers:
@@ -3247,8 +3331,8 @@ class Engine:
         ``lengths`` fetch through the dense decode kernel, by the kernel's
         own block rule; nothing where they take the eager read."""
         # kvmini: thread-ok — written once in start(), before this thread exists
-        blk = self._dense_decode_blk
-        if blk is None:
+        blk, by_kind = self._dense_decode_blk, self._window_decode_blks
+        if blk is None and by_kind is None:
             return
         # (pallas is loaded only where a step holds the kernel)
         from kserve_vllm_mini_tpu.ops.paged_attention import live_block_range
@@ -3257,6 +3341,23 @@ class Engine:
         qpos = np.minimum(
             lengths[None, :] + np.arange(steps, dtype=np.int32)[:, None], seq - 1
         )
+        if by_kind is not None:
+            # Two leaves, each read by the kernel's rule at its own length: a
+            # global layer's stripe up to the position, a window layer's ring
+            # up to min(position, R - 1). Booked a LAYER of each kind, so the
+            # totals weigh the kinds as a step reads them.
+            ring = self._cache["k_win"].shape[3]
+            for kind, n, span, reach, b in (
+                    ("global", self.cfg.n_global_layers, seq, qpos, by_kind[0]),
+                    ("window", self.cfg.n_window_layers, ring,
+                     np.minimum(qpos, ring - 1), by_kind[1])):
+                fetched = n * int((reach // b + 1).sum())
+                total = n * qpos.size * (span // b)
+                self.stats[f"decode_kv_blocks_fetched_{kind}"] += fetched
+                self.stats[f"decode_kv_blocks_{kind}"] += total
+                self.stats["decode_kv_blocks_fetched"] += fetched
+                self.stats["decode_kv_blocks"] += total
+            return
         first, last = live_block_range(qpos, self.cfg.sliding_window, blk)
         self.stats["decode_kv_blocks_fetched"] += int((last - first + 1).sum())
         self.stats["decode_kv_blocks"] += qpos.size * (seq // blk)

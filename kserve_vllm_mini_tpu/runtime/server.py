@@ -1364,6 +1364,15 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"{s['decode_kv_blocks_fetched']}",
             "# TYPE kvmini_tpu_decode_kv_blocks_total counter",
             f"kvmini_tpu_decode_kv_blocks_total {s['decode_kv_blocks']}",
+            # the same by kind of layer where a stack has two K/V leaves
+            # (docs/TRACING.md "Window and global layers"): a layer of each
+            # kind booked on its own, the two above their sums; 0 elsewhere
+            "# TYPE kvmini_tpu_decode_kv_blocks_fetched_by_kind_total counter",
+            *(f"kvmini_tpu_decode_kv_blocks_fetched_by_kind_total{{kind=\"{kind}\"}} "
+              f"{s['decode_kv_blocks_fetched_' + kind]}" for kind in ("global", "window")),
+            "# TYPE kvmini_tpu_decode_kv_blocks_by_kind_total counter",
+            *(f"kvmini_tpu_decode_kv_blocks_by_kind_total{{kind=\"{kind}\"}} "
+              f"{s['decode_kv_blocks_' + kind]}" for kind in ("global", "window")),
             # a hybrid stack's recurrent state (docs/TRACING.md "Hybrid
             # stacks"): what the cache tree reserves by kind of state, the
             # chunks its prefill scans ran and padded, the slot-steps its

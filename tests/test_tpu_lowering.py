@@ -61,6 +61,16 @@ def test_flash_prefill_lowers(d, t):
     )
 
 
+@pytest.mark.parametrize("t,window,block", [(2048, 512, 128), (8192, 4096, 512), (4096, 1000, 512)])
+def test_flash_prefill_under_a_window_lowers(t, window, block):
+    """A window layer's fresh piece (28 / 4 heads of 128): the key-block
+    index clamped into the q tile's live range, the long pieces' 512 tiles."""
+    _lowers_for_tpu(
+        lambda q, k, v: flash_attention(q, k, v, block_q=block, block_k=block, window=window),
+        _sds((1, 28, t, 128), BF), _sds((1, 4, t, 128), BF), _sds((1, 4, t, 128), BF),
+    )
+
+
 @pytest.mark.parametrize("d", [128, 64])
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
 def test_paged_decode_lowers(d, quantized):

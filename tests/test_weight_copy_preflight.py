@@ -3,8 +3,9 @@ stack: checked in the programs the chip's own compiler builds, with no chip.
 
 ``tests/tpu_preflight_worker.py`` compiles the decode step and a fresh
 prefill piece for a TPU v5e by the README's pre-flight recipe (libtpu
-compiles for a described chip), at the benchmark's six configurations
-(published widths, two layers, one period of the hybrid, one block of
+compiles for a described chip), at the benchmark's seven configurations
+(published widths, two layers (of the window stack: its global layer and
+a window layer, both K/V leaves, all 64 experts under each), one period of the hybrid, one block of
 each kind of the pattern stack with 8 experts held, two blocks of the
 two-mixer stack, or three layers of the short-convolution stack: both
 operators, the dense MLP and two layers of all 32 experts). Asked of each
@@ -41,13 +42,14 @@ import pytest
 from tests import env_guards
 
 MODELS = ("mistral-7b-int8", "qwen2-7b-int8", "olmo-hybrid-7b-int8",
-          "nemotron-3-super-ep4-int8", "falcon-h1-34b-int8", "lfm2-8b-a1b-int8")
+          "nemotron-3-super-ep4-int8", "falcon-h1-34b-int8", "lfm2-8b-a1b-int8",
+          "smallthinker-21b-pp4-int8")
 CASES = [f"{m}:{s}" for m in MODELS for s in ("decode", "prefill")]
 # A decode step with no attention kernel yet: 64-wide heads, which
 # ops/paged_attention.py dense_decode_block turns away (head_dim % 128), so the
 # six attention layers read K/V eagerly (ROADMAP A: a kernel for them)
 NO_DECODE_KERNEL = {"lfm2-8b-a1b-int8:decode"}
-# twelve compiles take ~200 s here; under a loaded suite several times that
+# fourteen compiles take ~230 s here; under a loaded suite several times that
 TIMEOUT_S = 900
 
 

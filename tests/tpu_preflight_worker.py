@@ -13,7 +13,8 @@ their published widths and two layers (the hybrid: one period of four; the
 pattern stack: one block of each kind, ``M*E``, with 8 of its experts held;
 the two-mixer stack: two blocks, ``PP``; the short-convolution stack: a
 convolution, an attention layer and a convolution, the dense MLP under the
-first and all 32 experts under the other two).
+first and all 32 experts under the other two; the window stack: its global
+layer and a window layer, all 64 experts under each).
 
 Prints one JSON line: ``{"skip": reason}`` where libtpu or the topology cannot
 be had, else ``{"cases": {case: CompileStats.to_dict()}}`` (``mosaic_calls``,
