@@ -713,6 +713,7 @@ def attn_out_and_mlp(
     h: Optional[jnp.ndarray] = None,
     lora: Optional[Params] = None,
     lora_ids: Optional[jnp.ndarray] = None,
+    kernels: str = "off",
 ) -> jnp.ndarray:
     """Layer tail shared by every execution path.
 
@@ -757,7 +758,7 @@ def attn_out_and_mlp(
     if cfg.is_moe:
         from kserve_vllm_mini_tpu.models.moe import moe_mlp
 
-        return x + moe_mlp(p, cfg, h)
+        return x + moe_mlp(p, cfg, h, kernels)
     return x + swiglu_mlp(p, cfg, h, lora, lora_ids)
 
 
@@ -1095,7 +1096,7 @@ def run_cached_layers(
         h = block_norm(p, cfg, y0, "attn_norm")
         o, cache = attend(h, cache, p, lora_p, lidx)
         return (
-            attn_out_and_mlp(p, cfg, y0, o, h, lora=lora_p, lora_ids=lora_ids),
+            attn_out_and_mlp(p, cfg, y0, o, h, lora=lora_p, lora_ids=lora_ids, kernels=kernels),
             cache,
         )
 
@@ -1318,10 +1319,13 @@ def run_cached_layers(
                                   scale=attn_scale, softcap=attn_cap)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
             x_in, x = x, x + linear(o, p["wo"], mode=cfg.quant_mode)
-            p = jax.tree_util.tree_map(lambda a: a[l], layers["experts"])
-            # the router reads the layer's INPUT; the experts the post-attention norm
-            out, counts = moe_glu_block(p, cfg, rms_norm(x, p["ffn_norm"], cfg.rms_eps),
-                                        real, route_from=x_in)
+            # the router reads the layer's INPUT; the experts the post-attention
+            # norm. The stack and the index go down, not a slice: a long piece's
+            # grouped dispatch reads the expert stacks where they lie
+            experts = layers["experts"]
+            out, counts = moe_glu_block(
+                experts, cfg, rms_norm(x, experts["ffn_norm"][l], cfg.rms_eps),
+                real, route_from=x_in, kernels=kernels, layer=l)
             stats = stats + counts
             x = x + out
         return (x, cache, stats) if with_stats else (x, cache)
@@ -1365,8 +1369,11 @@ def run_cached_layers(
                 p = at("mlp", l)
                 out = swiglu_mlp(p, cfg, rms_norm(x, p["ffn_norm"], cfg.rms_eps))
             else:
-                p = at("experts", l - cfg.num_dense_layers)
-                out, counts = moe_glu_block(p, cfg, rms_norm(x, p["ffn_norm"], cfg.rms_eps), real)
+                # the stack and the index, not a slice (the window walk above)
+                experts, i = layers["experts"], l - cfg.num_dense_layers
+                out, counts = moe_glu_block(
+                    experts, cfg, rms_norm(x, experts["ffn_norm"][i], cfg.rms_eps),
+                    real, kernels=kernels, layer=i)
                 stats = stats + counts
             x = x + out
         return (x, cache, stats) if with_stats else (x, cache)
@@ -1434,7 +1441,7 @@ def run_cached_layers(
                          + scaled(attended, cfg.attention_out_multiplier))
                 out = swiglu_mlp(p, cfg, rms_norm(x, p["mlp_norm"], cfg.rms_eps))
             else:
-                out, counts = moe_latent_block(p, cfg, h, real)
+                out, counts = moe_latent_block(p, cfg, h, real, kernels)
                 stats = stats + counts
             x = x + out
         return (x, cache, stats) if with_stats else (x, cache)

@@ -28,22 +28,53 @@ Four layers share one dispatch:
   norm) while the experts read the post-attention norm; the chosen
   probabilities renormalised; the same GLU body with ReLU on the gate.
 
-**Dispatch: every held expert runs every token of the block, and the routing
+**Two dispatches, chosen by what the piece is** (``grouped_blocks``), and
+neither can drop a token at any routing, because nothing is bounded by a
+capacity.
+
+*Dense: every held expert runs every token of the block, and the routing
 weight (zero where the token did not choose the expert) is applied at the
-combine.** No token can be dropped at any routing, because nothing is
-bounded by a capacity: there is no sort, scatter, buffer or gather, so no
-latency-bound operation between the router and the matmuls, and the int8
-expert stacks are read where they lie (one batched matmul over the expert
-axis). The price is arithmetic: held experts / chosen-and-held experts times
-what the chosen tokens need. A decode step is bound by the experts' weight
-stream, which a sweep over 32 slots x 22 choices touches three quarters of
-anyway, so there the arithmetic is free; a prefill piece of 128 positions
-touches every expert and its arithmetic still hides under the weight stream;
-longer pieces pay it (ROADMAP: a grouped matmul over tokens sorted by
-expert, which would also skip the experts a decode step does not touch).
-Pieces longer than ``TOKEN_BLOCK`` run block by block (unrolled) so that
-the ``[experts, tokens, width]`` intermediates stay bounded; pieces longer
-than ``LONG_PIECE`` in blocks of ``LONG_TOKEN_BLOCK``.
+combine.* No sort, scatter, buffer or gather, so no latency-bound operation
+between the router and the matmuls, and the int8 expert stacks are read where
+they lie (one batched matmul over the expert axis). The price is arithmetic:
+held experts / chosen-and-held experts times what the chosen tokens need. A
+decode step is bound by the experts' weight stream, which a sweep over 32
+slots x 22 choices touches three quarters of anyway, so there the arithmetic
+is free; a prefill piece of 128 positions touches every expert and its
+arithmetic still hides under the weight stream. This is what runs a decode
+step, a piece of up to one row tile (128 tokens), ``quant_mode="w8a8"`` (the int8
+contraction has its own arithmetic, and it is the control of the benchmark's
+``correct``), any piece without the Pallas kernels (the CPU; a mesh, so
+``moe_mlp`` under an ``ep`` axis) and shapes the kernel has no block for.
+There, pieces longer than ``TOKEN_BLOCK`` run block by block (unrolled) so
+that the ``[experts, tokens, width]`` intermediates stay bounded; pieces
+longer than ``LONG_PIECE`` in blocks of ``LONG_TOKEN_BLOCK``: since the
+grouped dispatch only those fallbacks reach these blocks.
+
+*Grouped: each expert runs the rows that chose it.* A piece longer than one
+row tile of the kernel (128 tokens: a group is padded to whole tiles, so up
+to there an expert with one token costs what the dense dispatch's every
+expert does, and past it the dense arithmetic, 2 FLOP a token for every
+int8 byte of every held expert, is more than a v5e does while it reads that
+byte), on one TPU, over int8 leaves in ``dequant`` mode or plain stacks:
+256-8192 tokens times 32 or 64 experts where a token chose 4 or 6 is
+arithmetic no weight stream hides (it was four fifths of the prefill
+programs at a twentieth of the MXU's peak). ``_run_grouped`` sorts the
+piece's ``S x K`` assignments that land on a held expert by expert, each
+expert's group padded to whole row tiles (``ops/grouped_matmul.py
+group_rows``: at most ``S K + held (tile - 1)`` rows, a static bound that
+holds every routing, all tokens on one expert included; assignments that
+land elsewhere are dropped from the groups and from nothing else), gathers
+the rows, runs the body as two calls of one Pallas kernel (``gmm``: the
+first matmul or gated pair with its activation, then the second) over the
+LAYER-STACKED leaves with the layer index and each tile's expert folded into
+the weights' index maps, so the stacks are read where they lie, and sums
+each token's ``K`` rows times their routing weights in float32: the dense
+dispatch's result up to the order of a float32 sum (and one rounding where
+the dense one rounds each product). It is one jitted function whose operands
+are the stacks and the layer index, so a walk's expert layers are one traced
+and lowered body a shape; the walks pass the stack and the index down
+(``moe_glu_block(..., layer=)``).
 
 Quantization: expert stacks quantize per output channel like dense weights
 (ops/quant.py works on any [..., in, out] stack); routers and the selection
@@ -52,19 +83,28 @@ bias stay unquantized (noise-level bytes, and they decide the routing).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
 from kserve_vllm_mini_tpu.models.config import ModelConfig
+from kserve_vllm_mini_tpu.ops.flash_attention import pallas_interpret
+from kserve_vllm_mini_tpu.ops.grouped_matmul import (
+    ACTIVATIONS,
+    group_rows,
+    grouped_matmul,
+    grouped_matmul_block,
+)
 from kserve_vllm_mini_tpu.ops.quant import is_quantized, linear, unpacked_q
 
-TOKEN_BLOCK = 256   # tokens the held experts run at once
-# A piece of more than LONG_PIECE tokens (longer than any piece the programs
-# above were measured on) runs LONG_TOKEN_BLOCK at once: the unrolled blocks
-# are what a prefill program's compile time grows with (an 8192-token piece of
-# 12 expert layers is 384 of them at 256 and takes 146 s to compile, 96 at 1024)
+TOKEN_BLOCK = 256   # tokens the held experts run at once in the dense dispatch
+# A piece of more than LONG_PIECE tokens that still runs the dense dispatch
+# (no kernels, ``w8a8``: the benchmark's control) runs LONG_TOKEN_BLOCK at
+# once: the unrolled blocks are what a prefill program's compile time grows
+# with (an 8192-token piece of 12 expert layers is 384 of them at 256 and
+# takes 146 s to compile, 96 at 1024)
 LONG_PIECE = 1024
 LONG_TOKEN_BLOCK = 1024
 _HI = jax.lax.Precision.HIGHEST
@@ -99,18 +139,146 @@ def held_weights(weights: jnp.ndarray, chosen: jnp.ndarray, offset: int,
     return take, jnp.any(hit, axis=1)
 
 
-def _run_held(x: jnp.ndarray, take: jnp.ndarray, body) -> jnp.ndarray:
-    """``sum_e take[s, e] * body(x)[e, s]`` -> [S, out] float32, the tokens
-    in blocks of ``TOKEN_BLOCK`` (``LONG_TOKEN_BLOCK`` past ``LONG_PIECE`` tokens)."""
-    def block(args):
-        xb, tb = args
-        return jnp.einsum("se,esd->sd", tb, body(xb),
+# An expert body as data, so that both dispatches run the same one: the
+# stacks of the first matmul (two: a gate and an up projection, multiplied
+# after the gate's activation), the activation (ops/grouped_matmul.py
+# ACTIVATIONS; in float32, cast back), the stack of the second matmul.
+GLU_SILU = (("w_gate", "w_up"), "silu", "w_down")    # Mixtral, LFM2-MoE
+GLU_RELU = (("w_gate", "w_up"), "relu", "w_down")    # SmallThinker
+LATENT_RELU2 = (("w1",), "relu2", "w2")              # Nemotron-H's latent experts
+
+
+def experts_of(p: dict[str, Any], body, qm: str):
+    """The dense expert body over ``p``'s stacks [E, in, mid] / [E, mid, out]:
+    ``x`` [S, in] -> [E, S, out], every expert on every token of the block."""
+    first, act, last = body
+
+    def experts(xb):
+        mid = ACTIVATIONS[act](
+            _expert_linear(xb, p[first[0]], qm).astype(jnp.float32)).astype(xb.dtype)
+        if len(first) == 2:
+            mid = mid * _expert_linear(xb, p[first[1]], qm)
+        return _expert_linear(mid, p[last], qm)
+
+    return experts
+
+
+def grouped_blocks(x: jnp.ndarray, p: dict[str, Any], body, qm: str,
+                   kernels: str) -> Optional[tuple[tuple[int, int], tuple[int, int]]]:
+    """The grouped dispatch's blocks for the piece ``x`` [S, in] over the
+    stacks ``p`` (each [..., E, in, out]), or None where the piece takes the
+    dense dispatch. Read off the input alone: more tokens than one row tile
+    of the kernel (up to there a group's padding costs what every expert on
+    every token does, and that arithmetic hides under the weight stream),
+    Pallas kernels on (a TPU, one device: under a mesh the engine
+    passes ``"off"``), stacks that are int8 leaves in ``dequant`` mode or
+    plain arrays of the activations' dtype (``w8a8`` contracts in int8 and
+    packed or AWQ leaves have their own arithmetic: the dense dispatch's),
+    and shapes the kernel has a block for."""
+    first, _, last = body
+    names = (*first, last)
+    if pallas_interpret(kernels) is None:
+        return None
+    quantized = [is_quantized(p[n]) for n in names]
+    plain_int8 = all(quantized) and qm == "dequant" and all(
+        set(p[n]) == {"q", "s"} and p[n]["q"].dtype == jnp.int8 for n in names)
+    if any(quantized) and not plain_int8:  # kvmini: static-shape
+        return None
+    blocks = []
+    for name, n_w in ((first[0], len(first)), (last, 1)):
+        w = p[name]["q"] if quantized[0] else p[name]
+        blocks.append(grouped_matmul_block(w.shape[-2], w.shape[-1], w.dtype, x.dtype, n_w))
+    if None in blocks or blocks[0][0] != blocks[1][0] or x.shape[0] <= blocks[0][0]:
+        return None
+    return blocks[0], blocks[1]
+
+
+def grouped_prefill(cfg: ModelConfig, layers: dict[str, Any], tokens: int,
+                    kernels: str) -> bool:
+    """Whether a prefill piece of ``tokens`` tokens takes the grouped
+    dispatch in the model's expert layers: what the walks decide, from the
+    same inputs (``layers`` the parameter tree's layer stacks), for the
+    engine's counter. False for a model without routed experts."""
+    if cfg.is_conv_stack or cfg.is_window_stack:
+        p, body = layers["experts"], GLU_RELU if cfg.is_window_stack else GLU_SILU
+        width = cfg.d_model
+    elif cfg.n_expert_layers:
+        p, body, width = layers["experts"], LATENT_RELU2, cfg.moe_latent_size
+    elif cfg.is_moe:
+        p, body, width = layers, GLU_SILU, cfg.d_model
+    else:
+        return False
+    x = jax.ShapeDtypeStruct((tokens, width), cfg.jnp_dtype)
+    return grouped_blocks(x, p, body, cfg.quant_mode, kernels) is not None
+
+
+@functools.partial(jax.jit, static_argnames=("offset", "body", "blocks", "interpret"))
+def _run_grouped(x, weights, chosen, stacks, layer, *, offset, body, blocks, interpret):
+    """The grouped dispatch of one piece, behind ONE jit: a walk's expert
+    layers call it with another ``layer`` of the same ``stacks`` and are one
+    traced and lowered body (a call site traced and lowered on its own is
+    0.1-0.3 s of every set-up, warm compile cache or not; XLA inlines the
+    calls). ``x`` [S, in], ``weights`` / ``chosen`` [S, K] over all the
+    routed experts, ``stacks`` the body's LAYER-STACKED leaves
+    [L, held, ., .] -> [S, out] float32."""
+    first, act, last = body
+    (tm, _), _ = blocks
+    k = chosen.shape[1]
+    held = jax.tree_util.tree_leaves(stacks[last])[0].shape[1]
+    local = chosen - offset
+    landed = (local >= 0) & (local < held)                                  # [S, K]
+    # assignments that land elsewhere are dropped from the groups, and from
+    # nothing else: the router weighted them as the whole model would
+    source, tiles, row = group_rows(jnp.where(landed, local, held).reshape(-1), held, tm)
+    rows = jnp.take(x, source // k, axis=0, mode="clip")                    # [R, in]
+    mid = grouped_matmul(rows, tiles, layer, *(stacks[n] for n in first), act=act,
+                         block=blocks[0], interpret=interpret)
+    out = grouped_matmul(mid, tiles, layer, stacks[last], block=blocks[1],
+                         interpret=interpret)                               # [R, out]
+    # each token's K rows times their routing weights: ONE gather, the rows
+    # in choice-major order, so that the sum runs over the leading axis of
+    # [K, S, out] (whole tiles added to whole tiles). A gathered row costs
+    # ~40 ns on a v5e whether one gather takes S K rows or K gathers S each
+    # (1.0 ms a layer at 4096 tokens). A row past the used tiles was never written
+    mine = jnp.take(out, row.reshape(chosen.shape).T.reshape(-1), axis=0)
+    mine = mine.reshape(k, x.shape[0], -1).astype(jnp.float32)              # [K, S, out]
+    share = weights.astype(jnp.float32).T[:, :, None]
+    return jnp.sum(jnp.where(landed.T[:, :, None], share * mine, 0.0), axis=0)
+
+
+def _run_held(x: jnp.ndarray, weights: jnp.ndarray, chosen: jnp.ndarray, offset: int,
+              p: dict[str, Any], body, qm: str, kernels: str = "off",
+              layer: Optional[int] = None) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of ``sum_k weights[s, k] * expert_chosen[s, k](x[s])``
+    -> ([S, out] float32, ``landed`` [S, held]: whether a token chose each
+    held expert). ``p`` holds the body's stacks [held, ., .], or with
+    ``layer`` the layer stacks [L, held, ., .] of which this is layer
+    ``layer``. A long piece takes the grouped dispatch (``grouped_blocks``);
+    any other runs every held expert on every token, in blocks of
+    ``TOKEN_BLOCK`` (``LONG_TOKEN_BLOCK`` past ``LONG_PIECE`` tokens)."""
+    names = (*body[0], body[2])
+    p = {n: p[n] for n in names}
+    held = jax.tree_util.tree_leaves(p[names[-1]])[0].shape[-3]
+    take, landed = held_weights(weights, chosen, offset, held)
+    blocks = grouped_blocks(x, p, body, qm, kernels)
+    if blocks is not None:
+        if layer is None:
+            p, layer = jax.tree_util.tree_map(lambda a: a[None], p), 0
+        out = _run_grouped(x, weights, chosen, p, jnp.int32(layer), offset=offset, body=body,
+                           blocks=blocks, interpret=pallas_interpret(kernels))
+        return out, landed
+    if layer is not None:
+        p = jax.tree_util.tree_map(lambda a: a[layer], p)
+    experts = experts_of(p, body, qm)
+
+    def block(xb, tb):
+        return jnp.einsum("se,esd->sd", tb, experts(xb),
                           preferred_element_type=jnp.float32)
 
     n = x.shape[0]
     size = TOKEN_BLOCK if n <= LONG_PIECE else LONG_TOKEN_BLOCK
     if n <= size:
-        return block((x, take))
+        return block(x, take), landed
     # Unrolled, not a ``lax.map``: a loop's operands must be buffers of their
     # own, so XLA sliced every expert stack out of its layer stack and copied
     # it (transposed) before the loop: 117 MB a stack, 6.9 GB of temporaries
@@ -118,25 +286,12 @@ def _run_held(x: jnp.ndarray, take: jnp.ndarray, body) -> jnp.ndarray:
     pad = -n % size
     xs = jnp.pad(x, ((0, pad), (0, 0)))
     ts = jnp.pad(take, ((0, pad), (0, 0)))
-    return jnp.concatenate([block((xs[i:i + size], ts[i:i + size]))
-                            for i in range(0, n + pad, size)])[:n]
+    return jnp.concatenate([block(xs[i:i + size], ts[i:i + size])
+                            for i in range(0, n + pad, size)])[:n], landed
 
 
-def glu_experts(p: dict[str, Any], qm: str, act=jax.nn.silu):
-    """The gated expert body over the stacks ``w_gate``/``w_up`` [E, D, F]
-    and ``w_down`` [E, F, D]: ``x`` [S, D] -> [E, S, D], every expert on
-    every token of the block. ``act`` is the gate's activation: SiLU
-    (Mixtral, LFM2-MoE) or ReLU (SmallThinker); one body."""
-    def experts(xb):
-        gated = act(
-            _expert_linear(xb, p["w_gate"], qm).astype(jnp.float32)
-        ).astype(xb.dtype) * _expert_linear(xb, p["w_up"], qm)
-        return _expert_linear(gated, p["w_down"], qm)
-
-    return experts
-
-
-def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
+def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
+            kernels: str = "off") -> jnp.ndarray:
     """Mixtral's routed SwiGLU MLP. ``h`` is the normed hidden [B, T, D];
     returns the MLP delta [B, T, D] (the caller adds the residual).
     ``p`` holds this layer's ``router`` [D, E] and the expert stacks
@@ -146,8 +301,7 @@ def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
     logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
     gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
     gates = gates / jnp.maximum(gates.sum(axis=-1, keepdims=True), 1e-9)
-    out = _run_held(x, held_weights(gates, chosen, 0, cfg.n_experts)[0],
-                    glu_experts(p, cfg.quant_mode))
+    out, _ = _run_held(x, gates, chosen, 0, p, GLU_SILU, cfg.quant_mode, kernels)
     return out.astype(h.dtype).reshape(B, T, D)
 
 
@@ -202,11 +356,16 @@ def moe_glu_block(
     p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
     real: Optional[jnp.ndarray] = None,
     route_from: Optional[jnp.ndarray] = None,
+    kernels: str = "off",
+    layer: Optional[int] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """A routed GLU feed-forward on the normed hidden ``h`` [B, T, D]
     -> (delta [B, T, D], stats as ``routing_stats``). ``p``: ``router``
     [D, n_routed] float32; the held experts' stacks ``w_gate``/``w_up``
-    [held, D, F], ``w_down`` [held, F, D].
+    [held, D, F], ``w_down`` [held, F, D]. With ``layer``, ``p`` is the whole
+    LAYER STACK of such leaves and this is its layer ``layer``: a walk passes
+    the stack and the index down, so that the grouped dispatch reads the
+    expert stacks where they lie.
 
     LFM2-MoE's: the biased sigmoid router (``router_bias`` [n_routed]) on
     ``h`` itself, SiLU on the gate. A ``sliding_window_layout`` stack's
@@ -214,14 +373,18 @@ def moe_glu_block(
     THAT tensor, ReLU on the gate."""
     B, T, D = h.shape
     x = h.reshape(B * T, D)
+
+    def small(name):
+        return p[name] if layer is None else p[name][layer]
+
     if route_from is None:
-        w, chosen = route_sigmoid(x, p["router"], p["router_bias"], cfg, NORM_EPS_GLU)
-        act = jax.nn.silu
+        w, chosen = route_sigmoid(x, small("router"), small("router_bias"), cfg, NORM_EPS_GLU)
+        body = GLU_SILU
     else:
-        w, chosen = route_softmax(route_from.reshape(B * T, D), p["router"], cfg)
-        act = jax.nn.relu
-    take, landed = held_weights(w, chosen, cfg.expert_offset, cfg.experts_held)
-    out = _run_held(x, take, glu_experts(p, cfg.quant_mode, act))
+        w, chosen = route_softmax(route_from.reshape(B * T, D), small("router"), cfg)
+        body = GLU_RELU
+    out, landed = _run_held(x, w, chosen, cfg.expert_offset, p, body, cfg.quant_mode,
+                            kernels, layer)
     return out.astype(h.dtype).reshape(B, T, D), routing_stats(landed, real)
 
 
@@ -232,6 +395,7 @@ def relu2(x: jnp.ndarray) -> jnp.ndarray:
 def moe_latent_block(
     p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
     real: Optional[jnp.ndarray] = None,
+    kernels: str = "off",
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The ``E`` block's mixer on the normed hidden ``h`` [B, T, D] ->
     (delta [B, T, D], stats). ``p``: ``router`` [D, n_routed] and
@@ -244,13 +408,8 @@ def moe_latent_block(
     dt, qm = h.dtype, cfg.quant_mode
     x = h.reshape(B * T, D)
     w, chosen = route_sigmoid(x, p["router"], p["router_bias"], cfg)
-    take, landed = held_weights(w, chosen, cfg.expert_offset, cfg.experts_held)  # [S, held]
     u = linear(x, p["w_in"], mode=qm)                                      # [S, latent]
-
-    def experts(ub):
-        return _expert_linear(relu2(_expert_linear(ub, p["w1"], qm)), p["w2"], qm)
-
-    routed = linear(_run_held(u, take, experts).astype(dt), p["w_out"], mode=qm)
+    routed, landed = _run_held(u, w, chosen, cfg.expert_offset, p, LATENT_RELU2, qm, kernels)
+    routed = linear(routed.astype(dt), p["w_out"], mode=qm)
     shared = linear(relu2(linear(x, p["s1"], mode=qm)), p["s2"], mode=qm)
-    stats = routing_stats(landed, real)
-    return (routed + shared).reshape(B, T, D), stats
+    return (routed + shared).reshape(B, T, D), routing_stats(landed, real)

@@ -433,6 +433,7 @@ class Engine:
         # GSPMD, which refuses a Mosaic call ("cannot be automatically
         # partitioned") — those run attention through XLA
         self._kernels = "off" if mesh is not None else "auto"
+        self._grouped_buckets: dict[int, bool] = {}
         # key-block size of the dense decode kernel where decode steps take
         # it (models/llama.py dense_decode_kernel_block), resolved in
         # start(): what _count_decode_kv_blocks counts by
@@ -984,6 +985,11 @@ class Engine:
             # what the pieces were padded to: prefill_tokens over this is
             # the share of the prefill programs' work that was real prompt
             "prefill_bucket_tokens": 0,
+            # those of them whose program dispatches its tokens to the
+            # routed experts grouped by expert (models/moe.py
+            # grouped_prefill: a piece longer than the kernel's row tile, with
+            # the kernels on); a program is one or the other by its shape
+            "prefill_grouped_bucket_tokens": 0,
             # how often the dense decode kernel's block selection engages
             # (ops/paged_attention.py live_block_range): key/value blocks
             # the target's plain and masked decode steps fetched, summed
@@ -2484,6 +2490,8 @@ class Engine:
             st["logits"] = last_logits
             # target pieces only, like prefill_tokens
             self.stats["prefill_bucket_tokens"] += bucket
+            if self._bucket_is_grouped(bucket):
+                self.stats["prefill_grouped_bucket_tokens"] += bucket
             if self._slot_prefill[slot] is not None:
                 # interleaved mode: advance the frontier so concurrent
                 # sweeps' garbage writes stay >= it (see _slot_prefill)
@@ -2491,6 +2499,17 @@ class Engine:
         return st["off"] >= n and (
             st["draft_off"] is None or st["draft_off"] >= n
         )
+
+    def _bucket_is_grouped(self, bucket: int) -> bool:
+        """Whether the prefill program of ``bucket`` tokens holds the grouped
+        expert dispatch: decided by the shapes when the program is built
+        (models/moe.py), asked again here by the same rule and kept."""
+        if bucket not in self._grouped_buckets:
+            from kserve_vllm_mini_tpu.models.moe import grouped_prefill
+
+            self._grouped_buckets[bucket] = grouped_prefill(
+                self.cfg, self.params["layers"], bucket, self._kernels)
+        return self._grouped_buckets[bucket]
 
     def _advance_prefills(self, on_decision=None) -> None:
         """Advance the OLDEST in-progress chunked prefill by ONE piece

@@ -1356,6 +1356,11 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             # pieces only): with prefill_tokens_total, the padding's share
             "# TYPE kvmini_tpu_prefill_bucket_tokens_total counter",
             f"kvmini_tpu_prefill_bucket_tokens_total {s['prefill_bucket_tokens']}",
+            # the bucket tokens of pieces whose program dispatched its tokens
+            # to the routed experts grouped by expert (models/moe.py)
+            "# TYPE kvmini_tpu_prefill_grouped_bucket_tokens_total counter",
+            "kvmini_tpu_prefill_grouped_bucket_tokens_total "
+            f"{s['prefill_grouped_bucket_tokens']}",
             # the dense decode kernel's block selection: key/value blocks
             # decode steps fetched against slots x S/BLK (0 / 0 where the
             # eager read runs)

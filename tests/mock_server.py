@@ -392,6 +392,10 @@ def make_app(
         "kvmini_tpu_prefills_total": 4.0,
         "kvmini_tpu_prefill_chunks_total": 6.0,
         "kvmini_tpu_prefill_chunk_stall_seconds_total": 0.125,
+        # what the pieces were padded to, and those of them whose program
+        # dispatched to the routed experts grouped by expert (docs/TRACING.md)
+        "kvmini_tpu_prefill_bucket_tokens_total": 2048.0,
+        "kvmini_tpu_prefill_grouped_bucket_tokens_total": 1536.0,
         # monitor-facing gauges/counters (docs/MONITORING.md) so the 1 Hz
         # sampler's timeline has runtime series without a JAX engine
         "kvmini_tpu_duty_cycle": 0.8,

@@ -113,6 +113,22 @@ TOLERANCE, SEEN = 2e-5, 1e-3
 
 
 SHAPES = [(50, 64), (70, 128), (23, 32), (97, 128)]
+RAGGED = [64, 23, 41]
+
+
+def sequences(m):
+    """Every sequence this module compares with the reference: the four
+    prompts of ``SHAPES`` with twelve tokens to decode, the prompt that is
+    prefilled in pieces, the three ragged rows."""
+    return ([a_sequence(m, n + 12, n) for n, _ in SHAPES] + [a_sequence(m, 76, 1)]
+            + [a_sequence(m, n + 2, 10 + i) for i, n in enumerate(RAGGED)])
+
+
+def want_of(m, seq):
+    """The reference's logits for ``seq`` at seed 7, out of ONE call for all
+    of ``sequences`` a model (a call compiles the reference anew)."""
+    seqs = sequences(m)
+    return wanted(7, m, seqs)[seqs.index(seq)]
 
 
 @pytest.mark.parametrize("quantization", ["int8", "none"])
@@ -124,9 +140,8 @@ def test_prefill_then_decode_against_the_references_full_forward(quantization, n
     leaves with their scales as an epilogue, the reference the dequantized
     float32 weights."""
     m = tiny_model(quantization)
-    seqs = [a_sequence(m, n + 12, n) for n, _ in SHAPES]
-    which = SHAPES.index((n_prompt, bucket))
-    seq, want = seqs[which], wanted(7, m, seqs)[which]
+    seq = sequences(m)[SHAPES.index((n_prompt, bucket))]
+    want = want_of(m, seq)
     got, _ = prefill_then_decode(served_weights(7, m), f32_config(m), seq, n_prompt, bucket)
     assert got.shape[0] == len(seq)
     assert off_by(got[:-1], want) < TOLERANCE
@@ -137,9 +152,9 @@ def test_a_prompt_in_pieces_against_the_reference_and_the_prompt_at_once(pieces)
     """The tail is carried from piece to piece: pieces of one token (shorter
     than the tail itself) and pieces that end anywhere."""
     m = tiny_model()
-    params, cfg = served_weights(3, m), f32_config(m)
+    params, cfg = served_weights(7, m), f32_config(m)
     seq = a_sequence(m, 76, 1)
-    want = wanted(3, m, [seq])[0]
+    want = want_of(m, seq)
     whole, c1 = prefill_then_decode(params, cfg, seq, 70, 128)
     split, c2 = prefill_then_decode(params, cfg, seq, 70, 128, pieces=pieces)
     assert off_by(split[:-1], want) < TOLERANCE and off_by(split, whole) < TOLERANCE
@@ -154,10 +169,10 @@ def test_a_batch_with_ragged_valid_lengths_is_each_row_alone():
     logits are the reference's for that row's sequence alone, and the parked
     row's tail does not advance."""
     m = tiny_model()
-    params, cfg = served_weights(5, m), f32_config(m)
-    lens = [64, 23, 41]
-    seqs = [a_sequence(m, n + 2, 10 + i) for i, n in enumerate(lens)]
-    want = reference.logits_after(5, m, seqs, [1, 1, 1], 128, 64)
+    params, cfg = served_weights(7, m), f32_config(m)
+    lens = RAGGED
+    seqs = sequences(m)[-3:]
+    want = [want_of(m, s) for s in seqs]
     toks = jnp.asarray([s[:n] + [0] * (64 - n) for s, n in zip(seqs, lens)], jnp.int32)
     step = make_step()
     cache = init_kv_cache(cfg, 3, max_seq=256)
@@ -336,7 +351,9 @@ def test_config_refuses_what_the_stack_is_not():
 def test_the_int8_tree_quantizes_the_four_stacks_matmuls_and_nothing_else():
     cfg = get_config("lfm2-moe-tiny")
     key = jax.random.PRNGKey(3)
-    direct, after = init_params_quantized(key, cfg), quantize_params(init_params(key, cfg))
+    # one program a tree: drawn eagerly, every leaf is a compile of its own
+    direct = jax.jit(lambda k: init_params_quantized(k, cfg))(key)
+    after = jax.jit(lambda k: quantize_params(init_params(k, cfg)))(key)
     quantized = {"conv": {"in_proj", "out_proj"}, "attn": {"wq", "wk", "wv", "wo"},
                  "mlp": {"w_gate", "w_up", "w_down"}, "experts": {"w_gate", "w_up", "w_down"}}
     for tree in (direct, after):
@@ -363,10 +380,11 @@ def test_the_reference_regenerates_the_served_weights_layer_by_layer():
     served = reference.weights.all_weights(9, m)
     root = reference.weights.root_key(9)
     seen = dict.fromkeys(("conv", "attn", "mlp", "experts"), 0)
+    draw = functools.cache(lambda op, ffn: jax.jit(partial(      # one program a kind of layer
+        reference.weights.layer_weights, m=m, operator=op, ffn=ffn)))
     for li in range(m["num_hidden_layers"]):
         op, ffn = reference.weights.operator_of(m, li), reference.weights.ffn_of(m, li)
-        w = jax.jit(partial(reference.weights.layer_weights, m=m, operator=op, ffn=ffn))(
-            root, layer=jnp.int32(li))
+        w = draw(op, ffn)(root, layer=jnp.int32(li))
         for stack, part in ((op, w["operator"]), (ffn, w["ffn"])):
             i = seen[stack]
             seen[stack] += 1

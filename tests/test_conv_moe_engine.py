@@ -207,3 +207,36 @@ def test_the_counters_are_exported_and_a_dense_model_counts_nothing():
     lines = scrape_of(dense)
     assert 'kvmini_tpu_cache_bytes{kind="conv_tail"} 0' in lines
     assert "kvmini_tpu_conv_tail_resets_total 0" in lines
+
+
+def test_the_engine_serves_a_long_piece_grouped_and_counts_its_bucket(monkeypatch):
+    """A 70-token prompt's piece takes the grouped dispatch, a 12-token one
+    and every decode sweep the dense one (the kernel's row tile cut to 16, the
+    experts 128 wide so that it has a block; tests/test_grouped_moe.py
+    holds the dispatch itself): the streams are the dense engine's, and the
+    counter holds the long piece's bucket alone."""
+    from kserve_vllm_mini_tpu.ops import grouped_matmul
+    from kserve_vllm_mini_tpu.runtime import steps
+
+    monkeypatch.setattr(grouped_matmul, "ROW_TILE", 16)
+    # a convolution, attention, a convolution; a dense MLP then two expert layers
+    cfg = get_config("lfm2-moe-tiny", dtype="float32", moe_intermediate_size=128, n_layers=3,
+                     layer_types=("conv", "full_attention", "conv"))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    prompts = [[int(t) for t in rng.integers(3, 500, n)] for n in (70, 12)]
+    outs, stats = {}, {}
+    for kernels in ("auto", "interpret"):
+        eng = Engine(params, cfg, EngineConfig(max_slots=2, max_seq_len=256, max_prefill_len=128))
+        eng._kernels = kernels
+        eng.start()
+        try:
+            outs[kernels] = run_requests(eng, prompts, max_new=4)
+        finally:
+            eng.stop()
+        stats[kernels] = eng.snapshot_stats()
+    assert outs["interpret"] == outs["auto"]
+    buckets = [steps.prefill_bucket(len(p), eng.ecfg) for p in prompts]
+    assert stats["interpret"]["prefill_bucket_tokens"] == sum(buckets)
+    assert stats["interpret"]["prefill_grouped_bucket_tokens"] == buckets[0]
+    assert stats["auto"]["prefill_grouped_bucket_tokens"] == 0

@@ -4,6 +4,8 @@ reference, logits not tokens, at tiny widths on the CPU; what must hold of a
 state-space state in a serving cache; that the shares of an expert layer add
 up to the uncut layer; and what the comparison must see."""
 
+import functools
+import json
 from functools import partial
 
 import jax
@@ -26,7 +28,7 @@ from kserve_vllm_mini_tpu.models.llama import (
 from kserve_vllm_mini_tpu.ops.quant import is_quantized, quantize_params
 # one padded prefill (or pieces of it) then token-by-token decode through the
 # cache, with a jitted step of its own where a test patches the program
-from tests.test_hybrid import make_step, prefill_then_decode
+from tests.test_hybrid import STEP, make_step, prefill_then_decode
 
 reference = bench_config.load_module("references", "ssm_moe_decoder")
 system = bench_config.load_module("systems", "engine")
@@ -47,12 +49,32 @@ def tiny_model(quantization="int8", **share):
     return m
 
 
-def served_weights(seed, m):
-    """The benchmark's weights as the program is handed them, the bfloat16
-    leaves widened (the values are the same): the program runs in float32."""
-    params = reference.weights.all_weights(seed, m)
+@functools.cache
+def _served_weights(seed, model_json):
+    params = reference.weights.all_weights(seed, json.loads(model_json))
     return jax.tree_util.tree_map(
         lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a, params)
+
+
+def served_weights(seed, m):
+    """The benchmark's weights as the program is handed them, the bfloat16
+    leaves widened (the values are the same): the program runs in float32.
+    Drawn once a (seed, model) in a worker; a caller gets a tree of its own
+    (new containers over the same arrays), so a control may swap a leaf."""
+    return jax.tree_util.tree_map(lambda a: a, _served_weights(seed, json.dumps(m, sort_keys=True)))
+
+
+@functools.cache
+def _wanted(seed, model_json, seqs):
+    m = json.loads(model_json)
+    return reference.logits_after(seed, m, [list(s) for s in seqs], [1] * len(seqs), 128, 64)
+
+
+def wanted(seed, m, seqs):
+    """The reference's logits for ``seqs`` (each from its second position on,
+    padded to 128), one call for all of them and once a worker: every call
+    of ``logits_after`` compiles its blocks anew (~8 s here)."""
+    return _wanted(seed, json.dumps(m, sort_keys=True), tuple(tuple(s) for s in seqs))
 
 
 def f32_config(m, **kw):
@@ -71,13 +93,30 @@ def a_sequence(m, n, seed):
     return [int(t) for t in np.random.default_rng(seed).integers(3, m["vocab_size"], n)]
 
 
+SHAPES = [(50, 64), (70, 128), (23, 32), (97, 128)]
+
+
+def sequences(m):
+    """Every sequence this module compares with the reference: the four
+    prompts of ``SHAPES`` with twelve tokens to decode, and the one the
+    broken programs run."""
+    return [a_sequence(m, n + 12, n) for n, _ in SHAPES] + [a_sequence(m, 70, 5)]
+
+
+def want_of(m, seq):
+    """The reference's logits for ``seq`` at seed 7, out of ONE call for all
+    of ``sequences`` a model (a call compiles the reference anew)."""
+    seqs = sequences(m)
+    return wanted(7, m, seqs)[seqs.index(seq)]
+
+
 @pytest.mark.parametrize("quantization", ["int8", "none"])
-@pytest.mark.parametrize("n_prompt,bucket", [(50, 64), (70, 128), (23, 32), (97, 128)])
+@pytest.mark.parametrize("n_prompt,bucket", SHAPES)
 def test_prefill_then_decode_against_the_references_full_forward(quantization, n_prompt, bucket):
     """Prompt lengths that are no multiple of the chunk (32) or the bucket."""
     m = tiny_model(quantization)
-    seq = a_sequence(m, n_prompt + 12, n_prompt)
-    want = reference.logits_after(7, m, [seq], [1], 128, 64)[0]
+    seq = sequences(m)[SHAPES.index((n_prompt, bucket))]
+    want = want_of(m, seq)
     got, _ = prefill_then_decode(served_weights(7, m), f32_config(m), seq, n_prompt, bucket)
     assert got.shape[0] == len(seq)
     assert np.max(np.abs(got[:-1] - want)) < TOLERANCE * np.max(np.abs(want))
@@ -94,6 +133,7 @@ def _capacity_bounded(weights, chosen, offset, held, capacity=3):
 HELD = moe.held_weights
 BROKEN = ["dropped_assignment", "bf16_state", "w8a8", "pad_in_state", "no_selection_bias",
           "no_conv_bias", "no_gated_norm", "unscaled_weights", "rotary"]
+PATCHED = {"dropped_assignment", "bf16_state", "no_gated_norm", "rotary"}   # by monkeypatch
 
 
 @pytest.mark.parametrize("broken", BROKEN)
@@ -102,7 +142,7 @@ def test_the_comparison_sees_what_it_must(broken, monkeypatch):
     moves the logits past the comparison's tolerance with room."""
     m = tiny_model()
     seq = a_sequence(m, 70, 5)
-    want = reference.logits_after(7, m, [seq], [1], 128, 64)[0]
+    want = want_of(m, seq)
     params, cfg, valid = served_weights(7, m), f32_config(m), 50
     if broken == "dropped_assignment":
         monkeypatch.setattr(moe, "held_weights", _capacity_bounded)
@@ -131,7 +171,10 @@ def test_the_comparison_sees_what_it_must(broken, monkeypatch):
         cfg = cfg.scaled(routed_scaling_factor=1.0)
     elif broken == "rotary":
         monkeypatch.setattr(ModelConfig, "rotary_dim", property(lambda self: self.head_dim))
-    got, _ = prefill_then_decode(params, cfg, seq, 50, 64, valid=valid, step=make_step())
+    # a program of its own where the program itself was patched; another leaf
+    # or length runs the sound program's executables
+    step = make_step() if broken in PATCHED else STEP
+    got, _ = prefill_then_decode(params, cfg, seq, 50, 64, valid=valid, step=step)
     assert np.max(np.abs(got[:-1] - want)) > SEEN * np.max(np.abs(want))
 
 
@@ -313,7 +356,9 @@ def test_config_counts_the_published_model_and_the_cut():
 def test_int8_tree_quantizes_every_matmul_and_nothing_small():
     cfg = get_config("nemotron-h-tiny")
     key = jax.random.PRNGKey(3)
-    q = init_params_quantized(key, cfg)
+    # one program a tree: drawn eagerly, every leaf is a compile of its own
+    q = jax.jit(lambda k: init_params_quantized(k, cfg))(key)
+    plain = jax.jit(lambda k: init_params(k, cfg))(key)
     quantized = {kind: {k for k, v in sub.items() if is_quantized(v)} for kind, sub in q["layers"].items()}
     assert quantized == {"mamba": {"in_proj", "out_proj"}, "attn": {"wq", "wk", "wv", "wo"},
                          "experts": {"w_in", "w_out", "w1", "w2", "s1", "s2"}}
@@ -321,7 +366,7 @@ def test_int8_tree_quantizes_every_matmul_and_nothing_small():
     assert ex["router"].dtype == jnp.float32 and ex["router_bias"].dtype == jnp.float32
     assert ex["w1"]["q"].shape == (3, 8, 64, 96) and ex["w1"]["s"].shape == (3, 8, 96)
     assert mb["A_log"].dtype == jnp.float32 and mb["conv"].dtype == jnp.bfloat16
-    after = quantize_params(init_params(key, cfg))
+    after = jax.jit(quantize_params)(plain)
     same = jax.tree_util.tree_map(
         lambda a, b: a.shape == b.shape and a.dtype == b.dtype
         and float(jnp.max(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32)))) <= 1.0,
@@ -329,7 +374,7 @@ def test_int8_tree_quantizes_every_matmul_and_nothing_small():
     assert all(jax.tree_util.tree_leaves(same))
     toks = jnp.asarray(np.random.default_rng(0).integers(3, 500, (1, 40)), jnp.int32)
     fwd = jax.jit(lambda p: forward(p, cfg, toks, jnp.arange(40)[None])[0])
-    a, b = fwd(q), fwd(init_params(key, cfg))
+    a, b = fwd(q), fwd(plain)
     assert float(jnp.mean(jnp.abs(a - b))) < 0.1 * float(jnp.max(jnp.abs(b)))
 
 

@@ -180,6 +180,35 @@ def test_ssm_decode_lowers(stack, groups):
     )
 
 
+@pytest.mark.parametrize("shape,tokens,top_k", [
+    ((22, 32, 2048, 1792), 1024, 4), ((12, 64, 2560, 768), 4096, 6), ((1, 8, 4096, 14336), 512, 2)],
+    ids=["lfm2", "smallthinker", "mixtral"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_grouped_matmul_lowers(shape, tokens, top_k, quantized):
+    """The expert stacks of the two cells that prefill long pieces and
+    Mixtral's (whose width takes more than one output block), as the grouped
+    dispatch calls the kernel: the gated pair with its activation, then the
+    down projection, at the blocks the rule gives; the layer and each tile's
+    expert scalar-prefetch values; int8 blocks converted into a scratch."""
+    from kserve_vllm_mini_tpu.ops.grouped_matmul import grouped_matmul, grouped_matmul_block
+
+    n_l, n_e, d, f = shape
+    rows = tokens * top_k
+
+    def leaf(n_in, n_out):
+        q = _sds((n_l, n_e, n_in, n_out), I8 if quantized else BF)
+        return {"q": q, "s": _sds((n_l, n_e, n_out), F32)} if quantized else q
+
+    for (n_in, n_out), n_w, act in (((d, f), 2, "silu"), ((f, d), 1, None)):
+        block = grouped_matmul_block(n_in, n_out, I8 if quantized else BF, BF, n_w)
+        total = -(-(rows + n_e * (block[0] - 1)) // block[0]) * block[0]
+        _lowers_for_tpu(
+            lambda x, tiles, layer, *w, act=act, block=block: grouped_matmul(
+                x, tiles, layer, *w, act=act, block=block),
+            _sds((total, n_in), BF), _sds((1 + total // block[0],), I32), _sds((), I32),
+            *[leaf(n_in, n_out)] * n_w)
+
+
 # -- which decode steps take the dense kernel ----------------------------------
 
 

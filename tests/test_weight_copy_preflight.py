@@ -26,6 +26,12 @@ rule's state is updated inside the cache's layer stack by
 the period beside the one ``kvmini_dense_decode``; the two pattern stacks'
 the same for their Mamba-2 state and ``kvmini_ssm_decode`` (ops/ssm_decode.py).
 
+The two stacks of routed GLU experts compile a prefill piece of 512 tokens,
+which takes the grouped dispatch (models/moe.py): ``weight_copy_bytes == 0``
+there says that the ``gmm`` kernel reads the int8 expert stacks where they
+lie, and the worker's ``grouped`` entry that the walk's expert layers lower
+ONE body and that the benchmark's readers find every call by its cut name.
+
 One file, one subprocess: libtpu is loaded in the child alone (``--dist
 loadfile`` keeps this file in one worker), and the child is held to a
 timeout, so nothing here can hang the suite. Skips, with the reason, where
@@ -141,3 +147,25 @@ def test_the_two_mixer_stack_holds_a_kernel_a_block_and_its_prefill_traffic_is_r
     assert decode["mosaic_calls"] >= 2 and prefill["mosaic_calls"] >= 2
     assert isinstance(prefill["state_copy_bytes"], int) and prefill["state_copy_bytes"] >= 0
     print("falcon-h1-34b-int8 prefill state_copy_bytes:", prefill["state_copy_bytes"])
+
+
+# expert layers of each stack of routed GLU experts as the worker cuts it
+GROUPED = {"lfm2-8b-a1b-int8": 2, "smallthinker-21b-pp4-int8": 2}
+
+
+@pytest.mark.parametrize("model", sorted(GROUPED))
+def test_a_long_piece_is_grouped_one_body_a_walk_and_the_readers_find_its_calls(compiled, model):
+    """``prefill[512]`` at the published widths: two ``gmm`` calls an expert
+    layer (the gated pair with its activation, the down projection) over the
+    int8 stacks in place (``weight_copy_bytes`` 0 is the bar above), ONE
+    ``_run_grouped`` function in the lowered module however many layers call
+    it (each call site lowered on its own is what made PR 41's set-up 14 s
+    longer), and every call an expert stack's operation to
+    ``reads_expert_stack`` of the stack's roofline module under the name a
+    trace shows: result, then operands, cut to 96 characters. A call they
+    missed would leave ``*_moe_prefill_flop_share`` dividing by too little
+    time."""
+    stats = compiled[f"{model}:prefill"]
+    layers = GROUPED[model]
+    assert stats["grouped"] == {"bodies": 1, "calls": 2 * layers, "found": 2 * layers}
+    assert stats["mosaic_calls"] >= 2 * layers + 1        # and a flash prefill call at least

@@ -150,8 +150,8 @@ def test_the_comparison_sees_what_it_must(broken, monkeypatch):
         cfg = BY_CONFIG[broken](cfg)
     elif broken == "router_fed_the_post_attention_stream":
         block = moe.moe_glu_block
-        monkeypatch.setattr(moe, "moe_glu_block", lambda p, c, h, real=None, route_from=None: block(
-            p, c, h, real, route_from=h))
+        monkeypatch.setattr(moe, "moe_glu_block", lambda p, c, h, real=None, route_from=None, **kw: block(
+            p, c, h, real, route_from=h, **kw))
     elif broken == "silu_for_relu":
         monkeypatch.setattr(jax.nn, "relu", jax.nn.silu)
     elif broken == "ring_written_before_it_is_read":
@@ -187,8 +187,9 @@ def test_the_cache_free_forward_is_the_fresh_prefill_and_agrees_too():
     m = tiny_model()
     seq = a_sequence(m, 70, 5)
     toks = jnp.asarray([seq + [0] * 58], jnp.int32)
-    lg, none = forward(served_weights(7, m), f32_config(m), toks, jnp.arange(128)[None],
-                       valid_len=jnp.asarray([70]))
+    lg, none = jax.jit(forward, static_argnames="cfg")(      # eager, every operation is a dispatch
+        served_weights(7, m), f32_config(m), toks, jnp.arange(128)[None],
+        valid_len=jnp.asarray([70]))
     assert none is None
     assert off_by(np.asarray(lg[0, :69]), wanted(7, m, [seq])[0]) < TOLERANCE
 
@@ -241,20 +242,24 @@ def test_the_softmax_router_is_the_softmax_over_all_its_top_renormalised():
 
 @pytest.mark.parametrize("n,blocks", [(200, 1), (256, 1), (512, 2), (1024, 4), (1025, 2),
                                       (4096, 4), (8192, 8)])
-def test_only_pieces_longer_than_any_accepted_cell_sends_run_the_long_token_block(n, blocks):
-    """``lfm2-8b-a1b.chat`` and ``nemotron-3-super-ep4.decode-sat`` send pieces
-    of up to 1024 tokens: their programs unroll the blocks of 256 they always
-    did (so their executables, and ``setup_s``, are the parent's)."""
+def test_only_pieces_longer_than_any_accepted_cell_sends_run_the_long_token_block(
+        n, blocks, monkeypatch):
+    """The dense dispatch (what a piece runs without the kernels: the CPU, a
+    mesh, ``w8a8``) unrolls blocks of 256 up to 1024 tokens, as it always
+    did, and blocks of 1024 past that."""
     calls = []
 
     def body(xb):
         calls.append(xb.shape[0])
         return jnp.zeros((3, xb.shape[0], 8), xb.dtype)
 
-    out = jax.eval_shape(lambda x, t: moe._run_held(x, t, body),
-                         jax.ShapeDtypeStruct((n, 8), jnp.float32),
-                         jax.ShapeDtypeStruct((n, 3), jnp.float32))
-    assert out.shape == (n, 8) and len(calls) == blocks
+    monkeypatch.setattr(moe, "experts_of", lambda p, b, qm: body)
+    stacks = {k: jax.ShapeDtypeStruct((3, 8, 8), jnp.float32) for k in ("w_gate", "w_up", "w_down")}
+    out, landed = jax.eval_shape(
+        lambda x, w, c, p: moe._run_held(x, w, c, 0, p, moe.GLU_SILU, "dequant"),
+        jax.ShapeDtypeStruct((n, 8), jnp.float32), jax.ShapeDtypeStruct((n, 2), jnp.float32),
+        jax.ShapeDtypeStruct((n, 2), jnp.int32), stacks)
+    assert out.shape == (n, 8) and landed.shape == (n, 3) and len(calls) == blocks
     assert set(calls) <= {n, moe.TOKEN_BLOCK if n <= moe.LONG_PIECE else moe.LONG_TOKEN_BLOCK}
 
 
@@ -294,15 +299,17 @@ def test_both_leaves_go_through_the_decode_kernel_and_agree_with_the_eager_reads
     assert window_decode_kernel_blocks(cfg, "off", cache) is None
     assert window_decode_kernel_blocks(TINY, "interpret", init_kv_cache(TINY, 2, max_seq=64)) is None
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 40), 3, 500)
-    _, cache = forward(params, cfg, toks[:, :32], jnp.broadcast_to(jnp.arange(32), (2, 32)),
-                       cache, jnp.zeros((2,), jnp.int32), fresh_prefill=True, kernels="off")
+    # jitted, one program a mode: an eager forward dispatches every operation on its own
+    run = jax.jit(forward, static_argnames=("cfg", "fresh_prefill", "kernels"))
+    _, cache = run(params, cfg, toks[:, :32], jnp.broadcast_to(jnp.arange(32), (2, 32)),
+                   cache, jnp.zeros((2,), jnp.int32), fresh_prefill=True, kernels="off")
     caches = {"off": cache, "interpret": cache}
     live = jnp.asarray([1, 0])
     for q in range(32, 36):
         lens = jnp.asarray([q, 7], jnp.int32)
         out = {}
         for kernels in caches:
-            out[kernels], caches[kernels] = forward(
+            out[kernels], caches[kernels] = run(
                 params, cfg, toks[:, q:q + 1], lens[:, None], caches[kernels], lens,
                 kernels=kernels, valid_len=live)
         assert float(jnp.abs(out["off"][0] - out["interpret"][0]).max()) < 2e-5

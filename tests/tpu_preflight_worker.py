@@ -7,8 +7,9 @@ the caller can hold the whole thing to a timeout.
 
 Each case is ``<model>:<step>``: ``decode`` is ``steps.build_decode(cfg,
 forward, "auto", 1)``, ``prefill`` is ``steps.build_prefill(cfg, forward,
-"auto", "slot", True)`` at bucket 128, over abstract arguments (no weights are
-made). Models: the benchmark's configurations by name (``BENCHMARK.json``), at
+"auto", "slot", True)`` at bucket 128 (512 for the two stacks of routed GLU
+experts: a piece that takes the grouped dispatch, models/moe.py), over
+abstract arguments (no weights are made). Models: the benchmark's configurations by name (``BENCHMARK.json``), at
 their published widths and two layers (the hybrid: one period of four; the
 pattern stack: one block of each kind, ``M*E``, with 8 of its experts held;
 the two-mixer stack: two blocks, ``PP``; the short-convolution stack: a
@@ -18,8 +19,10 @@ layer and a window layer, all 64 experts under each).
 
 Prints one JSON line: ``{"skip": reason}`` where libtpu or the topology cannot
 be had, else ``{"cases": {case: CompileStats.to_dict()}}`` (``mosaic_calls``,
-``weight_copy_bytes``, ``state_copy_bytes`` among its keys). ``--dump DIR``
-also writes each case's optimized HLO there.
+``weight_copy_bytes``, ``state_copy_bytes`` among its keys; a prefill case
+adds ``grouped``: what the grouped dispatch left in the program, see
+``grouped_dispatch``). ``--dump DIR`` also writes each case's optimized HLO
+there.
 """
 
 import argparse
@@ -36,16 +39,24 @@ os.environ.update(
 )
 
 SLOTS, CONTEXT, BUCKET = 16, 2048, 128
+LONG_BUCKET = 512   # longer than the kernel's row tile: the grouped dispatch's
 
 
-def model_config(name: str):
-    """The configuration as a cell of the benchmark serves it
-    (``bench.config.load_cell``), cut to two layers or one period."""
+def bench_model(name: str) -> dict:
+    """The configuration's model as a cell of the benchmark serves it
+    (``bench.config.load_cell``)."""
     from bench import config as bench_config
 
     cell = next(w["name"] for w in bench_config.benchmark()["workloads"]
                 if w["config"] == name)
-    m = dict(bench_config.load_cell(cell)["config"]["model"])
+    return dict(bench_config.load_cell(cell)["config"]["model"])
+
+
+def model_config(name: str):
+    """``bench_model`` cut to two layers or one period."""
+    from bench import config as bench_config
+
+    m = bench_model(name)
     m["num_hidden_layers"] = 2
     kinds = m.get("model_extra", {}).get("layer_types")
     if kinds and "conv" in kinds:
@@ -69,7 +80,8 @@ def model_config(name: str):
     return bench_config.load_module("systems", "engine").model_config(m)
 
 
-def compile_case(cfg, step: str, device):
+def build_case(cfg, step: str, device):
+    """(the jitted step, its abstract arguments, its keywords) of a case."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -79,7 +91,6 @@ def compile_case(cfg, step: str, device):
         init_kv_cache,
         init_params_quantized,
     )
-    from kserve_vllm_mini_tpu.profiling.compile_stats import capture_compile_stats
     from kserve_vllm_mini_tpu.runtime import steps
 
     sh = SingleDeviceSharding(device)
@@ -105,9 +116,43 @@ def compile_case(cfg, step: str, device):
         kw = {"live": arg(row, jnp.bool_)} if cfg.is_hybrid else {}
     else:
         fn = steps.build_prefill(cfg, forward, "auto", "slot", True)
-        args = (params, cache, arg((1, BUCKET), i32), arg((), i32), arg((), i32))
+        bucket = LONG_BUCKET if cfg.is_conv_stack or cfg.is_window_stack else BUCKET
+        args = (params, cache, arg((1, bucket), i32), arg((), i32), arg((), i32))
         kw = {}
+    return fn, args, kw
+
+
+def compile_case(cfg, step: str, device):
+    from kserve_vllm_mini_tpu.profiling.compile_stats import capture_compile_stats
+
+    fn, args, kw = build_case(cfg, step, device)
     return capture_compile_stats(fn, *args, label=step, **kw)
+
+
+def grouped_dispatch(model: dict, lowered: str, optimized: str) -> dict:
+    """What the grouped dispatch left in a prefill program: ``bodies``, the
+    functions of ``_run_grouped`` in the lowered module (one a shape, however
+    many layers call it); ``calls``, the ``gmm`` custom calls of the
+    optimized program (two an expert layer); ``found``, those of them the
+    benchmark's reader of this stack would count as an expert stack's
+    operation, by the name a trace would show (result and operands with their
+    shapes, cut to 96 characters)."""
+    import re
+
+    from bench import conv_moe_roofline, trace_reduce, window_moe_roofline
+
+    roofline = conv_moe_roofline if conv_moe_roofline.sizes(model) else window_moe_roofline
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", optimized, re.M))
+    calls = re.findall(r"^\s*(?:ROOT )?((%gmm[\w.\-]*) = (\S+) custom-call\(([^)]*)\))",
+                       optimized, re.M)
+    found = 0
+    for _, name, result, operands in calls:
+        shown = ", ".join(f"{types.get(o, '?')} {o}" for o in
+                          re.findall(r"%[\w.\-]+", operands))
+        found += roofline.reads_expert_stack(
+            trace_reduce.short_name(f"{name} = {result} custom-call({shown})"), model)
+    return {"bodies": lowered.count("func.func private @_run_grouped"),
+            "calls": len(calls), "found": found}
 
 
 def main() -> int:
@@ -130,12 +175,18 @@ def main() -> int:
         print(json.dumps({"skip": f"no chipless v5e topology: {type(e).__name__}: {e}"[:400]}))
         return 0
     jax.default_backend = lambda: "tpu"   # what the kernel dispatch asks
+    from kserve_vllm_mini_tpu.profiling.compile_stats import capture_compile_stats
 
     out = {}
     for case in ns.cases:
         model, step = case.split(":")
-        exe, stats = compile_case(model_config(model), step, topo.devices[0])
+        cfg = model_config(model)
+        fn, args, kw = build_case(cfg, step, topo.devices[0])
+        exe, stats = capture_compile_stats(fn, *args, label=step, **kw)
         out[case] = stats.to_dict()
+        if step == "prefill" and (cfg.is_conv_stack or cfg.is_window_stack):
+            out[case]["grouped"] = grouped_dispatch(
+                bench_model(model), fn.lower(*args, **kw).as_text(), exe.as_text())
         if ns.dump:
             Path(ns.dump).mkdir(parents=True, exist_ok=True)
             (Path(ns.dump) / f"{case.replace(':', '.')}.hlo.txt").write_text(exe.as_text())
