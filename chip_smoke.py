@@ -643,7 +643,8 @@ def leg_engines(dry: bool) -> dict:
               kv_int8=False)),
         # gated short convolutions (a two-position tail, no state) and rotary
         # attention with a per-head QK-norm (32 / 8 heads x 64: flash prefill;
-        # no "decode[", 64-wide heads keep the eager decode read), a dense MLP
+        # 64-wide heads keep the eager decode read, so "decode[" is not asked
+        # for here: what it holds since PR 46 is the experts' sweep), a dense MLP
         # then SwiGLU experts, all 32 held; continuation pieces carry the tail
         ("conv_moe", {"model": sz["conv_model"], "quantization": "int8",
                       "prefill_chunk": sz["chunk"]},

@@ -934,7 +934,7 @@ def run_cached_layers(
                                  # untouched (models/linear_attention.py);
                                  # None = all T
     with_stats: bool = False,    # a pattern stack with expert blocks: also
-                                 # return their routing counts, int32 [3]
+                                 # return their routing counts, int32 [4]
                                  # summed over the blocks (models/moe.py
                                  # moe_latent_block), over the real positions
 ) -> tuple[jnp.ndarray, KVCache]:
@@ -1260,7 +1260,7 @@ def run_cached_layers(
             ring_src = last - jnp.mod(last - jnp.arange(R)[None, :], R)   # [B, R]
             ring_src = jnp.clip(ring_src, 0, T - 1)[:, None, :, None]     # [B, 1, R, 1]
         seen = {True: 0, False: 0}
-        stats = jnp.zeros((3,), jnp.int32)
+        stats = jnp.zeros((4,), jnp.int32)
 
         def keep(leaf, i, new, at):
             """``new`` [B, KVH, T', D] into layer ``i`` of ``leaf`` at positions
@@ -1342,7 +1342,7 @@ def run_cached_layers(
         real = jnp.arange(T)[None, :] < valid[:, None]
         cache = dict(kv_cache)
         seen = dict.fromkeys(("conv", "attn"), 0)
-        stats = jnp.zeros((3,), jnp.int32)
+        stats = jnp.zeros((4,), jnp.int32)
 
         def at(kind, i):
             return jax.tree_util.tree_map(lambda a: a[i], layers[kind])
@@ -1393,7 +1393,7 @@ def run_cached_layers(
         real = jnp.arange(T)[None, :] < valid[:, None]
         cache = dict(kv_cache)
         seen = dict.fromkeys(PATTERN_KINDS, 0)
-        stats = jnp.zeros((3,), jnp.int32)
+        stats = jnp.zeros((4,), jnp.int32)
 
         # a decode step on a TPU hands the mixer the whole state stack: the
         # kernel updates the block's layer inside it (no slice out, no write
@@ -1441,7 +1441,9 @@ def run_cached_layers(
                          + scaled(attended, cfg.attention_out_multiplier))
                 out = swiglu_mlp(p, cfg, rms_norm(x, p["mlp_norm"], cfg.rms_eps))
             else:
-                out, counts = moe_latent_block(p, cfg, h, real, kernels)
+                # the stack and the index, not the slice (the window walk above)
+                out, counts = moe_latent_block(
+                    layers[PATTERN_KINDS[ch]], cfg, h, real, kernels, layer=i)
                 stats = stats + counts
             x = x + out
         return (x, cache, stats) if with_stats else (x, cache)

@@ -1,7 +1,7 @@
 """Sparse mixture-of-experts layers, drop-free, for a device that holds some
 or all of the experts.
 
-Four layers share one dispatch:
+Four layers share one rule of dispatch:
 
 - ``moe_mlp`` (Mixtral): a softmax router, the top ``n_experts_per_tok``
   renormalised, SwiGLU experts at the model's width, every expert held. The
@@ -28,28 +28,54 @@ Four layers share one dispatch:
   norm) while the experts read the post-attention norm; the chosen
   probabilities renormalised; the same GLU body with ReLU on the gate.
 
-**Two dispatches, chosen by what the piece is** (``grouped_blocks``), and
-neither can drop a token at any routing, because nothing is bounded by a
+**Three dispatches, chosen by what the piece is** (``grouped_blocks``), and
+none can drop a token at any routing, because nothing is bounded by a
 capacity.
 
 *Dense: every held expert runs every token of the block, and the routing
 weight (zero where the token did not choose the expert) is applied at the
 combine.* No sort, scatter, buffer or gather, so no latency-bound operation
 between the router and the matmuls, and the int8 expert stacks are read where
-they lie (one batched matmul over the expert axis). The price is arithmetic:
-held experts / chosen-and-held experts times what the chosen tokens need. A
-decode step is bound by the experts' weight stream, which a sweep over 32
-slots x 22 choices touches three quarters of anyway, so there the arithmetic
-is free; a prefill piece of 128 positions touches every expert and its
-arithmetic still hides under the weight stream. This is what runs a decode
-step, a piece of up to one row tile (128 tokens), ``quant_mode="w8a8"`` (the int8
+they lie (one batched matmul over the expert axis, at 92% of the HBM peak on
+a v5e). The price is the stream and the arithmetic of every held expert,
+chosen or not. This is what runs a piece of 33 tokens up to one row tile
+(128: a prefill piece that short touches every expert and its arithmetic
+still hides under the weight stream), ``quant_mode="w8a8"`` (the int8
 contraction has its own arithmetic, and it is the control of the benchmark's
 ``correct``), any piece without the Pallas kernels (the CPU; a mesh, so
-``moe_mlp`` under an ``ep`` axis) and shapes the kernel has no block for.
-There, pieces longer than ``TOKEN_BLOCK`` run block by block (unrolled) so
-that the ``[experts, tokens, width]`` intermediates stay bounded; pieces
-longer than ``LONG_PIECE`` in blocks of ``LONG_TOKEN_BLOCK``: since the
-grouped dispatch only those fallbacks reach these blocks.
+``moe_mlp`` under an ``ep`` axis), packed int4 and AWQ leaves, shapes the
+kernels have no block for, and a decode step whose caller has only a layer's
+slice of the stacks (``moe_mlp`` under Mixtral's layer scan: a slice handed to
+an opaque call is a copy of the slice). There, pieces longer than
+``TOKEN_BLOCK`` run block by block (unrolled) so that the ``[experts, tokens,
+width]`` intermediates stay bounded; pieces longer than ``LONG_PIECE`` in
+blocks of ``LONG_TOKEN_BLOCK``: since the grouped dispatch only those
+fallbacks reach these blocks.
+
+*Swept: a decode step visits the held experts that a LIVE row chose, one
+after the other, and every other expert is never fetched.* A decode step is
+bound by the experts' weight stream, and its few rows choose few experts: the
+benchmark's cells read 49% of 32 held at ~8 live rows (LFM2-MoE), 15% of 64
+at ~3 (SmallThinker), 51% of 128 with 32 of 32 live (Nemotron-H) (ledger,
+PR 45, ``*_expert_touch_share``), so the dense step streamed twice to six
+times what it needed. A piece of up to 32 rows (a decode step; a prompt's
+shortest piece takes it too, where it costs what the dense dispatch does) on
+one TPU, over int8 leaves in ``dequant`` mode or plain stacks, handed the
+LAYER STACKS with their index: ``ops/grouped_matmul.py expert_sweep``, ONE
+Pallas call a layer whose grid is the experts held. Each step multiplies one
+touched expert's blocks by ALL the rows (the gated pair or the first matmul,
+the activation in float32, the second matmul: the ``[rows, mid]``
+intermediate never leaves VMEM) and adds the product times that expert's
+column of ``held_weights`` (zero where a row did not choose it) to a float32
+result that stays in VMEM until the last step. No sort, gather or combine:
+the dense dispatch's arithmetic over fewer experts, W8A16 as
+``ops/quant.linear``. The touched experts come first in the grid and a step
+past their count stays on the last one's blocks, so the call's shape is the
+same at every routing (all rows on one expert; no row live) and nothing is
+fetched for an expert no live row chose. A parked row (``real``) chooses
+nothing; its result, which nothing reads, is zero. ``visited`` (the fourth
+of ``routing_stats``) says how many held experts a step's dispatch
+multiplied: the touched count here, every held expert in the dense step.
 
 *Grouped: each expert runs the rows that chose it.* A piece longer than one
 row tile of the kernel (128 tokens: a group is padded to whole tiles, so up
@@ -93,6 +119,8 @@ from kserve_vllm_mini_tpu.models.config import ModelConfig
 from kserve_vllm_mini_tpu.ops.flash_attention import pallas_interpret
 from kserve_vllm_mini_tpu.ops.grouped_matmul import (
     ACTIVATIONS,
+    expert_sweep,
+    expert_sweep_fits,
     group_rows,
     grouped_matmul,
     grouped_matmul_block,
@@ -163,18 +191,32 @@ def experts_of(p: dict[str, Any], body, qm: str):
     return experts
 
 
-def grouped_blocks(x: jnp.ndarray, p: dict[str, Any], body, qm: str,
-                   kernels: str) -> Optional[tuple[tuple[int, int], tuple[int, int]]]:
-    """The grouped dispatch's blocks for the piece ``x`` [S, in] over the
-    stacks ``p`` (each [..., E, in, out]), or None where the piece takes the
-    dense dispatch. Read off the input alone: more tokens than one row tile
-    of the kernel (up to there a group's padding costs what every expert on
-    every token does, and that arithmetic hides under the weight stream),
-    Pallas kernels on (a TPU, one device: under a mesh the engine
-    passes ``"off"``), stacks that are int8 leaves in ``dequant`` mode or
-    plain arrays of the activations' dtype (``w8a8`` contracts in int8 and
+SWEEP = "sweep"     # ``grouped_blocks``: a short piece, every touched expert times all its rows
+
+
+def grouped_blocks(x: jnp.ndarray, p: dict[str, Any], body, qm: str, kernels: str,
+                   stacked: bool = False):
+    """How the piece ``x`` [S, in] runs over the stacks ``p`` (each
+    [..., E, in, out]): None, the dense dispatch; ``SWEEP``, the touched
+    experts one after the other (``ops/grouped_matmul.py expert_sweep``);
+    or the grouped dispatch's blocks. Read off the input alone.
+
+    Either kernel needs Pallas on (a TPU, one device: under a mesh the
+    engine passes ``"off"``), stacks that are int8 leaves in ``dequant`` mode
+    or plain arrays of the activations' dtype (``w8a8`` contracts in int8 and
     packed or AWQ leaves have their own arithmetic: the dense dispatch's),
-    and shapes the kernel has a block for."""
+    and shapes the kernel has a block for. Then by the piece's rows:
+
+    - up to ``SWEEP_ROWS`` of the kernel's (32: a decode step, a prompt's
+      shortest piece) the sweep, where one expert's blocks fit its VMEM
+      budget and the caller passed the LAYER STACKS with their index
+      (``stacked``): a layer's slice handed to an opaque call is a copy of
+      the whole slice, 350-705 MB of expert stacks here, so a caller that
+      has only a slice keeps the dense dispatch;
+    - more, up to one row tile of the grouped kernel: dense (a group's
+      padding costs what every expert on every token does, and that
+      arithmetic hides under the weight stream);
+    - longer: grouped."""
     first, _, last = body
     names = (*first, last)
     if pallas_interpret(kernels) is None:
@@ -184,11 +226,14 @@ def grouped_blocks(x: jnp.ndarray, p: dict[str, Any], body, qm: str,
         set(p[n]) == {"q", "s"} and p[n]["q"].dtype == jnp.int8 for n in names)
     if any(quantized) and not plain_int8:  # kvmini: static-shape
         return None
-    blocks = []
-    for name, n_w in ((first[0], len(first)), (last, 1)):
-        w = p[name]["q"] if quantized[0] else p[name]
-        blocks.append(grouped_matmul_block(w.shape[-2], w.shape[-1], w.dtype, x.dtype, n_w))
-    if None in blocks or blocks[0][0] != blocks[1][0] or x.shape[0] <= blocks[0][0]:
+    w_first, w_last = (p[n]["q"] if quantized[0] else p[n] for n in (first[0], last))
+    if stacked and expert_sweep_fits(*w_first.shape[-2:], w_last.shape[-1], w_first.dtype,
+                                     x.dtype, len(first), x.shape[0]):
+        return SWEEP
+    blocks = [grouped_matmul_block(w.shape[-2], w.shape[-1], w.dtype, x.dtype, n_w)
+              for w, n_w in ((w_first, len(first)), (w_last, 1))]
+    if (None in blocks or blocks[0][0] != blocks[1][0]  # kvmini: static-shape
+            or x.shape[0] <= blocks[0][0]):
         return None
     return blocks[0], blocks[1]
 
@@ -209,7 +254,7 @@ def grouped_prefill(cfg: ModelConfig, layers: dict[str, Any], tokens: int,
     else:
         return False
     x = jax.ShapeDtypeStruct((tokens, width), cfg.jnp_dtype)
-    return grouped_blocks(x, p, body, cfg.quant_mode, kernels) is not None
+    return grouped_blocks(x, p, body, cfg.quant_mode, kernels, True) not in (None, SWEEP)
 
 
 @functools.partial(jax.jit, static_argnames=("offset", "body", "blocks", "interpret"))
@@ -248,25 +293,38 @@ def _run_grouped(x, weights, chosen, stacks, layer, *, offset, body, blocks, int
 
 def _run_held(x: jnp.ndarray, weights: jnp.ndarray, chosen: jnp.ndarray, offset: int,
               p: dict[str, Any], body, qm: str, kernels: str = "off",
-              layer: Optional[int] = None) -> tuple[jnp.ndarray, jnp.ndarray]:
+              layer: Optional[int] = None, real: Optional[jnp.ndarray] = None,
+              ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The held experts' part of ``sum_k weights[s, k] * expert_chosen[s, k](x[s])``
-    -> ([S, out] float32, ``landed`` [S, held]: whether a token chose each
-    held expert). ``p`` holds the body's stacks [held, ., .], or with
+    -> ([S, out] float32; ``landed`` [S, held], whether a token chose each
+    held expert; ``visited``, int32, the held experts whose weights the
+    dispatch multiplied). ``p`` holds the body's stacks [held, ., .], or with
     ``layer`` the layer stacks [L, held, ., .] of which this is layer
-    ``layer``. A long piece takes the grouped dispatch (``grouped_blocks``);
-    any other runs every held expert on every token, in blocks of
-    ``TOKEN_BLOCK`` (``LONG_TOKEN_BLOCK`` past ``LONG_PIECE`` tokens)."""
+    ``layer``. ``real`` [S] marks the rows that are read: only the sweep of a
+    short piece looks at it, to leave out the experts that none but a parked
+    row chose (such a row's result is then zero). ``grouped_blocks`` says
+    which dispatch; the dense one runs every held expert on every token, in
+    blocks of ``TOKEN_BLOCK`` (``LONG_TOKEN_BLOCK`` past ``LONG_PIECE``
+    tokens)."""
     names = (*body[0], body[2])
     p = {n: p[n] for n in names}
     held = jax.tree_util.tree_leaves(p[names[-1]])[0].shape[-3]
     take, landed = held_weights(weights, chosen, offset, held)
-    blocks = grouped_blocks(x, p, body, qm, kernels)
-    if blocks is not None:
+    how = grouped_blocks(x, p, body, qm, kernels, layer is not None)
+    if how == SWEEP:  # kvmini: static-shape
+        chose = landed
+        if real is not None:    # a parked row chooses nothing, and reads zero
+            chose, take = landed & real[:, None], jnp.where(real[:, None], take, 0.0)
+        touched = jnp.any(chose, axis=0)
+        out = expert_sweep(x, take, touched, jnp.int32(layer), *(p[n] for n in names),
+                           act=body[1], interpret=pallas_interpret(kernels))
+        return out, landed, jnp.sum(touched, dtype=jnp.int32)
+    if how is not None:
         if layer is None:
             p, layer = jax.tree_util.tree_map(lambda a: a[None], p), 0
         out = _run_grouped(x, weights, chosen, p, jnp.int32(layer), offset=offset, body=body,
-                           blocks=blocks, interpret=pallas_interpret(kernels))
-        return out, landed
+                           blocks=how, interpret=pallas_interpret(kernels))
+        return out, landed, jnp.sum(jnp.any(landed, axis=0), dtype=jnp.int32)
     if layer is not None:
         p = jax.tree_util.tree_map(lambda a: a[layer], p)
     experts = experts_of(p, body, qm)
@@ -278,7 +336,7 @@ def _run_held(x: jnp.ndarray, weights: jnp.ndarray, chosen: jnp.ndarray, offset:
     n = x.shape[0]
     size = TOKEN_BLOCK if n <= LONG_PIECE else LONG_TOKEN_BLOCK
     if n <= size:
-        return block(x, take), landed
+        return block(x, take), landed, jnp.int32(held)
     # Unrolled, not a ``lax.map``: a loop's operands must be buffers of their
     # own, so XLA sliced every expert stack out of its layer stack and copied
     # it (transposed) before the loop: 117 MB a stack, 6.9 GB of temporaries
@@ -286,8 +344,9 @@ def _run_held(x: jnp.ndarray, weights: jnp.ndarray, chosen: jnp.ndarray, offset:
     pad = -n % size
     xs = jnp.pad(x, ((0, pad), (0, 0)))
     ts = jnp.pad(take, ((0, pad), (0, 0)))
-    return jnp.concatenate([block(xs[i:i + size], ts[i:i + size])
-                            for i in range(0, n + pad, size)])[:n], landed
+    out = jnp.concatenate([block(xs[i:i + size], ts[i:i + size])
+                           for i in range(0, n + pad, size)])[:n]
+    return out, landed, jnp.int32(held)
 
 
 def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
@@ -295,13 +354,15 @@ def moe_mlp(p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
     """Mixtral's routed SwiGLU MLP. ``h`` is the normed hidden [B, T, D];
     returns the MLP delta [B, T, D] (the caller adds the residual).
     ``p`` holds this layer's ``router`` [D, E] and the expert stacks
-    ``w_gate``/``w_up`` [E, D, F], ``w_down`` [E, F, D]."""
+    ``w_gate``/``w_up`` [E, D, F], ``w_down`` [E, F, D]: a slice of the layer
+    scan's, which is a copy, so a decode step keeps the dense dispatch
+    (``grouped_blocks``; no cell of the benchmark runs this layer)."""
     B, T, D = h.shape
     x = h.reshape(B * T, D)
     logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
     gates, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.n_experts_per_tok)
     gates = gates / jnp.maximum(gates.sum(axis=-1, keepdims=True), 1e-9)
-    out, _ = _run_held(x, gates, chosen, 0, p, GLU_SILU, cfg.quant_mode, kernels)
+    out, _, _ = _run_held(x, gates, chosen, 0, p, GLU_SILU, cfg.quant_mode, kernels)
     return out.astype(h.dtype).reshape(B, T, D)
 
 
@@ -341,15 +402,26 @@ def route_softmax(x: jnp.ndarray, router: jnp.ndarray, cfg: ModelConfig,
     return jnp.exp(top - jax.nn.logsumexp(logits, axis=-1, keepdims=True)), chosen
 
 
-def routing_stats(landed: jnp.ndarray, real: Optional[jnp.ndarray]) -> jnp.ndarray:
-    """int32 [3] from ``landed`` [S, held] (whether a token chose a held
+def routing_stats(landed: jnp.ndarray, real: Optional[jnp.ndarray],
+                  visited: jnp.ndarray) -> jnp.ndarray:
+    """int32 [4] from ``landed`` [S, held] (whether a token chose a held
     expert), over the positions ``real`` [B, T] marks (all when None):
     assignments that landed on an expert held here, held experts with at
-    least one token, and the fullest held expert's tokens."""
+    least one token, and the fullest held expert's tokens; then ``visited``,
+    the held experts whose weights the dispatch multiplied (``_run_held``)."""
     if real is not None:
         landed = landed & real.reshape(-1, 1)
     load = jnp.sum(landed, axis=0, dtype=jnp.int32)                        # [held]
-    return jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load)])
+    return jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32), jnp.max(load),
+                      visited])
+
+
+def _of_layer(p: dict[str, Any], layer: Optional[int], *names: str) -> dict[str, Any]:
+    """The leaves ``names`` of ``p``, of its layer ``layer`` where ``p`` is a
+    layer stack: the small ones a block reads beside its expert stacks."""
+    if layer is None:
+        return {n: p[n] for n in names}
+    return {n: jax.tree_util.tree_map(lambda a: a[layer], p[n]) for n in names}
 
 
 def moe_glu_block(
@@ -364,8 +436,8 @@ def moe_glu_block(
     [D, n_routed] float32; the held experts' stacks ``w_gate``/``w_up``
     [held, D, F], ``w_down`` [held, F, D]. With ``layer``, ``p`` is the whole
     LAYER STACK of such leaves and this is its layer ``layer``: a walk passes
-    the stack and the index down, so that the grouped dispatch reads the
-    expert stacks where they lie.
+    the stack and the index down, so that the kernels of a decode step and
+    of a long piece read the expert stacks where they lie.
 
     LFM2-MoE's: the biased sigmoid router (``router_bias`` [n_routed]) on
     ``h`` itself, SiLU on the gate. A ``sliding_window_layout`` stack's
@@ -373,19 +445,17 @@ def moe_glu_block(
     THAT tensor, ReLU on the gate."""
     B, T, D = h.shape
     x = h.reshape(B * T, D)
-
-    def small(name):
-        return p[name] if layer is None else p[name][layer]
-
     if route_from is None:
-        w, chosen = route_sigmoid(x, small("router"), small("router_bias"), cfg, NORM_EPS_GLU)
+        small = _of_layer(p, layer, "router", "router_bias")
+        w, chosen = route_sigmoid(x, small["router"], small["router_bias"], cfg, NORM_EPS_GLU)
         body = GLU_SILU
     else:
-        w, chosen = route_softmax(route_from.reshape(B * T, D), small("router"), cfg)
+        router = _of_layer(p, layer, "router")["router"]
+        w, chosen = route_softmax(route_from.reshape(B * T, D), router, cfg)
         body = GLU_RELU
-    out, landed = _run_held(x, w, chosen, cfg.expert_offset, p, body, cfg.quant_mode,
-                            kernels, layer)
-    return out.astype(h.dtype).reshape(B, T, D), routing_stats(landed, real)
+    out, landed, visited = _run_held(x, w, chosen, cfg.expert_offset, p, body, cfg.quant_mode,
+                                     kernels, layer, None if real is None else real.reshape(-1))
+    return out.astype(h.dtype).reshape(B, T, D), routing_stats(landed, real, visited)
 
 
 def relu2(x: jnp.ndarray) -> jnp.ndarray:
@@ -396,20 +466,25 @@ def moe_latent_block(
     p: dict[str, Any], cfg: ModelConfig, h: jnp.ndarray,
     real: Optional[jnp.ndarray] = None,
     kernels: str = "off",
+    layer: Optional[int] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The ``E`` block's mixer on the normed hidden ``h`` [B, T, D] ->
     (delta [B, T, D], stats). ``p``: ``router`` [D, n_routed] and
     ``router_bias`` [n_routed] float32; ``w_in`` [D, latent], ``w_out``
     [latent, D]; the held experts' stacks ``w1`` [held, latent, F], ``w2``
     [held, F, latent]; the shared expert ``s1`` [D, Fs], ``s2`` [Fs, D].
+    With ``layer``, ``p`` is the LAYER STACK of the ``E`` blocks and this is
+    its block ``layer`` (``moe_glu_block``).
 
     ``stats`` is ``routing_stats`` over the positions ``real`` marks."""
     B, T, D = h.shape
     dt, qm = h.dtype, cfg.quant_mode
     x = h.reshape(B * T, D)
-    w, chosen = route_sigmoid(x, p["router"], p["router_bias"], cfg)
-    u = linear(x, p["w_in"], mode=qm)                                      # [S, latent]
-    routed, landed = _run_held(u, w, chosen, cfg.expert_offset, p, LATENT_RELU2, qm, kernels)
-    routed = linear(routed.astype(dt), p["w_out"], mode=qm)
-    shared = linear(relu2(linear(x, p["s1"], mode=qm)), p["s2"], mode=qm)
-    return (routed + shared).reshape(B, T, D), routing_stats(landed, real)
+    q = _of_layer(p, layer, "router", "router_bias", "w_in", "w_out", "s1", "s2")
+    w, chosen = route_sigmoid(x, q["router"], q["router_bias"], cfg)
+    u = linear(x, q["w_in"], mode=qm)                                      # [S, latent]
+    routed, landed, visited = _run_held(u, w, chosen, cfg.expert_offset, p, LATENT_RELU2, qm,
+                                        kernels, layer, None if real is None else real.reshape(-1))
+    routed = linear(routed.astype(dt), q["w_out"], mode=qm)
+    shared = linear(relu2(linear(x, q["s1"], mode=qm)), q["s2"], mode=qm)
+    return (routed + shared).reshape(B, T, D), routing_stats(landed, real, visited)

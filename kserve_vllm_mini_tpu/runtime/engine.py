@@ -1028,12 +1028,16 @@ class Engine:
             # the expert layers: assignments the live rows made (rows x
             # experts a token), those that landed on an expert held here,
             # held experts with at least one token, and the fullest held
-            # expert's tokens; the last three counted on the device and
-            # read back with the step's tokens
+            # expert's tokens; then the held experts whose weights the
+            # steps' dispatch multiplied (every held one where it is dense,
+            # the touched where the kernel sweeps them: models/moe.py
+            # grouped_blocks); the last four counted on the device and read
+            # back with the step's tokens
             "moe_assignments": 0,
             "moe_local_assignments": 0,
             "moe_expert_touches": 0,
             "moe_expert_load_max": 0,
+            "moe_expert_visits": 0,
             "requests_completed": 0,
             "busy_s": 0.0,        # exported: busy_seconds_total + duty_cycle
             "started_at": _now(),  # kvmini: metrics-ok — raw input; exposed as duty_cycle
@@ -3875,25 +3879,29 @@ class Engine:
     @staticmethod
     def _routed_args(routed: list, steps: int) -> dict[str, int]:
         """Arguments of the ``emit`` span of a sweep whose model has expert
-        layers: the held experts its ``moe_steps`` steps touched, summed over
-        the expert layers, so that a trace holds the count beside the device
-        time of the very steps it belongs to. Nothing for any other model."""
+        layers: the held experts its ``moe_steps`` steps touched, and those
+        their dispatch multiplied (``moe_visited``), summed over the expert
+        layers, so that a trace holds the counts beside the device time of
+        the very steps they belong to. Nothing for any other model."""
         if not routed:
             return {}
-        return {"moe_touched": int(routed[0][:, 1].sum()), "moe_steps": steps}
+        return {"moe_touched": int(routed[0][:, 1].sum()),
+                "moe_visited": int(routed[0][:, 3].sum()), "moe_steps": steps}
 
     def _book_routed(self, routed: list, live: int, steps: int) -> None:
         """A retired sweep's expert-block counts: ``routed`` holds the
-        decode program's [steps, 3] read-back (landed, touched, fullest,
-        over its ``live`` rows), or nothing for a model without such blocks."""
+        decode program's [steps, 4] read-back (landed, touched, fullest,
+        over its ``live`` rows, and visited), or nothing for a model without
+        such blocks."""
         if not routed:
             return
-        landed, touched, fullest = routed[0].sum(axis=0).tolist()
+        landed, touched, fullest, visited = routed[0].sum(axis=0).tolist()
         self.stats["moe_assignments"] += (
             live * steps * self.cfg.num_experts_per_tok * self.cfg.n_expert_layers)
         self.stats["moe_local_assignments"] += landed
         self.stats["moe_expert_touches"] += touched
         self.stats["moe_expert_load_max"] += fullest
+        self.stats["moe_expert_visits"] += visited
 
     def _note_sweep(self, t_dispatch: float, t_ready: float) -> None:
         """Watchdog food (docs/RESILIENCE.md): a sweep read back IS scheduler

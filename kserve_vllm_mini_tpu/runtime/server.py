@@ -1420,6 +1420,11 @@ def make_app(engine: Engine, tok: Tokenizer, model_name: str,
             f"kvmini_tpu_moe_expert_touches_total {s['moe_expert_touches']}",
             "# TYPE kvmini_tpu_moe_expert_load_max_total counter",
             f"kvmini_tpu_moe_expert_load_max_total {s['moe_expert_load_max']}",
+            # held experts whose weights the decode steps' dispatch multiplied:
+            # touches where only the touched are visited, held x layers x steps
+            # where every held expert runs every row
+            "# TYPE kvmini_tpu_moe_expert_visits_total counter",
+            f"kvmini_tpu_moe_expert_visits_total {s['moe_expert_visits']}",
             # decode-pipeline telemetry (docs/DECODE_PIPELINE.md): depth >= 2
             # + low bubble = the double-buffered steady state is engaged
             "# TYPE kvmini_tpu_dispatch_depth gauge",

@@ -207,7 +207,7 @@ def build_decode(cfg: ModelConfig, fwd, kernels: str, n_steps: int = 1,
         def one(c, toks, lens, r, cnt):
             kw = forward_kwargs(cfg, kernels, table=table, lora=lora,
                                 ids=ids, live=live, stats=True)
-            # routed: the expert blocks' counts over the live rows, int32 [3]
+            # routed: the expert blocks' counts over the live rows, int32 [4]
             # (nothing for a model without such blocks)
             logits, nc, *routed = fwd(
                 params, cfg, toks[:, None], lens[:, None], c, lens, **kw,

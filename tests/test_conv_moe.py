@@ -288,8 +288,9 @@ def test_the_routing_counts_are_over_the_real_rows():
     real = jnp.arange(37)[None] < 20
     _, stats = moe.moe_glu_block(w, cfg, x[None], real)
     took = np.asarray(reference.route(x, w, m=m))[:20] > 0
+    # ... and, fourth, the experts the dense dispatch multiplied: every held one
     assert stats.tolist() == [int(took.sum()), int(took.any(axis=0).sum()),
-                              int(took.sum(axis=0).max())]
+                              int(took.sum(axis=0).max()), cfg.experts_held]
     assert int(stats[0]) == 20 * m["num_experts_per_tok"]
 
 

@@ -134,9 +134,11 @@ def test_a_parked_or_mid_prefill_slot_keeps_its_tail_through_neighbours_sweeps()
     # three sweeps of one live row: 2 choices x 5 expert layers x 3 steps, each its own expert
     assert eng.stats["moe_assignments"] - offered == 3 * 2 * 5
     assert eng.stats["moe_expert_touches"] - touched == 3 * 2 * 5
-    # the span that follows a sweep's read-back carries that sweep's touched experts
+    # the span that follows a sweep's read-back carries that sweep's touched experts, and
+    # those its dispatch multiplied: every held one of the five layers, dense on the CPU
     emits = [args for name, args in spans if name == "kvmini.emit"]
-    assert emits == [{"moe_touched": 10, "moe_steps": 1}] * 3
+    assert emits == [{"moe_touched": 10, "moe_visited": 5 * TINY.experts_held,
+                      "moe_steps": 1}] * 3
     # a piece's span says what it offers the expert layers; a fresh prefill into the
     # marked slot starts from an empty tail whatever it held
     eng._free = [parked]
@@ -211,10 +213,11 @@ def test_the_counters_are_exported_and_a_dense_model_counts_nothing():
 
 def test_the_engine_serves_a_long_piece_grouped_and_counts_its_bucket(monkeypatch):
     """A 70-token prompt's piece takes the grouped dispatch, a 12-token one
-    and every decode sweep the dense one (the kernel's row tile cut to 16, the
-    experts 128 wide so that it has a block; tests/test_grouped_moe.py
-    holds the dispatch itself): the streams are the dense engine's, and the
-    counter holds the long piece's bucket alone."""
+    and every decode step sweep the experts their rows chose (the kernel's
+    row tile cut to 16, the experts 128 wide so that it has a block;
+    tests/test_grouped_moe.py holds the dispatches themselves): the streams
+    are the dense engine's, and the counter holds the long piece's bucket
+    alone."""
     from kserve_vllm_mini_tpu.ops import grouped_matmul
     from kserve_vllm_mini_tpu.runtime import steps
 
@@ -240,3 +243,57 @@ def test_the_engine_serves_a_long_piece_grouped_and_counts_its_bucket(monkeypatc
     assert stats["interpret"]["prefill_bucket_tokens"] == sum(buckets)
     assert stats["interpret"]["prefill_grouped_bucket_tokens"] == buckets[0]
     assert stats["auto"]["prefill_grouped_bucket_tokens"] == 0
+
+
+def served_with_and_without_kernels(cfg, prompts, max_new=6):
+    """The streams, the engine's counters and the lines of ``/metrics`` with
+    the kernels off and interpreted, two requests on four slots."""
+    import asyncio
+
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from kserve_vllm_mini_tpu.runtime.server import make_app
+    from kserve_vllm_mini_tpu.runtime.tokenizer import ByteTokenizer
+
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    got = {}
+    for kernels in ("off", "interpret"):
+        eng = Engine(params, cfg, EngineConfig(max_slots=4, max_seq_len=128, max_prefill_len=64))
+        eng._kernels = kernels
+
+        async def scrape():
+            async with TestClient(TestServer(make_app(eng, ByteTokenizer(), "m"))) as client:
+                return await (await client.get("/metrics")).text()
+
+        eng.start()
+        try:
+            outs = run_requests(eng, prompts, max_new=max_new)
+            got[kernels] = (outs, eng.snapshot_stats(), asyncio.run(scrape()).splitlines())
+        finally:
+            eng.stop()
+    return got
+
+
+def assert_a_decode_step_visits_the_touched_experts_alone(cfg, prompts):
+    """Greedy streams equal with the kernels interpreted and off; the dense
+    steps visit every held expert of every expert layer, the kernel's the
+    experts a live row chose (two rows live of four: fewer), and ``/metrics``
+    carries the count."""
+    got = served_with_and_without_kernels(cfg, prompts)
+    (dense_outs, dense, dense_lines), (outs, swept, lines) = got["off"], got["interpret"]
+    assert outs == dense_outs and all(len(o) == 6 for o in outs)
+    assert dense["moe_expert_visits"] == (
+        dense["decode_steps"] * cfg.n_expert_layers * cfg.experts_held)
+    assert 0 < swept["moe_expert_visits"] == swept["moe_expert_touches"]
+    assert swept["moe_expert_visits"] < (
+        swept["decode_steps"] * cfg.n_expert_layers * cfg.experts_held)
+    assert f"kvmini_tpu_moe_expert_visits_total {swept['moe_expert_visits']}" in lines
+    assert f"kvmini_tpu_moe_expert_visits_total {dense['moe_expert_visits']}" in dense_lines
+
+
+def test_a_decode_step_visits_the_touched_experts_alone():
+    # a convolution, attention, a convolution; a dense MLP then two layers of 8 experts 128 wide
+    cfg = get_config("lfm2-moe-tiny", dtype="float32", moe_intermediate_size=128, n_layers=3,
+                     layer_types=("conv", "full_attention", "conv"))
+    assert_a_decode_step_visits_the_touched_experts_alone(
+        cfg, [list(range(3, 43)), list(range(50, 60))])

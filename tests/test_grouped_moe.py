@@ -1,7 +1,8 @@
 """The grouped dispatch of a long piece (models/moe.py ``_run_grouped`` over
-ops/grouped_matmul.py) against the dense dispatch it stands beside: the same
-sum up to the order of a float32 addition, at any routing, with no capacity
-and no drop. Tiny shapes, the kernel in interpret mode.
+ops/grouped_matmul.py) and the sweep of a decode step's touched experts
+(``expert_sweep``) against the dense dispatch they stand beside: the same sum
+up to the order of a float32 addition, at any routing, with no capacity and
+no drop. Tiny shapes, the kernels in interpret mode.
 """
 
 import jax
@@ -21,8 +22,10 @@ BODIES = {"glu_silu": moe.GLU_SILU, "glu_relu": moe.GLU_RELU, "latent_relu2": mo
 @pytest.fixture(autouse=True)
 def small_tiles(monkeypatch):
     """Row tiles of 8: a piece of 9 tokens or more is grouped, and a 40-token
-    piece has groups of several tiles."""
+    piece has groups of several tiles. A sweep of up to 6 rows: 7 and 8 rows
+    are what 33-128 are at the kernels' own sizes, dense."""
     monkeypatch.setattr(gm, "ROW_TILE", 8)
+    monkeypatch.setattr(gm, "SWEEP_ROWS", 6)
 
 
 def stacks_of(body, quantized, dtype=jnp.float32, experts=E, seed=0):
@@ -50,11 +53,18 @@ def random_choice(tokens, experts=E, seed=2):
     return jax.lax.top_k(scores, K)[1]
 
 
-def both(x, w, chosen, p, body, qm="dequant", offset=0, layer=1):
-    dense, landed = moe._run_held(x, w, chosen, offset, p, body, qm, "off", layer)
-    grouped, landed_g = moe._run_held(x, w, chosen, offset, p, body, qm, "interpret", layer)
-    assert np.array_equal(landed, landed_g)
-    return np.asarray(dense), np.asarray(grouped)
+def both(x, w, chosen, p, body, qm="dequant", offset=0, layer=1, real=None):
+    """The dense dispatch's result and the kernels'. What each visited: every
+    held expert; the experts a (live) row chose."""
+    dense, landed, all_held = moe._run_held(x, w, chosen, offset, p, body, qm, "off", layer, real)
+    kernel, landed_k, visited = moe._run_held(x, w, chosen, offset, p, body, qm, "interpret",
+                                              layer, real)
+    assert np.array_equal(landed, landed_k)
+    assert all_held == landed.shape[1]
+    live = np.asarray(landed) if real is None or x.shape[0] > gm.SWEEP_ROWS else (
+        np.asarray(landed) & np.asarray(real)[:, None])
+    assert visited == live.any(axis=0).sum()
+    return np.asarray(dense), np.asarray(kernel)
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
@@ -130,19 +140,20 @@ def test_a_token_none_of_whose_experts_is_held_gets_zero():
     np.testing.assert_allclose(grouped, dense, rtol=1e-4, atol=1e-4)
 
 
-def grouped_calls(fn, *args):
-    return str(jax.make_jaxpr(fn)(*args)).count("_run_grouped")
+def grouped_calls(fn, *args, name="_run_grouped"):
+    return str(jax.make_jaxpr(fn)(*args)).count(name)
 
 
-@pytest.mark.parametrize("tokens,calls", [(8, 0), (9, 1)])
-def test_the_piece_length_chooses_the_dispatch(tokens, calls):
-    """A row tile of tokens runs every expert (a decode sweep, a short
-    piece); one more takes the grouped dispatch."""
+@pytest.mark.parametrize("tokens,grouped,swept", [(6, 0, 1), (7, 0, 0), (8, 0, 0), (9, 1, 0)])
+def test_the_piece_length_chooses_the_dispatch(tokens, grouped, swept):
+    """A decode step's few rows sweep the touched experts; more, up to a row
+    tile of tokens, run every expert; one more takes the grouped dispatch."""
     p = stacks_of(moe.GLU_SILU, True)
     x, w = piece(tokens)
     run = lambda x, w, c, p: moe._run_held(x, w, c, 0, p, moe.GLU_SILU, "dequant",
                                            "interpret", 0)[0]
-    assert grouped_calls(run, x, w, random_choice(tokens), p) == calls
+    assert grouped_calls(run, x, w, random_choice(tokens), p) == grouped
+    assert grouped_calls(run, x, w, random_choice(tokens), p, name="expert_sweep") == swept
 
 
 @pytest.mark.parametrize("why,kernels,qm,quantized,width", [
@@ -177,6 +188,10 @@ def test_a_walk_of_expert_layers_lowers_the_dispatch_once():
     text = jax.jit(walk).lower(x, w, random_choice(40), p).as_text()
     assert text.count("func.func private @_run_grouped") == 1
     assert text.count("call @_run_grouped") == L
+    # and a decode step's sweep
+    text = jax.jit(walk).lower(x[:6], w[:6], random_choice(6), p).as_text()
+    assert text.count("func.func private @expert_sweep") == 1
+    assert text.count("call @expert_sweep") == L
 
 
 def test_the_three_layers_take_it_through_their_blocks():
@@ -192,7 +207,7 @@ def test_the_three_layers_take_it_through_their_blocks():
     dense, s0 = moe.moe_glu_block(p, cfg, h, layer=2)
     stacked, s1 = moe.moe_glu_block(p, cfg, h, kernels="interpret", layer=2)
     np.testing.assert_allclose(stacked, dense, rtol=1e-4, atol=1e-4)
-    assert np.array_equal(s0, s1)
+    assert np.array_equal(s0[:3], s1[:3]) and s0[3] == held and s1[3] == s1[1]
 
     mixtral = get_config("mixtral-tiny", dtype="float32")
     q = jax.tree_util.tree_map(lambda a: a[0], stacks_of(moe.GLU_SILU, False, experts=mixtral.n_experts))
@@ -237,3 +252,202 @@ def test_group_rows_lays_every_assignment_out_once(counts):
 def test_the_block_rule(n_in, n_out, w_dtype, n_w, want, monkeypatch):
     monkeypatch.setattr(gm, "ROW_TILE", 128)
     assert gm.grouped_matmul_block(n_in, n_out, w_dtype, jnp.bfloat16, n_w) == want
+
+
+# -- a decode step: the touched experts swept, the others never fetched ----------
+
+ROWS = 6    # a decode step's rows under the fixture's SWEEP_ROWS
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["plain", "int8"])
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_a_decode_step_sweeps_what_the_dense_dispatch_sums(body, quantized, dtype):
+    """The three bodies over plain stacks of the rows' dtype and int8 leaves:
+    float32 rows differ by the order of the sums, bfloat16 rows by the dense
+    dispatch's rounding of each product (the prefill cases' tolerances).
+    ``both`` holds ``visited`` to the touched experts, and to every held one
+    on the dense side."""
+    dt = jnp.dtype(dtype)
+    p = stacks_of(BODIES[body], quantized, dt)
+    x, w = piece(ROWS, dtype=dt)
+    dense, swept = both(x, w, random_choice(ROWS), p, BODIES[body])
+    assert dense.shape == swept.shape == (ROWS, D) and np.abs(dense).max() > 0.1
+    if dt == jnp.float32:
+        np.testing.assert_allclose(swept, dense, rtol=1e-4, atol=1e-4)
+    else:
+        assert np.abs(swept - dense).max() <= 0.03 * np.abs(dense).max()
+
+
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_a_parked_row_chooses_nothing(body):
+    """Rows 1 and 4 are parked and alone chose experts 1 and 3: those are
+    not visited (``both`` counts), the live rows read what the dense
+    dispatch gives them, a parked row reads zero."""
+    p = stacks_of(BODIES[body], True)
+    x, w = piece(ROWS)
+    chosen = jnp.array([[0, 2], [1, 3], [2, 0], [0, 2], [3, 1], [2, 0]], jnp.int32)
+    real = jnp.array([True, False, True, True, False, True])
+    dense, swept = both(x, w, chosen, p, BODIES[body], real=real)
+    np.testing.assert_allclose(swept[real], dense[real], rtol=1e-4, atol=1e-4)
+    assert (swept[~real] == 0).all() and np.abs(dense[~real]).max() > 0
+
+
+@pytest.mark.parametrize("routing,live", [("all_on_one", ROWS), ("two_empty", ROWS),
+                                          ("ragged", ROWS), ("ragged", 0)])
+def test_a_sweep_is_right_at_any_routing(routing, live):
+    """Every row on one pair of experts (the others' blocks are never
+    fetched); no row live at all (nothing is visited, the result is zero and
+    finite: the call's shape does not depend on the routing)."""
+    p = stacks_of(moe.GLU_SILU, True)
+    x, w = piece(ROWS)
+    real = jnp.arange(ROWS) < live
+    dense, swept = both(x, w, ROUTINGS[routing](ROWS).astype(jnp.int32), p, moe.GLU_SILU,
+                        real=real)
+    np.testing.assert_allclose(swept[:live], dense[:live], rtol=1e-4, atol=1e-4)
+    assert np.isfinite(swept).all() and (swept[live:] == 0).all()
+    assert live == 0 or (np.abs(swept).sum(axis=1) > 0).all()
+
+
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_a_quarter_of_the_experts_held(body):
+    """Four devices each hold a quarter of 16 routed experts: an assignment
+    that lands elsewhere is dropped from the sweep and from nothing else (the
+    weights are the whole model's), so the four shares add up to the uncut
+    layer."""
+    routed, held = 16, 4
+    whole = stacks_of(BODIES[body], True, experts=routed)
+    x, w = piece(ROWS)
+    chosen = random_choice(ROWS, experts=routed, seed=7)
+    total = 0.0
+    for offset in range(0, routed, held):
+        mine = jax.tree_util.tree_map(lambda a: a[:, offset:offset + held], whole)
+        dense, swept = both(x, w, chosen, mine, BODIES[body], offset=offset)
+        np.testing.assert_allclose(swept, dense, rtol=1e-4, atol=1e-4)
+        total = total + swept
+    uncut = moe._run_held(x, w, chosen, 0, whole, BODIES[body], "dequant", "off", 1)[0]
+    np.testing.assert_allclose(total, uncut, rtol=1e-4, atol=1e-4)
+
+
+def _leaves(kind):
+    p = stacks_of(moe.GLU_SILU, kind != "plain")
+    if kind == "int4":      # packed: two nibbles a byte
+        p = {n: {"q": w["q"][..., ::2].astype(jnp.uint8), "s": w["s"]} for n, w in p.items()}
+    if kind == "awq":       # an input scaling beside the scale
+        p = {n: {**w, "a": jnp.ones(w["q"].shape[:-1])} for n, w in p.items()}
+    return p
+
+
+@pytest.mark.parametrize("why,rows,kernels,qm,kind,stacked,want", [
+    ("a decode step over int8 leaves", ROWS, "interpret", "dequant", "int8", True, moe.SWEEP),
+    ("and over plain stacks", ROWS, "interpret", "dequant", "plain", True, moe.SWEEP),
+    ("w8a8 contracts in int8: correct's control", ROWS, "interpret", "w8a8", "int8", True, None),
+    ("packed int4 has its own arithmetic", ROWS, "interpret", "dequant", "int4", True, None),
+    ("and so has an AWQ leaf", ROWS, "interpret", "dequant", "awq", True, None),
+    ("a slice without its stack would be copied", ROWS, "interpret", "dequant", "int8", False,
+     None),
+    ("no kernels: the CPU, or a mesh", ROWS, "off", "dequant", "int8", True, None),
+    ("more rows than a sweep takes", ROWS + 1, "interpret", "dequant", "int8", True, None),
+    ("up to a row tile", 8, "interpret", "dequant", "int8", True, None),
+])
+def test_what_a_short_piece_takes(why, rows, kernels, qm, kind, stacked, want):
+    x = jax.ShapeDtypeStruct((rows, D), jnp.float32)
+    assert moe.grouped_blocks(x, _leaves(kind), moe.GLU_SILU, qm, kernels, stacked) == want, why
+
+
+@pytest.mark.parametrize("rows,want", [(1, "sweep"), (16, "sweep"), (32, "sweep"), (33, None),
+                                       (128, None), (129, "grouped")])
+def test_the_rule_at_the_kernels_own_sizes(rows, want, monkeypatch):
+    """LFM2-MoE's stacks, shapes alone: a decode step of up to 32 rows
+    sweeps, 33-128 rows stay dense, longer pieces are grouped."""
+    monkeypatch.setattr(gm, "ROW_TILE", 128)
+    monkeypatch.setattr(gm, "SWEEP_ROWS", 32)
+    leaf = lambda *shape: {"q": jax.ShapeDtypeStruct(shape, jnp.int8),
+                           "s": jax.ShapeDtypeStruct((*shape[:-2], shape[-1]), jnp.float32)}
+    p = {"w_gate": leaf(22, 32, 2048, 1792), "w_up": leaf(22, 32, 2048, 1792),
+         "w_down": leaf(22, 32, 1792, 2048)}
+    x = jax.ShapeDtypeStruct((rows, 2048), jnp.bfloat16)
+    how = moe.grouped_blocks(x, p, moe.GLU_SILU, "dequant", "interpret", True)
+    assert {"sweep": how == moe.SWEEP, None: how is None,
+            "grouped": how == ((128, 1792), (128, 2048))}[want]
+
+
+@pytest.mark.parametrize("n_in,n_mid,n_out,w_dtype,n_first,rows,want", [
+    (2048, 1792, 2048, "int8", 2, 16, True),      # lfm2's experts, 11 MB each
+    (2560, 768, 2560, "int8", 2, 16, True),       # SmallThinker's
+    (1024, 2688, 1024, "int8", 1, 32, True),      # Nemotron-H's latent experts
+    (1024, 2688, 1024, "bfloat16", 1, 32, True),
+    (4096, 14336, 4096, "int8", 2, 16, False),    # Mixtral's: 176 MB, no block holds one
+    (2048, 1792, 2048, "float32", 2, 16, False),  # neither int8 nor the rows' dtype
+    (2048, 1000, 2048, "int8", 2, 16, False),     # no whole lane tiles
+    (2048, 1792, 2048, "int8", 2, 33, False),     # more rows than a sweep takes
+])
+def test_what_a_sweep_has_a_block_for(n_in, n_mid, n_out, w_dtype, n_first, rows, want,
+                                      monkeypatch):
+    monkeypatch.setattr(gm, "SWEEP_ROWS", 32)
+    assert gm.expert_sweep_fits(n_in, n_mid, n_out, w_dtype, jnp.bfloat16, n_first, rows) == want
+
+
+def test_the_latent_block_takes_its_stack_and_index():
+    """``moe_latent_block`` handed the ``E`` blocks' layer stack and an
+    index: what it gives for that layer's own leaves, dense, and its counts
+    with ``visited`` the touched experts where the kernel sweeps."""
+    cfg = get_config("nemotron-h-tiny", dtype="float32").scaled(     # whole lane tiles
+        moe_latent_size=128, moe_intermediate_size=256)
+    latent, width = cfg.moe_latent_size, cfg.moe_intermediate_size
+    ks = jax.random.split(jax.random.PRNGKey(11), 8)
+
+    def stack(key, *shape):
+        return quantize_weight(jax.random.normal(key, (L, *shape)) * shape[-2] ** -0.5)
+
+    p = {"router": jax.random.normal(ks[0], (L, cfg.d_model, cfg.n_routed_experts)),
+         "router_bias": jnp.zeros((L, cfg.n_routed_experts)),
+         "w_in": stack(ks[1], cfg.d_model, latent), "w_out": stack(ks[2], latent, cfg.d_model),
+         "w1": stack(ks[3], cfg.experts_held, latent, width),
+         "w2": stack(ks[4], cfg.experts_held, width, latent),
+         "s1": stack(ks[5], cfg.d_model, cfg.moe_shared_expert_intermediate_size),
+         "s2": stack(ks[6], cfg.moe_shared_expert_intermediate_size, cfg.d_model)}
+    h = jax.random.normal(ks[7], (ROWS, 1, cfg.d_model), jnp.float32)
+    real = (jnp.arange(ROWS) != 2)[:, None]
+    own, s0 = moe.moe_latent_block(jax.tree_util.tree_map(lambda a: a[2], p), cfg, h, real)
+    swept, s1 = moe.moe_latent_block(p, cfg, h, real, kernels="interpret", layer=2)
+    live = np.asarray(real[:, 0])
+    np.testing.assert_allclose(swept[live], own[live], rtol=1e-4, atol=1e-4)
+    assert np.array_equal(s0[:3], s1[:3]) and s0[3] == cfg.experts_held and s1[3] == s1[1]
+
+
+SWEPT_CELLS = {   # cell -> (roofline module, decode rows, the first stack and the result's width)
+    "lfm2-8b-a1b.chat": ("conv_moe_roofline", 16, (22, 32, 2048, 1792), 2048),
+    "smallthinker-21b-pp4.doc-chat": ("window_moe_roofline", 16, (12, 64, 2560, 768), 2560),
+    "nemotron-3-super-ep4.decode-sat": ("ssm_moe_roofline", 32, (10, 128, 1024, 2688), 1024),
+}
+
+
+@pytest.mark.parametrize("meta", ["pad_add_fusion.1234", "get-tuple-element.1234", "fusion.987"])
+@pytest.mark.parametrize("cell", sorted(SWEPT_CELLS))
+def test_the_benchmarks_readers_find_the_sweep_by_the_name_a_trace_prints(cell, meta):
+    """The call as a TPU trace names it at the three configurations' own
+    shapes (result, then operands with their layouts: the scalars, the first
+    stack, ...), cut as ``bench/trace_reduce.py`` keeps it: the stack's shape
+    must survive the cut whatever produced the scalars, or the ``*_decode_ms``
+    and ``*_expert_hbm_share`` readers lose the experts' time.
+    tests/test_weight_copy_preflight.py holds the compiled programs to the
+    same."""
+    import importlib
+
+    from bench import config as bench_config
+    from bench import trace_reduce
+
+    module, rows, stack, width = SWEPT_CELLS[cell]
+    roofline = importlib.import_module(f"bench.{module}")
+    model = dict(bench_config.load_cell(cell)["config"]["model"])
+    dims = ",".join(map(str, stack))
+    name = (f"%emm.21 = f32[{rows},{width}]{{1,0:T(8,128)}} custom-call("
+            f"s32[{2 + stack[1]}]{{0:T(128)S(1)}} %{meta}, "
+            f"s8[{dims}]{{3,2,1,0:T(8,128)(4,1)}} %get-tuple-element.4321, "
+            f"s8[{dims}]{{3,2,1,0:T(8,128)(4,1)}} %get-tuple-element.4322), "
+            'custom_call_target="tpu_custom_call"')
+    shown = f"jit_decode/{trace_reduce.short_name(name)}"
+    assert f"s8[{dims}]" in shown
+    assert roofline.reads_expert_stack(shown, model)
+    assert not roofline.reads_expert_stack(shown.replace(dims, "10,2688,1024"), model)

@@ -302,7 +302,9 @@ def test_routing_counts_are_of_the_real_rows_and_the_held_experts():
     _, stats = moe.moe_latent_block(p, cfg, h, real)
     rows = chosen[np.asarray(real)]
     load = np.bincount(rows[rows < cfg.experts_held], minlength=cfg.experts_held)
-    assert [int(s) for s in stats] == [int(load.sum()), int((load > 0).sum()), int(load.max())]
+    # ... and, fourth, the experts the dense dispatch multiplied: every held one
+    assert [int(s) for s in stats] == [int(load.sum()), int((load > 0).sum()), int(load.max()),
+                                       cfg.experts_held]
     out_all, _ = moe.moe_latent_block(p, cfg, h)
     out_real, _ = moe.moe_latent_block(p, cfg, h, real)
     assert np.array_equal(np.asarray(out_all), np.asarray(out_real))   # counts only, never the result

@@ -12,6 +12,7 @@ from kserve_vllm_mini_tpu.models.config import get_config
 from kserve_vllm_mini_tpu.models.llama import forward, init_params
 from kserve_vllm_mini_tpu.runtime.engine import Engine, EngineConfig, GenRequest
 # the settings a state without positions refuses, and the stream collector: the delta rule's
+from tests.test_conv_moe_engine import assert_a_decode_step_visits_the_touched_experts_alone
 from tests.test_hybrid_engine import REFUSED, run_requests
 
 # all three kinds of block, 8 experts held of 32, float32
@@ -135,7 +136,7 @@ def test_engine_refuses_what_a_state_without_positions_cannot_do(what):
 
 
 def test_the_counters_are_exported_and_a_dense_model_counts_nothing():
-    """/metrics carries the four expert counters and the state-space kinds of
+    """/metrics carries the five expert counters and the state-space kinds of
     kvmini_tpu_cache_bytes for every model; a model without such blocks
     leaves them at 0 and its decode program returns no routing counts."""
     import asyncio
@@ -158,8 +159,17 @@ def test_the_counters_are_exported_and_a_dense_model_counts_nothing():
         text = asyncio.run(scrape())
     finally:
         eng.stop()
-    for name in ("assignments", "local_assignments", "expert_touches", "expert_load_max"):
+    for name in ("assignments", "local_assignments", "expert_touches", "expert_load_max",
+                 "expert_visits"):
         assert f"kvmini_tpu_moe_{name}_total 0" in text.splitlines()
     assert 'kvmini_tpu_cache_bytes{kind="ssm_state"} 0' in text
     assert 'kvmini_tpu_cache_bytes{kind="ssm_conv_state"} 0' in text
     assert eng.stats["decode_steps"] > 0
+
+
+def test_a_decode_step_visits_the_touched_experts_alone():
+    """A latent and experts of whole lane tiles, so that the kernel has a
+    block for them: the ``E`` blocks hand it their stack and index."""
+    assert_a_decode_step_visits_the_touched_experts_alone(
+        TINY.scaled(moe_latent_size=128, moe_intermediate_size=128),
+        [list(range(3, 43)), list(range(50, 60))])

@@ -31,6 +31,9 @@ which takes the grouped dispatch (models/moe.py): ``weight_copy_bytes == 0``
 there says that the ``gmm`` kernel reads the int8 expert stacks where they
 lie, and the worker's ``grouped`` entry that the walk's expert layers lower
 ONE body and that the benchmark's readers find every call by its cut name.
+The decode step of all three stacks of routed experts sweeps the touched
+experts (``emm``, one call an expert layer): the worker's ``swept`` entry
+says the same of it.
 
 One file, one subprocess: libtpu is loaded in the child alone (``--dist
 loadfile`` keeps this file in one worker), and the child is held to a
@@ -51,10 +54,6 @@ MODELS = ("mistral-7b-int8", "qwen2-7b-int8", "olmo-hybrid-7b-int8",
           "nemotron-3-super-ep4-int8", "falcon-h1-34b-int8", "lfm2-8b-a1b-int8",
           "smallthinker-21b-pp4-int8")
 CASES = [f"{m}:{s}" for m in MODELS for s in ("decode", "prefill")]
-# A decode step with no attention kernel yet: 64-wide heads, which
-# ops/paged_attention.py dense_decode_block turns away (head_dim % 128), so the
-# six attention layers read K/V eagerly (ROADMAP A: a kernel for them)
-NO_DECODE_KERNEL = {"lfm2-8b-a1b-int8:decode"}
 # fourteen compiles take ~230 s here; under a loaded suite several times that
 TIMEOUT_S = 900
 
@@ -84,8 +83,10 @@ def test_projections_read_their_weights_in_place(compiled, case):
     assert stats["weight_copy_bytes"] == 0, (
         f"{case}: {stats['weight_copy_bytes']} bytes of per-layer integer "
         "weight slices are copied before their matmul")
-    if case not in NO_DECODE_KERNEL:
-        assert stats["mosaic_calls"] >= 1, f"{case}: no Pallas kernel compiled in"
+    # lfm2's decode step holds no attention kernel (64-wide heads, which
+    # ops/paged_attention.py dense_decode_block turns away: ROADMAP A), but
+    # its expert layers' sweeps
+    assert stats["mosaic_calls"] >= 1, f"{case}: no Pallas kernel compiled in"
 
 
 def test_the_hybrids_decode_step_updates_its_state_in_place(compiled):
@@ -167,5 +168,27 @@ def test_a_long_piece_is_grouped_one_body_a_walk_and_the_readers_find_its_calls(
     time."""
     stats = compiled[f"{model}:prefill"]
     layers = GROUPED[model]
-    assert stats["grouped"] == {"bodies": 1, "calls": 2 * layers, "found": 2 * layers}
+    found = {k: stats["grouped"][k] for k in ("bodies", "calls", "found")}
+    assert found == {"bodies": 1, "calls": 2 * layers, "found": 2 * layers}, stats["grouped"]
     assert stats["mosaic_calls"] >= 2 * layers + 1        # and a flash prefill call at least
+
+
+# expert layers of each stack of routed experts as the worker cuts it
+SWEPT = {**GROUPED, "nemotron-3-super-ep4-int8": 1}
+
+
+@pytest.mark.parametrize("model", sorted(SWEPT))
+def test_a_decode_step_sweeps_one_body_a_walk_and_the_readers_find_its_calls(compiled, model):
+    """The decode step at the published widths: ONE ``emm`` call an expert
+    layer over the int8 stacks in place (``weight_copy_bytes`` 0 is the bar
+    above: the latent experts' walk hands the kernel its stack and index like
+    the other two), ONE ``expert_sweep`` function in the lowered module, and
+    every call an expert stack's operation to ``reads_expert_stack`` of the
+    stack's roofline module under the name a trace shows. A call they missed
+    would leave ``*_moe_decode_ms`` the leftovers and ``*_expert_hbm_share``
+    hundreds of percent."""
+    stats = compiled[f"{model}:decode"]
+    layers = SWEPT[model]
+    found = {k: stats["swept"][k] for k in ("bodies", "calls", "found")}
+    assert found == {"bodies": 1, "calls": layers, "found": layers}, stats["swept"]
+    assert stats["mosaic_calls"] >= layers

@@ -255,7 +255,7 @@ def test_only_pieces_longer_than_any_accepted_cell_sends_run_the_long_token_bloc
 
     monkeypatch.setattr(moe, "experts_of", lambda p, b, qm: body)
     stacks = {k: jax.ShapeDtypeStruct((3, 8, 8), jnp.float32) for k in ("w_gate", "w_up", "w_down")}
-    out, landed = jax.eval_shape(
+    out, landed, _ = jax.eval_shape(
         lambda x, w, c, p: moe._run_held(x, w, c, 0, p, moe.GLU_SILU, "dequant"),
         jax.ShapeDtypeStruct((n, 8), jnp.float32), jax.ShapeDtypeStruct((n, 2), jnp.float32),
         jax.ShapeDtypeStruct((n, 2), jnp.int32), stacks)

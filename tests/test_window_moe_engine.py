@@ -15,6 +15,7 @@ from kserve_vllm_mini_tpu.models.config import get_config
 from kserve_vllm_mini_tpu.models.llama import forward, init_params
 from kserve_vllm_mini_tpu.runtime.engine import Engine, EngineConfig, GenRequest
 # the settings a per-slot state that positions do not address refuses, and the stream collector
+from tests.test_conv_moe_engine import assert_a_decode_step_visits_the_touched_experts_alone
 from tests.test_hybrid_engine import REFUSED, run_requests
 
 # two periods of a global layer and three window-16 layers, 8 experts under each; float32
@@ -237,3 +238,9 @@ def test_the_gauges_and_counters_are_exported_and_a_dense_model_has_neither_kind
     lines = scrape_of(dense)
     assert not any("kv_window" in line or "kv_global" in line for line in lines)
     assert 'kvmini_tpu_decode_kv_blocks_fetched_by_kind_total{kind="global"} 0' in lines
+
+
+def test_a_decode_step_visits_the_touched_experts_alone():
+    """Experts 128 wide, so that the kernel has a block for them."""
+    assert_a_decode_step_visits_the_touched_experts_alone(
+        TINY.scaled(moe_intermediate_size=128), [list(range(3, 43)), list(range(50, 60))])

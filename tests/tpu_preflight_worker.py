@@ -20,8 +20,8 @@ layer and a window layer, all 64 experts under each).
 Prints one JSON line: ``{"skip": reason}`` where libtpu or the topology cannot
 be had, else ``{"cases": {case: CompileStats.to_dict()}}`` (``mosaic_calls``,
 ``weight_copy_bytes``, ``state_copy_bytes`` among its keys; a prefill case
-adds ``grouped``: what the grouped dispatch left in the program, see
-``grouped_dispatch``). ``--dump DIR`` also writes each case's optimized HLO
+of routed GLU experts adds ``grouped`` and a decode case of routed experts
+``swept``: what the dispatch left in the program, see ``kernel_dispatch``). ``--dump DIR`` also writes each case's optimized HLO
 there.
 """
 
@@ -129,30 +129,32 @@ def compile_case(cfg, step: str, device):
     return capture_compile_stats(fn, *args, label=step, **kw)
 
 
-def grouped_dispatch(model: dict, lowered: str, optimized: str) -> dict:
-    """What the grouped dispatch left in a prefill program: ``bodies``, the
-    functions of ``_run_grouped`` in the lowered module (one a shape, however
-    many layers call it); ``calls``, the ``gmm`` custom calls of the
-    optimized program (two an expert layer); ``found``, those of them the
-    benchmark's reader of this stack would count as an expert stack's
-    operation, by the name a trace would show (result and operands with their
-    shapes, cut to 96 characters)."""
+def kernel_dispatch(model: dict, lowered: str, optimized: str, body: str, kernel: str) -> dict:
+    """What an expert dispatch left in a program: ``bodies``, the functions
+    of ``body`` (``_run_grouped``, ``expert_sweep``) in the lowered module
+    (one a shape, however many layers call it); ``calls``, the custom calls
+    of ``kernel`` (``gmm``, ``emm``) in the optimized program; ``found``,
+    those of them the benchmark's reader of this stack would count as an
+    expert stack's operation, by the name a trace would show (result and
+    operands with their shapes, cut to 96 characters), every operand's number
+    written with four digits as a program of the whole depth has them;
+    ``names``, those cut names."""
     import re
 
-    from bench import conv_moe_roofline, trace_reduce, window_moe_roofline
+    from bench import conv_moe_roofline, ssm_moe_roofline, trace_reduce, window_moe_roofline
 
-    roofline = conv_moe_roofline if conv_moe_roofline.sizes(model) else window_moe_roofline
+    roofline = next(r for r in (conv_moe_roofline, window_moe_roofline, ssm_moe_roofline)
+                    if r.sizes(model))
     types = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)", optimized, re.M))
-    calls = re.findall(r"^\s*(?:ROOT )?((%gmm[\w.\-]*) = (\S+) custom-call\(([^)]*)\))",
+    calls = re.findall(rf"^\s*(?:ROOT )?((%{kernel}[\w.\-]*) = (\S+) custom-call\(([^)]*)\))",
                        optimized, re.M)
-    found = 0
+    names = []
     for _, name, result, operands in calls:
-        shown = ", ".join(f"{types.get(o, '?')} {o}" for o in
+        shown = ", ".join(f"{types.get(o, '?')} {re.sub(r'[.]\d+$', '.1234', o)}" for o in
                           re.findall(r"%[\w.\-]+", operands))
-        found += roofline.reads_expert_stack(
-            trace_reduce.short_name(f"{name} = {result} custom-call({shown})"), model)
-    return {"bodies": lowered.count("func.func private @_run_grouped"),
-            "calls": len(calls), "found": found}
+        names.append(trace_reduce.short_name(f"{name}.123 = {result} custom-call({shown})"))
+    return {"bodies": lowered.count(f"func.func private @{body}"), "calls": len(calls),
+            "found": sum(roofline.reads_expert_stack(n, model) for n in names), "names": names}
 
 
 def main() -> int:
@@ -185,8 +187,15 @@ def main() -> int:
         exe, stats = capture_compile_stats(fn, *args, label=step, **kw)
         out[case] = stats.to_dict()
         if step == "prefill" and (cfg.is_conv_stack or cfg.is_window_stack):
-            out[case]["grouped"] = grouped_dispatch(
-                bench_model(model), fn.lower(*args, **kw).as_text(), exe.as_text())
+            out[case]["grouped"] = kernel_dispatch(
+                bench_model(model), fn.lower(*args, **kw).as_text(), exe.as_text(),
+                "_run_grouped", "gmm")
+        if step == "decode" and cfg.n_expert_layers:
+            # the readers look for the experts this case holds
+            held = {"n_routed_experts": cfg.experts_held} if cfg.is_pattern_stack else {}
+            out[case]["swept"] = kernel_dispatch(
+                {**bench_model(model), **held}, fn.lower(*args, **kw).as_text(), exe.as_text(),
+                "expert_sweep", "emm")
         if ns.dump:
             Path(ns.dump).mkdir(parents=True, exist_ok=True)
             (Path(ns.dump) / f"{case.replace(':', '.')}.hlo.txt").write_text(exe.as_text())
