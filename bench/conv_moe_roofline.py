@@ -43,7 +43,7 @@ Loops and calls are never counted (``hybrid_roofline.is_leaf``).
 
 from __future__ import annotations
 
-from bench import hybrid_roofline
+from bench import hybrid_roofline, metrics, peaks
 
 shapes_in = hybrid_roofline.shapes_in
 is_leaf = hybrid_roofline.is_leaf
@@ -138,9 +138,32 @@ def seconds_and_runs(trace: dict | None, m: dict, program: str, rule) -> tuple[f
     operations ``rule`` names over the traced window, and the times it ran;
     nothing without a trace, for a model without such layers or without such
     operations."""
-    runs = len((trace or {}).get("programs", {}).get(program, []))
-    if sizes(m) is None or not runs:
+    if sizes(m) is None:
         return None
-    took = sum(op["seconds"] for name, op in trace["ops"].items()
-               if name.startswith(program + "/") and rule(name, m))
-    return (took, runs) if took else None
+    return hybrid_roofline.seconds_and_runs(trace, program, lambda name: rule(name, m))
+
+
+def expert_hbm_share(ctx, stack) -> float | None:
+    """What the routed experts' matmuls must read in a decode step over the
+    memory bandwidth, as a share of their device time, count and time from the
+    SAME seconds: the held experts a step touched are the traced slice's own
+    (``moe_touched`` over ``moe_steps``, summed over the ``kvmini.emit`` spans
+    that retired its sweeps; ``trace_reduce.span_counts``), the time a step that
+    of the operations that read an expert stack in the slice's ``jit_decode``.
+    ``stack`` is the roofline module of the cell's kind of expert (this one,
+    ``window_moe_roofline``, ``ssm_moe_roofline``): its ``seconds_and_runs``,
+    ``reads_expert_stack``, ``expert_bytes`` and ``expert_activation_bytes``.
+    The assignments' activations (8 KB each beside 5.5-11 MB an expert, under
+    0.2% of the bytes) keep the 45 s window's mean a step. Nothing for a trace
+    whose spans carry no counts (an older program, a model without experts)."""
+    m = ctx.memory["model"]
+    found = stack.seconds_and_runs(ctx.trace, m, "jit_decode", stack.reads_expert_stack)
+    emit = metrics.span_sums(ctx.trace, "kvmini.emit")
+    if found is None or not emit.get("moe_steps") or not emit.get("moe_touched"):
+        return None
+    steps = ctx.counters.get("kvmini_tpu_decode_steps_total", 0.0)
+    landed = ctx.counters.get("kvmini_tpu_moe_local_assignments_total", 0.0) / max(steps, 1.0)
+    need = (emit["moe_touched"] / emit["moe_steps"] * stack.expert_bytes(m)
+            + stack.expert_activation_bytes(m, landed))
+    least_s = need / peaks.for_kind(ctx.device_kind)["hbm_bytes_per_s"]
+    return 100.0 * least_s / (found[0] / found[1])

@@ -55,6 +55,18 @@ def is_leaf(op_name: str) -> bool:
     return not op_name.split("/", 1)[-1].startswith(_CONTROL)
 
 
+def seconds_and_runs(trace: dict | None, program: str, named) -> tuple[float, int] | None:
+    """Seconds ``program`` (``jit_decode``, ``jit_prefill``) spent over the
+    traced window in the operations whose name ``named`` accepts, and the times
+    it ran; nothing without a trace, without a run or without such operations."""
+    runs = len((trace or {}).get("programs", {}).get(program, []))
+    if not runs:
+        return None
+    took = sum(op["seconds"] for name, op in trace["ops"].items()
+               if name.startswith(program + "/") and named(name))
+    return (took, runs) if took else None
+
+
 def touches_state(op_name: str, m: dict) -> bool:
     """An operation that reads or writes the recurrent state."""
     x = mixer(m)

@@ -114,6 +114,14 @@ def step_ms(trace: Optional[dict], program: str) -> Optional[float]:
     return 1000.0 * durs[len(durs) // 2] if durs else None
 
 
+def span_sums(trace: Optional[dict], name: str) -> dict:
+    """What the program's ``name`` spans counted in the traced slice: the sum of
+    each numeric argument over the spans that began in the seconds the device
+    times come from (``trace_reduce.span_counts``). Empty without a trace,
+    without such spans or where they carry no arguments (an older program)."""
+    return ((trace or {}).get("spans") or {}).get(name, {}).get("sums", {})
+
+
 def idle_share(trace: Optional[dict]) -> Optional[float]:
     """Share of the traced window in which no operation ran on the device."""
     t = trace or {}

@@ -1,17 +1,6 @@
-"""What the routed experts' matmuls must read in a decode step over the memory bandwidth, as a share of their device time: the held experts that at least one live token chose (the program's ``expert_touches`` counter over the window, a step's mean over all expert layers) times one expert's int8 W1 and W2 with their scales, plus the latent activations of the assignments that landed here (read and written, bf16), over 819 GB/s, over the time of the operations that read an expert stack (found by shape, bench/ssm_moe_roofline.py) a step of the traced slice. Bound by HBM. The program multiplies every held expert, touched or not, so the share cannot pass 100% by miscounting; what it lacks to 100% is the untouched experts' stream and the matmuls' own shortfall."""
-from bench import peaks, ssm_moe_roofline
+"""What the routed latent experts' matmuls must read in a decode step over the memory bandwidth, as a share of their device time: the held experts that at least one live token chose in a step OF THE TRACED SLICE (``moe_touched`` over ``moe_steps`` of the slice's ``kvmini.emit`` spans, the mean over its steps, all expert layers) times one expert's int8 W1 and W2 with their scales, plus the latent activations of the assignments that landed here (read and written, bf16; the 45 s window's mean, under 0.2% of the bytes), over 819 GB/s, over the time of the operations that read an expert stack (found by shape, bench/ssm_moe_roofline.py) a step of the same slice. Bound by HBM. Count and time come from the same seconds (``conv_moe_roofline.expert_hbm_share``); in a saturated cell slice and window agree, so this reads what it read from the window's counters. What it lacks to 100% is the call's fixed time a layer and the matmuls' own shortfall. Nothing for a model without such blocks or a trace whose spans carry no counts."""
+from bench import conv_moe_roofline, ssm_moe_roofline
 
 
 def read(ctx):
-    m = ctx.memory["model"]
-    found = ssm_moe_roofline.decode_seconds_and_steps(
-        ctx.trace, m, ssm_moe_roofline.reads_expert_stack)
-    steps = ctx.counters.get("kvmini_tpu_decode_steps_total", 0.0)
-    touched = ctx.counters.get("kvmini_tpu_moe_expert_touches_total", 0.0)
-    if found is None or steps <= 0 or touched <= 0:
-        return None
-    landed = ctx.counters.get("kvmini_tpu_moe_local_assignments_total", 0.0)
-    need = (touched * ssm_moe_roofline.expert_bytes(m)
-            + ssm_moe_roofline.expert_activation_bytes(m, landed)) / steps
-    least_s = need / peaks.for_kind(ctx.device_kind)["hbm_bytes_per_s"]
-    return 100.0 * least_s / (found[0] / found[1])
+    return conv_moe_roofline.expert_hbm_share(ctx, ssm_moe_roofline)

@@ -1,4 +1,4 @@
-"""The arithmetic the prompts' real tokens need of the CHOSEN experts over the bf16 peak, as a share of the device time of the operations that read an expert stack in the prefill programs. Tokens and time come from the same traced seconds: the pieces the slice ran are counted by the length their flash-prefill calls show (calls of a length over the 12 a program makes, times that length), and what of a bucket is real comes from the program's ``prefill_tokens`` over ``prefill_bucket_tokens`` counters (the window's ratio); times 6 chosen experts x 12 layers x 11.80 MFLOP, over 197 TFLOP/s. Bound by compute. The program runs all 64 experts on every token of the padded bucket where a token chose 6, so the share stays under a tenth; what it lacks to 100% is that, the padding, and the matmuls' own shortfall. Nothing for a model without such layers."""
+"""The arithmetic the prompts' real tokens need of the CHOSEN experts over the bf16 peak, as a share of the device time of the operations that read an expert stack in the prefill programs. Tokens and time come from the same traced seconds: the pieces the slice ran are counted by the length their flash-prefill calls show (calls of a length over the 12 a program makes, times that length), and what of a bucket is real comes from the program's ``prefill_tokens`` over ``prefill_bucket_tokens`` counters: that ratio alone is the 45 s WINDOW's, and padding does not follow the live rows, so it is safe beside a slice's time; times 6 chosen experts x 12 layers x 11.80 MFLOP, over 197 TFLOP/s. Bound by compute. Every prompt here is longer than one row tile, so since PR 45 each piece takes the grouped dispatch (each expert multiplied by the rows that chose it): what the share lacks to 100% is the padding inside a group's tiles and the grouped matmul's own shortfall (47.6% at PR 46; under a tenth while every held expert ran on every token). Nothing for a model without such layers."""
 from bench import peaks, window_moe_roofline
 
 

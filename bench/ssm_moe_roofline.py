@@ -101,13 +101,16 @@ def in_expert_block(op_name: str, m: dict) -> bool:
                or (len(s) >= 2 and s[-2] == x["top_k"]) for s in shapes_in(op_name))
 
 
-def decode_seconds_and_steps(trace: dict | None, m: dict, rule) -> tuple[float, int] | None:
-    """Seconds ``jit_decode`` spent in the operations ``rule`` names over the
-    traced window, and the steps it ran; nothing without a trace, for a model
+def seconds_and_runs(trace: dict | None, m: dict, program: str, rule) -> tuple[float, int] | None:
+    """Seconds ``program`` spent in the operations ``rule`` names over the
+    traced window, and the times it ran; nothing without a trace, for a model
     without such blocks or without such operations."""
-    steps = len((trace or {}).get("programs", {}).get("jit_decode", []))
-    if sizes(m) is None or not steps:
+    if sizes(m) is None:
         return None
-    took = sum(op["seconds"] for name, op in trace["ops"].items()
-               if name.startswith("jit_decode/") and rule(name, m))
-    return (took, steps) if took else None
+    return hybrid_roofline.seconds_and_runs(trace, program, lambda name: rule(name, m))
+
+
+def decode_seconds_and_steps(trace: dict | None, m: dict, rule) -> tuple[float, int] | None:
+    """``seconds_and_runs`` of ``jit_decode``: the seconds in the operations
+    ``rule`` names, and the steps it ran."""
+    return seconds_and_runs(trace, m, "jit_decode", rule)

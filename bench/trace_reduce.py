@@ -2,8 +2,11 @@
 
 ``events_of`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote (with
 nothing but JAX) into plain events: plane, line, name, start and duration in
-nanoseconds. ``reduce`` works on those events alone, so that it can be checked
-on the small recorded trace under ``bench/testdata/``.
+nanoseconds, and for the program's own ``kvmini.*`` host spans the arguments
+they were opened with (``args``; no other event keeps any: the device's
+operations are tens of thousands and need none). ``reduce`` works on those
+events alone, so that it can be checked on the small recorded traces under
+``bench/testdata/``.
 
 What a TPU trace holds (read by hand first, PR 25): one plane a device,
 ``/device:TPU:<n>``, with the lines ``XLA Modules`` (one event per executed
@@ -11,7 +14,9 @@ program: ``jit_decode(...)``, ``jit_prefill(...)``, ``jit_first(...)``) and
 ``XLA Ops`` (every operation inside them, nested: a ``%while`` spans its
 body); one plane ``/host:CPU`` whose thread lines carry the program's
 ``jax.profiler.TraceAnnotation`` spans (``kvmini.decode_dispatch``,
-``kvmini.decode_retire``). All planes share one clock.
+``kvmini.decode_retire``, ``kvmini.emit`` with the experts its sweep touched,
+``kvmini.prefill`` with its piece's real tokens; docs/TRACING.md). All planes
+share one clock.
 
 - busy: the union of the intervals in which an operation ran on the device
   (``XLA Ops``), averaged over the device planes; the window is the span from
@@ -23,6 +28,15 @@ body); one plane ``/host:CPU`` whose thread lines carry the program's
 - ``breakdown.idle_gaps``: the longest stretches with no operation on the
   device, each named by the host span that covers most of it, or
   ``other-host``.
+- ``spans``: what the program's ``kvmini.*`` spans counted in the SAME seconds
+  the device times come from: for each span name, how many began inside the
+  device's window (its first to its last event) and the sum of each numeric
+  argument over them. A reader that divides a count by a device time takes
+  both from here (``bench/metrics.span_sums``), never the count from the 45 s
+  window's counters: the slice holds more or fewer live rows than the window's
+  mean, and a time that follows the count then reads a share that is off by
+  that ratio, over 100% on a quiet slice (PR 48). This is the one place that
+  says what "inside the slice" means.
 """
 
 from __future__ import annotations
@@ -37,6 +51,7 @@ from pathlib import Path
 
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 HOST_SPANS = ("kvmini.decode_dispatch", "kvmini.decode_retire")
+SPAN_PREFIX = "kvmini."
 _LAYOUT = re.compile(r"\{[^{}]*\}")
 
 
@@ -47,12 +62,22 @@ def events_of(trace_dir) -> list[dict]:
     if not paths:
         return []
     data = jax.profiler.ProfileData.from_file(paths[-1])
-    return [{"plane": plane.name, "line": line.name, "name": ev.name,
-             "start_ns": float(ev.start_ns), "dur_ns": float(ev.duration_ns)}
-            for plane in data.planes for line in plane.lines for ev in line.events]
+    events = []
+    for plane in data.planes:
+        own = plane.name.startswith("/host:")
+        for line in plane.lines:
+            for ev in line.events:
+                event = {"plane": plane.name, "line": line.name, "name": ev.name,
+                         "start_ns": float(ev.start_ns), "dur_ns": float(ev.duration_ns)}
+                if own and ev.name.startswith(SPAN_PREFIX) and (args := dict(ev.stats)):
+                    event["args"] = args
+                events.append(event)
+    return events
 
 
 def load_events(path) -> list[dict]:
+    """A recorded head (``bench/testdata/``): the events as ``events_of`` gave
+    them, with ``args`` on the spans that had any, or on none (the older heads)."""
     with gzip.open(path, "rt") as f:
         return json.load(f)
 
@@ -94,13 +119,37 @@ def self_times(ops: list[dict]) -> list[float]:
     return own
 
 
+def span_counts(events: list[dict]) -> dict:
+    """``{name: {"count": n, "sums": {argument: sum}}}`` over the program's
+    ``kvmini.*`` host spans that BEGIN between the device's first event and its
+    last: the seconds every device time of the trace comes from. Sums and not
+    the spans, so that what is kept of a trace stays small; an argument that is
+    no number (a request's id) is not summed."""
+    device = [e for e in events if e["plane"].startswith("/device:")]
+    if not device:
+        return {}
+    lo = min(e["start_ns"] for e in device)
+    hi = max(e["start_ns"] + e["dur_ns"] for e in device)
+    out: dict[str, dict] = {}
+    for e in events:
+        if (e["plane"].startswith("/host:") and e["name"].startswith(SPAN_PREFIX)
+                and lo <= e["start_ns"] <= hi):
+            span = out.setdefault(e["name"], {"count": 0, "sums": {}})
+            span["count"] += 1
+            for key, value in (e.get("args") or {}).items():
+                if isinstance(value, (int, float)) and not isinstance(value, bool):
+                    span["sums"][key] = span["sums"].get(key, 0) + value
+    return out
+
+
 def reduce(events: list[dict]) -> dict:
     """``busy_s``, ``window_s``, each program's step times (``programs``),
-    seconds and calls by operation (``ops``) and the ``breakdown`` of a traced
-    window. Empty (``busy_s`` 0) when the trace holds no device plane."""
+    seconds and calls by operation (``ops``), what the program's spans counted
+    in those seconds (``spans``) and the ``breakdown`` of a traced window.
+    Empty (``busy_s`` 0) when the trace holds no device plane."""
     device_planes = sorted({e["plane"] for e in events if e["plane"].startswith("/device:")})
     if not events or not device_planes:
-        return {"busy_s": 0.0, "window_s": 0.0, "programs": {}, "ops": {},
+        return {"busy_s": 0.0, "window_s": 0.0, "programs": {}, "ops": {}, "spans": {},
                 "breakdown": {"device_ops": [], "idle_gaps": []}}
     t_lo = min(e["start_ns"] for e in events)
     t_hi = max(e["start_ns"] + e["dur_ns"] for e in events)
@@ -145,6 +194,7 @@ def reduce(events: list[dict]) -> dict:
         "window_s": window_ns / 1e9,
         "programs": {p: sorted(d / 1e9 for d in durs) for p, durs in programs.items()},
         "ops": {k: {"seconds": v / 1e9, "calls": op_count[k]} for k, v in op_total.items()},
+        "spans": span_counts(events),
         "breakdown": {
             "device_ops": [[k, v / 1e9] for k, v in
                            sorted(op_self.items(), key=lambda kv: -kv[1])[:10]],

@@ -119,12 +119,9 @@ def seconds_and_runs(trace: dict | None, m: dict, program: str, rule) -> tuple[f
     operations ``rule`` names over the traced window, and the times it ran;
     nothing without a trace, for a model without such layers or without such
     operations."""
-    runs = len((trace or {}).get("programs", {}).get(program, []))
-    if sizes(m) is None or not runs:
+    if sizes(m) is None:
         return None
-    took = sum(op["seconds"] for name, op in trace["ops"].items()
-               if name.startswith(program + "/") and rule(name, m))
-    return (took, runs) if took else None
+    return hybrid_roofline.seconds_and_runs(trace, program, lambda name: rule(name, m))
 
 
 def flash_calls(trace: dict | None, m: dict) -> dict[int, tuple[float, int]]:

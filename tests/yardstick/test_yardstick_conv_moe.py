@@ -254,23 +254,37 @@ def test_the_readers_on_the_recorded_trace():
     # the experts are four fifths of a step, the convolutions a twentieth
     assert 0.7 * step_ms < got["glu_moe_decode_ms"] < 0.9 * step_ms
     assert 0.02 * step_ms < got["short_conv_decode_ms"] < 0.08 * step_ms
-    # 13.5 touched experts a layer of 32, and 440 assignments' activations, over the stream's time
+    # the slice's own ten retired steps touched 11.8 experts a layer of 32 (the window's counters
+    # say 13.5: not read), with 440 assignments' activations, over the stream's time
+    emits = [e["args"] for e in events if e["name"] == "kvmini.emit"]
+    assert len(emits) == 11 and trace["spans"]["kvmini.emit"] == {
+        "count": 11, "sums": {"moe_steps": 11, "moe_touched": sum(a["moe_touched"] for a in emits)}}
+    touched = sum(a["moe_touched"] for a in emits) / 11.0
+    assert 11.5 * 22 < touched < 12.0 * 22
     stack_ms = 1000.0 * conv_moe_roofline.seconds_and_runs(
         trace, m, "jit_decode", conv_moe_roofline.reads_expert_stack)[0] / len(steps)
-    need_ms = (13.5 * 22 * 11_032_576 + 5.0 * 4 * 22 * 8192) / 819e9 * 1000.0
+    need_ms = (touched * 11_032_576 + 5.0 * 4 * 22 * 8192) / 819e9 * 1000.0
     assert got["glu_moe_expert_hbm_share"] == pytest.approx(100.0 * need_ms / stack_ms)
     assert 30.0 < got["glu_moe_expert_hbm_share"] < 50.0 and stack_ms <= got["glu_moe_decode_ms"]
-    # every expert touched: under 100 still, the stream itself runs at nine tenths of the peak
-    every = {**COUNTERS, "kvmini_tpu_moe_expert_touches_total": 3000.0 * 32 * 22}
-    full = config.load_module("readers", "glu_moe_expert_hbm_share").read(ctx(every, trace))
+    assert got["glu_moe_expert_hbm_share"] == conv_moe_roofline.expert_hbm_share(c, conv_moe_roofline)
+    # every expert touched in the slice: under 100 still, this program's stream (PR 40: every
+    # held expert multiplied) runs at nine tenths of the peak; the window's counters move nothing
+    every = {**trace, "spans": {"kvmini.emit": {"count": 10, "sums": {
+        "moe_steps": 10, "moe_touched": 10 * 32 * 22}}}}
+    full = config.load_module("readers", "glu_moe_expert_hbm_share").read(ctx(COUNTERS, every))
     assert 85.0 < full < 100.0
-    assert run.collect([{"name": "glu_moe_expert_hbm_share", "unit": "%"}], ctx(every, trace))
-    # the one prefill: the expert layers are most of it; 260 real tokens' chosen experts are a
-    # twentieth of what the bf16 peak does in the time all 32 experts took on 1024 positions
+    assert run.collect([{"name": "glu_moe_expert_hbm_share", "unit": "%"}], ctx(COUNTERS, every))
+    loud = {**COUNTERS, "kvmini_tpu_moe_expert_touches_total": 3000.0 * 32 * 22}
+    assert config.load_module("readers", "glu_moe_expert_hbm_share").read(ctx(loud, trace)) == (
+        got["glu_moe_expert_hbm_share"])
+    # the one prefill: the expert layers are most of it; the piece's own 567 real tokens (its
+    # span's, not the window's mean of 260) need of their chosen experts a tenth of what the
+    # bf16 peak does in the time all 32 experts took on 1024 positions
     assert 80.0 < got["glu_moe_prefill_share"] < 92.0
     stack_s = conv_moe_roofline.seconds_and_runs(
         trace, m, "jit_prefill", conv_moe_roofline.reads_expert_stack)[0]
-    flops = 260.0 * 22 * 4 * 3 * 2 * 2048 * 1792
+    assert trace["spans"]["kvmini.prefill"]["sums"]["prompt_tokens"] == 567
+    flops = 567.0 * 22 * 4 * 3 * 2 * 2048 * 1792
     assert got["glu_moe_prefill_flop_share"] == pytest.approx(100.0 * flops / 197e12 / stack_s)
     assert 1.0 < got["glu_moe_prefill_flop_share"] < 12.5
     # the rules name leaf operations only; an operation that reads a stack is the layer's

@@ -10,7 +10,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import check, config, run, ssm_moe_roofline, trace_reduce
+from bench import check, config, conv_moe_roofline, run, ssm_moe_roofline, trace_reduce
 from tests.yardstick.test_yardstick_hybrid import serve
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -265,15 +265,24 @@ def test_the_readers_on_the_recorded_trace():
     need_ms = 2 * 31.68 * 41_943_040 / 819e9 * 1000.0
     assert got["ssm_state_hbm_share"] == pytest.approx(100.0 * need_ms / got["ssm_decode_ms"])
     assert 20.0 < got["ssm_state_hbm_share"] < 100.0
-    # 95 touched experts a layer, ten layers, and 1,740 landed assignments' activations
+    # PR 32's head carries no counts on its spans: nothing to divide, so nothing is read
+    assert got["moe_expert_hbm_share"] is None and "kvmini.emit" in trace["spans"]
+    # the slice's retired steps touched 95 experts a layer, ten layers; 1,740 landed
+    # assignments' activations a step are the window's
+    n = len(steps)
+    counted = {**trace, "spans": {"kvmini.emit": {"count": n, "sums": {
+        "moe_steps": n, "moe_touched": 950 * n}}}}
     stack_ms = 1000.0 * ssm_moe_roofline.decode_seconds_and_steps(
-        trace, m, ssm_moe_roofline.reads_expert_stack)[0] / len(steps)
+        trace, m, ssm_moe_roofline.reads_expert_stack)[0] / n
     need_ms = (950 * ssm_moe_roofline.expert_bytes(m) + 1740 * 4096) / 819e9 * 1000.0
-    assert got["moe_expert_hbm_share"] == pytest.approx(100.0 * need_ms / stack_ms)
-    assert 30.0 < got["moe_expert_hbm_share"] < 100.0 and stack_ms <= got["moe_decode_ms"]
-    # every held expert touched: still under 100, because the program multiplies them all
-    every = {**COUNTERS, "kvmini_tpu_moe_expert_touches_total": 2000.0 * 128 * 10}
-    assert run.collect([{"name": "moe_expert_hbm_share", "unit": "%"}], ctx(every, trace))
+    share = config.load_module("readers", "moe_expert_hbm_share").read(ctx(COUNTERS, counted))
+    assert share == pytest.approx(100.0 * need_ms / stack_ms)
+    assert share == conv_moe_roofline.expert_hbm_share(ctx(COUNTERS, counted), ssm_moe_roofline)
+    assert 30.0 < share < 100.0 and stack_ms <= got["moe_decode_ms"]
+    # every held expert touched: still under 100, because that program multiplied them all
+    every = {**trace, "spans": {"kvmini.emit": {"count": n, "sums": {
+        "moe_steps": n, "moe_touched": 128 * 10 * n}}}}
+    assert run.collect([{"name": "moe_expert_hbm_share", "unit": "%"}], ctx(COUNTERS, every))
     assert run.collect([{"name": "ssm_state_hbm_share", "unit": "%"}], ctx(
         {**COUNTERS, "kvmini_tpu_linear_state_held_slot_steps_total": 0.0}, trace))
     # the rules name leaf operations only, and the state's are exactly its own

@@ -10,7 +10,7 @@ from pathlib import Path
 
 import pytest
 
-from bench import check, config, run, trace_reduce, traffic, window_moe_roofline
+from bench import check, config, conv_moe_roofline, run, trace_reduce, traffic, window_moe_roofline
 from tests.yardstick.test_yardstick_hybrid import serve
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -323,17 +323,22 @@ def test_the_readers_on_the_recorded_trace():
     assert len(kv) == 12 and all("kvmini_dense_decode" in n for n in kv)
     assert 0.05 * step_ms < got["mixed_kv_decode_ms"] < 0.2 * step_ms
     assert 0.6 * step_ms < got["reglu_moe_decode_ms"] < 0.8 * step_ms
-    # 24 touched experts a layer of 64, and 360 assignments' activations, over the stream's time
+    # the slice's own two retired steps touched 130 and 188 experts over the twelve layers (13 a
+    # layer of 64; the window's counters say 24: not read), with 360 assignments' activations
+    assert trace["spans"]["kvmini.emit"] == {"count": 2, "sums": {"moe_touched": 318, "moe_steps": 2}}
     stack_ms = 1000.0 * window_moe_roofline.seconds_and_runs(
         trace, m, "jit_decode", window_moe_roofline.reads_expert_stack)[0] / len(steps)
-    need_ms = (24.0 * 12 * 5_914_624 + 5.0 * 6 * 12 * 10_240) / 819e9 * 1000.0
+    need_ms = (159.0 * 5_914_624 + 5.0 * 6 * 12 * 10_240) / 819e9 * 1000.0
     assert got["reglu_moe_expert_hbm_share"] == pytest.approx(100.0 * need_ms / stack_ms)
-    assert 25.0 < got["reglu_moe_expert_hbm_share"] < 45.0 and stack_ms <= got["reglu_moe_decode_ms"]
-    # every expert touched: under 100 still
-    every = {**COUNTERS, "kvmini_tpu_moe_expert_touches_total": 3000.0 * 64 * 12}
-    full = config.load_module("readers", "reglu_moe_expert_hbm_share").read(ctx(every, trace))
+    assert 12.0 < got["reglu_moe_expert_hbm_share"] < 25.0 and stack_ms <= got["reglu_moe_decode_ms"]
+    assert got["reglu_moe_expert_hbm_share"] == conv_moe_roofline.expert_hbm_share(
+        c, window_moe_roofline)
+    # every expert touched in the slice: under 100 still (PR 44's program multiplies them all)
+    every = {**trace, "spans": {"kvmini.emit": {"count": 2, "sums": {
+        "moe_steps": 2, "moe_touched": 2 * 64 * 12}}}}
+    full = config.load_module("readers", "reglu_moe_expert_hbm_share").read(ctx(COUNTERS, every))
     assert 80.0 < full < 100.0
-    assert run.collect([{"name": "reglu_moe_expert_hbm_share", "unit": "%"}], ctx(every, trace))
+    assert run.collect([{"name": "reglu_moe_expert_hbm_share", "unit": "%"}], ctx(COUNTERS, every))
     # the one prefill: the expert layers are four fifths of it; the slice ran ONE piece of 4096
     # positions (twelve flash calls of that length), 72.3% of a bucket real by the window's
     # counters: the chosen experts' arithmetic is a twentieth of the peak over the stacks' time
