@@ -229,9 +229,10 @@ def require_state_in_place(engine, exes: dict[str, dict], dry: bool) -> None:
         f"kvmini_tpu_ssm_state_kernel={took['ssm']}")
     for lab in sorted(exes):
         say(f"  {lab}: state_copy_bytes={exes[lab]['state_copy_bytes']}")
-    if dry or not engine.cfg.is_hybrid or engine.cfg.is_conv_stack:
-        return          # a short-convolution stack keeps a tail and no recurrent state
-    kind = "ssm" if engine.cfg.is_pattern_stack else "linear"
+    cfg = engine.cfg
+    kind = "ssm" if cfg.n_ssm_layers else "linear" if cfg.n_linear_layers else None
+    if dry or kind is None:
+        return          # no layer of the plan keeps a recurrent state (a tail, a ring: no kernel)
     check(took[kind] == 1, f"the decode program did not take the {kind} state's kernel")
     n = exes["decode[chunk=1]"]["state_copy_bytes"]
     check(n == 0, f"decode[chunk=1] moves {n} bytes of state layers outside the kernel")

@@ -76,14 +76,102 @@ orthogonal architecture axes extend it to the other families:
   moe_glu_block). ``sliding_window`` (one window for every layer) and
   ``alt_sliding_window`` (even layers) stay what they are for the stacks
   that spell them so.
+
+**One list of layers.** The three spellings above (a string of characters,
+a tuple of kind names, two tuples of 0/1) and ``num_dense_layers`` are INPUTS,
+kept under their published names. ``_plan`` reads them once into
+``ModelConfig.layer_plan``: one ``Layer`` a layer, which says what the layer
+runs (``mixers``, ``ffn``), where its weights lie (``stack``/``index``,
+``ffn_stack``/``ffn_index``/``ffn_norm``), which cache leaves it owns
+(``cache``, ``kv``) and how its attention reads them (``window``, ``rope``,
+a ring or stripes), and what its router reads (``route_from_input``):
+
+    M -> ssm in "mamba"        * -> attn in "attn"       E -> latent experts
+    P -> ssm + attn side by side and an MLP, all three in "parallel"
+    "conv" / "full_attention" -> conv in "conv" / attn in "attn", then the
+        MLP in "mlp" (the first num_dense_layers) or GLU experts in "experts"
+    sliding_window_layout[l], rope_layout[l] -> attn in "attn" over a ring
+        ("k_win") or stripes ("k"), rotating or not, then GLU experts routed
+        from the layer's input
+    "linear_attention" / "full_attention" (the delta-rule period) -> the olmo2
+        block's linear or attention mixer in "linear" / "full", with its MLP
+    none of them -> cfg.block's attention + feed-forward in the flat tree
+
+Every count (``n_full_layers`` ...), the initialiser's stacks, the cache
+tree's leaves and the unrolled walk (models/llama.py) are read off the plan;
+nothing else reads a spelling. The two stacks that REPEAT are scanned, not
+walked: a uniform stack, and the delta-rule period (``layer_period``).
 """
 
 from __future__ import annotations
 
+from collections import Counter
 from dataclasses import dataclass, replace
+from functools import cached_property
 from typing import Optional
 
 import jax.numpy as jnp
+
+
+@dataclass(frozen=True)
+class Layer:
+    """One layer (or block) of ``ModelConfig.layer_plan``: plain static data,
+    the same whichever spelling published the stack."""
+    # sequence mixers on the layer's normed input, of "attn", "ssm" (Mamba-2),
+    # "conv" (short convolution), "linear" (delta rule); two run side by side
+    # and join the residual stream in one add; () = none (an expert block)
+    mixers: tuple[str, ...] = ("attn",)
+    # the weight stack of ``layers`` that holds the mixers and their norm, and
+    # this layer's place in it; None = the flat tree of a uniform stack
+    stack: Optional[str] = None
+    index: int = 0
+    # (leaf, layer index inside it) of every cache leaf the layer owns
+    cache: tuple[tuple[str, int], ...] = ()
+    # attention's K/V leaves among them: ("k", "v"), stripes as long as the
+    # context, or ("k_win", "v_win"), a ring of ``window`` positions
+    kv: Optional[tuple[str, str]] = None
+    # attention reaches the last ``window`` positions; None = every earlier one
+    window: Optional[int] = None
+    # False: no position enters this layer's attention, whatever the stack's
+    # ``rotary_dim`` (which is 0 for a stack that rotates nowhere)
+    rope: bool = True
+    # the feed-forward after the mixers: "mlp" (dense), "moe" (Mixtral's, in
+    # the uniform block), "glu" (routed GLU experts), "latent" (routed experts
+    # in a latent beside a shared one); None = none. Its weight stack, its
+    # place there and the gain that norms its input (None: ``cfg.block`` says
+    # how a uniform block norms)
+    ffn: Optional[str] = None
+    ffn_stack: Optional[str] = None
+    ffn_index: int = 0
+    ffn_norm: Optional[str] = None
+    route_from_input: bool = False    # the router reads the LAYER's input, not the ffn's
+
+    def at(self, leaf: str) -> int:
+        """The layer's index inside the cache leaf ``leaf``."""
+        return dict(self.cache)[leaf]
+
+    @property
+    def ring(self) -> bool:
+        return self.kv == ("k_win", "v_win")
+
+
+class LayerPlan(tuple):
+    """``ModelConfig.layer_plan``: one ``Layer`` a layer, in stack order;
+    ``leaves``, the cache tree's leaves (the spelling fixes them, so a leaf
+    stays where the cut stack leaves it no layer: a ring of none); and
+    ``n``, how many layers run each kind of mixer and of feed-forward and own
+    a layer of each leaf, counted once (the engine asks every sweep)."""
+
+    def __new__(cls, layers, leaves: tuple[str, ...]):
+        plan = super().__new__(cls, layers)
+        plan.leaves = leaves
+        plan.n = Counter(name for e in plan for name in (
+            *e.mixers, e.ffn, *(leaf for leaf, _ in e.cache)) if name)
+        return plan
+
+    def __getnewargs__(self):
+        # a config that has built its plan still copies and pickles
+        return tuple(self), self.leaves
 
 
 @dataclass(frozen=True)
@@ -353,12 +441,18 @@ class ModelConfig:
                                  "moe_shared_expert_intermediate_size >= 1")
             self._check_experts_held()
 
+    @cached_property
+    def layer_plan(self) -> LayerPlan:
+        """One ``Layer`` a layer: what every count below, the initialiser, the
+        cache and the walk read (``_plan``: the one reader of the spellings)."""
+        return _plan(self)
+
     @property
     def is_hybrid(self) -> bool:
-        """A stack that keeps, per slot and beside K/V, something that has no
-        positions: a recurrent state, a convolution tail, or both."""
-        return (self.layer_types is not None or self.is_pattern_stack
-                or self.is_window_stack)
+        """A stack that keeps, per slot and beside K/V stripes, something that
+        positions do not address: a recurrent state, a convolution tail, a
+        ring of K/V."""
+        return self.layer_plan.leaves != ("k", "v")
 
     @property
     def is_window_stack(self) -> bool:
@@ -366,20 +460,20 @@ class ModelConfig:
         K/V is a ring, beside global layers. The ring is what makes it a
         hybrid for the engine: positions do not address it, so it cannot be
         rewound, paged, shared or handed over as a stripe."""
-        return self.sliding_window_layout is not None
+        return "k_win" in self.layer_plan.leaves
 
     @property
     def window_layers(self) -> tuple[bool, ...]:
         """Per layer of the stack as it is cut: whether it is a window layer."""
-        return tuple(bool(v) for v in (self.sliding_window_layout or ())[: self.n_layers])
+        return tuple(e.ring for e in self.layer_plan) if self.is_window_stack else ()
 
     @property
     def n_window_layers(self) -> int:
-        return sum(self.window_layers)
+        return self.layer_plan.n["k_win"]
 
     @property
     def n_global_layers(self) -> int:
-        return self.n_layers - self.n_window_layers if self.is_window_stack else 0
+        return self.n_full_layers if self.is_window_stack else 0
 
     def ring_len(self, max_seq: int) -> int:
         """Positions of a window layer's ring in a cache ``max_seq`` long.
@@ -392,46 +486,42 @@ class ModelConfig:
 
     @property
     def is_pattern_stack(self) -> bool:
-        return self.hybrid_override_pattern is not None
+        """``hybrid_override_pattern``: single-mixer and two-mixer blocks."""
+        return "ssm_state" in self.layer_plan.leaves
 
     @property
     def is_conv_stack(self) -> bool:
         """``layer_types`` of short-convolution and attention operators."""
-        return self.layer_types is not None and "conv" in self.layer_types
+        return "conv_tail" in self.layer_plan.leaves
 
     @property
     def layer_period(self) -> int:
-        """Layers in one period of a linear-attention stack (k linear + 1 full)."""
-        if self.layer_types is None or self.is_conv_stack:
+        """Layers in one period of a linear-attention stack (k linear + 1
+        full): what its scan repeats. 1 for every other stack."""
+        if not self.n_linear_layers:
             return 1
-        return self.layer_types.index("full_attention") + 1
+        return [e.mixers for e in self.layer_plan].index(("attn",)) + 1
 
     @property
     def n_full_layers(self) -> int:
-        """Layers that hold K/V: all of them, a layer_types stack's
-        full-attention ones, or a pattern's '*' and 'P' blocks."""
-        if self.is_pattern_stack:
-            return sum(map(self.hybrid_override_pattern.count, "*P"))
-        if self.is_conv_stack:
-            return self.layer_types.count("full_attention")
-        if self.is_window_stack:
-            # the layers whose K/V is a stripe as long as the context
-            return self.n_global_layers
-        return self.n_layers // self.layer_period
+        """Layers whose K/V is a stripe as long as the context: all of a
+        uniform stack, a hybrid's full-attention ones, a pattern's '*' and
+        'P' blocks, a window stack's global layers."""
+        return self.layer_plan.n["k"]
 
     @property
     def n_conv_layers(self) -> int:
         """Layers that keep a short convolution's tail."""
-        return self.layer_types.count("conv") if self.is_conv_stack else 0
+        return self.layer_plan.n["conv"]
 
     @property
     def n_ssm_layers(self) -> int:
         """Blocks that keep a Mamba-2 state and tail: a pattern's 'M' and 'P'."""
-        return sum(map((self.hybrid_override_pattern or "").count, "MP"))
+        return self.layer_plan.n["ssm"]
 
     @property
     def n_parallel_layers(self) -> int:
-        return (self.hybrid_override_pattern or "").count("P")
+        return sum(len(e.mixers) == 2 for e in self.layer_plan)
 
     @property
     def block_mixers(self) -> int:
@@ -441,13 +531,10 @@ class ModelConfig:
 
     @property
     def n_expert_layers(self) -> int:
-        """Layers with routed experts: a pattern's 'E' blocks, a 'conv'
-        stack's layers past ``num_dense_layers``."""
-        if self.is_conv_stack:
-            return self.n_layers - self.num_dense_layers
-        if self.is_window_stack:
-            return self.n_layers
-        return (self.hybrid_override_pattern or "").count("E")
+        """Layers with routed experts that count their routing: a pattern's
+        'E' blocks, a 'conv' stack's layers past ``num_dense_layers``, every
+        layer of a window stack."""
+        return self.layer_plan.n["glu"] + self.layer_plan.n["latent"]
 
     @property
     def experts_held(self) -> int:
@@ -465,9 +552,7 @@ class ModelConfig:
 
     @property
     def n_linear_layers(self) -> int:
-        if self.is_conv_stack or self.is_window_stack:
-            return 0
-        return self.n_layers - self.n_full_layers
+        return self.layer_plan.n["linear"]
 
     @property
     def linear_conv_channels(self) -> int:
@@ -501,77 +586,129 @@ class ModelConfig:
 
     @property
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + blocks + head)."""
-        emb = self.vocab_size * self.d_model
-        if self.is_pattern_stack:
-            return 2 * emb + self.d_model + self._pattern_block_params()
-        if self.is_conv_stack:
-            head = 0 if self.tie_embeddings else emb
-            return emb + head + self.d_model + self._conv_stack_params()
-        if self.is_window_stack:
-            head = 0 if self.tie_embeddings else emb
-            hd = self.head_dim
-            attn = 2 * self.d_model * self.n_heads * hd + 2 * self.d_model * self.n_kv_heads * hd
-            experts = (self.d_model * self.n_routed_experts
-                       + self.experts_held * 3 * self.d_model * self.moe_intermediate_size)
-            return (emb + head + self.d_model
-                    + self.n_layers * (attn + experts + 2 * self.d_model))
-        qo = self.d_model * self.n_heads * self.head_dim  # q and o projections
-        attn = 2 * qo + 2 * self.d_model * (self.n_kv_heads * self.head_dim)
-        mlp = (2 if self.block == "phi" else 3) * self.d_model * self.d_ff
-        if self.is_moe:
-            mlp = self.n_experts * mlp + self.d_model * self.n_experts
+        """Approximate parameter count: embeddings, head, and every mixer,
+        feed-forward and gain of the plan's layers, the experts held here."""
+        d, hd, emb = self.d_model, self.head_dim, self.vocab_size * self.d_model
+        attn = 2 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
         if self.attn_bias:
-            attn += self.n_heads * self.head_dim + 2 * self.n_kv_heads * self.head_dim
-        norms = (4 if self.block == "gemma2" else 2) * self.d_model
+            attn += self.n_heads * hd + 2 * self.n_kv_heads * hd
         if self.block == "olmo2":
-            attn += self.n_heads * self.head_dim + self.n_kv_heads * self.head_dim  # QK-norm gains
-        head = 0 if self.tie_embeddings else self.vocab_size * self.d_model
-        blocks = self.n_full_layers * (attn + mlp + norms)
-        if self.is_hybrid:
-            h = self.linear_num_key_heads
-            kd, vd = h * self.linear_key_head_dim, h * self.linear_value_head_dim
-            mixer = (
-                self.d_model * (2 * kd + 2 * vd)          # q, k, v, output gate
-                + vd * self.d_model                        # o
-                + 2 * self.d_model * h                     # a, b
-                + self.linear_conv_channels * self.linear_conv_kernel_dim
-                + 2 * h + self.linear_value_head_dim       # A_log, dt_bias, norm gain
-            )
-            blocks += self.n_linear_layers * (mixer + mlp + norms)
-        return emb + blocks + self.d_model + head
-
-    def _pattern_block_params(self) -> int:
-        """Parameters of a pattern stack's blocks, the experts held here."""
-        d = self.d_model
+            attn += self.n_heads * hd + self.n_kv_heads * hd    # QK-norm over the projections
+        elif self.is_conv_stack:
+            attn += 2 * hd                                       # QK-norm over one head
         di, ch = self.mamba_d_inner, self.mamba_conv_channels
-        mamba = (d * (di + ch + self.mamba_num_heads) + di * d   # in_proj, out_proj
-                 + ch * (self.conv_kernel + 1)                    # taps and bias
-                 + 3 * self.mamba_num_heads + di)                 # A_log, D, dt_bias, gain
-        attn = 2 * d * self.n_heads * self.head_dim + 2 * d * self.n_kv_heads * self.head_dim
+        h = self.linear_num_key_heads
+        kd, vd = h * self.linear_key_head_dim, h * self.linear_value_head_dim
         lat, ff, sh = (self.moe_latent_size, self.moe_intermediate_size,
                        self.moe_shared_expert_intermediate_size)
-        experts = (d * self.n_routed_experts + self.n_routed_experts  # router, bias
-                   + 2 * d * lat + 2 * d * sh + self.experts_held * 2 * lat * ff)
-        n_p = self.n_parallel_layers
-        # a 'P' block: both mixers off one norm, then a pre-normed SwiGLU MLP
-        parallel = mamba + attn + 3 * d * self.d_ff + 2 * d
-        return ((self.n_ssm_layers - n_p) * (mamba + d) + (self.n_full_layers - n_p) * (attn + d)
-                + self.n_expert_layers * (experts + d) + n_p * parallel)
-
-    def _conv_stack_params(self) -> int:
-        """Parameters of a 'conv' stack's layers, the experts held here."""
-        d, hd = self.d_model, self.head_dim
-        conv = 4 * d * d + self.conv_L_cache * d           # in_proj (3d), out_proj, taps
-        attn = 2 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + 2 * hd
-        experts = (d * self.n_routed_experts + self.n_routed_experts   # router, bias
-                   + self.experts_held * 3 * d * self.moe_intermediate_size)
-        return (self.n_conv_layers * conv + self.n_full_layers * attn
-                + self.num_dense_layers * 3 * d * self.d_ff
-                + self.n_expert_layers * experts + self.n_layers * 2 * d)
+        mlp = (2 if self.block == "phi" else 3) * d * self.d_ff
+        router = d * self.n_routed_experts
+        size = {
+            "attn": attn,
+            "ssm": (d * (di + ch + self.mamba_num_heads) + di * d      # in_proj, out_proj
+                    + ch * (self.conv_kernel + 1)                       # taps and bias
+                    + 3 * self.mamba_num_heads + di),                   # A_log, D, dt_bias, gain
+            "conv": 4 * d * d + self.conv_L_cache * d,                  # in_proj (3d), out_proj, taps
+            "linear": (d * (2 * kd + 2 * vd) + vd * d + 2 * d * h       # q k v gate, o, a b
+                       + self.linear_conv_channels * self.linear_conv_kernel_dim
+                       + 2 * h + self.linear_value_head_dim),           # A_log, dt_bias, norm gain
+            "mlp": mlp,
+            "moe": self.n_experts * mlp + d * self.n_experts,
+            "glu": router + self.experts_held * 3 * d * ff,
+            "latent": (router + self.n_routed_experts                   # router, selection bias
+                       + 2 * d * lat + 2 * d * sh + self.experts_held * 2 * lat * ff),
+        }
+        gain = (2 if self.block == "gemma2" else 1) * d     # gemma2: a norm before and after
+        layers = sum(
+            sum(size[m] for m in e.mixers) + (size[e.ffn] if e.ffn else 0)
+            + (bool(e.mixers) + bool(e.ffn)) * gain
+            # a sigmoid router's selection bias; the softmax router that
+            # reads the layer's input has none
+            + (self.n_routed_experts if e.ffn == "glu" and not e.route_from_input else 0)
+            for e in self.layer_plan)
+        return emb + (0 if self.tie_embeddings else emb) + d + layers
 
     def scaled(self, **kwargs) -> "ModelConfig":
         return replace(self, **kwargs)
+
+
+def _plan(cfg: ModelConfig) -> LayerPlan:
+    """``cfg.layer_plan``, from whichever spelling names the stack's layers:
+    the ONE place that reads ``sliding_window_layout`` / ``rope_layout``,
+    ``hybrid_override_pattern``, ``layer_types`` and ``num_dense_layers``
+    (``__post_init__`` has validated them). A new spelling is read here and
+    nowhere else."""
+    seen: dict[str, int] = {}
+
+    def nth(name: str) -> int:
+        """How many layers before this one took ``name``: its index there."""
+        seen[name] = seen.get(name, 0) + 1
+        return seen[name] - 1
+
+    def owns(*leaves: str) -> tuple[tuple[str, int], ...]:
+        i = nth(leaves[0])
+        return tuple((leaf, i) for leaf in leaves)
+
+    stripes, state = ("k", "v"), ("ssm_state", "ssm_conv")
+    # every attention layer but a ring's shares the stack's one window
+    attn = dict(kv=stripes, window=cfg.sliding_window)
+    layers: list[Layer] = []
+    if cfg.sliding_window_layout is not None:
+        # attention then routed experts in every layer, two stacks n_layers long
+        ring = ("k_win", "v_win")
+        for l in range(cfg.n_layers):
+            kv, window = ((ring, cfg.sliding_window_size) if cfg.sliding_window_layout[l]
+                          else (stripes, None))
+            layers.append(Layer(
+                ("attn",), "attn", l, owns(*kv), kv, window, rope=bool(cfg.rope_layout[l]),
+                ffn="glu", ffn_stack="experts", ffn_index=l, ffn_norm="ffn_norm",
+                route_from_input=True))
+        return LayerPlan(layers, stripes + ring)
+    if cfg.hybrid_override_pattern is not None:
+        # one stack a character; a block has one norm, whatever it runs
+        for ch in cfg.hybrid_override_pattern:
+            if ch == "M":
+                layers.append(Layer(("ssm",), "mamba", nth("mamba"), owns(*state)))
+            elif ch == "*":
+                layers.append(Layer(("attn",), "attn", nth("attn"), owns(*stripes), **attn))
+            elif ch == "P":
+                i = nth("parallel")
+                layers.append(Layer(("ssm", "attn"), "parallel", i, owns(*state) + owns(*stripes),
+                                    ffn="mlp", ffn_stack="parallel", ffn_index=i,
+                                    ffn_norm="mlp_norm", **attn))
+            else:
+                layers.append(Layer((), ffn="latent", ffn_stack="experts",
+                                    ffn_index=nth("experts"), ffn_norm="norm_in"))
+        return LayerPlan(layers, stripes + state)
+    if cfg.layer_types is not None and "conv" in cfg.layer_types:
+        # an operator's stack and a feed-forward's stack a layer, four in all
+        for l, kind in enumerate(cfg.layer_types):
+            ffn = (dict(ffn="mlp", ffn_stack="mlp", ffn_index=l) if l < cfg.num_dense_layers
+                   else dict(ffn="glu", ffn_stack="experts", ffn_index=l - cfg.num_dense_layers))
+            ffn["ffn_norm"] = "ffn_norm"
+            if kind == "conv":
+                layers.append(Layer(("conv",), "conv", nth("conv"), owns("conv_tail"), **ffn))
+            else:
+                layers.append(Layer(("attn",), "attn", nth("attn"), owns(*stripes), **attn, **ffn))
+        return LayerPlan(layers, stripes + ("conv_tail",))
+    if cfg.layer_types is not None:
+        # the delta-rule period: each kind's whole block (cfg.block) in its stack
+        for kind in cfg.layer_types:
+            if kind == "linear_attention":
+                i = nth("linear")
+                layers.append(Layer(("linear",), "linear", i, owns("lin_state", "lin_conv"),
+                                    ffn="mlp", ffn_stack="linear", ffn_index=i))
+            else:
+                i = nth("full")
+                layers.append(Layer(("attn",), "full", i, owns(*stripes), ffn="mlp",
+                                    ffn_stack="full", ffn_index=i, **attn))
+        return LayerPlan(layers, stripes + ("lin_state", "lin_conv"))
+    # a uniform stack: cfg.block's pair in every layer of the flat tree
+    for l in range(cfg.n_layers):
+        local = cfg.sliding_window if not (cfg.alt_sliding_window and l % 2) else None
+        layers.append(Layer(("attn",), None, l, owns(*stripes), stripes, local,
+                            ffn="moe" if cfg.is_moe else "mlp", ffn_index=l))
+    return LayerPlan(layers, stripes)
 
 
 # Presets. "llama-tiny" is the CI/test model (runs on CPU in <1s); the 8B and

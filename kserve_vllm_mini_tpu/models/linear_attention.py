@@ -55,7 +55,7 @@ _HI = jax.lax.Precision.HIGHEST
 def mixer_weight_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
     """Per-layer shape of every matmul of the linear mixer, ``[in, out]``.
     The int8 checkpoint tree quantizes the five projections
-    (ops/quant.py HYBRID_QUANTIZABLE); wa, wb, the convolution taps,
+    (ops/quant.py QUANTIZABLE); wa, wb, the convolution taps,
     A_log, dt_bias and the gains stay as they are."""
     h = cfg.linear_num_key_heads
     kd, vd = h * cfg.linear_key_head_dim, h * cfg.linear_value_head_dim

@@ -36,40 +36,44 @@ Params = dict[str, Any]
 # "k_s" is the static flag that selects the quantized cache path)
 KVCache = dict[str, jnp.ndarray]
 
-def _stacked_weight_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
-    """Per-layer shape of every stacked transformer matmul weight (last two
-    axes are [in, out]; MoE expert weights carry a leading expert axis), in a
-    fixed order shared by the bf16 and quantized initializers (the order
-    defines which RNG key each weight gets, so the two inits draw identical
-    values)."""
-    hd, kvd = cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
-    shapes: dict[str, tuple[int, ...]] = {
-        "wq": (cfg.d_model, cfg.n_heads * hd),
-        "wk": (cfg.d_model, kvd),
-        "wv": (cfg.d_model, kvd),
-        "wo": (cfg.n_heads * hd, cfg.d_model),
-    }
+def matmul_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
+    """Per-layer shape of every matmul (the last two axes are [in, out];
+    expert weights carry a leading expert axis), by kind of mixer and of
+    feed-forward as ``cfg.layer_plan`` names them (``Layer.mixers``,
+    ``Layer.ffn``). THE table: the initialisers stack a kind's rows for the
+    layers that run it, in this order (the order fixes which rng key each
+    weight draws from, so the bf16 and the quantized init draw the same
+    values), and the int8 tree quantizes the rows ops/quant.py QUANTIZABLE
+    names (all but the routers and the delta rule's ``wa``, ``wb``). A new
+    kind of layer adds its row here and its names there."""
+    from kserve_vllm_mini_tpu.models import linear_attention, mamba, short_conv
+
+    d, hd, ff, n = cfg.d_model, cfg.head_dim, cfg.d_ff, cfg.n_experts
+    mid, lat, held = cfg.moe_intermediate_size, cfg.moe_latent_size, cfg.experts_held
+    shared = cfg.moe_shared_expert_intermediate_size
+    mlp = {"w_gate": (d, ff), "w_up": (d, ff), "w_down": (ff, d)}
     if cfg.block == "phi":
-        # phi MLP is fc1/fc2 (up/down) with GELU — no gate projection
-        shapes.update({
-            "w_up": (cfg.d_model, cfg.d_ff),
-            "w_down": (cfg.d_ff, cfg.d_model),
-        })
-        return shapes
-    if cfg.is_moe:
-        shapes.update({
-            "w_gate": (cfg.n_experts, cfg.d_model, cfg.d_ff),
-            "w_up": (cfg.n_experts, cfg.d_model, cfg.d_ff),
-            "w_down": (cfg.n_experts, cfg.d_ff, cfg.d_model),
-            "router": (cfg.d_model, cfg.n_experts),
-        })
-    else:
-        shapes.update({
-            "w_gate": (cfg.d_model, cfg.d_ff),
-            "w_up": (cfg.d_model, cfg.d_ff),
-            "w_down": (cfg.d_ff, cfg.d_model),
-        })
-    return shapes
+        del mlp["w_gate"]         # phi MLP is fc1/fc2 (up/down) with GELU: no gate
+    return {
+        "attn": {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
+                 "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)},
+        "ssm": mamba.mixer_weight_shapes(cfg),
+        "conv": short_conv.mixer_weight_shapes(cfg),
+        "linear": linear_attention.mixer_weight_shapes(cfg),
+        "mlp": mlp,
+        "moe": {"w_gate": (n, d, ff), "w_up": (n, d, ff), "w_down": (n, ff, d),
+                "router": (d, n)},
+        "glu": {"w_gate": (held, d, mid), "w_up": (held, d, mid), "w_down": (held, mid, d)},
+        "latent": {"w_in": (d, lat), "w_out": (lat, d), "w1": (held, lat, mid),
+                   "w2": (held, mid, lat), "s1": (d, shared), "s2": (shared, d)},
+    }
+
+
+def _stacked_weight_shapes(cfg: ModelConfig) -> dict[str, tuple[int, ...]]:
+    """A uniform stack's matmuls: its one block's attention and feed-forward."""
+    shapes = matmul_shapes(cfg)
+    moe = cfg.is_moe and cfg.block != "phi"
+    return {**shapes["attn"], **shapes["moe" if moe else "mlp"]}
 
 
 def _init_keys(rng: jax.Array, cfg: ModelConfig) -> dict[str, jax.Array]:
@@ -84,6 +88,21 @@ def _nrm(key: jax.Array, shape: tuple, dt) -> jnp.ndarray:
     return (jax.random.normal(key, shape, dtype=jnp.float32) * 0.02).astype(dt)
 
 
+def stack(key: jax.Array, n: int, shape: tuple, dt, fn) -> Any:
+    """``n`` layers of one matmul, each drawn from its own key inside a scan
+    and stored as ``fn`` of it: ``init_params_quantized`` never holds more
+    than one layer in full precision."""
+    def body(_, k):
+        return None, fn(_nrm(k, shape, dt))
+
+    return jax.lax.scan(body, None, jax.random.split(key, n))[1]
+
+
+def gains(key: jax.Array, n: int, width: int, dt) -> jnp.ndarray:
+    """Norm gains that show when they are left out (not 1)."""
+    return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
+
+
 def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
     """Shared init skeleton. ``leaf_fn(w)`` maps each per-layer bf16 matmul
     weight to its stored leaf inside the per-layer scan — identity for the
@@ -92,14 +111,10 @@ def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
     the equivalence oracle in tests/test_quant.py rests on)."""
     dt = cfg.jnp_dtype
     keys = _init_keys(rng, cfg)
-    if cfg.is_pattern_stack:
-        return _init_pattern(keys, cfg, leaf_fn)
-    if cfg.is_conv_stack:
-        return _init_conv_stack(keys, cfg, leaf_fn)
-    if cfg.is_window_stack:
-        return _init_window_stack(keys, cfg, leaf_fn)
-    if cfg.is_hybrid:
+    if cfg.layer_period > 1:
         return _init_hybrid(keys, cfg, leaf_fn)
+    if cfg.is_hybrid:
+        return _init_stacks(keys, cfg, leaf_fn)
     L = cfg.n_layers
 
     layers: Params = {"attn_norm": jnp.ones((L, cfg.d_model), dtype=dt)}
@@ -120,89 +135,31 @@ def _init_impl(rng: jax.Array, cfg: ModelConfig, leaf_fn) -> Params:
     else:
         layers["mlp_norm"] = jnp.ones((L, cfg.d_model), dtype=dt)
     for name, shape in _stacked_weight_shapes(cfg).items():
-        lkeys = jax.random.split(keys[name], L)
         # the router is accuracy-critical and noise-level bytes — it stays
         # full precision even in the int8 tree (models/moe.py contract)
-        fn = leaf_fn if name != "router" else (lambda w: w)
-
-        def body(_, k, s=shape, f=fn):
-            return None, f(_nrm(k, s, dt))
-
-        _, layers[name] = jax.lax.scan(body, None, lkeys)
+        layers[name] = stack(keys[name], L, shape, dt,
+                              leaf_fn if name != "router" else (lambda w: w))
     if cfg.attn_bias:
         hd, kvd = cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
         layers["bq"] = jnp.zeros((L, cfg.n_heads * hd), dtype=dt)
         layers["bk"] = jnp.zeros((L, kvd), dtype=dt)
         layers["bv"] = jnp.zeros((L, kvd), dtype=dt)
 
-    params: Params = {
-        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
-        "layers": layers,
-        "final_norm": (
-            jnp.zeros((cfg.d_model,), dtype=dt) if cfg.block == "gemma2"
-            else jnp.ones((cfg.d_model,), dtype=dt)
-        ),
-    }
+    params = _tree_around(keys, cfg, layers)
+    if cfg.block == "gemma2":
+        params["final_norm"] = jnp.zeros((cfg.d_model,), dtype=dt)
     if cfg.block == "phi":
         params["final_norm_b"] = jnp.zeros((cfg.d_model,), dtype=dt)
         params["lm_head_b"] = jnp.zeros((cfg.vocab_size,), dtype=dt)
-    if not cfg.tie_embeddings:
-        params["lm_head"] = _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt)
     return params
 
 
-def _init_hybrid(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
-    """A hybrid stack's tree: ``layers`` holds each kind's weights stacked
-    on their own, ``{"full": [n_full, ...], "linear": [n_linear, ...]}``,
-    in stack order within the kind. Gains, taps and gate parameters get
-    values that show when they are left out (not 1, not 0)."""
-    from kserve_vllm_mini_tpu.models.linear_attention import mixer_weight_shapes
-    from kserve_vllm_mini_tpu.ops.quant import HYBRID_QUANTIZABLE
-
+def _tree_around(keys: dict[str, jax.Array], cfg: ModelConfig, layers: Params) -> Params:
+    """The parameter tree around ``layers``: embedding, final gain, head."""
     dt = cfg.jnp_dtype
-    shapes = _stacked_weight_shapes(cfg)
-
-    def stack(key, n, shape, fn):
-        def body(_, k):
-            return None, fn(_nrm(k, shape, dt))
-
-        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
-
-    def gains(key, n, width):
-        return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
-
-    def mlp_and_norms(key, n):
-        ks = jax.random.split(key, 5)
-        out = {name: stack(k, n, shapes[name], leaf_fn)
-               for name, k in zip(("w_gate", "w_up", "w_down"), ks)}
-        out["post_attn_norm"] = gains(ks[3], n, cfg.d_model)
-        out["post_mlp_norm"] = gains(ks[4], n, cfg.d_model)
-        return out
-
-    nf, nl, h = cfg.n_full_layers, cfg.n_linear_layers, cfg.linear_num_key_heads
-    kf = jax.random.split(keys["wq"], 8)
-    full: Params = mlp_and_norms(kf[0], nf)
-    for name, k in zip(("wq", "wk", "wv", "wo"), kf[1:5]):
-        full[name] = stack(k, nf, shapes[name], leaf_fn)
-    # a hybrid stack is an olmo2 block (ModelConfig holds it to that): QK-norm
-    full["q_norm"] = gains(kf[5], nf, cfg.n_heads * cfg.head_dim)
-    full["k_norm"] = gains(kf[6], nf, cfg.n_kv_heads * cfg.head_dim)
-    kl = jax.random.split(keys["wk"], 12)
-    lin: Params = mlp_and_norms(kl[0], nl)
-    for (name, shape), k in zip(mixer_weight_shapes(cfg).items(), kl[1:8]):
-        # wa, wb [d, heads] stay as they are in the int8 tree (ops/quant.py)
-        lin[name] = stack(k, nl, shape,
-                          leaf_fn if name in HYBRID_QUANTIZABLE else (lambda w: w))
-    lin["conv"] = (0.3 * jax.random.normal(
-        kl[8], (nl, cfg.linear_conv_kernel_dim, cfg.linear_conv_channels), jnp.float32)
-    ).astype(dt)
-    # decays between ~0.5 and ~0.999 a step at a zero pre-activation
-    lin["A_log"] = jnp.log(jax.random.uniform(kl[9], (nl, h), jnp.float32, 0.5, 4.0))
-    lin["dt_bias"] = jax.random.uniform(kl[10], (nl, h), jnp.float32, -6.0, -1.0)
-    lin["o_norm"] = gains(kl[11], nl, cfg.linear_value_head_dim)
     params: Params = {
         "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
-        "layers": {"full": full, "linear": lin},
+        "layers": layers,
         "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
     }
     if not cfg.tie_embeddings:
@@ -210,8 +167,48 @@ def _init_hybrid(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Param
     return params
 
 
-# a hybrid_override_pattern's characters -> the stack of ``layers`` each kind lives in
-PATTERN_KINDS = {"M": "mamba", "*": "attn", "E": "experts", "P": "parallel"}
+def _init_hybrid(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
+    """The delta-rule period's tree: ``layers`` holds each kind's whole block
+    stacked on its own, ``{"full": [n_full, ...], "linear": [n_linear, ...]}``,
+    in stack order within the kind. Its keys split as they did before the
+    other stacks came (the MLP and its norms first, under one key), so it
+    keeps its own body beside ``_init_stacks``. Gains, taps and gate
+    parameters get values that show when they are left out (not 1, not 0)."""
+    from kserve_vllm_mini_tpu.ops.quant import QUANTIZABLE
+
+    dt = cfg.jnp_dtype
+    shapes = matmul_shapes(cfg)
+
+    def mlp_and_norms(key, n):
+        ks = jax.random.split(key, 5)
+        out = {name: stack(k, n, shape, dt, leaf_fn)
+               for (name, shape), k in zip(shapes["mlp"].items(), ks)}
+        out["post_attn_norm"] = gains(ks[3], n, cfg.d_model, dt)
+        out["post_mlp_norm"] = gains(ks[4], n, cfg.d_model, dt)
+        return out
+
+    nf, nl, h = cfg.n_full_layers, cfg.n_linear_layers, cfg.linear_num_key_heads
+    kf = jax.random.split(keys["wq"], 8)
+    full: Params = mlp_and_norms(kf[0], nf)
+    for (name, shape), k in zip(shapes["attn"].items(), kf[1:5]):
+        full[name] = stack(k, nf, shape, dt, leaf_fn)
+    # a hybrid stack is an olmo2 block (ModelConfig holds it to that): QK-norm
+    full["q_norm"] = gains(kf[5], nf, cfg.n_heads * cfg.head_dim, dt)
+    full["k_norm"] = gains(kf[6], nf, cfg.n_kv_heads * cfg.head_dim, dt)
+    kl = jax.random.split(keys["wk"], 12)
+    lin: Params = mlp_and_norms(kl[0], nl)
+    for (name, shape), k in zip(shapes["linear"].items(), kl[1:8]):
+        # wa, wb [d, heads] stay as they are in the int8 tree (ops/quant.py)
+        lin[name] = stack(k, nl, shape, dt,
+                           leaf_fn if name in QUANTIZABLE else (lambda w: w))
+    lin["conv"] = (0.3 * jax.random.normal(
+        kl[8], (nl, cfg.linear_conv_kernel_dim, cfg.linear_conv_channels), jnp.float32)
+    ).astype(dt)
+    # decays between ~0.5 and ~0.999 a step at a zero pre-activation
+    lin["A_log"] = jnp.log(jax.random.uniform(kl[9], (nl, h), jnp.float32, 0.5, 4.0))
+    lin["dt_bias"] = jax.random.uniform(kl[10], (nl, h), jnp.float32, -6.0, -1.0)
+    lin["o_norm"] = gains(kl[11], nl, cfg.linear_value_head_dim, dt)
+    return _tree_around(keys, cfg, {"full": full, "linear": lin})
 
 
 def scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
@@ -221,211 +218,89 @@ def scaled(x: jnp.ndarray, by: float) -> jnp.ndarray:
     return x if by == 1.0 else (x.astype(jnp.float32) * by).astype(x.dtype)
 
 
-def pattern_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
-    """Per-layer shape of every matmul of a ``hybrid_override_pattern``
-    stack, by kind of block; every one is quantized in the int8 tree
-    (ops/quant.py PATTERN_QUANTIZABLE)."""
-    from kserve_vllm_mini_tpu.models.mamba import mixer_weight_shapes
+def _init_stacks(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
+    """The tree of a stack whose kinds of layer lie each in a stack of its
+    own (every ``cfg.layer_plan`` walked unrolled): ``layers`` holds
+    ``{stack: {leaf: [n, ...]}}`` for the stacks the plan's layers name
+    (``Layer.stack``, ``Layer.ffn_stack``), in stack order within each; a
+    stack of no layers has no entry. The table below gives each stack its
+    matmuls (``matmul_shapes`` rows) and its small leaves, each a
+    ``(name, draw(key, n))``: a mixer's stack holds its layer's ``norm_in``,
+    a feed-forward's its ``ffn_norm``. The stacks take the four matmul keys
+    in the table's order; a stack splits its key into the matmuls' keys, then
+    the small leaves' from ``first`` on. Small leaves get values that show
+    when they are left out. A new kind of layer adds its stack here."""
+    dt, d = cfg.jnp_dtype, cfg.d_model
+    shapes = matmul_shapes(cfg)
 
-    d, hd = cfg.d_model, cfg.head_dim
-    lat, ff = cfg.moe_latent_size, cfg.moe_intermediate_size
-    sh, held = cfg.moe_shared_expert_intermediate_size, cfg.experts_held
-    attn = {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
-            "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)}
-    return {
-        "mamba": mixer_weight_shapes(cfg),
-        "attn": attn,
-        "experts": {"w_in": (d, lat), "w_out": (lat, d), "w1": (held, lat, ff),
-                    "w2": (held, ff, lat), "s1": (d, sh), "s2": (sh, d)},
-        # both mixers and the MLP of a two-mixer block, in one stack
-        "parallel": {**mixer_weight_shapes(cfg), **attn, "w_gate": (d, cfg.d_ff),
-                     "w_up": (d, cfg.d_ff), "w_down": (cfg.d_ff, d)},
-    }
+    def gain(width=d):
+        return lambda k, n: gains(k, n, width, dt)
 
+    def noise(std, *shape):
+        return lambda k, n: (std * jax.random.normal(k, (n, *shape), jnp.float32)).astype(dt)
 
-def _init_pattern(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
-    """A pattern stack's tree: ``layers`` holds each kind of block stacked
-    on its own, ``{"mamba": [n_M, ...], "attn": [n_*, ...], "experts":
-    [n_E, ...]}``, in stack order within the kind; a kind the pattern lacks
-    has no entry. Small leaves get values that show when they are left out."""
-    dt = cfg.jnp_dtype
-    counts = {kind: cfg.hybrid_override_pattern.count(ch) for ch, kind in PATTERN_KINDS.items()}
-    shapes = pattern_weight_shapes(cfg)
+    def uniform(lo, hi):
+        return lambda k, n: jax.random.uniform(
+            k, (n, cfg.mamba_num_heads), jnp.float32, lo, hi)
 
-    def stack(key, n, shape, fn=leaf_fn):
-        def body(_, k):
-            return None, fn(_nrm(k, shape, dt))
+    def router(k, n):
+        return stack(k, n, (d, cfg.n_routed_experts), dt, lambda w: w.astype(jnp.float32))
 
-        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
-
-    def gains(key, n, width):
-        return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
-
+    biased = [("router", router),
+              ("router_bias", lambda k, n: 0.05 * jax.random.normal(
+                  k, (n, cfg.n_routed_experts), jnp.float32))]
+    if cfg.is_pattern_stack:
+        # one norm a block, whatever it runs; the small leaves' keys from the
+        # ninth on, where the three single-mixer kinds have always had them
+        least = 8
+        ssm = [("conv", noise(0.3, cfg.conv_kernel, cfg.mamba_conv_channels)),
+               ("conv_b", noise(0.1, cfg.mamba_conv_channels)),
+               ("A_log", lambda k, n: jnp.log(uniform(1.0, 16.0)(k, n))),
+               ("dt_bias", uniform(-5.0, -1.0)), ("D", uniform(0.5, 1.5)),
+               ("norm", gain(cfg.mamba_d_inner))]
+        table = {
+            "mamba": (shapes["ssm"], [("norm_in", gain()), *ssm]),
+            "attn": (shapes["attn"], [("norm_in", gain())]),
+            "experts": (shapes["latent"], [("norm_in", gain()), *biased]),
+            # both mixers and the MLP of a two-mixer block, in one stack
+            "parallel": ({**shapes["ssm"], **shapes["attn"], **shapes["mlp"]},
+                         [("norm_in", gain()), *ssm, ("mlp_norm", gain())]),
+        }
+    elif cfg.is_conv_stack:
+        least = 4
+        table = {
+            "conv": (shapes["conv"], [("norm_in", gain()),
+                                      ("conv", noise(0.5, cfg.conv_L_cache, d))]),
+            # QK-norm: one gain a head dimension, shared by every head
+            "attn": (shapes["attn"], [("norm_in", gain()), ("q_norm", gain(cfg.head_dim)),
+                                      ("k_norm", gain(cfg.head_dim))]),
+            "mlp": (shapes["mlp"], [("ffn_norm", gain())]),
+            "experts": (shapes["glu"], [("ffn_norm", gain()), *biased]),
+        }
+    else:
+        # window and global layers share one attention stack: the plan, not
+        # the weights, tells them apart. The router (no selection bias) draws
+        # from the key that is the router's in a uniform stack
+        least = 4
+        table = {
+            "attn": (shapes["attn"], [("norm_in", gain())]),
+            "experts": (shapes["glu"], [("ffn_norm", gain()),
+                                        ("router", lambda _, n: router(keys["router"], n))]),
+        }
+    held = [e.stack for e in cfg.layer_plan if e.mixers]
+    held += [e.ffn_stack for e in cfg.layer_plan if e.ffn and e.ffn_stack != e.stack]
     layers: Params = {}
-    for kind, key in zip(counts, (keys["wq"], keys["wk"], keys["wv"], keys["wo"])):
-        n = counts[kind]
+    for (name, (matmuls, small)), key in zip(
+            table.items(), (keys["wq"], keys["wk"], keys["wv"], keys["wo"])):
+        n = held.count(name)
         if not n:
             continue
-        # the matmuls' keys first, then the small leaves' (from the ninth on,
-        # where the three single-mixer kinds have always had them)
-        n_mat = max(len(shapes[kind]), 8)
-        ks = jax.random.split(key, n_mat + 8)
-        sub: Params = {name: stack(k, n, shape)
-                       for (name, shape), k in zip(shapes[kind].items(), ks)}
-        ks = ks[n_mat:]
-        sub["norm_in"] = gains(ks[0], n, cfg.d_model)
-        if kind in ("mamba", "parallel"):
-            h, ch = cfg.mamba_num_heads, cfg.mamba_conv_channels
-            sub["conv"] = (0.3 * jax.random.normal(
-                ks[1], (n, cfg.conv_kernel, ch), jnp.float32)).astype(dt)
-            sub["conv_b"] = (0.1 * jax.random.normal(ks[2], (n, ch), jnp.float32)).astype(dt)
-            sub["A_log"] = jnp.log(jax.random.uniform(ks[3], (n, h), jnp.float32, 1.0, 16.0))
-            sub["dt_bias"] = jax.random.uniform(ks[4], (n, h), jnp.float32, -5.0, -1.0)
-            sub["D"] = jax.random.uniform(ks[5], (n, h), jnp.float32, 0.5, 1.5)
-            sub["norm"] = gains(ks[6], n, cfg.mamba_d_inner)
-            if kind == "parallel":
-                sub["mlp_norm"] = gains(ks[7], n, cfg.d_model)
-        elif kind == "experts":
-            sub["router"] = stack(ks[1], n, (cfg.d_model, cfg.n_routed_experts),
-                                  lambda w: w.astype(jnp.float32))
-            sub["router_bias"] = 0.05 * jax.random.normal(
-                ks[2], (n, cfg.n_routed_experts), jnp.float32)
-        layers[kind] = sub
-    return {
-        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
-        "layers": layers,
-        "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
-        "lm_head": _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt),
-    }
-
-
-def conv_stack_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
-    """Per-layer shape of every matmul of a ``"conv"`` stack, by the four
-    stacks of ``layers``: the two kinds of sequence operator and the two
-    kinds of feed-forward. Every one is quantized in the int8 tree
-    (ops/quant.py CONV_STACK_QUANTIZABLE)."""
-    from kserve_vllm_mini_tpu.models.short_conv import mixer_weight_shapes
-
-    d, hd = cfg.d_model, cfg.head_dim
-    ff, held = cfg.moe_intermediate_size, cfg.experts_held
-    return {
-        "conv": mixer_weight_shapes(cfg),
-        "attn": {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
-                 "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)},
-        "mlp": {"w_gate": (d, cfg.d_ff), "w_up": (d, cfg.d_ff), "w_down": (cfg.d_ff, d)},
-        "experts": {"w_gate": (held, d, ff), "w_up": (held, d, ff), "w_down": (held, ff, d)},
-    }
-
-
-def conv_stack_counts(cfg: ModelConfig) -> dict[str, int]:
-    """Layers in each of a ``"conv"`` stack's four weight stacks."""
-    return {"conv": cfg.n_conv_layers, "attn": cfg.n_full_layers,
-            "mlp": cfg.num_dense_layers, "experts": cfg.n_expert_layers}
-
-
-def _init_conv_stack(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
-    """A ``"conv"`` stack's tree: ``layers`` holds ``{"conv": [n_conv, ...],
-    "attn": [n_attn, ...], "mlp": [num_dense_layers, ...], "experts":
-    [n_expert_layers, ...]}``, in stack order within the kind (a stack of
-    no layers has no entry). An operator's stack holds its layer's
-    ``norm_in``, a feed-forward's its ``ffn_norm``. Small leaves get values
-    that show when they are left out."""
-    dt = cfg.jnp_dtype
-    shapes = conv_stack_weight_shapes(cfg)
-
-    def stack(key, n, shape, fn=leaf_fn):
-        def body(_, k):
-            return None, fn(_nrm(k, shape, dt))
-
-        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
-
-    def gains(key, n, width):
-        return (1.0 + 0.1 * jax.random.normal(key, (n, width), jnp.float32)).astype(dt)
-
-    layers: Params = {}
-    for (kind, n), key in zip(conv_stack_counts(cfg).items(),
-                              (keys["wq"], keys["wk"], keys["wv"], keys["wo"])):
-        if not n:
-            continue
-        ks = jax.random.split(key, 8)
-        sub: Params = {name: stack(k, n, shape)
-                       for (name, shape), k in zip(shapes[kind].items(), ks)}
-        if kind == "conv":
-            sub["norm_in"] = gains(ks[4], n, cfg.d_model)
-            sub["conv"] = (0.5 * jax.random.normal(
-                ks[5], (n, cfg.conv_L_cache, cfg.d_model), jnp.float32)).astype(dt)
-        elif kind == "attn":
-            sub["norm_in"] = gains(ks[4], n, cfg.d_model)
-            # one gain a head dimension, shared by every head
-            sub["q_norm"] = gains(ks[5], n, cfg.head_dim)
-            sub["k_norm"] = gains(ks[6], n, cfg.head_dim)
-        else:
-            sub["ffn_norm"] = gains(ks[4], n, cfg.d_model)
-            if kind == "experts":
-                sub["router"] = stack(ks[5], n, (cfg.d_model, cfg.n_routed_experts),
-                                      lambda w: w.astype(jnp.float32))
-                sub["router_bias"] = 0.05 * jax.random.normal(
-                    ks[6], (n, cfg.n_routed_experts), jnp.float32)
-        layers[kind] = sub
-    params: Params = {
-        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
-        "layers": layers,
-        "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt)
-    return params
-
-
-def window_stack_weight_shapes(cfg: ModelConfig) -> dict[str, dict[str, tuple[int, ...]]]:
-    """Per-layer shape of every matmul of a ``sliding_window_layout`` stack,
-    by the two stacks of ``layers``: every layer's attention (window and
-    global layers share one stack: the layouts, not the weights, tell them
-    apart) and every layer's experts. Every one is quantized in the int8
-    tree (ops/quant.py QUANTIZABLE)."""
-    d, hd = cfg.d_model, cfg.head_dim
-    ff, held = cfg.moe_intermediate_size, cfg.experts_held
-    return {
-        "attn": {"wq": (d, cfg.n_heads * hd), "wk": (d, cfg.n_kv_heads * hd),
-                 "wv": (d, cfg.n_kv_heads * hd), "wo": (cfg.n_heads * hd, d)},
-        "experts": {"w_gate": (held, d, ff), "w_up": (held, d, ff), "w_down": (held, ff, d)},
-    }
-
-
-def _init_window_stack(keys: dict[str, jax.Array], cfg: ModelConfig, leaf_fn) -> Params:
-    """A ``sliding_window_layout`` stack's tree: ``layers`` holds ``{"attn":
-    [n_layers, ...], "experts": [n_layers, ...]}``, in stack order. The
-    attention stack holds its layer's ``norm_in``, the expert stack its
-    ``ffn_norm`` and the float32 ``router`` (no selection bias). Gains get
-    values that show when they are left out."""
-    dt, n = cfg.jnp_dtype, cfg.n_layers
-    shapes = window_stack_weight_shapes(cfg)
-
-    def stack(key, shape, fn=leaf_fn):
-        def body(_, k):
-            return None, fn(_nrm(k, shape, dt))
-
-        return jax.lax.scan(body, None, jax.random.split(key, n))[1]
-
-    def gains(key):
-        return (1.0 + 0.1 * jax.random.normal(key, (n, cfg.d_model), jnp.float32)).astype(dt)
-
-    layers: Params = {}
-    for kind, key in (("attn", keys["wq"]), ("experts", keys["wk"])):
-        ks = jax.random.split(key, 8)
-        layers[kind] = {name: stack(k, shape)
-                        for (name, shape), k in zip(shapes[kind].items(), ks)}
-        layers[kind]["norm_in" if kind == "attn" else "ffn_norm"] = gains(ks[4])
-    layers["experts"]["router"] = stack(
-        keys["router"], (cfg.d_model, cfg.n_routed_experts), lambda w: w.astype(jnp.float32))
-    params: Params = {
-        "embed": _nrm(keys["embed"], (cfg.vocab_size, cfg.d_model), dt),
-        "layers": layers,
-        "final_norm": jnp.ones((cfg.d_model,), dtype=dt),
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = _nrm(keys["lm_head"], (cfg.vocab_size, cfg.d_model), dt)
-    return params
+        first = max(len(matmuls), least)
+        ks = jax.random.split(key, first + least)
+        layers[name] = {leaf: stack(k, n, shape, dt, leaf_fn)
+                        for (leaf, shape), k in zip(matmuls.items(), ks)}
+        layers[name].update({leaf: draw(k, n) for (leaf, draw), k in zip(small, ks[first:])})
+    return _tree_around(keys, cfg, layers)
 
 
 def init_params(rng: jax.Array, cfg: ModelConfig) -> Params:
@@ -474,12 +349,15 @@ def init_kv_cache(
     read is the second-largest stream after the weights). Values are quantized on write with
     a per-position amax scale — reference analog: the kv-cache-dtype knob
     the quantization sweep measures (sweeps/quantization_sweep.py:40-234)."""
+    from kserve_vllm_mini_tpu.models import linear_attention, mamba, short_conv
+
     s = max_seq or cfg.max_seq_len
     # a hybrid stack keeps K/V for its full-attention layers only, and beside
-    # them (same tree, slot axis still dim 1) what its linear layers keep
+    # them (same tree, slot axis still dim 1) the other leaves its plan names
     shape = (cfg.n_full_layers, batch, cfg.n_kv_heads, s, cfg.head_dim)
+    leaves = cfg.layer_plan.leaves
     extra: KVCache = {}
-    if cfg.is_window_stack:
+    if "k_win" in leaves:
         # two K/V leaves of different lengths in the one tree: ``k``/``v``
         # above are the global layers' stripes; the window layers keep a
         # ring of ring_len positions, addressed ``p mod R``
@@ -489,16 +367,10 @@ def init_kv_cache(
         ring = (cfg.n_window_layers, batch, cfg.n_kv_heads, cfg.ring_len(s), cfg.head_dim)
         extra = {name: jnp.zeros(ring, dtype=dtype or cfg.jnp_dtype)
                  for name in ("k_win", "v_win")}
-    elif cfg.is_hybrid:
-        if cfg.is_pattern_stack:
-            from kserve_vllm_mini_tpu.models.mamba import state_shapes
-        elif cfg.is_conv_stack:
-            from kserve_vllm_mini_tpu.models.short_conv import state_shapes
-        else:
-            from kserve_vllm_mini_tpu.models.linear_attention import state_shapes
-
-        extra = {name: jnp.zeros(shp, dtype=dt)
-                 for name, (shp, dt) in state_shapes(cfg, batch).items()}
+    for mixer in (mamba, short_conv, linear_attention):
+        extra.update({name: jnp.zeros(shp, dtype=dt)
+                      for name, (shp, dt) in mixer.state_shapes(cfg, batch).items()
+                      if name in leaves})
     if quantized:
         return {
             "k": jnp.zeros(shape, dtype=jnp.int8),
@@ -992,10 +864,13 @@ def run_cached_layers(
     # Dense decode kernel, bf16 and int8 KV alike: each slot fetches only
     # the key/value blocks its query position (and a uniform window) can
     # reach, instead of attending over the whole [B,KVH,S,D] stripe.
+    # A cache with a ring beside its stripes reads both through the kernel or
+    # neither, each by the block of its own length.
     use_dense_kernel = (
         full_slots
         and T == 1
-        and dense_decode_kernel_block(cfg, kernels, kv_cache, paged) is not None
+        and (window_decode_kernel_blocks(cfg, kernels, kv_cache) if "k_win" in kv_cache
+             else dense_decode_kernel_block(cfg, kernels, kv_cache, paged)) is not None
     )
     # Int8-KV cached-prefill kernel (ops/flash_attention.py
     # cached_prefill_attention): continuation chunks (T > 1 against the
@@ -1038,6 +913,30 @@ def run_cached_layers(
     else:
         b_idx = base + jnp.arange(B)[:, None, None]          # [B, 1, 1]
         w_idx = t_idx
+    if cfg.is_hybrid:
+        if lora is not None or paged or write_gate is not None or slot_base is not None:
+            raise ValueError(
+                "a hybrid stack serves from the dense cache on one device, "
+                "without adapters (no block table, pipeline stage or LoRA bank)"
+            )
+        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
+                 else valid_len.astype(jnp.int32))
+        real = jnp.arange(T)[None, :] < valid[:, None]
+    if "k_win" in kv_cache:
+        if quantized_kv or not (fresh_prefill or T == 1):
+            raise ValueError(
+                "a sliding_window_layout stack serves a fresh prefill piece or a decode "
+                "step from bf16 K/V: a continuation piece (T > 1 against the cache) "
+                "would attend ring + piece, which nothing here computes")
+        R = kv_cache["k_win"].shape[3]
+        qpos = cache_offsets.astype(jnp.int32)                    # [B], a decode step's
+        if fresh_prefill and T > R:
+            # The prompt's last R REAL positions stay behind: ring index r
+            # gets the largest position p < valid with p = r (mod R). The
+            # bucket's padding past ``valid`` must not push real keys out.
+            last = valid[:, None] - 1                              # [B, 1]
+            ring_src = last - jnp.mod(last - jnp.arange(R)[None, :], R)   # [B, R]
+            ring_src = jnp.clip(ring_src, 0, T - 1)[:, None, :, None]     # [B, 1, R, 1]
 
     def _gate(cache, name, lidx, new):
         """Value actually scattered: ``new``, or — when write_gate is False —
@@ -1093,57 +992,83 @@ def run_cached_layers(
         return full_layer(y0, cache, p, lora_p, lidx), None
 
     def full_layer(y0, cache, p, lora_p, lidx):
+        # a scan's attention layers are one entry of the plan, whichever the
+        # layer (alt_sliding_window alternates its mask by the traced index)
+        e = next(e for e in cfg.layer_plan if e.kv)
         h = block_norm(p, cfg, y0, "attn_norm")
-        o, cache = attend(h, cache, p, lora_p, lidx)
+        o, cache = attend(h, cache, p, lora_p, lidx, e)
         return (
             attn_out_and_mlp(p, cfg, y0, o, h, lora=lora_p, lora_ids=lora_ids, kernels=kernels),
             cache,
         )
 
-    def attend(h, cache, p, lora_p, lidx):
+    def ring_place(cache, e, lidx, k, v):
+        """Where this block's K/V goes in layer ``lidx`` of a RING of R
+        positions, position p at ``p mod R`` -> (k, v, their indices on the
+        ring's axis, the index up to which a decode step reads the ring)."""
+        kn, vn = e.kv
+        if fresh_prefill and T > R:
+            return (jnp.take_along_axis(k, ring_src, axis=2),
+                    jnp.take_along_axis(v, ring_src, axis=2), jnp.arange(R)[None, None, :], None)
+        if fresh_prefill:
+            return k, v, t_idx, None
+        # a row that is not decoding writes back what was there (its query
+        # position is not its slot's next one: it would push a live key out)
+        at = jnp.mod(t_idx, R)
+        live = (valid > 0)[:, None, None, None]
+        k = jnp.where(live, k.astype(cache[kn].dtype), cache[kn][lidx, b_idx, h_idx, at])
+        v = jnp.where(live, v.astype(cache[vn].dtype), cache[vn][lidx, b_idx, h_idx, at])
+        # Written before it is read, the ring holds exactly the positions
+        # q - R < p <= q: with R = W every index that holds a position >= 0
+        # is visible, the indices 0 .. min(q, R - 1); no position enters the
+        # scores after the rotation, so their order in the ring does not
+        # matter and the read is a causal one at min(q, R - 1)
+        return k, v, at, jnp.minimum(qpos, R - 1)
+
+    def attend(h, cache, p, lora_p, lidx, e):
         """The attention mixer on the normed ``h``: project, write this
         block's K/V into layer ``lidx`` of the cache, attend -> (o
-        [B,H,T,hd], cache)."""
+        [B,H,T,hd], cache). ``e``, the layer's entry of ``cfg.layer_plan``,
+        says which two leaves hold its K/V and whether they are a ring,
+        how far back it attends, and whether q and k rotate."""
+        kn, vn = e.kv
         q, k, v = qkv_proj(p, cfg, h, positions, cos, sin,
-                           lora=lora_p, lora_ids=lora_ids)
+                           lora=lora_p, lora_ids=lora_ids, rope=e.rope)
         cache = dict(cache)
-        if quantized_kv:
-            kq, ks = _quantize_kv_block(k)
-            vq, vs = _quantize_kv_block(v)
-            idx_s = (lidx, b_idx, h_idx, w_idx)
-            cache["k"] = cache["k"].at[lidx, b_idx, h_idx, w_idx].set(
-                _gate(cache, "k", lidx, kq)
-            )
-            cache["v"] = cache["v"].at[lidx, b_idx, h_idx, w_idx].set(
-                _gate(cache, "v", lidx, vq)
-            )
-            cache["k_s"] = cache["k_s"].at[idx_s].set(_gate(cache, "k_s", lidx, ks))
-            cache["v_s"] = cache["v_s"].at[idx_s].set(_gate(cache, "v_s", lidx, vs))
-        else:
-            cache["k"] = cache["k"].at[lidx, b_idx, h_idx, w_idx].set(
-                _gate(cache, "k", lidx, k.astype(cache["k"].dtype))
-            )
-            cache["v"] = cache["v"].at[lidx, b_idx, h_idx, w_idx].set(
-                _gate(cache, "v", lidx, v.astype(cache["v"].dtype))
-            )
+        # where the block lands on the leaves' own axis, and what a decode
+        # step's kernel reads: keys up to the query's position, inside the
+        # window; a ring holds its window and no more
+        new, at, reach, window = {kn: k, vn: v}, w_idx, cache_offsets, e.window
+        if e.ring:
+            # a fresh piece attends its own projections below: what the ring
+            # keeps of them is taken from them, not read back
+            new[kn], new[vn], at, reach = ring_place(cache, e, lidx, k, v)
+            window = None
+        elif quantized_kv:
+            (new[kn], new["k_s"]), (new[vn], new["v_s"]) = (
+                _quantize_kv_block(k), _quantize_kv_block(v))
+        for name, val in new.items():
+            cache[name] = cache[name].at[lidx, b_idx, h_idx, at].set(
+                _gate(cache, name, lidx, val.astype(cache[name].dtype)))
         glidx = layer_offset + lidx  # global layer index (mask phase)
         if fresh_prefill:
             # block-causal flash over the fresh block is exact for a
             # windowed model too as long as T <= window (every causal
-            # key is inside the window); longer prefills take the masked
+            # key is inside the window), and a ring's layer hands the
+            # kernel its window; longer prefills of the others take the masked
             # jnp path. T is static, so this is a trace-time branch. The
             # flash kernel has no softcap, so gemma's capped attention
             # always takes the masked path.
             needs_mask_path = (
                 attn_cap is not None
                 or attn_scale != float(cfg.head_dim) ** -0.5
-                or (cfg.sliding_window is not None and T > cfg.sliding_window)
+                or (e.window is not None and T > e.window and not e.ring)
             )
             if needs_mask_path:
                 fj = jnp.arange(T)[None, None, :]
                 fcausal = fj <= qi
-                if cfg.sliding_window is not None:
-                    fwin = fcausal & (fj > qi - cfg.sliding_window)
+                if e.window is not None:
+                    fwin = fcausal & (fj > qi - e.window)
                     if cfg.alt_sliding_window:
                         fmask = jnp.where(glidx % 2 == 0, fwin, fcausal)
                     else:
@@ -1155,7 +1080,8 @@ def run_cached_layers(
             else:
                 from kserve_vllm_mini_tpu.ops.flash_attention import prefill_attention
 
-                o = prefill_attention(q, k, v, kernels=kernels)
+                o = prefill_attention(q, k, v, kernels=kernels,
+                                      window=e.window if e.ring else None)
         elif use_paged_kernel:
             # Pallas paged decode: the block table drives per-block DMA
             # straight from the LAYER-STACKED pool — no gathered KV copy,
@@ -1177,7 +1103,7 @@ def run_cached_layers(
             o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
         elif use_dense_kernel:
             # dense decode: only the blocks of the LAYER-STACKED cache
-            # that cache_offsets (and the window) reach are DMA'd, int8
+            # that ``reach`` (and the window) reach are DMA'd, int8
             # ones dequantized in-kernel — no full-stripe read, no
             # materialized bf16 KV tensor, and no per-layer cache slice
             # either (lidx rides the kernel's index map, same contract as
@@ -1189,10 +1115,10 @@ def run_cached_layers(
             G = cfg.n_heads // cfg.n_kv_heads
             qg = q[:, :, 0, :].reshape(B, cfg.n_kv_heads, G, cfg.head_dim)
             og = dense_decode_attention(
-                qg, cache["k"], cache["v"], cache_offsets,
+                qg, cache[kn], cache[vn], reach,
                 layer=lidx, scale=attn_scale,
-                k_scale=cache.get("k_s"), v_scale=cache.get("v_s"),
-                window=cfg.sliding_window, interpret=interpret,
+                k_scale=cache.get(kn + "_s"), v_scale=cache.get(vn + "_s"),
+                window=window, interpret=interpret,
             )
             o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
         elif use_chunk_kernel:
@@ -1212,198 +1138,47 @@ def run_cached_layers(
                 interpret=interpret,
             )
         else:
-            k_layer = _read_layer(cache, "k", lidx)
-            v_layer = _read_layer(cache, "v", lidx)
-            m = mask
-            if mask_global is not None:
+            k_layer = _read_layer(cache, kn, lidx)
+            v_layer = _read_layer(cache, vn, lidx)
+            if e.ring:
+                m = (jnp.arange(R)[None, None, :] <= reach[:, None, None])[:, None]
+            elif mask_global is not None:
                 # gemma alternation: even global layers local, odd global
                 m = jnp.where(glidx % 2 == 0, mask, mask_global)
+            else:
+                # every layer that keeps stripes shares the stack's one window
+                m = mask
             o = attention(q, k_layer, v_layer, m,
                           scale=attn_scale, softcap=attn_cap)
         return o, cache
 
-    if cfg.is_hybrid and (lora is not None or paged or write_gate is not None
-                          or slot_base is not None):
-        raise ValueError(
-            "a hybrid stack serves from the dense cache on one device, "
-            "without adapters (no block table, pipeline stage or LoRA bank)"
-        )
-    if cfg.is_window_stack:
-        # Attention and routed experts in every layer; what differs by layer
-        # is static: the mask (window or global), the rotation, and the K/V
-        # leaf (a ring of R positions or a stripe of the context). Unrolled
-        # with static indices like the walks below: each layer reads its
-        # slice of the two weight stacks where it lies, and both leaves are
-        # updated in place in the donated cache.
-        from kserve_vllm_mini_tpu.models.moe import moe_glu_block
-        from kserve_vllm_mini_tpu.ops.flash_attention import prefill_attention
-        from kserve_vllm_mini_tpu.ops.paged_attention import dense_decode_attention
-
-        if quantized_kv or not (fresh_prefill or T == 1):
-            raise ValueError(
-                "a sliding_window_layout stack serves a fresh prefill piece or a decode "
-                "step from bf16 K/V: a continuation piece (T > 1 against the cache) "
-                "would attend ring + piece, which nothing here computes")
-        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
-                 else valid_len.astype(jnp.int32))
-        real = jnp.arange(T)[None, :] < valid[:, None]
-        cache = dict(kv_cache)
-        W, R = cfg.sliding_window_size, kv_cache["k_win"].shape[3]
-        G = cfg.n_heads // cfg.n_kv_heads
-        blocks = window_decode_kernel_blocks(cfg, kernels, kv_cache)
-        qpos = cache_offsets.astype(jnp.int32)                    # [B], a decode step's
-        if fresh_prefill and T > R:
-            # The prompt's last R REAL positions stay behind: ring index r
-            # gets the largest position p < valid with p = r (mod R). The
-            # bucket's padding past ``valid`` must not push real keys out.
-            last = valid[:, None] - 1                              # [B, 1]
-            ring_src = last - jnp.mod(last - jnp.arange(R)[None, :], R)   # [B, R]
-            ring_src = jnp.clip(ring_src, 0, T - 1)[:, None, :, None]     # [B, 1, R, 1]
-        seen = {True: 0, False: 0}
-        stats = jnp.zeros((4,), jnp.int32)
-
-        def keep(leaf, i, new, at):
-            """``new`` [B, KVH, T', D] into layer ``i`` of ``leaf`` at positions
-            ``at`` [B, 1, T'] of the slots' own axis."""
-            return cache[leaf].at[i, b_idx, h_idx, at].set(new.astype(cache[leaf].dtype))
-
-        for l, windowed in enumerate(cfg.window_layers):
-            i = seen[windowed]
-            seen[windowed] += 1
-            p = jax.tree_util.tree_map(lambda a: a[l], layers["attn"])
-            h = rms_norm(x, p["norm_in"], cfg.rms_eps)
-            q, k, v = qkv_proj(p, cfg, h, positions, cos, sin, rope=bool(cfg.rope_layout[l]))
-            kn, vn = ("k_win", "v_win") if windowed else ("k", "v")
-            if fresh_prefill:
-                # attention over the piece's own projections FIRST: the ring
-                # is written after, from them (a ring written first would
-                # have lost the keys the piece's first queries attend)
-                o = prefill_attention(q, k, v, kernels=kernels,
-                                      window=W if windowed else None)
-                if windowed and T > R:  # kvmini: static-shape
-                    at = jnp.arange(R)[None, None, :]
-                    k = jnp.take_along_axis(k, ring_src, axis=2)
-                    v = jnp.take_along_axis(v, ring_src, axis=2)
-                else:
-                    at = t_idx
-                cache[kn], cache[vn] = keep(kn, i, k, at), keep(vn, i, v, at)
-            else:
-                if windowed:  # kvmini: static-shape
-                    # position q lives at q mod R; a row that is not decoding
-                    # writes back what was there (its query position is not
-                    # its slot's next one: it would push a live key out)
-                    at = jnp.mod(t_idx, R)
-                    live = (valid > 0)[:, None, None, None]
-                    k = jnp.where(live, k.astype(cache[kn].dtype), cache[kn][i, b_idx, h_idx, at])
-                    v = jnp.where(live, v.astype(cache[vn].dtype), cache[vn][i, b_idx, h_idx, at])
-                    # Written before it is read, the ring holds exactly the
-                    # positions q - R < p <= q: with R = W every index that
-                    # holds a position >= 0 is visible, the indices 0 ..
-                    # min(q, R - 1); no position enters the scores after the
-                    # rotation, so their order in the ring does not matter
-                    # and the read is a causal one at min(q, R - 1)
-                    reach = jnp.minimum(qpos, R - 1)
-                else:
-                    at, reach = t_idx, qpos
-                cache[kn], cache[vn] = keep(kn, i, k, at), keep(vn, i, v, at)
-                if blocks is not None:
-                    qg = q[:, :, 0, :].reshape(B, cfg.n_kv_heads, G, cfg.head_dim)
-                    og = dense_decode_attention(
-                        qg, cache[kn], cache[vn], reach, layer=jnp.int32(i),
-                        scale=attn_scale, interpret=interpret)
-                    o = og.reshape(B, cfg.n_heads, 1, cfg.head_dim)
-                else:
-                    span = cache[kn].shape[3]
-                    m = (jnp.arange(span)[None, None, :] <= reach[:, None, None])[:, None]
-                    o = attention(q, cache[kn][i].astype(dt), cache[vn][i].astype(dt), m,
-                                  scale=attn_scale, softcap=attn_cap)
-            o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
-            x_in, x = x, x + linear(o, p["wo"], mode=cfg.quant_mode)
-            # the router reads the layer's INPUT; the experts the post-attention
-            # norm. The stack and the index go down, not a slice: a long piece's
-            # grouped dispatch reads the expert stacks where they lie
-            experts = layers["experts"]
-            out, counts = moe_glu_block(
-                experts, cfg, rms_norm(x, experts["ffn_norm"][l], cfg.rms_eps),
-                real, route_from=x_in, kernels=kernels, layer=l)
-            stats = stats + counts
-            x = x + out
-        return (x, cache, stats) if with_stats else (x, cache)
-
-    if cfg.is_conv_stack:
-        # An operator and a feed-forward a layer, in the published (irregular)
-        # order; four weight stacks, each read where it lies. Unrolled with
-        # static indices like the pattern walk below, for the same reason.
-        from kserve_vllm_mini_tpu.models.moe import moe_glu_block
+    if cfg.is_hybrid and cfg.layer_period == 1:
+        # Each kind of layer lies in a weight stack of its own, in the plan's
+        # (irregular) order. The walk is unrolled with static indices: every
+        # layer reads its slice of its stack where it lies (a scan over a
+        # [periods, k, ...] view, or a switch on the kind inside one, makes
+        # XLA copy the slice out every step), and state, tail and both kinds
+        # of K/V are updated in place in the donated cache.
+        from kserve_vllm_mini_tpu.models.mamba import mamba_mixer, ssm_decode_kernel_block
+        from kserve_vllm_mini_tpu.models.moe import moe_glu_block, moe_latent_block
         from kserve_vllm_mini_tpu.models.short_conv import short_conv_mixer
 
-        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
-                 else valid_len.astype(jnp.int32))
-        real = jnp.arange(T)[None, :] < valid[:, None]
         cache = dict(kv_cache)
-        seen = dict.fromkeys(("conv", "attn"), 0)
         stats = jnp.zeros((4,), jnp.int32)
+        # a decode step on a TPU hands the Mamba-2 mixer the whole state
+        # stack: the kernel updates the block's layer inside it (no slice
+        # out, no write back); never a fresh prefill
+        in_place = ("ssm_state" in kv_cache and not fresh_prefill and ssm_decode_kernel_block(
+            kernels, kv_cache["ssm_state"], T) is not None)
 
-        def at(kind, i):
-            return jax.tree_util.tree_map(lambda a: a[i], layers[kind])
+        def at(name, i):
+            return jax.tree_util.tree_map(lambda a: a[i], layers[name])
 
-        for l, kind in enumerate(cfg.layer_types):
-            kind = "attn" if kind == "full_attention" else kind
-            i = seen[kind]
-            seen[kind] += 1
-            p = at(kind, i)
-            h = rms_norm(x, p["norm_in"], cfg.rms_eps)
-            if kind == "conv":
-                tail = cache["conv_tail"][i]
-                if fresh_prefill:
-                    # a new sequence starts from an empty tail whatever the slot held
-                    tail = jnp.zeros_like(tail)
-                out, tail = short_conv_mixer(p, cfg, h, tail, valid)
-                cache["conv_tail"] = cache["conv_tail"].at[i].set(tail)
-            else:
-                o, cache = attend(h, cache, p, None, jnp.int32(i))
-                o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
-                out = linear(o, p["wo"], mode=cfg.quant_mode)
-            x = x + out
-            if l < cfg.num_dense_layers:
-                p = at("mlp", l)
-                out = swiglu_mlp(p, cfg, rms_norm(x, p["ffn_norm"], cfg.rms_eps))
-            else:
-                # the stack and the index, not a slice (the window walk above)
-                experts, i = layers["experts"], l - cfg.num_dense_layers
-                out, counts = moe_glu_block(
-                    experts, cfg, rms_norm(x, experts["ffn_norm"][i], cfg.rms_eps),
-                    real, kernels=kernels, layer=i)
-                stats = stats + counts
-            x = x + out
-        return (x, cache, stats) if with_stats else (x, cache)
-
-    if cfg.is_pattern_stack:
-        # One mixer a block, in the pattern's (irregular) order; each kind's
-        # weights are stacked on their own. The walk is unrolled with static
-        # indices: every block reads its slice of its stack where it lies
-        # (a scan over a [periods, k, ...] view, or a switch on the kind
-        # inside one, makes XLA copy the slice out every step), and state,
-        # tail and K/V are updated in place in the donated cache.
-        from kserve_vllm_mini_tpu.models.mamba import mamba_mixer, ssm_decode_kernel_block
-        from kserve_vllm_mini_tpu.models.moe import moe_latent_block
-
-        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
-                 else valid_len.astype(jnp.int32))
-        real = jnp.arange(T)[None, :] < valid[:, None]
-        cache = dict(kv_cache)
-        seen = dict.fromkeys(PATTERN_KINDS, 0)
-        stats = jnp.zeros((4,), jnp.int32)
-
-        # a decode step on a TPU hands the mixer the whole state stack: the
-        # kernel updates the block's layer inside it (no slice out, no write
-        # back); never a fresh prefill
-        in_place = not fresh_prefill and ssm_decode_kernel_block(
-            kernels, kv_cache["ssm_state"], T) is not None
-
-        def ssm(p, h, cache, i):
-            """The Mamba-2 mixer on the normed ``h``, its state and tail
-            layer ``i`` of the cache's two state-space leaves."""
+        # A mixer on the normed ``h`` -> (its delta of the residual stream,
+        # cache): ``e`` says which layer of which cache leaves is its own,
+        # ``p`` is its layer's slice of its weight stack.
+        def ssm(e, p, h, cache):
+            i = e.at("ssm_state")
             state, layer = (cache["ssm_state"], jnp.int32(i)) if in_place else (
                 cache["ssm_state"][i], None)
             tail = cache["ssm_conv"][i]
@@ -1415,40 +1190,60 @@ def run_cached_layers(
             cache["ssm_conv"] = cache["ssm_conv"].at[i].set(tail)
             return out, cache
 
-        def attn(p, h, cache, i):
-            """Attention on the normed ``h``, its K/V layer ``i`` of the cache."""
-            o, cache = attend(h, cache, p, None, jnp.int32(i))
+        def conv(e, p, h, cache):
+            i = e.at("conv_tail")
+            tail = cache["conv_tail"][i]
+            if fresh_prefill:
+                # a new sequence starts from an empty tail whatever the slot held
+                tail = jnp.zeros_like(tail)
+            out, tail = short_conv_mixer(p, cfg, h, tail, valid)
+            cache["conv_tail"] = cache["conv_tail"].at[i].set(tail)
+            return out, cache
+
+        def attn(e, p, h, cache):
+            o, cache = attend(h, cache, p, None, jnp.int32(e.at(e.kv[0])), e)
             o = o.transpose(0, 2, 1, 3).reshape(B, T, cfg.n_heads * cfg.head_dim)
             return linear(o, p["wo"], mode=cfg.quant_mode), cache
 
-        for ch in cfg.hybrid_override_pattern:
-            # a block's place in its kind's weight stack, and in the cache's
-            # state-space and K/V leaves (a 'P' block holds a layer of each)
-            i, i_ssm, i_kv = seen[ch], seen["M"] + seen["P"], seen["*"] + seen["P"]
-            seen[ch] += 1
-            p = jax.tree_util.tree_map(lambda a: a[i], layers[PATTERN_KINDS[ch]])
-            h = rms_norm(x, p["norm_in"], cfg.rms_eps)
-            if ch == "M":
-                out, cache = ssm(p, h, cache, i_ssm)
-            elif ch == "*":
-                out, cache = attn(p, h, cache, i_kv)
-            elif ch == "P":
-                # two mixers side by side on ONE normed input, one residual
-                # add for both; then the block's own pre-normed MLP
-                mixed, cache = ssm(p, h, cache, i_ssm)
-                attended, cache = attn(p, h, cache, i_kv)
-                x = x + (scaled(mixed, cfg.ssm_out_multiplier)
-                         + scaled(attended, cfg.attention_out_multiplier))
-                out = swiglu_mlp(p, cfg, rms_norm(x, p["mlp_norm"], cfg.rms_eps))
-            else:
-                # the stack and the index, not the slice (the window walk above)
-                out, counts = moe_latent_block(
-                    layers[PATTERN_KINDS[ch]], cfg, h, real, kernels, layer=i)
+        mixers = {"ssm": ssm, "conv": conv, "attn": attn}
+        out_by = {"ssm": cfg.ssm_out_multiplier, "attn": cfg.attention_out_multiplier}
+        for e in cfg.layer_plan:
+            x_in = x
+            if e.mixers:
+                p = at(e.stack, e.index)
+                h = rms_norm(x, p["norm_in"], cfg.rms_eps)
+                outs = []
+                for kind in e.mixers:
+                    out, cache = mixers[kind](e, p, h, cache)
+                    outs.append(out)
+                if len(outs) > 1:
+                    # two mixers side by side on ONE normed input, each by its
+                    # muP multiplier, one residual add for both
+                    first, second = (scaled(o, out_by[kind]) for kind, o in zip(e.mixers, outs))
+                    out = first + second
+                x = x + out
+            if e.ffn == "mlp":
+                # a two-mixer block's MLP lies in its mixers' stack
+                p = p if e.ffn_stack == e.stack else at(e.ffn_stack, e.ffn_index)
+                out = swiglu_mlp(p, cfg, rms_norm(x, p[e.ffn_norm], cfg.rms_eps))
+            elif e.ffn:
+                # The stack and the index go down, not a slice: a long
+                # piece's grouped dispatch and a decode step's sweep read the
+                # expert stacks where they lie.
+                experts, i = layers[e.ffn_stack], e.ffn_index
+                h = rms_norm(x, experts[e.ffn_norm][i], cfg.rms_eps)
+                if e.ffn == "glu":
+                    out, counts = moe_glu_block(
+                        experts, cfg, h, real, route_from=x_in if e.route_from_input else None,
+                        kernels=kernels, layer=i)
+                else:
+                    out, counts = moe_latent_block(experts, cfg, h, real, kernels, layer=i)
                 stats = stats + counts
-            x = x + out
+            if e.ffn:
+                x = x + out
         return (x, cache, stats) if with_stats else (x, cache)
 
-    if cfg.is_hybrid:
+    if cfg.layer_period > 1:
         # one period = k linear layers then one full layer; each kind's
         # weights are stacked on their own. The stack is ONE scan over the
         # periods with the period's linear layers unrolled in its body, each
@@ -1465,8 +1260,6 @@ def run_cached_layers(
         n_lin = cfg.layer_period - 1
         in_place = not fresh_prefill and linear_decode_kernel_block(
             kernels, kv_cache["lin_state"], T) is not None
-        valid = (jnp.full((B,), T, jnp.int32) if valid_len is None
-                 else valid_len.astype(jnp.int32))
         lin_layers = layers["linear"]
 
         def linear_layer(y0, cache, lidx):

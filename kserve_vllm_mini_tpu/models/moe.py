@@ -244,15 +244,17 @@ def grouped_prefill(cfg: ModelConfig, layers: dict[str, Any], tokens: int,
     dispatch in the model's expert layers: what the walks decide, from the
     same inputs (``layers`` the parameter tree's layer stacks), for the
     engine's counter. False for a model without routed experts."""
-    if cfg.is_conv_stack or cfg.is_window_stack:
-        p, body = layers["experts"], GLU_RELU if cfg.is_window_stack else GLU_SILU
-        width = cfg.d_model
-    elif cfg.n_expert_layers:
-        p, body, width = layers["experts"], LATENT_RELU2, cfg.moe_latent_size
-    elif cfg.is_moe:
-        p, body, width = layers, GLU_SILU, cfg.d_model
-    else:
+    e = next((e for e in cfg.layer_plan if e.ffn in ("glu", "latent", "moe")), None)
+    if e is None:
         return False
+    if e.ffn == "glu":
+        # the body follows what the router reads, as in ``moe_glu_block``
+        p, body = layers[e.ffn_stack], GLU_RELU if e.route_from_input else GLU_SILU
+        width = cfg.d_model
+    elif e.ffn == "latent":
+        p, body, width = layers[e.ffn_stack], LATENT_RELU2, cfg.moe_latent_size
+    else:
+        p, body, width = layers, GLU_SILU, cfg.d_model
     x = jax.ShapeDtypeStruct((tokens, width), cfg.jnp_dtype)
     return grouped_blocks(x, p, body, cfg.quant_mode, kernels, True) not in (None, SWEEP)
 

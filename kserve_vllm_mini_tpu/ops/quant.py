@@ -140,53 +140,32 @@ def linear(x: jnp.ndarray, w: Any, mode: str = "dequant") -> jnp.ndarray:
     return x @ w
 
 
-# Names of the per-layer matmul weights that quantization applies to
-# (models/llama.py init_params layout). Norms, embeddings, and the lm_head
-# stay high-precision — standard practice, and the embed is a gather anyway.
-QUANTIZABLE = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-# a hybrid stack's linear mixer (models/linear_attention.py) adds its output
-# gate; its wq/wk/wv/wo carry the attention names. Its small leaves (wa, wb
-# [d, heads], the convolution taps, A_log, dt_bias, gains) stay as they are:
-# noise-level bytes that set the state's decay and write strength.
-HYBRID_QUANTIZABLE = QUANTIZABLE + ("wg",)
-# a hybrid_override_pattern stack (models/llama.py pattern_weight_shapes):
-# the Mamba-2 projections, the attention projections, and in an expert block
-# the latent projections, the held experts' stacks and the shared expert.
-# The router and its selection bias, convolution taps and bias, A_log, D,
-# dt_bias and every gain stay as they are.
-PATTERN_QUANTIZABLE = ("in_proj", "out_proj", "wq", "wk", "wv", "wo",
-                       "w_in", "w_out", "w1", "w2", "s1", "s2",
-                       # a two-mixer block's MLP, in the same stack as its mixers;
-                       # a sliding_window_layout stack's {"attn", "experts"}
-                       # (models/llama.py window_stack_weight_shapes) goes by
-                       # this list too: the expert stacks carry the MLP's names
-                       "w_gate", "w_up", "w_down")
-# a "conv" stack (models/llama.py conv_stack_weight_shapes): the short
-# convolution's two projections, the attention projections, the dense MLP and
-# the expert stacks (which carry the MLP's names). Routers, selection biases,
-# taps and gains stay as they are.
-CONV_STACK_QUANTIZABLE = ("in_proj", "out_proj") + QUANTIZABLE
+# Names of the per-layer matmul weights that quantization applies to, in
+# whichever stack of ``layers`` they lie: every row of models/llama.py
+# matmul_shapes but the routers. Norms, embeddings, and the lm_head stay
+# high-precision — standard practice, and the embed is a gather anyway. So do
+# the small leaves of the mixers and the expert blocks: routers and their
+# selection biases, convolution taps and biases, the delta rule's wa, wb
+# [d, heads], A_log, D, dt_bias and every gain are noise-level bytes that set
+# a state's decay, a write's strength or a token's experts. No such leaf
+# shares a name with one below (tests/test_layer_plan.py holds that).
+QUANTIZABLE = (
+    "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",   # attention; an MLP or GLU experts
+    "wg",                                                 # the delta-rule mixer's output gate
+    "in_proj", "out_proj",                                # Mamba-2; the short convolution
+    "w_in", "w_out", "w1", "w2", "s1", "s2",              # latent experts beside a shared one
+)
 
 
 def quantize_params(params: dict[str, Any], bits: int = 8) -> dict[str, Any]:
-    """Quantize every transformer matmul weight in a Llama param tree (or,
-    for a hybrid stack, in each kind's sub-tree of ``layers``)."""
-    def stack(layers: dict[str, Any], names: tuple[str, ...]) -> dict[str, Any]:
-        return {k: (quantize_weight(v, bits=bits) if k in names else v)
+    """Quantize every transformer matmul weight of a param tree, whether
+    ``layers`` is one flat stack or holds a stack a kind of layer."""
+    def stack(layers: dict[str, Any]) -> dict[str, Any]:
+        return {k: (quantize_weight(v, bits=bits) if k in QUANTIZABLE
+                    else stack(v) if isinstance(v, dict) else v)
                 for k, v in layers.items()}
 
-    out = dict(params)
-    layers = params["layers"]
-    if "linear" in layers and "full" in layers:
-        out["layers"] = {kind: stack(sub, HYBRID_QUANTIZABLE) for kind, sub in layers.items()}
-    elif "conv" in layers:
-        out["layers"] = {kind: stack(sub, CONV_STACK_QUANTIZABLE)
-                         for kind, sub in layers.items()}
-    elif set(layers) <= {"mamba", "attn", "experts", "parallel"}:
-        out["layers"] = {kind: stack(sub, PATTERN_QUANTIZABLE) for kind, sub in layers.items()}
-    else:
-        out["layers"] = stack(layers, QUANTIZABLE)
-    return out
+    return {**params, "layers": stack(params["layers"])}
 
 
 def quantized_bytes(params: dict[str, Any]) -> int:

@@ -142,15 +142,15 @@ def estimate_serving_bytes(
     weights = int(cfg.param_count * _weight_bytes_per_param(quant))
     kv_elem = kv_elem_bytes(cfg.head_dim, cfg.jnp_dtype.itemsize, kv_quant)
     # a hybrid stack keeps K/V for its full-attention layers only, and a
-    # float32 recurrent state and a convolution tail per slot for the rest
+    # float32 recurrent state and a convolution tail per slot for its
+    # delta-rule layers (the plan has none of them in any other stack)
     kv = int(2 * cfg.n_full_layers * slots * cfg.n_kv_heads * max_seq
              * cfg.head_dim * kv_elem)
-    if cfg.is_hybrid:
-        h = cfg.linear_num_key_heads
-        kv += int(cfg.n_linear_layers * slots * (
-            4 * h * cfg.linear_key_head_dim * cfg.linear_value_head_dim
-            + cfg.jnp_dtype.itemsize * (cfg.linear_conv_kernel_dim - 1)
-            * cfg.linear_conv_channels))
+    h = cfg.linear_num_key_heads
+    kv += int(cfg.n_linear_layers * slots * (
+        4 * h * cfg.linear_key_head_dim * cfg.linear_value_head_dim
+        + cfg.jnp_dtype.itemsize * (cfg.linear_conv_kernel_dim - 1)
+        * cfg.linear_conv_channels))
     # widest live activation set tracks the widest compiled call: the
     # full prefill bucket monolithically, one chunk bucket when chunked
     prefill_len = (

@@ -682,12 +682,13 @@ class Engine:
         for name, leaf in self._cache.items():
             self._cache_bytes[kinds.get(name, "kv")] += int(leaf.nbytes)
         # positions a prefill piece's scan solves at once, and what the span
-        # of a piece calls its count: the delta rule's or the Mamba-2 mixer's
-        # (a short convolution scans nothing: 0)
+        # of a piece calls its count: the Mamba-2 mixer's or the delta rule's,
+        # whichever the plan's layers run (a short convolution and attention
+        # scan nothing: 0)
         self._scan_chunk, self._scan_arg = (
-            (cfg.chunk_size, "ssm_chunks") if cfg.is_pattern_stack
-            else (0, "") if cfg.is_conv_stack or cfg.is_window_stack
-            else (LINEAR_CHUNK, "linear_chunks"))
+            (cfg.chunk_size, "ssm_chunks") if cfg.n_ssm_layers
+            else (LINEAR_CHUNK, "linear_chunks") if cfg.n_linear_layers
+            else (0, ""))
         # which rows of a decode sweep may move a hybrid's recurrent state:
         # the device mask of the last dispatch, keyed by its active set
         self._live_dev: Optional[tuple[tuple[int, ...], jnp.ndarray]] = None

@@ -17,7 +17,6 @@ from bench import config as bench_config
 from kserve_vllm_mini_tpu.models import moe
 from kserve_vllm_mini_tpu.models.config import ModelConfig, get_config
 from kserve_vllm_mini_tpu.models.llama import (
-    conv_stack_counts,
     init_kv_cache,
     init_paged_kv_cache,
     init_params,
@@ -317,7 +316,10 @@ def test_config_counts_the_published_model():
     assert (full.head_dim, full.rotary_dim, full.experts_held) == (64, 64, 32)
     assert full.is_hybrid and full.is_conv_stack and not full.is_pattern_stack
     assert full.n_linear_layers == 0 and full.n_ssm_layers == 0 and full.layer_period == 1
-    assert conv_stack_counts(full) == {"conv": 18, "attn": 6, "mlp": 2, "experts": 22}
+    # the four weight stacks, as the plan's layers name them
+    stacks = [s for e in full.layer_plan for s in (e.stack, e.ffn_stack)]
+    assert {s: stacks.count(s) for s in set(stacks)} == {
+        "conv": 18, "attn": 6, "mlp": 2, "experts": 22}
     m = bench_config.load_cell(CELL)["config"]["model"]
     cfg = system.model_config(m)
     assert hash(cfg) is not None

@@ -259,8 +259,12 @@ def test_int8_tree_quantizes_the_mixers_matmuls_and_nothing_small():
 # decode_hlo / prefill_hlo digests were re-taken at PR 31's tree, on purpose:
 # qkv_proj gained one optimization_barrier before the head split (the q, k
 # and v projections read their weights in place), so both programs differ
-# from the parent's by that one operation.
-PARENT = json.loads('''{"llama-tiny": {"params": "f326e46ecc0d9315", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a", "params_int8": "f25451afb7173884", "decode_hlo": "796b38f2403139d8", "prefill_hlo": "bbb66e9249c3eadc"}, "mistral-tiny": {"params": "f326e46ecc0d9315", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a", "params_int8": "f25451afb7173884", "decode_hlo": "f88c95e63b1b9932", "prefill_hlo": "bbb66e9249c3eadc"}, "qwen-tiny": {"params": "27fbb1315783b56a", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a", "params_int8": "290c0c9654e97c79", "decode_hlo": "40c6717d18125800", "prefill_hlo": "1f00dba2b2c4eab2"}, "phi-tiny": {"params": "841795a40581b66b", "cache": "2816b46ab1964ad1", "cache_int8": "256289a52c3fd8d4"}, "gemma-tiny": {"params": "d74fc28b45ddc2c0", "cache": "d8564aaa7d973d13", "cache_int8": "7227f7177bbf0635"}, "mixtral-tiny": {"params": "8ea6f1cdcca11876", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a"}}''')
+# from the parent's by that one operation. The five hybrid tiny presets'
+# tree digests were taken at d695df8, the parent of the PR that folded the
+# stacked kinds' three initialisers into one (``_init_stacks``) and read the
+# cache's leaves off ``cfg.layer_plan``: no drawn value and no leaf moved.
+# They carry no program digest (a ring's write may move past its attention).
+PARENT = json.loads('''{"llama-tiny": {"params": "f326e46ecc0d9315", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a", "params_int8": "f25451afb7173884", "decode_hlo": "796b38f2403139d8", "prefill_hlo": "bbb66e9249c3eadc"}, "mistral-tiny": {"params": "f326e46ecc0d9315", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a", "params_int8": "f25451afb7173884", "decode_hlo": "f88c95e63b1b9932", "prefill_hlo": "bbb66e9249c3eadc"}, "qwen-tiny": {"params": "27fbb1315783b56a", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a", "params_int8": "290c0c9654e97c79", "decode_hlo": "40c6717d18125800", "prefill_hlo": "1f00dba2b2c4eab2"}, "phi-tiny": {"params": "841795a40581b66b", "cache": "2816b46ab1964ad1", "cache_int8": "256289a52c3fd8d4"}, "gemma-tiny": {"params": "d74fc28b45ddc2c0", "cache": "d8564aaa7d973d13", "cache_int8": "7227f7177bbf0635"}, "mixtral-tiny": {"params": "8ea6f1cdcca11876", "cache": "61efc8faa242a0a3", "cache_int8": "1ad0de8f2066f61a"}, "falcon-h1-tiny": {"params": "b627e6c501e13b27", "cache": "fc3d845b2f1785f9", "cache_int8": "bc14ccb5c35f0127", "params_int8": "baae9f2688022100"}, "lfm2-moe-tiny": {"params": "d4ab9d0209f1b303", "cache": "bec8c1b1524199cc", "cache_int8": "11bdfbc73a33acbd", "params_int8": "b460f4ba8c820b35"}, "nemotron-h-tiny": {"params": "95653b2cd3f336e2", "cache": "095dc6b229243597", "cache_int8": "8e915373f9f96469", "params_int8": "fd09f544bc4d20f1"}, "olmo-hybrid-tiny": {"params": "808e4496c3ba42c2", "cache": "955bf329b4167fe9", "cache_int8": "e61abfaa98317899", "params_int8": "5564db4c187e706f"}, "smallthinker-tiny": {"params": "b6036cdb62a12d2c", "cache": "a9ccd274133e0de4", "params_int8": "1d9ba9b09dd73b77"}}''')
 
 
 def _digest(tree):
@@ -280,10 +284,13 @@ def test_homogeneous_trees_and_programs_are_byte_for_byte_the_parents(name):
     params = init_params(key, cfg)
     assert _digest(params) == want["params"]
     assert _digest(init_kv_cache(cfg, 3, max_seq=64)) == want["cache"]
-    assert _digest(init_kv_cache(cfg, 3, max_seq=64, quantized=True)) == want["cache_int8"]
+    if "cache_int8" in want:         # a ring has no int8 leaves
+        assert _digest(init_kv_cache(cfg, 3, max_seq=64, quantized=True)) == want["cache_int8"]
     if "params_int8" not in want:
         return
     assert _digest(init_params_quantized(key, cfg)) == want["params_int8"]
+    if "decode_hlo" not in want:
+        return
     cache = init_kv_cache(cfg, 3, max_seq=64)
     toks, lens = jnp.zeros((3, 1), jnp.int32), jnp.asarray([5, 9, 0], jnp.int32)
     dec = jax.jit(lambda p, c, t, l: forward(p, cfg, t, l[:, None], c, l)).lower(  # noqa: E741

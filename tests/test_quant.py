@@ -304,7 +304,7 @@ def test_awq_stats_cover_all_targets():
     cfg, params = _outlier_model()
     cal = calibration_tokens(cfg.vocab_size, None, n_tokens=64, seed=1)
     stats = collect_activation_stats(params, cfg, cal)
-    assert set(stats) == set(QUANTIZABLE)
+    assert set(stats) == set(QUANTIZABLE) & set(params["layers"])
     for name, a in stats.items():
         assert a.shape[0] == cfg.n_layers
         assert a.ndim == 2 and (a >= 0).all(), name
